@@ -7,8 +7,9 @@ shards for the shard-local optimizer update. ``analysis.hlo`` already
 checks the gather/reduce pair *exists*; this module derives the full
 expected schedule — which phases, in what order, moving how many bytes —
 from the :class:`parallel.partition.Partitioner` rules + the actual
-parameter tree, and diffs it against the collective sequence GSPMD
-really emitted into the compiled HLO.
+parameter tree, and diffs it against the collective sequence the SPMD
+partitioner (Shardy in the installed jax; the thresholds below were
+first measured under GSPMD) really emitted into the compiled HLO.
 
 What the diff catches, each with a prior in this repo's history:
 
@@ -16,8 +17,8 @@ What the diff catches, each with a prior in this repo's history:
   rename, regex typo) and the param gather silently disappears: params
   replicate again and the per-chip HBM win evaporates with no error.
   Detected by *volume collapse*, not mere absence: even a fully
-  replicated program carries a few incidental small all-gathers (GSPMD
-  boundary handling on the batch-sharded spatial ops — measured on the
+  replicated program carries a few incidental small all-gathers
+  (boundary handling on the batch-sharded spatial ops — measured on the
   flagship), so the check is "actual gather volume fell below half the
   sharded-parameter mass". Symmetrically, a vanished grad reduce means
   shards silently diverge.
@@ -31,9 +32,11 @@ Two drift classes deliberately live in the *pinned budget*
 (``analysis.cost.Budget``), not here: byte growth within the contract,
 and resharding-op growth (``all-to-all``/``collective-permute``). The
 healthy flagship programs legitimately contain a handful of permutes
-(GSPMD halo/boundary movement on batch-sharded spatial ops), so "any
-permute is a bug" would be red on day one; "more permutes than the
-pinned count" is the actionable signal.
+(halo/boundary movement on batch-sharded spatial ops) and, under
+Shardy, three all-to-alls: the encoder concatenates the image pair
+along the batch axis, which is the sharded one (GSPMD moved the same
+bytes as a pad + all-reduce). So "any reshard is a bug" would be red on
+day one; "more than the pinned count" is the actionable signal.
 """
 
 import re
@@ -103,14 +106,51 @@ class CollectiveOp:
         return {"op": self.op, "index": self.index, "bytes": self.bytes}
 
 
-def parse_schedule(text):
-    """Collective ops of a compiled (post-GSPMD) HLO module, in schedule
-    order, each with its result-buffer byte volume.
+def _tuple_elements(result):
+    """Top-level elements of an HLO result type: ``(a, (b, c))`` gives
+    ``["a", "(b, c)"]``, a bare shape gives itself. Commas inside
+    dimension lists and layouts do not split."""
+    result = result.strip()
+    if not result.startswith("("):
+        return [result]
+    elements, depth, start = [], 0, 1
+    for i, ch in enumerate(result):
+        if ch in "([{":
+            depth += 1
+        elif ch in ")]}":
+            depth -= 1
+            if depth == 0:
+                elements.append(result[start:i])
+                break
+        elif ch == "," and depth == 1:
+            elements.append(result[start:i])
+            start = i + 1
+    return [e.strip() for e in elements if e.strip()]
 
-    The result type precedes the op name on an HLO instruction line; for
-    async ``-start`` tuples the *last* shaped buffer is the op's output
-    (the leading elements alias the operands), and ``-done`` lines are
-    skipped — they unwrap a start op already counted.
+
+def _result_bytes(op, suffix, result):
+    """Output volume of one collective from its result type.
+
+    The combiner passes merge neighbouring collectives into one variadic
+    op whose result is the tuple of every output — the flagship's 174
+    gradient all-reduces arrive as a handful of tuple-valued ones — so
+    every element counts. The async ``all-gather-start`` and
+    ``collective-permute-start`` differ: their tuple is (operands,
+    outputs, context...), of which only the second element is output.
+    """
+    if suffix == "-start" and op in ("all-gather", "collective-permute"):
+        elements = _tuple_elements(result)
+        result = elements[1] if len(elements) > 1 else elements[0]
+    return sum(_shape_bytes(*shape) for shape in _SHAPE_RE.findall(result))
+
+
+def parse_schedule(text):
+    """Collective ops of a compiled (post-partitioner) HLO module, in
+    schedule order, each with its output byte volume.
+
+    The result type precedes the op name on an HLO instruction line
+    (:func:`_result_bytes` reads it); ``-done`` lines are skipped — they
+    unwrap a start op already counted.
     """
     ops = []
     for line in text.splitlines():
@@ -120,11 +160,9 @@ def parse_schedule(text):
         m = _COLL_OP_RE.search(rhs)
         if not m or m.group(2) == "-done":
             continue
-        result = rhs[:m.start()]
-        shapes = _SHAPE_RE.findall(result)
-        nbytes = _shape_bytes(*shapes[-1]) if shapes else 0
-        ops.append(CollectiveOp(op=m.group(1), index=len(ops),
-                                bytes=nbytes))
+        ops.append(CollectiveOp(
+            op=m.group(1), index=len(ops),
+            bytes=_result_bytes(m.group(1), m.group(2), rhs[:m.start()])))
     return ops
 
 
@@ -198,7 +236,8 @@ def expected_schedule(kind, n_devices, partitioner=None, params=None):
 
 
 def diff(expectation, summary, key=""):
-    """Structural findings: the contract's phases vs what GSPMD emitted.
+    """Structural findings: the contract's phases vs what the
+    partitioner emitted.
 
     Operates on a :func:`summarize_schedule` dict (not the raw op list)
     so reports pinned in ``hlo-budget.json`` — which store exactly that

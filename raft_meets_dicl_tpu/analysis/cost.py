@@ -451,7 +451,8 @@ class Budget:
                             f"new TPU hazard class grew into this "
                             f"program"))
         # resharding ops are grandfathered per pinned count (the healthy
-        # flagship legitimately carries a few GSPMD boundary permutes);
+        # flagship legitimately carries a few boundary permutes and the
+        # image-pair concat's all-to-alls);
         # only growth beyond the pin flags
         from .collectives import RESHARD_OPS
         pinned_c = entry.get("collectives", {})
@@ -462,7 +463,8 @@ class Budget:
                     rule="collective-reshard", path="analysis/cost",
                     line=1,
                     message=f"{key}: {actual_c.get(op, 0)} {op} op(s) vs "
-                            f"{pinned_c.get(op, 0)} pinned — GSPMD is "
+                            f"{pinned_c.get(op, 0)} pinned — the "
+                            f"partitioner is "
                             f"resharding an activation the contract "
                             f"never asks to move; a sharding constraint "
                             f"disagrees with its neighbours"))
@@ -491,7 +493,7 @@ class Budget:
                 "Pinned per-program static cost budgets "
                 "(scripts/graftcost.py). flops/bytes are the "
                 "deterministic StableHLO-walker totals, "
-                "collective_bytes the compiled post-GSPMD schedule "
+                "collective_bytes the compiled post-partitioner schedule "
                 "volume. Re-pin deliberately with --update; stale "
                 "entries are reported so this file tracks the program "
                 "registry."),

@@ -10,7 +10,7 @@ lowers the jitted function and audits the result:
   container, a closure capturing fresh objects) makes every boot a
   persistent-cache miss and every AOT artifact unreachable — precisely
   the cold-start tax PR-7 exists to kill.
-- **collective counts** — taken from the *compiled* (post-GSPMD) HLO,
+- **collective counts** — taken from the *compiled* (post-partitioner) HLO,
   where sharding constraints have become all-gather/all-reduce/
   reduce-scatter ops. This guards the PR-6 ZeRO contract: a sharded
   train step must contain its gather/reduce pair, and any multi-device
@@ -116,7 +116,7 @@ def audit_stablehlo(text):
 
 
 def audit_compiled(text):
-    """Collective counts over compiled (post-GSPMD) HLO text."""
+    """Collective counts over compiled (post-partitioner) HLO text."""
     counts = {}
     for line in text.splitlines():
         if " = " not in line:
@@ -132,7 +132,7 @@ def audit_program(program, args, expect_bf16=False, n_devices=1,
 
     Returns ``(report, findings)``. The program is lowered twice for the
     fingerprint-stability check; when ``do_compile``, the second lowering
-    is compiled (persistent-cache eligible) and its post-GSPMD HLO
+    is compiled (persistent-cache eligible) and its post-partitioner HLO
     provides the collective counts.
 
     ``cost_context`` (``partitioner``/``params``) is accepted and unused:

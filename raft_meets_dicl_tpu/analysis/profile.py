@@ -28,7 +28,7 @@ Two attribution modes, because module names are not unique:
   unambiguous.
 
 The roofline prediction is deliberately crude (peak FLOP/s and
-bandwidth per platform, no overlap model): the *ratio* is the
+bandwidth per device kind, no overlap model): the *ratio* is the
 calibrated quantity, pinned per machine with wide multiplicative
 tolerances, so machine constants and model error cancel out of the
 gate. What the gate catches is the ratio *moving* — a kernel change
@@ -64,13 +64,17 @@ DEFAULT_TOLERANCE = {"ratio": 1.5, "class_ratio": 3.0}
 # predicted step (tiny classes have noise-dominated ratios)
 MIN_CLASS_SHARE = 0.05
 
-# (peak FLOP/s, peak memory bytes/s) per jax platform; the TPU numbers
-# are PERF.md's v4 measurements (197 TFLOP/s bf16 MXU peak), the rest
-# are order-of-magnitude placeholders — the pinned calibration ratio
-# absorbs the constant, see module docstring
+# (peak FLOP/s, peak memory bytes/s) keyed by ``device_kind`` as jax
+# reports it. "TPU v5 lite" is one v5e chip: 197 TFLOP/s bf16, 819 GB/s
+# HBM (Google Cloud documentation, "TPU v5e"). An accelerator that is
+# not listed is an error, not a default.
+#
+# The "cpu" row is NOT a device peak: XLA:CPU is nobody's deployment
+# target. It is the order-of-magnitude constant under the ``cpu:cpu``
+# calibration pins in prof-budget.json that tier-1 gates on (the pinned
+# ratio absorbs the constant, see module docstring).
 _PEAKS = {
-    "tpu": (197e12, 1.2e12),
-    "gpu": (1.0e14, 1.0e12),
+    "TPU v5 lite": (197e12, 819e9),
     "cpu": (1.0e11, 2.0e10),
 }
 
@@ -258,7 +262,12 @@ def machine_spec():
     platform = dev.platform
     kind = getattr(dev, "device_kind", platform) or platform
     machine_id = f"{platform}:{kind}".lower().replace(" ", "-")
-    peak_flops, peak_bw = _PEAKS.get(platform, _PEAKS["cpu"])
+    if kind not in _PEAKS:
+        raise ValueError(
+            f"no peak FLOP/s / bytes/s on record for device_kind "
+            f"'{kind}' (platform '{platform}'); add a row with its "
+            f"source to analysis.profile._PEAKS")
+    peak_flops, peak_bw = _PEAKS[kind]
     return {"machine_id": machine_id, "platform": platform,
             "device_kind": str(kind), "n_devices": jax.device_count(),
             "peak_flops": peak_flops, "peak_bytes_per_s": peak_bw}

@@ -64,20 +64,23 @@ def serve(args):
             telemetry.create(Path(args.telemetry), nonblocking=True))
         if tele.path:
             logging.info(f"writing telemetry events to '{tele.path}'")
+    import jax
+
+    from .train import describe_devices, select_devices
+
+    devices = select_devices(args.device, args.device_ids)
+    jax.config.update("jax_default_device", devices[0])
+    where = describe_devices(devices)
     tele.emit(
         "boot",
         compile_cache=compcache.effective_dir(),
         aot_dir=str(programs.programs_dir()) if programs.aot_enabled()
         else None,
         aot=programs.aot_enabled(),
+        **where,
     )
-
-    import jax
-
-    from .train import select_devices
-
-    devices = select_devices(args.device, args.device_ids)
-    jax.config.update("jax_default_device", devices[0])
+    logging.info("serving on {device_kind} [{platform}], default backend "
+                 "'{backend}'".format(**where))
 
     cfg = {}
     if getattr(args, "config", None):
@@ -245,6 +248,13 @@ def serve(args):
     if getattr(args, "telemetry", None):
         telemetry.deactivate()
 
+    # sheds and malformed requests are the server doing its job; a
+    # failed device dispatch is the server failing at it
+    internal = report["errors"].get("internal", 0)
+    if internal:
+        sys.exit(f"serve: {internal} of {report['requests']} requests "
+                 f"ended with an internal error (failed device dispatch)")
+
 
 def _serve_replica_blocking(args, session, scheduler, tele):
     """Replica mode: bind the fleet API, write the port-file rendezvous,
@@ -310,6 +320,23 @@ def _child_argv(extra):
     return head + argv + extra
 
 
+def _check_fleet_platform(n, device):
+    """Replicas are processes and each claims its platform's default
+    devices with no device visibility set — on an accelerator that is
+    every chip of the host, and a chip belongs to one process. More
+    than one replica therefore only works on the CPU platform; say so
+    at once (the parent cannot look: it must not initialise jax itself)
+    instead of waiting out the boot deadline on a child that hangs.
+    One process per host driving a replica per device is ROADMAP D6."""
+    platform = device or os.environ.get("JAX_PLATFORMS", "")
+    if n > 1 and platform.split(",")[0].strip() != "cpu":
+        raise ValueError(
+            f"--fleet {n}: replica processes would each claim the "
+            f"accelerator, which belongs to one process at a time. Run "
+            f"--fleet 1, or name the CPU platform (--device cpu or "
+            f"JAX_PLATFORMS=cpu)")
+
+
 def _serve_fleet(args):
     """Fleet mode: supervise N replica processes behind the router,
     then drive them (open-loop load or the kill/rejoin drill)."""
@@ -346,6 +373,7 @@ def _serve_fleet(args):
 
     n = int(args.fleet) if int(args.fleet) > 0 \
         else env.get_int("RMD_FLEET_REPLICAS")
+    _check_fleet_platform(n, args.device)
     logging.info(f"fleet: {n} replicas, buckets {buckets.describe()}"
                  + (f", wire {wire.describe()}" if wire else ""))
 

@@ -104,8 +104,7 @@ class Environment:
         # fill in what they left at the default). Runs before any
         # backend use, like every other env flag here.
         cache = self.compile.get("cache")
-        if (cache and not utils.env.raw("RMD_COMPILE_CACHE")
-                and not utils.env.raw("RMD_COMPILE_CACHE_DIR")):
+        if cache and not utils.env.raw("RMD_COMPILE_CACHE"):
             from ..utils.compcache import enable_persistent_cache
 
             enable_persistent_cache(str(cache))
@@ -141,24 +140,22 @@ def select_devices(device=None, device_ids=None):
     import jax
 
     if device:
-        # make the requested platform the jax default too — site
-        # configuration may pin a different platform, and only a
-        # pre-backend-init config update lets e.g. `--device cpu` on an
-        # accelerator host pick up XLA_FLAGS like
-        # --xla_force_host_platform_device_count
+        # make the requested platform the jax default. An explicit
+        # platform list turns a failed initialisation into an error
+        # (left to itself jax skips a backend that does not come up and
+        # hands out CPU devices). 'cpu' stays in the list because the
+        # host side of the input pipeline computes there (data.synth
+        # renders its samples on the host CPU, off the accelerator the
+        # train step owns).
+        platforms = device if device == "cpu" else f"{device},cpu"
         try:
-            jax.config.update("jax_platforms", device)
+            jax.config.update("jax_platforms", platforms)
         except RuntimeError:
             pass  # backend already initialized; fall through to filtering
 
-    if device:
         try:
             devices = jax.devices(device)
         except RuntimeError as e:
-            # surface an unknown/unavailable platform as a config-level
-            # message: the config update above is a global side effect,
-            # and backend init otherwise fails later with a confusing
-            # error
             raise ValueError(
                 f"--device '{device}': no such jax platform available "
                 f"({e})"
@@ -171,6 +168,23 @@ def select_devices(device=None, device_ids=None):
         devices = [devices[i] for i in ids]
 
     return devices
+
+
+def describe_devices(devices):
+    """What a run's telemetry says about where it ran: the platform and
+    kind of the selected devices, how many of the platform's devices
+    jax sees and how many the run uses, and the default backend (which
+    decides whether the Pallas kernels or their XLA references are
+    traced)."""
+    import jax
+
+    return {
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "device_count": len(jax.devices(devices[0].platform)),
+        "devices_used": len(devices),
+        "backend": jax.default_backend(),
+    }
 
 
 def load_config_parts(args):
@@ -235,6 +249,11 @@ def _train(args):
             process_id=args.dist_process_id,
         )
         primary = parallel.is_primary()
+
+    # devices: the first backend use of the run. --device must name the
+    # platform before anything else (the seeds' first PRNG key, the
+    # model's init) brings a backend up, or it cannot be honoured
+    devices = select_devices(args.device, args.device_ids)
 
     suffix = ""
     if args.suffix:
@@ -365,7 +384,6 @@ def _train(args):
     # param/optimizer storage per parallel.partition's rules.
     import jax
 
-    devices = select_devices(args.device, args.device_ids)
     mesh_cfg = (getattr(args, "mesh", None)
                 or utils.env.raw("RMD_MESH")
                 or env.parallel.get("mesh"))
@@ -382,15 +400,13 @@ def _train(args):
         # jitted step would fall back to the default backend's device 0
         mesh = None
         jax.config.update("jax_default_device", devices[0])
+    where = ("{devices_used}× {device_kind} [{platform}], default backend "
+             "'{backend}'".format(**describe_devices(devices)))
     if mesh is not None:
         shape = ", ".join(f"{n}={mesh.shape[n]}" for n in mesh.axis_names)
-        logging.info(
-            f"devices: {len(devices)}× {devices[0].platform} "
-            f"(SPMD mesh: {shape})")
+        logging.info(f"devices: {where} (SPMD mesh: {shape})")
     else:
-        logging.info(
-            f"devices: {len(devices)}× {devices[0].platform} "
-            "(single device)")
+        logging.info(f"devices: {where} (single device)")
 
     # in-step gradient accumulation: --accumulate > RMD_ACCUMULATE > env
     # 'parallel' section; k microbatches per optimizer step inside the
@@ -521,7 +537,7 @@ def _train(args):
 
     tele.emit("run_start", dir=str(path_out),
               commit=utils.vcs.get_git_head_hash(),
-              comment=args.comment or "")
+              comment=args.comment or "", **describe_devices(devices))
 
     # trainer observability sidecar: --metrics-port > RMD_TRAIN_METRICS_PORT;
     # serves /metrics, /healthz, /statusz, /profilez off the shared

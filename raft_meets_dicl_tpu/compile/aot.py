@@ -28,7 +28,7 @@ from ..utils import env
 _MAGIC = "RMDP1"
 # bump to invalidate every existing artifact when the program contract
 # changes (arg order, aux layout, ...)
-_LAYOUT_VERSION = 1
+_LAYOUT_VERSION = 2
 
 _state = {"on": False, "dir": None}
 
@@ -204,6 +204,11 @@ def save(path, key, sig, compiled):
         "sig": repr(sig),
         "crc": zlib.crc32(payload),
         "payload": payload,
+        # the executable's device assignment, in order: loading needs it
+        # (the default is every device of the backend, which is wrong
+        # for a one-device program on a multi-device host)
+        "devices": [d.id for d in
+                    compiled.runtime_executable().local_devices()],
         "in_tree": in_tree,
         "out_tree": out_tree,
     }
@@ -254,10 +259,13 @@ def load(path, key, sig):
         if zlib.crc32(payload) != record.get("crc"):
             return None, "corrupt", "crc mismatch"
 
+        import jax
         from jax.experimental import serialize_executable
 
+        by_id = {d.id: d for d in jax.devices()}
         compiled = serialize_executable.deserialize_and_load(
-            payload, record["in_tree"], record["out_tree"])
+            payload, record["in_tree"], record["out_tree"],
+            execution_devices=[by_id[i] for i in record["devices"]])
         return compiled, "hit", {
             "bytes": len(data),
             "seconds": time.perf_counter() - t0,

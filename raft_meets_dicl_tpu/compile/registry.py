@@ -80,6 +80,16 @@ class ProgramKey:
         return f"{self.kind}[{self.model}]"
 
 
+def mosaic_calls(compiled):
+    """Mosaic (Pallas TPU) custom calls in a compiled executable's HLO.
+
+    The Pallas kernels give way to their XLA references at trace time
+    (off-TPU, or when a shape does not fit VMEM) without a word; this
+    count, carried by the program's ``aot`` events, is how a run shows
+    from the executable itself which form it got."""
+    return compiled.as_text().count('custom_call_target="tpu_custom_call"')
+
+
 def shape_signature(args):
     """Concrete (shape, dtype) tuple over every array leaf of ``args`` —
     the per-call index into a Program's compiled-executable family."""
@@ -101,10 +111,12 @@ class Program:
     Calls route through a per-shape-signature compiled executable when
     the AOT store is enabled — loaded from disk when an artifact exists
     (zero compiles), otherwise compiled ahead of time once and saved for
-    the next boot. Any mismatch (corrupt artifact, stale version,
-    incompatible input placement) falls back to the plain JIT path for
-    that signature, permanently and silently for the caller; the
-    telemetry trail records why.
+    the next boot. An unusable artifact (corrupt, stale version, not
+    reloadable on this backend) or an executable that rejects the
+    caller's argument types/placement puts that signature on the plain
+    JIT path for the rest of the process; every such decision emits an
+    ``aot`` ``fallback`` event, on every boot. A failed compile or a
+    failed execution is not a fallback: it raises.
 
     ``compiles``/``compile_seconds`` count actual backend compiles
     attributed to this program via the jax.monitoring listener — they
@@ -153,10 +165,13 @@ class Program:
             if entry is not _FALLBACK:
                 try:
                     return entry(*args)
-                except Exception as e:  # noqa: BLE001 - input mismatch
-                    # argument checks run before execution, so the args
-                    # (donated included) are intact; pin this signature
-                    # to the JIT path and carry on
+                except (TypeError, ValueError) as e:
+                    # the executable's argument checks (pytree, avals,
+                    # shardings, layouts) — they run before execution,
+                    # so the args (donated included) are intact; pin
+                    # this signature to the JIT path and carry on. A
+                    # runtime failure (out of memory, a device fault) is
+                    # a JaxRuntimeError and propagates.
                     self._compiled[sig] = _FALLBACK
                     self.aot_fallbacks += 1
                     self._emit("fallback",
@@ -176,13 +191,17 @@ class Program:
             path = aot.artifact_path(self.key, sig)
             if aot.tombstoned(path):
                 # a previous boot proved this executable doesn't survive
-                # serialization on this backend: plain JIT, no churn
+                # serialization on this backend: plain JIT, no save/fail
+                # churn — but said on every boot, not only the first
+                self.aot_fallbacks += 1
+                self._emit("fallback", reason="tombstoned: not reloadable "
+                                              "on this backend")
                 self._compiled[sig] = _FALLBACK
                 return _FALLBACK
             compiled, status, info = aot.load(path, self.key, sig)
             if compiled is not None:
                 self.aot_hits += 1
-                self._emit("hit", bytes=info["bytes"],
+                self._emit("hit", compiled, bytes=info["bytes"],
                            seconds=round(info["seconds"], 4))
                 self._compiled[sig] = compiled
                 return compiled
@@ -200,10 +219,10 @@ class Program:
                     # the artifact deserialized on save but not on load:
                     # this executable doesn't round-trip on this backend
                     # (e.g. XLA-CPU fusion symbol collisions). Tombstone
-                    # it so later boots take the JIT path silently
-                    # instead of re-saving and re-failing forever; the
-                    # marker is fingerprint-scoped, so a jax/backend
-                    # upgrade retries.
+                    # it so later boots take the JIT path instead of
+                    # re-saving and re-failing forever; the marker is
+                    # fingerprint-scoped, so a jax/backend upgrade
+                    # retries.
                     try:
                         os.remove(path)
                     except OSError:
@@ -215,33 +234,30 @@ class Program:
                 self._compiled[sig] = _FALLBACK
                 return _FALLBACK
 
+            # a compile that fails here (a kernel the compiler refuses,
+            # a program that does not fit) fails the same way through
+            # plain jit: it raises
             c0 = self.compiles
-            try:
-                with telemetry.jit_label(self.label, self):
-                    compiled = lower(*args).compile()
-            except Exception as e:  # noqa: BLE001 - fall back to plain jit
-                self.aot_fallbacks += 1
-                self._emit("fallback",
-                           reason=f"compile: {type(e).__name__}: "
-                                  f"{str(e)[:160]}")
-                self._compiled[sig] = _FALLBACK
-                return _FALLBACK
+            with telemetry.jit_label(self.label, self):
+                compiled = lower(*args).compile()
 
             if self.compiles == c0:
                 # the compile was served from the persistent XLA cache:
-                # no backend compile ran, and (on some backends) such
-                # executables serialize without their object code —
-                # writing them would poison the next boot. This boot is
-                # already warm through the cache; the artifact gets
-                # written by whichever boot pays the real compile.
-                self._emit("skip_save",
+                # no backend compile ran, and such executables serialize
+                # without their object code on XLA:CPU (jax 0.9.0: the
+                # payload is half the size and fails to load with
+                # "Function ... not found") — writing them would poison
+                # the next boot. This boot is already warm through the
+                # cache; the artifact gets written by whichever boot
+                # pays the real compile.
+                self._emit("skip_save", compiled,
                            reason="compile served from persistent cache")
             else:
                 try:
                     nbytes, seconds = aot.save(path, self.key, sig,
                                                compiled)
                     self.aot_saves += 1
-                    self._emit("save", bytes=nbytes,
+                    self._emit("save", compiled, bytes=nbytes,
                                seconds=round(seconds, 4))
                 except Exception as e:  # noqa: BLE001 - save is cosmetic
                     self._emit("fallback",
@@ -251,10 +267,12 @@ class Program:
             self._compiled[sig] = compiled
             return compiled
 
-    def _emit(self, event, **fields):
-        telemetry.get().emit(
-            "aot", event=event, program=self.key.kind,
-            model=self.key.model, **fields)
+    def _emit(self, event, compiled=None, **fields):
+        tele = telemetry.get()
+        if compiled is not None and tele.enabled:
+            fields["mosaic_calls"] = mosaic_calls(compiled)
+        tele.emit("aot", event=event, program=self.key.kind,
+                  model=self.key.model, **fields)
 
     def stats(self):
         return {

@@ -286,11 +286,21 @@ def perturb(key, img, kind, severity):
 
 
 def _host_device():
-    """Render on CPU when the host pipeline drives the generator."""
+    """The host CPU device the ``Synth`` collection renders on.
+
+    The collection is the *host* side of the input pipeline: its loader
+    threads run next to a train step that owns the accelerator, and a
+    sample rendered there would queue behind that step and come back
+    through the host anyway. ``cmd.train.select_devices`` keeps the
+    ``cpu`` platform in jax's platform list for this.
+    """
     try:
         return jax.devices("cpu")[0]
-    except RuntimeError:
-        return None
+    except RuntimeError as e:
+        raise RuntimeError(
+            "data 'type: synth' renders its samples on the host CPU, but "
+            "the jax 'cpu' platform is not available — add it to the "
+            f"platform list (e.g. JAX_PLATFORMS=tpu,cpu): {e}") from e
 
 
 class Synth(Collection):
@@ -354,8 +364,7 @@ class Synth(Collection):
         if not 0 <= index < self.size:
             raise IndexError(index)
 
-        dev = _host_device()
-        with jax.default_device(dev) if dev is not None else _nullcontext():
+        with jax.default_device(_host_device()):
             key = jax.random.fold_in(
                 jax.random.PRNGKey(self.seed), np.uint32(index))
             img1, img2, flow, valid = render_pair(
@@ -387,14 +396,6 @@ class Synth(Collection):
         return (f"synthetic scenes ({self.size} samples, "
                 f"{self.shape[0]}x{self.shape[1]}, "
                 f"{self.layers} layers{pert})")
-
-
-class _nullcontext:
-    def __enter__(self):
-        return None
-
-    def __exit__(self, *exc):
-        return False
 
 
 def perturbation_suite(base, severities=(0.25, 0.5, 0.75)):

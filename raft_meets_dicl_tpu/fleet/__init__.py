@@ -13,7 +13,7 @@ One router process fronting N serve replica processes:
 - :mod:`.wire` — edge encode/decode for the PR-2 wire presets plus the
   meta-header framing both hops speak;
 - :mod:`.client` — stdlib HTTP client with the typed transport failure
-  taxonomy (:class:`~.client.ReplicaDown` is safe to retry,
+  classes (:class:`~.client.ReplicaDown` is safe to retry,
   :class:`~.client.ReplicaTimeout` is not);
 - :mod:`.drill` — the kill/rejoin chaos drill the bench/dryrun
   acceptance gates run.

@@ -5,7 +5,7 @@ request volume is batched device work, not connection churn, and a
 fresh connection is what makes "the replica died mid-request" a clean,
 *typed* failure instead of a wedged keep-alive socket.
 
-Failure taxonomy the router dispatches on:
+Failure classes the router dispatches on:
 
 - :class:`ReplicaDown` — the TCP/HTTP exchange failed before a complete
   response arrived (refused, reset, remote disconnected): the request

@@ -97,7 +97,8 @@ def main():
     train.add_argument("--compile-cache", metavar="DIR",
                        help="persistent XLA compile cache directory "
                             "(also: RMD_COMPILE_CACHE; "
-                            "RMD_NO_COMPILE_CACHE=1 disables) "
+                            "RMD_NO_COMPILE_CACHE=1 configures none; "
+                            "all yield to JAX_COMPILATION_CACHE_DIR) "
                             "[default: <repo>/.jax_cache]. The AOT "
                             "program store lives in DIR/programs "
                             "(RMD_AOT=0 disables, RMD_AOT_DIR relocates)")
@@ -211,7 +212,8 @@ def main():
                             "the sweep (requires explicit --buckets sizes)")
     eval_.add_argument("--compile-cache", metavar="DIR",
                        help="persistent XLA compile cache directory "
-                            "(also: RMD_COMPILE_CACHE) "
+                            "(also: RMD_COMPILE_CACHE; yields to "
+                            "JAX_COMPILATION_CACHE_DIR) "
                             "[default: <repo>/.jax_cache]; AOT program "
                             "store in DIR/programs (RMD_AOT=0 disables)")
     eval_.add_argument("--telemetry", metavar="PATH",
@@ -291,7 +293,8 @@ def main():
                        help="comma-separated device indices")
     serve.add_argument("--compile-cache", metavar="DIR",
                        help="persistent XLA compile cache directory "
-                            "(also: RMD_COMPILE_CACHE) "
+                            "(also: RMD_COMPILE_CACHE; yields to "
+                            "JAX_COMPILATION_CACHE_DIR) "
                             "[default: <repo>/.jax_cache]; AOT program "
                             "store in DIR/programs (RMD_AOT=0 disables)")
     serve.add_argument("--telemetry", metavar="PATH",
@@ -373,8 +376,8 @@ def main():
     args = parser.parse_args()
 
     # persistent compile cache + AOT program store: configured after
-    # parsing (--compile-cache wins over RMD_COMPILE_CACHE over the
-    # default) but before any backend use
+    # parsing (JAX_COMPILATION_CACHE_DIR wins over --compile-cache over
+    # RMD_COMPILE_CACHE over the default) but before any backend use
     import os
 
     from . import compile as programs

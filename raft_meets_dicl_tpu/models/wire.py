@@ -1,8 +1,7 @@
 """Host→device wire format for input batches.
 
-The input pipeline's dominant cost on remote/tunneled backends is the
-host→device transfer of the batch (PERF.md). This module defines how a
-batch crosses that boundary: images ship in a compact dtype (``f32`` raw
+Every training batch crosses the host→device link (tens of MB at the
+Things shape). This module defines how a batch crosses that boundary: images ship in a compact dtype (``f32`` raw
 floats, ``bf16``, or quantized ``u8``), flow optionally in half precision,
 and valid masks optionally bit-packed — and the clip/range normalization
 that ``models.input.Input`` otherwise performs on the host moves inside

@@ -26,10 +26,30 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
-# jax renamed TPUCompilerParams -> CompilerParams around 0.5; support both
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+def _per_shard(fn):
+    """``fn`` mapped over the batch shards of the step being traced.
+
+    Mosaic kernels cannot be partitioned automatically ("wrap the call
+    in a shard_map"): under an SPMD mesh the jitted step fails to lower
+    at the first kernel. All three families are independent per sample
+    (per row, for the combine), and the step builders split the leading
+    dimension of every activation over every mesh axis
+    (``partition.batch_spec``), so each device runs the kernel on the
+    shard it already holds and nothing moves. Off-TPU the XLA references
+    are traced, which the partitioner handles itself.
+    """
+    from ..parallel.mesh import traced_mesh
+
+    mesh = traced_mesh()
+    if (mesh is None or mesh.devices.size == 1
+            or jax.default_backend() != "tpu"):
+        return fn
+    lead = P(tuple(mesh.axis_names))
+    return jax.shard_map(fn, mesh=mesh, in_specs=lead, out_specs=lead,
+                         check_vma=False)
+
 
 _TILE = 512
 _K = 9       # 3x3 neighbors
@@ -120,7 +140,7 @@ def _run_fwd(logits2d, win2d, inv_temp, interpret=False):
         ],
         out_specs=pl.BlockSpec((_TILE, _C * _S), lambda i: (i, 0),
                                memory_space=pltpu.VMEM),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=32 * 1024 * 1024),
         interpret=interpret,
     )(logits2d, win2d)
@@ -156,7 +176,7 @@ def _run_bwd(logits2d, win2d, dout2d, inv_temp, interpret=False):
         ),
         # f32 callers (the ctf family runs un-mixed) land just past the
         # 16M default with double buffering
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=32 * 1024 * 1024),
         interpret=interpret,
     )(logits2d, win2d, dout2d)
@@ -702,7 +722,7 @@ def _wcp_fwd_tpu(f1, f2_levels, coords, radius, interpret=False,
         out_specs=pl.BlockSpec((1, 1, n_jp, n_lvl * k, k),
                                lambda bi, ii: (bi, ii, 0, 0, 0),
                                memory_space=pltpu.VMEM),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
         interpret=interpret,
     )(coords, f1r, *f2p)
@@ -776,7 +796,7 @@ def _wcp_bwd_tpu(f1, f2_levels, coords, dout, radius, interpret=False,
             for f2 in f2p
         ],
         out_specs=row_spec,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
         interpret=interpret,
     )(coords, doutr, *f2p).reshape(b, n_i, n_jp, c)[:, :, :n_j]
@@ -799,7 +819,7 @@ def _wcp_bwd_tpu(f1, f2_levels, coords, dout, radius, interpret=False,
             out_specs=pl.BlockSpec((1,) + f2.shape[1:],
                                    lambda bi, ii: (bi, 0, 0, 0),
                                    memory_space=pltpu.VMEM),
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=100 * 1024 * 1024),
             interpret=interpret,
         )(coords, f1r, dout_l)
@@ -898,7 +918,8 @@ def windowed_corr_pyramid(f1, f2_levels, coords, radius=4, mask_costs=(),
     if normalize:
         f1 = (f1 / jnp.sqrt(jnp.asarray(c, jnp.float32))).astype(f1.dtype)
 
-    out = _wcp(f1, tuple(f2_levels), coords, radius)
+    out = _per_shard(lambda a, b, c: _wcp(a, b, c, radius))(
+        f1, tuple(f2_levels), coords)
 
     if mask_costs:
         keep = jnp.concatenate([
@@ -932,6 +953,15 @@ def windowed_corr_pyramid(f1, f2_levels, coords, radius=4, mask_costs=(),
 # windowed-correlation kernel's contract.
 
 
+def _x_weights(s, fx, dx):
+    """Column ``dx`` of ``_x_select`` as a (1, _XW, 1) sublane vector: the
+    bilinear weights of lanes s+dx and s+dx+1, ready to broadcast over a
+    (rows, _XW, C) slab."""
+    ix = jax.lax.broadcasted_iota(jnp.int32, (1, _XW, 1), 1)
+    return (jnp.where(ix == s + dx, 1.0 - fx, 0.0)
+            + jnp.where(ix == s + dx + 1, fx, 0.0))
+
+
 def _sw_fwd_kernel(coords_ref, f2_ref, out_ref, *, radius, dims):
     k = 2 * radius + 1
     h2, w2 = dims
@@ -945,14 +975,11 @@ def _sw_fwd_kernel(coords_ref, f2_ref, out_ref, *, radius, dims):
         slab = f2_ref[0, pl.ds(y0, k + 1), pl.ds(x8, _XW), :]
         slab = slab.astype(jnp.float32)                 # (k+1, _XW, C)
         t = (1.0 - fy) * slab[0:k] + fy * slab[1:k + 1]  # (k_dy, _XW, C)
-        m = _x_select(s, fx, k)                          # (_XW, k_dx)
 
-        # dx-major rows: column dx of m lerps lanes s+dx / s+dx+1
-        rows = [
-            jnp.sum(t * m[None, :, dx:dx + 1], axis=1)   # (k_dy, C)
-            for dx in range(k)
-        ]
-        out_ref[0, 0, j] = jnp.concatenate(rows, axis=0)  # (k², C) (dx, dy)
+        # dx-major (k², C) window rows: dx lerps lanes s+dx / s+dx+1
+        for dx in range(k):
+            out_ref[0, 0, j, dx * k:(dx + 1) * k, :] = jnp.sum(
+                t * _x_weights(s, fx, dx), axis=1)       # (k_dy, C)
         return 0
 
     jax.lax.fori_loop(0, n_j, body, 0)
@@ -975,18 +1002,24 @@ def _sw_bwd_kernel(coords_ref, dout_ref, df2_ref, *, radius, dims):
         cx = coords_ref[0, 0, j, 0]
         cy = coords_ref[0, 0, j, 1]
         x8, s, y0, fx, fy = _wcp_window(cx, cy, 0, h2, w2, radius)
-        m = _x_select(s, fx, k)                          # (_XW, k_dx)
 
-        dv = dout_ref[0, 0, j].astype(jnp.float32)       # (k², C) (dx, dy)
-        # transpose of the x-selection: spread each dx row block over lanes
-        dt = None
-        for dx in range(k):
-            part = (dv[dx * k:(dx + 1) * k][:, None, :]
-                    * m[None, :, dx:dx + 1])             # (k_dy, _XW, C)
-            dt = part if dt is None else dt + part
-        zr = jnp.zeros((1, _XW, dt.shape[-1]), jnp.float32)
-        dd = ((1.0 - fy) * jnp.concatenate([dt, zr], axis=0)
-              + fy * jnp.concatenate([zr, dt], axis=0))  # (k+1, _XW, C)
+        # transpose of the x-selection, one window row at a time: the
+        # (1, C) gradient of tap (dx, dy) spreads over lanes s+dx / s+dx+1
+        # of slab row dy as an outer product with the dx weights
+        wx = [_x_weights(s, fx, dx)[0] for dx in range(k)]   # (_XW, 1)
+        dt = []
+        for dy in range(k):
+            acc = None
+            for dx in range(k):
+                tap = dx * k + dy
+                g = dout_ref[0, 0, j, tap:tap + 1, :].astype(jnp.float32)
+                acc = wx[dx] * g if acc is None else acc + wx[dx] * g
+            dt.append(acc)                                   # (_XW, C)
+        # transpose of the y-lerp: slab row y gets (1-fy)·dt[y] + fy·dt[y-1]
+        dd = jnp.stack(
+            [(1.0 - fy) * dt[0]]
+            + [(1.0 - fy) * dt[y] + fy * dt[y - 1] for y in range(1, k)]
+            + [fy * dt[k - 1]])                              # (k+1, _XW, C)
 
         df2_ref[0, pl.ds(y0, k + 1), pl.ds(x8, _XW), :] += dd
         return 0
@@ -1015,7 +1048,7 @@ def _sw_fwd_tpu(f2, coords, radius, interpret=False):
         out_specs=pl.BlockSpec((1, 1, n_j, k * k, c),
                                lambda bi, ii: (bi, ii, 0, 0, 0),
                                memory_space=pltpu.VMEM),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
         interpret=interpret,
     )(coords, f2p)
@@ -1050,7 +1083,7 @@ def _sw_bwd_tpu(f2, coords, dout, radius, interpret=False):
         out_specs=pl.BlockSpec((1,) + f2p.shape[1:],
                                lambda bi, ii: (bi, 0, 0, 0),
                                memory_space=pltpu.VMEM),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
         interpret=interpret,
     )(coords, doutr)
@@ -1133,7 +1166,8 @@ def sample_window_fused(f2, coords, radius=4):
     treated as non-differentiable (zero gradient): callers inside the
     recurrent estimators detach the lookup centers.
     """
-    return _sw(f2, coords, radius).astype(f2.dtype)
+    return _per_shard(lambda a, c: _sw(a, c, radius))(
+        f2, coords).astype(f2.dtype)
 
 
 def convex_combine_8x(mask_logits, win, temperature=4.0):
@@ -1147,5 +1181,6 @@ def convex_combine_8x(mask_logits, win, temperature=4.0):
     lead = mask_logits.shape[:-1]
     logits2d = mask_logits.reshape(-1, _K * _S)
     win2d = win.astype(jnp.float32).reshape(-1, _K * _C)
-    out = _combine(logits2d, win2d, 1.0 / temperature)
+    out = _per_shard(lambda a, b: _combine(a, b, 1.0 / temperature))(
+        logits2d, win2d)
     return out.reshape(*lead, _C * _S)

@@ -60,6 +60,56 @@ def data_axis_size():
     return _data_axis.get()
 
 
+# the mesh of the step function currently being traced (None: a
+# single-device step). Code that cannot leave its partitioning to the
+# compiler reads it at trace time — the Pallas kernels, which Mosaic
+# refuses to partition automatically, map themselves over its shards.
+_mesh = contextvars.ContextVar("rmd_traced_mesh", default=None)
+
+
+def traced_mesh():
+    """The mesh the current trace is partitioned over, or None."""
+    return _mesh.get()
+
+
+def traced_under(mesh, fn):
+    """Publish ``mesh`` (and the data-parallel degree it implies: the
+    step builders split the batch over every mesh axis) to ``fn``'s
+    traces.
+
+    The model traces inside the first call of the jitted function, so
+    the scope must be pinned around the call, not at build time —
+    otherwise an interleaved unsharded trace (e.g. the inspector's
+    process-local validation jit) would read a stale value. Both scopes
+    restore the enclosing scope's value on exit, so nested/concurrent
+    step builds over different meshes can't leak into each other.
+    """
+
+    @contextlib.contextmanager
+    def scope():
+        token = _mesh.set(mesh)
+        try:
+            with scoped_data_axis_size(mesh.devices.size):
+                yield
+        finally:
+            _mesh.reset(token)
+
+    def wrapped(*args, **kwargs):
+        with scope():
+            return fn(*args, **kwargs)
+
+    inner_lower = getattr(fn, "lower", None)
+    if inner_lower is not None:
+        # AOT entry point: tracing happens inside lower(), so it needs
+        # the same scope as a live call
+        def lower(*args, **kwargs):
+            with scope():
+                return inner_lower(*args, **kwargs)
+
+        wrapped.lower = lower
+    return wrapped
+
+
 def data_mesh(n_devices=None, axis_name="data", devices=None):
     """1-D mesh over ``n_devices`` (default: all) for data parallelism."""
     devs = list(devices if devices is not None else jax.devices())

@@ -26,34 +26,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..compile import register_step
 from . import partition
-from .mesh import scoped_data_axis_size
-
-
-def _with_data_axis(n, fn):
-    """Scope the published data-parallel degree to ``fn``'s calls.
-
-    The model traces inside the first call of the jitted function, so the
-    degree must be pinned around the call, not at build time — otherwise
-    an interleaved unsharded trace (e.g. the inspector's process-local
-    validation jit) would read a stale value. ``scoped_data_axis_size``
-    restores the enclosing scope's degree on exit, so nested/concurrent
-    step builds over different meshes can't leak into each other.
-    """
-
-    def wrapped(*args, **kwargs):
-        with scoped_data_axis_size(n):
-            return fn(*args, **kwargs)
-
-    inner_lower = getattr(fn, "lower", None)
-    if inner_lower is not None:
-        # AOT entry point: tracing happens inside lower(), so it needs
-        # the same scoped degree as a live call
-        def lower(*args, **kwargs):
-            with scoped_data_axis_size(n):
-                return inner_lower(*args, **kwargs)
-
-        wrapped.lower = lower
-    return wrapped
+from .mesh import traced_under
 
 
 class TrainState(struct.PyTreeNode):
@@ -364,8 +337,8 @@ def make_train_step(model, loss_fn, tx, mesh=None, loss_args=None,
     if augment is not None:
         # sample ids shard with their samples; the epoch scalar replicates
         in_shardings = in_shardings + (data, None)
-    prog = register_step("train_step", _with_data_axis(
-        mesh.devices.size,
+    prog = register_step("train_step", traced_under(
+        mesh,
         jax.jit(
             public,
             in_shardings=in_shardings,
@@ -429,7 +402,7 @@ def make_eval_step(model, mesh=None, model_args=None, wire=None,
     data = partition.data_sharding(mesh)
     variables_in = (variables_sharding if variables_sharding is not None
                     else repl)
-    return register_step("eval_step", _with_data_axis(
-        mesh.devices.size,
+    return register_step("eval_step", traced_under(
+        mesh,
         jax.jit(step, in_shardings=(variables_in, data, data),
                 out_shardings=data)), key=key)

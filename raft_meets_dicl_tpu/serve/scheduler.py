@@ -36,6 +36,7 @@ session); per-request telemetry lands as ``serve`` events: ``request``
 ``reject``, and per-dispatch ``batch`` records.
 """
 
+import logging
 import threading
 import time
 
@@ -416,6 +417,9 @@ class Scheduler:
             try:
                 self._dispatch(bucket, batch)
             except Exception as e:  # noqa: BLE001 - loop must survive
+                logging.exception(
+                    f"serve: dispatch of a {len(batch)}-request batch on "
+                    f"bucket {bucket} failed")
                 for r in batch:
                     self._complete(r, error=ServeError("internal", str(e)))
 

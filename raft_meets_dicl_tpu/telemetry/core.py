@@ -41,7 +41,7 @@ SCHEMA_VERSION = 1
 # readers keep working); a reader seeing ``v`` with the same major but a
 # larger fractional minor (e.g. 1.2 from a newer producer) should skip
 # the record, not reject the file — see :class:`NewerSchema`.
-SCHEMA_MINOR = 5
+SCHEMA_MINOR = 6
 
 # kind -> required payload fields (beyond the {v, t, kind} envelope).
 # Extra fields are allowed everywhere: the schema pins the floor a
@@ -54,6 +54,9 @@ SCHEMA = {
     "epoch_start": {"stage", "epoch", "step"},
     "epoch_end": {"stage", "epoch", "step"},
     "step": {"step", "phases", "step_time", "throughput_ema"},
+    # one amortized pipeline drain (every RMD_FINITE_CHECK_EVERY steps):
+    # how long the host blocked, and the loss of the step it resolved —
+    # with epoch_end's, the only loss values in the stream
     "device_sync": {"step", "seconds"},
     "compile": {"label", "seconds"},
     "cache": {"event"},
@@ -77,11 +80,17 @@ SCHEMA = {
     # kind/model/digest and bytes/seconds where applicable. A 'fallback'
     # means an artifact existed but could not be used (corruption,
     # version mismatch, incompatible inputs): the boot paid a cold JIT
-    # it expected to skip, which the report flags as an anomaly.
+    # it expected to skip, which the report flags as an anomaly. The
+    # events that hold an executable (hit | save | skip_save) carry
+    # mosaic_calls, the Pallas TPU custom calls in its HLO: the kernels
+    # give way to their XLA references at trace time without a word,
+    # and this is how the run shows which form it got.
     "aot": {"event"},
     # boot configuration: the effective persistent compile-cache and AOT
     # program directories (instead of silently defaulting), plus the
-    # prefetch knob — emitted once per CLI run
+    # prefetch knob — emitted once per CLI run. Where it ran (platform,
+    # device_kind, device_count, backend) rides on the first event after
+    # device selection: serve's boot, train's run_start.
     "boot": {"compile_cache"},
     # fault-tolerance trail (PR 5): graceful-stop request (SIGTERM/SIGINT),
     # --resume auto pickup, corrupt-checkpoint quarantine, decode-worker
@@ -548,13 +557,18 @@ def instrument_jit(label, fn):
 def install_listeners():
     """Register the process-wide jax.monitoring forwarders (idempotent).
 
-    jax emits '/jax/core/compile/backend_compile_duration' per backend
-    compile and '/jax/compilation_cache/cache_{hits,misses}' per
-    persistent-cache lookup; both forward to whatever sink is active at
-    fire time, labeled by the innermost ``jit_label`` scope. Compile
-    durations also increment the scoped registry Program's counters —
-    those count even with the sink disabled, so eval/warmup compile
-    accounting never falls back to guessing (the pre-PR-7 overcount).
+    jax emits '/jax/compilation_cache/cache_{hits,misses}' per
+    persistent-cache lookup and '/jax/core/compile/backend_compile_duration'
+    around ``compile_or_get_cached`` — i.e. around the lookup *and* the
+    backend compile, so the duration also fires when the executable came
+    out of the persistent cache. A duration that follows a cache hit on
+    the same thread is that retrieval, not a compile: it is dropped, so
+    that ``compile`` events and ``Program.compiles`` mean "a backend
+    compile ran". Everything forwards to whatever sink is active at fire
+    time, labeled by the innermost ``jit_label`` scope. Compile durations
+    also increment the scoped registry Program's counters — those count
+    even with the sink disabled, so eval/warmup compile accounting never
+    falls back to guessing (the pre-PR-7 overcount).
     """
     global _listeners_installed
     if _listeners_installed:
@@ -565,17 +579,21 @@ def install_listeners():
         return
 
     def on_event(event, **kwargs):
-        if not _active.enabled:
-            return
         if event == "/jax/compilation_cache/cache_hits":
-            _active.emit("cache", event="hit",
-                         label=getattr(_jit_label, "value", None))
+            _jit_label.cache_hit = True
+            if _active.enabled:
+                _active.emit("cache", event="hit",
+                             label=getattr(_jit_label, "value", None))
         elif event == "/jax/compilation_cache/cache_misses":
-            _active.emit("cache", event="miss",
-                         label=getattr(_jit_label, "value", None))
+            if _active.enabled:
+                _active.emit("cache", event="miss",
+                             label=getattr(_jit_label, "value", None))
 
     def on_duration(event, duration, **kwargs):
         if event != "/jax/core/compile/backend_compile_duration":
+            return
+        if getattr(_jit_label, "cache_hit", False):
+            _jit_label.cache_hit = False
             return
         program = getattr(_jit_label, "program", None)
         if program is not None:
@@ -616,13 +634,21 @@ def memory_snapshot():
         import jax
 
         snap["live_arrays"] = len(jax.live_arrays())
-        stats = jax.local_devices()[0].memory_stats() or {}
-        if "peak_bytes_in_use" in stats:
-            snap["device_peak_gib"] = round(
-                stats["peak_bytes_in_use"] / 2 ** 30, 3)
-        if "bytes_in_use" in stats:
-            snap["device_bytes_gib"] = round(
-                stats["bytes_in_use"] / 2 ** 30, 3)
+        # the fullest device: what decides whether the step fits. On the
+        # TPU runtime the allocator's *_in_use counts buffers only; a
+        # loaded program's temporaries are held as *_reserved (measured
+        # on a v5e: a step with 512 MiB of temporaries moved
+        # peak_bytes_reserved by exactly that and peak_bytes_in_use not
+        # at all), so the two add up to what the device had to hold
+        stats = [d.memory_stats() or {} for d in jax.local_devices()]
+        for name, used, reserved in (
+                ("device_peak_gib", "peak_bytes_in_use",
+                 "peak_bytes_reserved"),
+                ("device_bytes_gib", "bytes_in_use", "bytes_reserved")):
+            values = [s[used] + s.get(reserved, 0)
+                      for s in stats if used in s]
+            if values:
+                snap[name] = round(max(values) / 2 ** 30, 3)
     except Exception:  # noqa: BLE001 - telemetry must never break the run
         pass
     return snap

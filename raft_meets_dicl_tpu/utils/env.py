@@ -150,11 +150,11 @@ KNOBS = dict([
     # -- compile / AOT -----------------------------------------------------
     _k("RMD_COMPILE_CACHE", "str", None,
        "persistent XLA compile-cache directory (default "
-       "<repo>/.jax_cache)", "compile"),
-    _k("RMD_COMPILE_CACHE_DIR", "str", None,
-       "legacy alias of RMD_COMPILE_CACHE", "compile"),
+       "<repo>/.jax_cache); yields to JAX_COMPILATION_CACHE_DIR",
+       "compile"),
     _k("RMD_NO_COMPILE_CACHE", "flag", False,
-       "disable the persistent XLA compile cache entirely", "compile"),
+       "configure no persistent XLA compile cache (a cache placed from "
+       "outside through JAX_COMPILATION_CACHE_DIR stays on)", "compile"),
     _k("RMD_AOT", "switch", True,
        "AOT serialized-executable program store (0 disables)", "compile"),
     _k("RMD_AOT_DIR", "str", None,

@@ -11,7 +11,7 @@ a new hazard lands without a justification.
 
 HLO pass (``--hlo``) — lowers the registered flagship step programs
 twice each and audits fingerprint stability, collective counts
-(post-GSPMD), f32 convolutions, and baked-in constants. Needs jax; the
+(post-partitioner), f32 convolutions, and baked-in constants. Needs jax; the
 static pass does not. (The quantitative cost/budget gate lives in
 ``scripts/graftcost.py``.)
 

@@ -136,6 +136,27 @@ def test_parse_schedule_counts_starts_not_dones():
     assert s["order"] == ["all-gather", "all-reduce"]
 
 
+def test_parse_schedule_counts_every_output_of_a_combined_collective():
+    """The combiner passes emit variadic, tuple-valued collectives (the
+    flagship's 174 gradient all-reduces arrive as ~30 of them): every
+    output counts, not the last. Async gather/permute starts carry
+    (operands, outputs, context): only the outputs count."""
+    sched = collectives.parse_schedule(textwrap.dedent("""
+    %all-reduce.5 = (f32[256]{0}, f32[3,3,8,4]{3,2,1,0}, bf16[64]{0}) all-reduce(%a, %b, %c), channel_id=1
+    %all-to-all.3 = (f32[1,24,64,3]{3,2,1,0}, f32[1,24,64,3]{3,2,1,0}) all-to-all(%d, %e), channel_id=2
+    %all-gather-start.2 = ((f32[2,64]{1,0}, f32[2,8]{1,0}), (f32[16,64]{1,0}, f32[16,8]{1,0})) all-gather-start(%f, %g)
+    %collective-permute-start.1 = (f32[4,8]{1,0}, f32[4,8]{1,0}, u32[], u32[]) collective-permute-start(%h)
+    %all-reduce-start.9 = (f32[10]{0}, f32[20]{0}) all-reduce-start(%i, %j)
+    """))
+    assert [(op.op, op.bytes) for op in sched] == [
+        ("all-reduce", 256 * 4 + 3 * 3 * 8 * 4 * 4 + 64 * 2),
+        ("all-to-all", 2 * 24 * 64 * 3 * 4),
+        ("all-gather", 16 * 64 * 4 + 16 * 8 * 4),
+        ("collective-permute", 4 * 8 * 4),
+        ("all-reduce", 30 * 4),
+    ]
+
+
 def _mesh_partitioner():
     mesh = parallel.make_mesh((4, 2))
     rules = ((r".*kernel$", P("model")), (r".*", P()))

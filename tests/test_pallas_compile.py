@@ -1,0 +1,137 @@
+"""Mosaic accepts every Pallas kernel family — checked without a chip.
+
+Off-TPU the kernels only ever run through the Pallas interpreter, which
+accepts programs the Mosaic compiler refuses: ``sample_window_fused``
+passed every interpreted test from PR 3 on and had never compiled. libtpu
+can compile for a TPU topology it is not attached to, so this test hands
+each family's kernel entry points, forward and backward, to the real
+compiler for one v5e chip at the shape where a model dispatches to it,
+and then through the models' entry points under a four-chip mesh, where
+the kernels have to map themselves over the batch shards. Numerics on
+the chip are ``scripts/chip_kernels.py``'s job.
+
+The compile runs in a child process: it has to bring libtpu up, and
+whatever that does must not reach the test process. A host where libtpu
+cannot describe the topology skips; a kernel the compiler refuses fails.
+"""
+
+import os
+import subprocess
+import sys
+from pathlib import Path
+
+import pytest
+
+REPO = Path(__file__).parent.parent
+
+_CHILD = r"""
+import sys
+sys.path.insert(0, sys.argv[1])
+import jax
+import jax.numpy as jnp
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+
+try:
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+except Exception as e:
+    print(f"no compile-only TPU topology: {type(e).__name__}: {e}")
+    sys.exit(3)
+
+from raft_meets_dicl_tpu.ops import pallas as K
+
+chip = SingleDeviceSharding(topo.devices[0])
+bf16, f32 = jnp.bfloat16, jnp.float32
+
+
+def compile_for_v5e(fn, *specs):
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+            for shape, dtype in specs]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# Up8 combine in the raft/baseline train step: b6 400x720, 12 iterations
+m = 12 * 6 * 50 * 90
+compile_for_v5e(lambda a, b: K._run_fwd(a, b, 0.25),
+                ((m, 576), bf16), ((m, 18), f32))
+compile_for_v5e(lambda a, b, c: K._run_bwd(a, b, c, 0.25),
+                ((m, 576), bf16), ((m, 18), f32), ((m, 128), f32))
+
+# windowed correlation pyramid, raft/fs at 1080p: every level resident
+h, w, c, levels = 134, 320, 256, 4
+f1 = ((1, h, w, c), bf16)
+f2 = tuple(((1, h >> l, w >> l, c), bf16) for l in range(levels))
+coords = ((1, h, w, 2), f32)
+dout = ((1, h, w, levels * 81), f32)
+for band in (True, False):
+    compile_for_v5e(
+        lambda a, cc, *bb: K._wcp_fwd_tpu(a, bb, cc, 4, band=band),
+        f1, coords, *f2)
+    compile_for_v5e(
+        lambda a, cc, d, *bb: K._wcp_bwd_tpu(a, bb, cc, d, 4, band=band),
+        f1, coords, dout, *f2)
+
+# fused DICL window sampler, raft+dicl/ml: b6 384x704, C=32, 4 levels
+b, h, w, c = 6, 48, 88, 32
+for lvl in range(4):
+    for dtype in (f32, bf16):
+        f2 = ((b, h >> lvl, w >> lvl, c), dtype)
+        compile_for_v5e(lambda a, cc: K._sw_fwd_tpu(a, cc, 4),
+                        f2, ((b, h, w, 2), f32))
+        compile_for_v5e(lambda a, cc, d: K._sw_bwd_tpu(a, cc, d, 4),
+                        f2, ((b, h, w, 2), f32), ((b, 9, 9, h, w, c), f32))
+
+# Under an SPMD mesh Mosaic refuses to partition a kernel automatically:
+# the step builders publish their mesh (parallel.mesh.traced_under) and
+# the kernels map themselves over its batch shards. Four chips, batch 8.
+import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from raft_meets_dicl_tpu.ops.upsample import convex_upsample_8x
+from raft_meets_dicl_tpu.parallel.mesh import traced_under
+
+mesh = Mesh(np.array(topo.devices), ("data",))
+batch = NamedSharding(mesh, P("data"))
+jax.default_backend = lambda: "tpu"    # trace the on-TPU dispatch
+
+
+def compile_for_four_chips(fn, *specs):
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=batch)
+            for shape, dtype in specs]
+    step = traced_under(mesh, jax.jit(fn, in_shardings=batch))
+    compiled = step.lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def grad_of(fn, argnum):
+    return jax.grad(lambda *a: fn(*a).astype(f32).sum(), argnums=argnum)
+
+
+compile_for_four_chips(grad_of(convex_upsample_8x, 1),
+                       ((8, 50, 90, 2), f32), ((8, 50, 90, 576), bf16))
+compile_for_four_chips(
+    grad_of(lambda a, b, cc: K.windowed_corr_pyramid(a, (b,), cc), 0),
+    ((8, 48, 64, 256), bf16), ((8, 48, 64, 256), bf16),
+    ((8, 48, 64, 2), f32))
+compile_for_four_chips(
+    grad_of(lambda a, cc: K.sample_window_fused(a, cc), 0),
+    ((8, 48, 88, 32), f32), ((8, 48, 88, 2), f32))
+print("compiled")
+"""
+
+
+def test_every_kernel_family_compiles_for_v5e():
+    # compile-only: no chip is taken, so libtpu's one-process lock (a
+    # stale /tmp/libtpu_lockfile, a neighbour compiling) must not matter
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               TPU_WORKER_HOSTNAMES="localhost",
+               TPU_ACCELERATOR_TYPE="v5litepod-4",
+               ALLOW_MULTIPLE_LIBTPU_LOAD="1")
+    proc = subprocess.run(
+        [sys.executable, "-c", _CHILD, str(REPO)], env=env,
+        capture_output=True, text=True, timeout=600)
+    if proc.returncode == 3:
+        pytest.skip(proc.stdout.strip().splitlines()[-1])
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    assert proc.stdout.strip().endswith("compiled")

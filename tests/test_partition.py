@@ -113,6 +113,34 @@ def test_scoped_data_axis_size_nesting():
     assert parallel.data_axis_size() == 1
 
 
+def test_traced_under_publishes_the_mesh_to_calls_and_lowerings():
+    """What the Pallas kernels read to map themselves over the batch
+    shards: the mesh is visible while the step traces — through a call
+    and through the AOT ``lower`` — and nowhere else."""
+    from raft_meets_dicl_tpu.parallel.mesh import traced_mesh, traced_under
+
+    outer, inner = parallel.make_mesh((4, 2)), parallel.data_mesh(2)
+    seen = []
+
+    def probe(x):
+        seen.append((traced_mesh(), parallel.data_axis_size()))
+        return x + 1
+
+    step = traced_under(outer, jax.jit(probe))
+    assert traced_mesh() is None
+    step(jnp.zeros(2))
+    step.lower(jnp.zeros(3))
+    assert seen == [(outer, 8), (outer, 8)]
+
+    def nested(x):
+        return traced_under(inner, jax.jit(probe))(x) + traced_mesh().size
+
+    seen.clear()
+    assert float(traced_under(outer, nested)(jnp.zeros(()))) == 9.0
+    assert seen == [(inner, 2)]
+    assert traced_mesh() is None and parallel.data_axis_size() == 1
+
+
 # -- rule matching -----------------------------------------------------------
 
 

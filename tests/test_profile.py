@@ -21,6 +21,30 @@ CANNED = Path(__file__).parent / "data" / "graftprof"
 MACHINE = "cpu:test"
 
 
+# -- machine + peaks ----------------------------------------------------------
+
+
+def test_peaks_are_keyed_by_device_kind_and_unknown_is_an_error(monkeypatch):
+    import jax
+
+    class Device:
+        platform = "tpu"
+
+        def __init__(self, kind):
+            self.device_kind = kind
+
+    monkeypatch.setattr(jax, "devices", lambda: [Device("TPU v5 lite")])
+    spec = prof.machine_spec()
+    assert spec["machine_id"] == "tpu:tpu-v5-lite"
+    # one v5e chip: 197 TFLOP/s bf16, 819 GB/s (Google Cloud, "TPU v5e")
+    assert spec["peak_flops"] == 197e12
+    assert spec["peak_bytes_per_s"] == 819e9
+
+    monkeypatch.setattr(jax, "devices", lambda: [Device("TPU v9")])
+    with pytest.raises(ValueError, match="TPU v9"):
+        prof.machine_spec()
+
+
 # -- op-class bucketing -------------------------------------------------------
 
 

@@ -197,9 +197,13 @@ def test_val_step_matches_fused_reference():
     ref_est = result.final()
     ref_loss = loss_fn(model, result.output(), flow, valid)
 
+    # the reference runs op by op, the step as fused programs: the f32
+    # convolutions accumulate in another order (2.4e-5 absolute on
+    # flows of magnitude 2 under jaxlib 0.9.0); a mis-wired step is off
+    # by O(1)
     np.testing.assert_allclose(np.asarray(est), np.asarray(ref_est),
-                               atol=1e-5, rtol=1e-5)
-    assert float(loss) == pytest.approx(float(ref_loss), rel=1e-5)
+                               atol=1e-4, rtol=1e-4)
+    assert float(loss) == pytest.approx(float(ref_loss), rel=1e-4)
     programs.reset()
 
 
@@ -324,6 +328,135 @@ def test_aot_version_mismatch_falls_back(aot_store):
     assert prog2.aot_saves == 1
 
 
+def test_tombstoned_program_says_so_on_every_boot(aot_store):
+    """A program that cannot reload stays on JIT, but not silently: each
+    boot's ``aot`` trail carries the fallback."""
+    from raft_meets_dicl_tpu.compile import aot
+
+    key = programs.ProgramKey("train_step", "toy-tombstone")
+    state, x = {"w": jnp.asarray(1.0)}, jnp.ones((3,))
+    sig = programs.shape_signature((state, x))
+    aot_store.mkdir(parents=True, exist_ok=True)
+    aot.tombstone(aot.artifact_path(key, sig))
+
+    for _ in range(2):
+        programs.reset()
+        sink = telemetry.activate(telemetry.Telemetry())
+        try:
+            prog = programs.register_step("train_step", _toy_step_fn(),
+                                          key=key)
+            prog(state, x)
+            events = [e for e in sink.events if e["kind"] == "aot"]
+        finally:
+            telemetry.deactivate()
+        assert [e["event"] for e in events] == ["fallback"]
+        assert "tombstoned" in events[0]["reason"]
+        assert prog.aot_fallbacks == 1 and prog.aot_saves == 0
+
+
+def test_failed_compile_raises_instead_of_compiling_twice(aot_store):
+    """A program the compiler refuses fails the same way through plain
+    jit: the registry must not answer with a second compile and a
+    fallback event."""
+    class Refused(Exception):
+        pass
+
+    lowerings = []
+
+    class Lowered:
+        def compile(self):
+            raise Refused("Mosaic says no")
+
+    class Step:
+        def lower(self, *args):
+            lowerings.append(args)
+            return Lowered()
+
+        def __call__(self, *args):
+            raise AssertionError("fell back to the jit path")
+
+    key = programs.ProgramKey("train_step", "toy-refused")
+    prog = programs.register_step("train_step", Step(), key=key)
+    with pytest.raises(Refused):
+        prog(jnp.ones((2,)))
+    assert len(lowerings) == 1 and prog.aot_fallbacks == 0
+
+
+def test_runtime_failure_of_a_compiled_call_propagates(aot_store):
+    """Only the executable's own argument checks (TypeError/ValueError)
+    put a signature on the jit path; a failed execution raises."""
+    key = programs.ProgramKey("eval_step", "toy-runtime")
+    prog = programs.register_step("eval_step", jax.jit(lambda x: x + 1),
+                                  key=key)
+    x = jnp.ones((2,))
+    prog(x)
+    sig = programs.shape_signature((x,))
+
+    def out_of_memory(*args):
+        raise RuntimeError("RESOURCE_EXHAUSTED")
+
+    prog._compiled[sig] = out_of_memory
+    with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
+        prog(x)
+    assert prog.aot_fallbacks == 0
+
+    def wrong_placement(*args):
+        raise ValueError("input sharding does not match")
+
+    prog._compiled[sig] = wrong_placement
+    assert np.array_equal(np.asarray(prog(x)), np.asarray(x) + 1)
+    assert prog.aot_fallbacks == 1
+
+
+def test_cache_served_compile_is_not_counted_as_a_compile(aot_store):
+    """jax 0.9 reports backend_compile_duration around the persistent
+    cache lookup too; a duration that follows a cache hit is a retrieval
+    and must not reach Program.compiles or the compile events."""
+    from jax import monitoring
+
+    event = "/jax/core/compile/backend_compile_duration"
+    key = programs.ProgramKey("train_step", "toy-cache-served")
+    prog = programs.register_step("train_step", _toy_step_fn(), key=key)
+    sink = telemetry.activate(telemetry.Telemetry())
+    try:
+        with telemetry.jit_label("train_step", prog):
+            monitoring.record_event("/jax/compilation_cache/cache_hits")
+            monitoring.record_event_duration_secs(event, 0.07)
+            assert prog.compiles == 0
+            monitoring.record_event("/jax/compilation_cache/cache_misses")
+            monitoring.record_event_duration_secs(event, 1.5)
+            assert prog.compiles == 1
+        kinds = [(e["kind"], e.get("event")) for e in sink.events
+                 if e["kind"] in ("cache", "compile")]
+        assert kinds == [("cache", "hit"), ("cache", "miss"),
+                         ("compile", None)]
+    finally:
+        telemetry.deactivate()
+
+
+def test_aot_roundtrip_of_a_one_device_program_on_a_many_device_host(
+        aot_store):
+    """The artifact records its device assignment: jax 0.9 loads onto
+    every device of the backend unless told otherwise, and a one-device
+    executable then rejects its arguments (8 virtual devices here)."""
+    key = programs.ProgramKey("eval_step", "toy-devices")
+    dev = jax.devices()[-1]
+    x = jax.device_put(jnp.arange(4.0), dev)
+    prog = programs.register_step("eval_step", jax.jit(lambda x: x * 2),
+                                  key=key)
+    prog(x)
+    assert prog.aot_saves == 1
+
+    programs.reset()
+    prog2 = programs.register_step("eval_step", jax.jit(lambda x: x * 2),
+                                   key=key)
+    out = prog2(x)
+    assert prog2.aot_hits == 1 and prog2.aot_fallbacks == 0
+    assert prog2.compiles == 0
+    assert out.devices() == {dev}
+    assert np.array_equal(np.asarray(out), 2 * np.arange(4.0))
+
+
 def test_aot_train_step_roundtrip_through_builder(aot_store):
     """End-to-end through parallel.make_train_step: a keyed tiny train
     step saves its executable; a fresh build reloads it with zero
@@ -400,44 +533,93 @@ def test_warmup_compiles_not_overcounted_when_warm(aot_store):
 # -- compcache satellite --------------------------------------------------
 
 
-def test_compile_cache_dir_configurable(tmp_path, monkeypatch):
+@pytest.fixture
+def cache_config(monkeypatch):
+    """Restore jax's cache options and compcache's state after a test;
+    start with none of the placement variables set (the driver may
+    export JAX_COMPILATION_CACHE_DIR)."""
     from raft_meets_dicl_tpu.utils import compcache
 
-    orig_dir = jax.config.jax_compilation_cache_dir
-    orig_entry = jax.config.jax_persistent_cache_min_entry_size_bytes
-    orig_secs = jax.config.jax_persistent_cache_min_compile_time_secs
-    try:
-        monkeypatch.delenv("RMD_NO_COMPILE_CACHE", raising=False)
-        monkeypatch.setenv("RMD_COMPILE_CACHE", str(tmp_path / "env-cache"))
-        got = compcache.enable_persistent_cache()
-        assert got == str(tmp_path / "env-cache")
-        assert compcache.effective_dir() == got
-        assert os.path.isdir(got)
-
-        # an explicit path (the --compile-cache flag) wins over the env
-        got = compcache.enable_persistent_cache(str(tmp_path / "cli-cache"))
-        assert got == str(tmp_path / "cli-cache")
-        assert compcache.effective_dir() == got
-
-        # kill switch
-        monkeypatch.setenv("RMD_NO_COMPILE_CACHE", "1")
-        assert compcache.enable_persistent_cache() is None
-        assert compcache.effective_dir() is None
-    finally:
-        jax.config.update("jax_compilation_cache_dir", orig_dir)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                          orig_entry)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          orig_secs)
-        compcache._effective = None
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_entry_size_bytes",
+             "jax_persistent_cache_min_compile_time_secs")
+    saved = {n: getattr(jax.config, n) for n in names}
+    for var in (compcache.EXTERNAL_VAR, "RMD_COMPILE_CACHE",
+                "RMD_NO_COMPILE_CACHE"):
+        monkeypatch.delenv(var, raising=False)
+    monkeypatch.setattr(compcache, "_configured", None)
+    yield compcache
+    for n, v in saved.items():
+        jax.config.update(n, v)
 
 
-def test_aot_dir_defaults_next_to_compile_cache(tmp_path, monkeypatch):
-    from raft_meets_dicl_tpu.utils import compcache
+def test_compile_cache_precedence_without_external_dir(tmp_path, monkeypatch,
+                                                       cache_config):
+    compcache = cache_config
+    monkeypatch.setenv("RMD_COMPILE_CACHE", str(tmp_path / "env-cache"))
+    got = compcache.enable_persistent_cache()
+    assert got == str(tmp_path / "env-cache")
+    assert compcache.effective_dir() == got
+    assert jax.config.jax_compilation_cache_dir == got
+    assert os.path.isdir(got)
 
+    # an explicit path (the --compile-cache flag) wins over the env
+    got = compcache.enable_persistent_cache(str(tmp_path / "cli-cache"))
+    assert got == str(tmp_path / "cli-cache")
+    assert compcache.effective_dir() == got
+
+    # neither: the repo-local default
+    monkeypatch.delenv("RMD_COMPILE_CACHE")
+    monkeypatch.setattr(compcache, "DEFAULT_DIR", str(tmp_path / "default"))
+    assert compcache.enable_persistent_cache() == str(tmp_path / "default")
+
+    # kill switch
+    monkeypatch.setenv("RMD_NO_COMPILE_CACHE", "1")
+    assert compcache.enable_persistent_cache() is None
+    assert compcache.effective_dir() is None
+
+
+def test_external_cache_dir_wins_and_is_not_set_in_code(tmp_path,
+                                                        monkeypatch,
+                                                        cache_config):
+    compcache = cache_config
+    external = str(tmp_path / "placed-from-outside")
+    monkeypatch.setenv(compcache.EXTERNAL_VAR, external)
+    monkeypatch.setenv("RMD_COMPILE_CACHE", str(tmp_path / "env-cache"))
+    monkeypatch.setattr(compcache, "DEFAULT_DIR", str(tmp_path / "default"))
+    before = jax.config.jax_compilation_cache_dir
+
+    # flag, knob and kill switch all yield; the option is left to jax
+    assert compcache.enable_persistent_cache(
+        str(tmp_path / "cli-cache")) == external
+    monkeypatch.setenv("RMD_NO_COMPILE_CACHE", "1")
+    assert compcache.enable_persistent_cache() == external
+    assert compcache.effective_dir() == external
+    assert jax.config.jax_compilation_cache_dir == before
+    for name in ("cli-cache", "env-cache", "default"):
+        assert not (tmp_path / name).exists()
+
+    # the AOT store follows it
     monkeypatch.delenv("RMD_AOT", raising=False)
     monkeypatch.delenv("RMD_AOT_DIR", raising=False)
-    monkeypatch.setattr(compcache, "_effective", str(tmp_path / "cc"))
+    try:
+        assert programs.enable_aot() == os.path.join(external, "programs")
+    finally:
+        programs.disable_aot()
+
+
+def test_unusable_cache_dir_raises(tmp_path, cache_config):
+    blocker = tmp_path / "file"
+    blocker.write_text("not a directory")
+    with pytest.raises(OSError):
+        cache_config.enable_persistent_cache(str(blocker / "cache"))
+
+
+def test_aot_dir_defaults_next_to_compile_cache(tmp_path, monkeypatch,
+                                                cache_config):
+    monkeypatch.delenv("RMD_AOT", raising=False)
+    monkeypatch.delenv("RMD_AOT_DIR", raising=False)
+    monkeypatch.setattr(cache_config, "_configured", str(tmp_path / "cc"))
     try:
         got = programs.enable_aot()
         assert got == os.path.join(str(tmp_path / "cc"), "programs")

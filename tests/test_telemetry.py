@@ -137,6 +137,32 @@ def test_memory_snapshot_fields():
     assert isinstance(snap["live_arrays"], int)
 
 
+def test_memory_snapshot_reports_the_fullest_device_with_its_temporaries(
+        monkeypatch):
+    """Every local device is read, and a program's reserved temporaries
+    count (the v5e's ``*_reserved``, which ``*_in_use`` leaves out)."""
+    import jax
+
+    gib = 2 ** 30
+
+    class Device:
+        def __init__(self, stats):
+            self.stats = stats
+
+        def memory_stats(self):
+            return self.stats
+
+    monkeypatch.setattr(jax, "local_devices", lambda: [
+        Device({"bytes_in_use": gib, "peak_bytes_in_use": 2 * gib,
+                "bytes_reserved": gib, "peak_bytes_reserved": 4 * gib}),
+        Device({"bytes_in_use": 3 * gib, "peak_bytes_in_use": 3 * gib}),
+        Device(None),
+    ])
+    snap = telemetry.memory_snapshot()
+    assert snap["device_peak_gib"] == 6.0
+    assert snap["device_bytes_gib"] == 3.0
+
+
 # -- report ---------------------------------------------------------------
 
 
