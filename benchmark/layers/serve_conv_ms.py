@@ -1,0 +1,6 @@
+"""Device time of the ``conv`` operation class per served batch."""
+from ._common import class_ms
+
+
+def read(run):
+    return class_ms(run, "serve", "conv")
