@@ -139,29 +139,33 @@ def select_devices(device=None, device_ids=None):
     """
     import jax
 
-    if device:
-        # make the requested platform the jax default. An explicit
-        # platform list turns a failed initialisation into an error
-        # (left to itself jax skips a backend that does not come up and
-        # hands out CPU devices). 'cpu' stays in the list because the
-        # host side of the input pipeline computes there (data.synth
-        # renders its samples on the host CPU, off the accelerator the
-        # train step owns).
-        platforms = device if device == "cpu" else f"{device},cpu"
-        try:
-            jax.config.update("jax_platforms", platforms)
-        except RuntimeError:
-            pass  # backend already initialized; fall through to filtering
+    # the first jax.devices() brings the backend up (seconds on a TPU):
+    # the ``backend_init`` span. Usually taken before a telemetry sink
+    # exists; ``telemetry.activate`` delivers it
+    with telemetry.interval("backend_init"):
+        if device:
+            # make the requested platform the jax default. An explicit
+            # platform list turns a failed initialisation into an error
+            # (left to itself jax skips a backend that does not come up
+            # and hands out CPU devices). 'cpu' stays in the list because
+            # the host side of the input pipeline computes there
+            # (data.synth renders its samples on the host CPU, off the
+            # accelerator the train step owns).
+            platforms = device if device == "cpu" else f"{device},cpu"
+            try:
+                jax.config.update("jax_platforms", platforms)
+            except RuntimeError:
+                pass  # backend already initialized; filter below
 
-        try:
-            devices = jax.devices(device)
-        except RuntimeError as e:
-            raise ValueError(
-                f"--device '{device}': no such jax platform available "
-                f"({e})"
-            ) from e
-    else:
-        devices = jax.devices()
+            try:
+                devices = jax.devices(device)
+            except RuntimeError as e:
+                raise ValueError(
+                    f"--device '{device}': no such jax platform available "
+                    f"({e})"
+                ) from e
+        else:
+            devices = jax.devices()
 
     if device_ids:
         ids = [int(i.strip()) for i in device_ids.split(",")]

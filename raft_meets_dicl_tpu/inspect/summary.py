@@ -677,18 +677,19 @@ class SummaryInspector(Inspector):
                 self.writer.add_scalar(k, v, ctx.step)
             m.reset()
 
-        # mirror the telemetry step record (emitted just before this
-        # callback) into the TB scalars, so phase timings sit next to the
-        # training curves without opening the JSONL
+        # mirror the newest telemetry step record into the TB scalars, so
+        # phase timings sit next to the training curves without opening
+        # the JSONL. A step's record is emitted once the step has closed
+        # (this callback lies inside it), so it is the previous step's
         ev = telemetry.get().last_step
-        if ev is not None and ev.get("step") == ctx.step:
+        if ev is not None and ev.get("step") == ctx.step - 1:
             for name, secs in ev["phases"].items():
                 self.writer.add_scalar(f"Telemetry/Phase/{name}",
-                                       secs * 1e3, ctx.step)
+                                       secs * 1e3, ev["step"])
             self.writer.add_scalar("Telemetry/StepTimeMs",
-                                   ev["step_time"] * 1e3, ctx.step)
+                                   ev["step_time"] * 1e3, ev["step"])
             self.writer.add_scalar("Telemetry/StepsPerSecEma",
-                                   ev["throughput_ema"], ctx.step)
+                                   ev["throughput_ema"], ev["step"])
 
         due = [v for v in self.val_step
                if ctx.step > 0 and ctx.step % v.frequency == 0]

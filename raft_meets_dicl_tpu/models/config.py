@@ -7,7 +7,7 @@ sections. Model and loss implementations self-register via
 registry grows with the zoo without a central edit point.
 """
 
-from .. import utils
+from .. import telemetry, utils
 from . import input as input_mod
 from . import model as model_mod
 
@@ -96,7 +96,8 @@ def load_model(cfg) -> model_mod.Model:
 
 
 def load(cfg) -> ModelSpec:
-    if not isinstance(cfg, dict):
-        cfg = utils.config.load(cfg)
+    with telemetry.interval("model_load"):
+        if not isinstance(cfg, dict):
+            cfg = utils.config.load(cfg)
 
-    return ModelSpec.from_config(cfg)
+        return ModelSpec.from_config(cfg)

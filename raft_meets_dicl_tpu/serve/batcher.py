@@ -84,17 +84,17 @@ class FlowRequest:
     klass: str = ""  # latency class ("" = plain eval, no ladder)
     sequence: bool = False  # video-session member (warm-start eligible)
     products: bool = False  # also wants fw/bw occlusion + confidence
-    spans: Dict[str, float] = field(default_factory=dict)
-    trace: Any = None  # telemetry.trace.RequestTrace (None = untraced)
+    trace: Any = None  # telemetry.trace.RequestTrace (the scheduler's)
 
 
 @dataclass
 class FlowResult:
     """One served flow: cropped to the request's original extent, with
-    the per-request latency spans (seconds) the telemetry event carries:
-    ``admission`` (validate + quantize + encode), ``queue`` (enqueue to
-    dispatch), ``dispatch`` (batch assembly + program call), ``device``
-    (result fetch)."""
+    the per-request latency spans (seconds) the telemetry event carries,
+    all differences of the request trace's marks: ``admission`` (validate
+    + quantize + encode), ``queue`` (enqueue to dispatch), ``dispatch``
+    (batch assembly + program call + the device's execution), ``device``
+    (result fetch), ``total``."""
 
     rid: int
     client: str

@@ -33,7 +33,9 @@ attaches fw/bw occlusion masks + confidence to the result.
 This module is host-side only (no jax import — device work lives in the
 session); per-request telemetry lands as ``serve`` events: ``request``
 (success, with admission/queue/dispatch/device spans), ``error``,
-``reject``, and per-dispatch ``batch`` records.
+``reject``, and per-dispatch ``batch`` records. Every time on this path
+is a mark of the request's or the batch's trace (``telemetry.trace``),
+stamped once; spans and phases are differences of those marks.
 """
 
 import logging
@@ -298,7 +300,6 @@ class Scheduler:
                 telemetry.get().emit("serve", event="reject", rid=rid,
                                      client=client, reason="shutdown")
                 raise ServeRejected("shutdown")
-            req.spans["admission"] = time.perf_counter() - t0
             if not self.batcher.offer(req):
                 self._m_shed.labels(reason="queue_full").inc()
                 telemetry.get().emit(
@@ -397,6 +398,7 @@ class Scheduler:
 
     def _loop(self):
         while True:
+            t_wait = time.perf_counter()    # back to waiting for a batch
             with self._cond:
                 while True:
                     self._heartbeat = time.monotonic()
@@ -415,7 +417,7 @@ class Scheduler:
                                         max(0.0, deadline - now)))
                     self._cond.wait(timeout)
             try:
-                self._dispatch(bucket, batch)
+                self._dispatch(bucket, batch, t_wait)
             except Exception as e:  # noqa: BLE001 - loop must survive
                 logging.exception(
                     f"serve: dispatch of a {len(batch)}-request batch on "
@@ -423,7 +425,7 @@ class Scheduler:
                 for r in batch:
                     self._complete(r, error=ServeError("internal", str(e)))
 
-    def _dispatch(self, bucket, batch):
+    def _dispatch(self, bucket, batch, t_wait=None):
         t0 = time.perf_counter()
 
         # per-request decode faults: remove the poisoned request, keep the
@@ -443,14 +445,15 @@ class Scheduler:
         btrace = trace_mod.BatchTrace(
             bucket, klass,
             program=fingerprint(klass) if fingerprint else None)
-        btrace.t_start = t0
+        if t_wait is not None:
+            btrace.mark("wait", t_wait)
+        btrace.mark("dispatch", t0)
         for r in live:
-            r.spans["queue"] = t0 - r.t_enqueue
-            if r.trace is not None:
-                r.trace.mark("dispatch", t0)
-                btrace.link(r.trace)
+            r.trace.mark("dispatch", t0)
+            btrace.link(r.trace)
 
         img1, img2, fill = self.batcher.assemble(live)
+        btrace.mark("assembled")
         btrace.fill = fill
         c0 = self.session.compiles()
         sequence = live[0].sequence  # lanes are same-sequence-ness too
@@ -463,6 +466,7 @@ class Scheduler:
             flow, info = self.session.run_ladder(img1, img2, klass)
         else:
             flow, info = self.session.run(img1, img2), None
+        called, ready = self._run_marks()
         products = any(r.products for r in live)
         flow_bw = None
         if products:
@@ -475,13 +479,15 @@ class Scheduler:
                 bw_dev, _ = self.session.run_ladder(img2, img1, klass)
             else:
                 bw_dev = self.session.run(img2, img1)
-        t1 = time.perf_counter()
+            _, ready = self._run_marks()
+        btrace.mark("called", called)
+        t1 = btrace.mark("ready", ready)
         flow = self.session.fetch(flow)
         if products:
             flow_bw = self.session.fetch(bw_dev)
         if sequence:
             self._store_carry(live, bucket, state)
-        t2 = time.perf_counter()
+        t2 = btrace.mark("fetched")
 
         tele = telemetry.get()
         batch_event = dict(
@@ -498,8 +504,6 @@ class Scheduler:
         if products:
             batch_event.update(products=True)
         tele.emit("serve", event="batch", **batch_event)
-        btrace.finish()
-        tele.emit("trace", event="batch", **btrace.record())
         self._m_batches.labels(
             bucket=f"{bucket[0]}x{bucket[1]}", klass=klass).inc()
         if fill > 0:
@@ -508,11 +512,8 @@ class Scheduler:
 
         for i, r in enumerate(live):
             h, w = r.shape
-            r.spans["dispatch"] = t1 - t0
-            r.spans["device"] = t2 - t1
-            if r.trace is not None:
-                r.trace.mark("launched", t1)
-                r.trace.mark("fetched", t2)
+            r.trace.mark("launched", t1)
+            r.trace.mark("fetched", t2)
             occ = conf = None
             if r.products and flow_bw is not None:
                 from ..video.products import fw_bw_products
@@ -521,10 +522,23 @@ class Scheduler:
                                            flow_bw[i, :h, :w, :])
             self._complete(r, result=FlowResult(
                 rid=r.rid, client=r.client, bucket=bucket, shape=r.shape,
-                flow=flow[i, :h, :w, :], spans=r.spans, klass=klass,
+                flow=flow[i, :h, :w, :], spans={}, klass=klass,
                 iterations=(info["iterations"] if info else 0),
                 warm=warm_rows[i] is not None,
                 occlusion=occ, confidence=conf))
+        btrace.mark("completed")
+        tele.emit("trace", event="batch", **btrace.record())
+
+    def _run_marks(self):
+        """``(called, ready)`` of the session's last run: when the program
+        call returned and when its result was ready on the device. The
+        session stamps them around its own ``block_until_ready``; a
+        stand-in session without them has just returned from both."""
+        marks = getattr(self.session, "run_marks", None)
+        if marks is None:
+            now = time.perf_counter()
+            return now, now
+        return marks
 
     # -- video session carry -------------------------------------------------
 
@@ -591,10 +605,12 @@ class Scheduler:
                 nxt += 1
             self._release_next[req.client] = nxt
         for r, res, err in ready:
-            total = time.perf_counter() - r.t_submit
+            total = r.trace.mark("released").total()
             tele = telemetry.get()
             if err is None:
-                res.spans["total"] = total
+                # the latency spans are the trace's marks under their
+                # older names, total included
+                res.spans.update(r.trace.spans())
                 extra = ({"klass": res.klass, "iterations": res.iterations}
                          if res.klass else {})
                 tele.emit(
@@ -607,12 +623,9 @@ class Scheduler:
                     klass=r.klass,
                     bucket=f"{r.bucket[0]}x{r.bucket[1]}").inc()
                 self._m_latency.labels(klass=r.klass).observe(total)
-                if r.trace is not None:
-                    r.trace.mark("released")
-                    record = r.trace.record()
-                    tele.emit("trace", event="request", rid=r.rid,
-                              **record)
-                    self.trace_summary.add(record)
+                record = r.trace.record()
+                tele.emit("trace", event="request", rid=r.rid, **record)
+                self.trace_summary.add(record)
                 self.slo.record(r.klass, total)
                 self.slo.maybe_emit(tele)
             else:

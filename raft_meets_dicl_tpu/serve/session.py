@@ -20,6 +20,21 @@ from .. import evaluation, models, telemetry
 from ..models.input import ShapeBuckets
 
 
+def _wait(session, flow, called=None):
+    """Block until ``flow`` is ready and stamp ``session.run_marks``. The
+    dispatch span must cover device compute: the scheduler's only pipeline
+    stage is this call, there is no async overlap to preserve. ``called``
+    is when the (first) program call returned: now, unless the caller
+    stamped it earlier."""
+    import jax
+
+    if called is None:
+        called = time.perf_counter()
+    jax.block_until_ready(flow)  # graftlint: disable=host-sync -- serving dispatch-span boundary
+    session.run_marks = (called, time.perf_counter())
+    return flow
+
+
 class ServeSession:
     """Device-side half of the serving path.
 
@@ -35,6 +50,7 @@ class ServeSession:
     def __init__(self, spec, buckets, wire=None, checkpoint=None,
                  batch_size=4, mesh=None, ladder=None, video=False,
                  quant=None):
+        t_prepare = time.perf_counter()
         buckets = ShapeBuckets.from_config(buckets) \
             if not isinstance(buckets, ShapeBuckets) else buckets
         if buckets is None or not buckets.sizes:
@@ -68,6 +84,10 @@ class ServeSession:
         # (or AOT-loaded) every bucket's program — before that a request
         # would pay a cold compile the operator thinks was prepaid
         self.ready = False
+        # (called, ready) of the last run*: perf_counter when the program
+        # call returned (inputs handed over, execution enqueued) and when
+        # block_until_ready returned — the batch trace's marks
+        self.run_marks = None
         # quantized matching tier (RMD_QUANT / --quant, ops.quant): the
         # latency-critical programs — the fast class's base rung and the
         # video warm frames — run with quantized correlation volumes.
@@ -109,6 +129,8 @@ class ServeSession:
                     evaluation.make_rung_fn(
                         self.model, self.warm_iterations, mesh=mesh,
                         wire=wire, model_id=spec.id, quant=self.quant)
+        # set-up span: everything a replica builds before its warm pool
+        telemetry.emit_span("prepare", t_prepare, time.perf_counter())
 
     @classmethod
     def from_config(cls, model_cfg, buckets, **kwargs):
@@ -158,14 +180,8 @@ class ServeSession:
     def run(self, img1, img2):
         """One batch through the eval program; returns the final flow as
         a ready device array (NHWC, f32)."""
-        import jax
-
         _, flow = self.eval_fn(self.variables, img1, img2)
-        # the dispatch span must cover device compute: the scheduler's
-        # only pipeline stage is this call, there is no async overlap to
-        # preserve
-        jax.block_until_ready(flow)  # graftlint: disable=host-sync -- serving dispatch-span boundary
-        return flow
+        return _wait(self, flow)
 
     def run_ladder(self, img1, img2, klass):
         """One batch through the ladder policy for ``klass``; returns
@@ -178,17 +194,16 @@ class ServeSession:
         only the per-sample ``delta`` norm crosses to the host — the
         decision point that makes escalation recompile-free.
         """
-        import jax
-
         lad = self.ladder
         if klass == "quality":
             flow, _ = self._rung_fns[(lad.rungs[-1], False)](
                 self.variables, img1, img2)
-            jax.block_until_ready(flow)  # graftlint: disable=host-sync -- serving dispatch-span boundary
-            return flow, {"rungs": 1, "iterations": lad.rungs[-1]}
+            return _wait(self, flow), {"rungs": 1,
+                                      "iterations": lad.rungs[-1]}
 
         flow, state = self._rung_fns[(lad.rungs[0], False)](
             self.variables, img1, img2)
+        called = time.perf_counter()    # of the first rung's call
         executed, rungs = lad.rungs[0], 1
         if klass == "balanced":
             for inc in lad.increments():
@@ -200,8 +215,8 @@ class ServeSession:
                     state["flow"], state["hidden"])
                 executed += inc
                 rungs += 1
-        jax.block_until_ready(flow)  # graftlint: disable=host-sync -- serving dispatch-span boundary
-        return flow, {"rungs": rungs, "iterations": executed}
+        return _wait(self, flow, called), {"rungs": rungs,
+                                          "iterations": executed}
 
     def run_video(self, img1, img2, carry=None):
         """One video-session batch; returns ``(flow, state, info)``.
@@ -214,8 +229,6 @@ class ServeSession:
         except what the caller fetches; the scheduler stores its
         ``flow`` rows back per client.
         """
-        import jax
-
         if not self.video:
             raise RuntimeError("run_video needs a video=True session")
         warm = carry is not None
@@ -224,10 +237,8 @@ class ServeSession:
         else:
             flow, state = self._rung_fns[(self.warm_iterations, False)](
                 self.variables, img1, img2)
-        jax.block_until_ready(flow)  # graftlint: disable=host-sync -- serving dispatch-span boundary
-        return flow, state, {"rungs": 1,
-                             "iterations": self.warm_iterations,
-                             "warm": warm}
+        return _wait(self, flow), state, {
+            "rungs": 1, "iterations": self.warm_iterations, "warm": warm}
 
     def fetch(self, flow):
         """Device flow → host numpy (the per-request ``device`` span)."""
@@ -285,6 +296,7 @@ class ServeSession:
             if getattr(step, "quant", None):
                 outcome["quant"] = step.quant
             outcomes.append(outcome)
+            telemetry.get().clock()
             telemetry.get().emit("serve", event="warmup", **outcome)
 
         for h, w in self.buckets.sizes:

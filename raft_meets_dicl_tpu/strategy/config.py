@@ -3,6 +3,7 @@
 
 from pathlib import Path
 
+from .. import telemetry
 from ..utils import config
 from . import spec
 
@@ -20,8 +21,10 @@ def load_stage(path, cfg=None):
 def load(path, cfg=None):
     path = Path(path)
 
-    if cfg is None:
-        return spec.Strategy.from_config(path.parent, config.load(path))
-    if not isinstance(cfg, dict):
-        return spec.Strategy.from_config((path / cfg).parent, config.load(path / cfg))
-    return spec.Strategy.from_config(path, cfg)
+    with telemetry.interval("strategy_load"):
+        if cfg is None:
+            return spec.Strategy.from_config(path.parent, config.load(path))
+        if not isinstance(cfg, dict):
+            return spec.Strategy.from_config((path / cfg).parent,
+                                             config.load(path / cfg))
+        return spec.Strategy.from_config(path, cfg)

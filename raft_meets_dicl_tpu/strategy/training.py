@@ -91,7 +91,7 @@ class NonFinitePolicy:
         }
 
 
-def _device_prefetch(samples, put, depth=2, tele=None):
+def _device_prefetch(samples, put, depth=2):
     """Double-buffered host→device prefetch: pipeline batches onto the
     device ahead of consumption.
 
@@ -99,25 +99,25 @@ def _device_prefetch(samples, put, depth=2, tele=None):
     otherwise serializes with compute. A background
     thread loads and ``put``s up to ``depth`` batches ahead (default 2:
     batch N+1 transfers while step N executes); the main loop receives
-    (host_batch, device_batch, meta) with transfers already in flight.
-    Loader exceptions re-raise at the consumption point.
+    (host_batch, device_batch, meta, put_span) with transfers already in
+    flight. Loader exceptions re-raise at the consumption point.
 
     ``RMD_PREFETCH=0`` swaps in :func:`_sync_transfer` (identical batch
     stream, transfer left on the critical path — the A/B baseline);
     ``RMD_PREFETCH_DEPTH`` tunes the buffer count.
 
-    ``tele`` gets two phase streams: ``device_put`` (the worker's
-    transfer-initiation time, attributed up to ``depth`` batches ahead of
-    the consuming step — the aggregate breakdown is what matters) and
-    ``data_wait`` (time the consumer blocks on the queue, i.e. the input
-    pipeline failing to keep ahead of the device).
+    ``put_span`` is the ``perf_counter`` ``(t0, t1)`` of the worker's
+    ``put`` (wire encode + transfer initiation) of *this* batch: it rides
+    through the queue with its batch and lands in the ``put`` field of the
+    step that consumes it, up to ``depth`` steps later. The time the
+    consumer blocks on the queue (the input pipeline failing to keep
+    ahead of the device) is the step trace's ``start`` → ``data``.
     """
     import queue
     import threading
 
     q = queue.Queue(maxsize=depth)
     _END = object()
-    tele = tele if tele is not None else telemetry.get()
 
     def worker():
         try:
@@ -125,47 +125,35 @@ def _device_prefetch(samples, put, depth=2, tele=None):
                 host = (img1, img2, flow, valid)
                 t0 = time.perf_counter()
                 dev = put(host)
-                tele.add_phase("device_put", time.perf_counter() - t0)
-                q.put((host, dev, meta))
+                q.put((host, dev, meta, (t0, time.perf_counter())))
         except BaseException as e:  # noqa: BLE001 - re-raised by consumer
-            q.put((_END, e, None))
+            q.put((_END, e, None, None))
             return
-        q.put((_END, None, None))
+        q.put((_END, None, None, None))
 
     t = threading.Thread(target=worker, daemon=True)
     t.start()
     while True:
-        t0 = time.perf_counter()
-        host, dev, meta = q.get()
-        tele.add_phase("data_wait", time.perf_counter() - t0)
+        host, dev, meta, put_span = q.get()
         if host is _END:
             if dev is not None:
                 raise dev
             return
-        yield host, dev, meta
+        yield host, dev, meta, put_span
 
 
-def _sync_transfer(samples, put, tele=None):
-    """RMD_PREFETCH=0: the same (host, dev, meta) stream as
+def _sync_transfer(samples, put):
+    """RMD_PREFETCH=0: the same (host, dev, meta, put_span) stream as
     :func:`_device_prefetch` with the transfer kept synchronous on the
     critical path — the bit-identical A/B baseline for the prefetch
     overlap, and an escape hatch for backends whose background-thread
-    ``device_put`` misbehaves. The ``device_put`` phase then lands on
-    the consuming step's wall time instead of overlapping it."""
-    tele = tele if tele is not None else telemetry.get()
-    it = iter(samples)
-    while True:
-        t0 = time.perf_counter()
-        try:
-            item = next(it)
-        except StopIteration:
-            return
-        tele.add_phase("data_wait", time.perf_counter() - t0)
-        img1, img2, flow, valid, meta = item
+    ``device_put`` misbehaves. The put then lies inside the consuming
+    step's ``start`` → ``data`` and counts towards its wall time."""
+    for img1, img2, flow, valid, meta in samples:
         host = (img1, img2, flow, valid)
-        with tele.span("device_put"):
-            dev = put(host)
-        yield host, dev, meta
+        t0 = time.perf_counter()
+        dev = put(host)
+        yield host, dev, meta, (t0, time.perf_counter())
 
 
 class _StepResult:
@@ -292,6 +280,7 @@ class TrainingContext:
         # micro-batch instead of desyncing host and device counters
         self._accum = 0
         self._in_step = False
+        self._step_phases = {}       # phases of the open step's micro-batches
 
         # per-run / per-stage state
         self.variables = None       # model variables when no stage is active
@@ -507,6 +496,9 @@ class TrainingContext:
     def run_stage(self, log, stage: Stage, start_epoch=0, checkpoint=None):
         assert 0 <= start_epoch < stage.data.epochs
 
+        # set-up spans: ``prepare`` (here to stage_start) and its
+        # children ``data``, ``state``, ``step_build``
+        t_prepare = time.perf_counter()
         self.current_stage = stage
         self.prepare_stage(log, stage)
 
@@ -571,6 +563,9 @@ class TrainingContext:
                 f"{stage.data.source.description()}"
             )
 
+        t_data = time.perf_counter()
+        telemetry.emit_span("data", t_prepare, t_data)
+
         # optimizer (fresh per stage, like the reference)
         log.info("setting up optimizer")
         self.tx, self.base_lr = stage.optimizer.build(stage.gradient)
@@ -628,6 +623,9 @@ class TrainingContext:
         # baked into the compiled program
         self.model_adapter.on_stage(stage, **stage.model_on_stage_args)
 
+        t_state = time.perf_counter()
+        telemetry.emit_span("state", t_data, t_state)
+
         # gradients enter the step's aux output only if observability asks
         # (gradient metrics/hooks) — they cost a params-sized live buffer
         with_grads = bool(getattr(self.inspector, "wants_gradients", False))
@@ -648,9 +646,11 @@ class TrainingContext:
             key=self._train_step_key(stage, with_grads),
             augment=self.augment,
         )
+        telemetry.emit_span("step_build", t_state, time.perf_counter())
 
         self._accum = 0
         self._in_step = False
+        self._step_phases = {}
         self._pending_finite = None
         # non-finite recovery bookkeeping: the device counter restarts at
         # zero with the fresh TrainState, host mirrors follow
@@ -672,6 +672,8 @@ class TrainingContext:
         self._pending_scalars = None
 
         self.inspector.on_stage_start(log, self, stage)
+        telemetry.emit_span("prepare", t_prepare, time.perf_counter())
+        telemetry.get().clock()
         telemetry.get().emit(
             "stage_start", stage=stage.index, step=self.step,
             id=stage.id, name=stage.name, epochs=stage.data.epochs,
@@ -809,11 +811,12 @@ class TrainingContext:
         # never sits on the step critical path. RMD_PREFETCH=0 restores
         # the synchronous put (bit-identical results, for A/B and as an
         # escape hatch); RMD_PREFETCH_DEPTH tunes how far ahead.
-        if not utils.env.get_bool("RMD_PREFETCH"):
-            batches = _sync_transfer(samples, put, tele=tele)
+        prefetch = utils.env.get_bool("RMD_PREFETCH")
+        if not prefetch:
+            batches = _sync_transfer(samples, put)
         else:
             depth = max(1, utils.env.get_int("RMD_PREFETCH_DEPTH"))
-            batches = _device_prefetch(samples, put, depth=depth, tele=tele)
+            batches = _device_prefetch(samples, put, depth=depth)
 
         it = enumerate(batches)
         while True:
@@ -824,7 +827,8 @@ class TrainingContext:
             nxt = next(it, None)
             if nxt is None:
                 break
-            i, (host, dev, meta) = nxt
+            i, (host, dev, meta, strace.put) = nxt
+            strace.put_inline = not prefetch
             strace.mark("data")
 
             log_ = log.new(f"step {self.step}", sep=", ")
@@ -1082,23 +1086,22 @@ class TrainingContext:
         self.inspector.on_batch_start(log, self, stage, epoch, i, img1, img2,
                                       flow, valid, meta)
 
-        # host prep done; the transfer itself was staged by the prefetch
-        # worker (its cost is the worker-attributed device_put phase), so
-        # the consumer-side device_put mark lands immediately
+        # host prep done; the transfer itself was staged before the pull
+        # returned (its interval is the trace's ``put``), so the
+        # consumer-side put mark lands immediately
         strace.mark("prep")
         strace.mark("put")
 
         tele = telemetry.get()
-        with tele.span("dispatch"):
-            if self.augment is not None:
-                # device augmentation: per-sample ids + the epoch scalar
-                # key the on-device draws; ids derive from the metadata
-                # so they are independent of shuffle order and resume
-                ids = device_augment.sample_id_array(meta)
-                self.state, aux = self.step_fn(
-                    self.state, lr, *dev, ids, np.int32(epoch))
-            else:
-                self.state, aux = self.step_fn(self.state, lr, *dev)
+        if self.augment is not None:
+            # device augmentation: per-sample ids + the epoch scalar
+            # key the on-device draws; ids derive from the metadata
+            # so they are independent of shuffle order and resume
+            ids = device_augment.sample_id_array(meta)
+            self.state, aux = self.step_fn(
+                self.state, lr, *dev, ids, np.int32(epoch))
+        else:
+            self.state, aux = self.step_fn(self.state, lr, *dev)
         self._dispatched += 1
         strace.mark("dispatched")
 
@@ -1119,9 +1122,10 @@ class TrainingContext:
                                     aux.get("nonfinite_count"))
             if (i + 1) % self._finite_every == 0:
                 prev, self._pending_finite = self._pending_finite, None
-                t0 = time.perf_counter()
+                # the drain runs from the 'dispatched' mark: no second
+                # stamp in front of the fetch
                 finite = bool(prev[0])
-                drain = time.perf_counter() - t0
+                drain = time.perf_counter() - strace.marks["dispatched"]
                 self._sample_scalars()
                 self._emit_device_sync(tele, drain)
                 self._resolve_finite(
@@ -1133,9 +1137,8 @@ class TrainingContext:
             # validation disabled: the finite fetch (our usual free sync
             # point) never happens, so sample the pipeline drain
             # explicitly at the same amortized cadence
-            t0 = time.perf_counter()
             jax.block_until_ready(aux["loss"])
-            drain = time.perf_counter() - t0
+            drain = time.perf_counter() - strace.marks["dispatched"]
             self._sample_scalars()
             self._emit_device_sync(tele, drain)
         # device phase = how long the fetch above blocked (zero on the
@@ -1148,34 +1151,31 @@ class TrainingContext:
         # host-side metrics compare against this process's local targets —
         # reassemble the local slice from the addressable shards (ordered
         # by their global offset; each process owns one contiguous stripe)
-        with tele.span("host"):
-            if self.mesh is not None and jax.process_count() > 1:
-                # dedupe by batch offset: on a 2-D mesh a batch range can
-                # be materialized on more than one local device (model
-                # axis), and each copy must contribute exactly once
-                parts = {}
-                for s in aux["final"].addressable_shards:
-                    parts.setdefault(s.index[0].start or 0,
-                                     np.asarray(s.data))
-                aux = aux | {"final": np.concatenate(
-                    [parts[k] for k in sorted(parts)])}
+        if self.mesh is not None and jax.process_count() > 1:
+            # dedupe by batch offset: on a 2-D mesh a batch range can
+            # be materialized on more than one local device (model
+            # axis), and each copy must contribute exactly once
+            parts = {}
+            for s in aux["final"].addressable_shards:
+                parts.setdefault(s.index[0].start or 0,
+                                 np.asarray(s.data))
+            aux = aux | {"final": np.concatenate(
+                [parts[k] for k in sorted(parts)])}
 
-            result = _StepResult(aux)
+        result = _StepResult(aux)
 
-            self.inspector.on_batch(log, self, stage, epoch, i, img1, img2,
-                                    flow, valid, meta, result, loss)
+        self.inspector.on_batch(log, self, stage, epoch, i, img1, img2,
+                                flow, valid, meta, result, loss)
 
         self._accum += 1
-        if self._accum % accumulate == 0:
+        boundary = self._accum % accumulate == 0
+        step = self.step
+        if boundary:
             # the optimizer update itself happened inside the jitted step
             # (optax.MultiSteps applies on every accumulate-th call)
             for s in self.lr_sched_inst:
                 s.step()
 
-            # step event precedes on_step_end so the inspector can mirror
-            # this step's phases to the TB scalars under the same step
-            tele.step_event(self.step, stage=stage.index, epoch=epoch,
-                            batch=stage.data.batch_size)
             self.inspector.on_step_end(log, self, stage, epoch, i)
             self.step += 1
             self.steps_completed += 1
@@ -1186,6 +1186,19 @@ class TrainingContext:
         strace.mark("done")
         rec = self.steptraces.add(strace)
         blackbox.get().record_step(rec)
+        if tele.enabled:
+            for name, seconds in strace.step_phases().items():
+                self._step_phases[name] = (self._step_phases.get(name, 0.0)
+                                           + seconds)
+        if boundary:
+            # the step's record: the marks of its (last) micro-batch, the
+            # phases of all of them. Emitted once the step is closed, so
+            # the inspector's callbacks lie inside ``synced`` → ``done``
+            phases, self._step_phases = self._step_phases, {}
+            fields = {"put": rec["put"]} if "put" in rec else {}
+            tele.step_event(step, phases=phases, marks=rec["marks"],
+                            stage=stage.index, epoch=epoch,
+                            batch=stage.data.batch_size, **fields)
         if tele.enabled and (i + 1) % self._finite_every == 0:
             ev = self.steptraces.event(self.step)
             if ev is not None:

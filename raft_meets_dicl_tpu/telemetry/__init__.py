@@ -1,4 +1,4 @@
-"""Unified telemetry: span timers, JSONL event sink, run reports.
+"""Unified telemetry: JSONL event sink, marks on one clock, run reports.
 
 See ``core`` for the sink/schema and ``report`` for rendering. Typical
 producer usage::
@@ -7,9 +7,11 @@ producer usage::
 
     tele = telemetry.activate(telemetry.create(run_dir / "events.jsonl"))
     tele.emit("run_start", dir=str(run_dir))
-    with tele.span("dispatch"):
-        state, aux = step_fn(state, lr, *batch)
-    tele.step_event(step, stage=0, epoch=0)
+    with telemetry.interval("model_load"):      # a ``span`` event
+        spec = models.load(cfg)
+    strace = telemetry.steptrace.StepTrace(step).mark("start")
+    ...                                         # one mark per boundary
+    tele.step_event(step, phases=strace.phases(), marks=strace.marks)
 
 ``RMD_TELEMETRY=0`` turns every call into a no-op (``create`` returns the
 null sink and ``activate`` skips the jax.monitoring hookup).
@@ -25,6 +27,7 @@ from . import (
     slo,
     steptrace,
     trace,
+    witness,
 )
 from .core import (
     SCHEMA,
@@ -37,10 +40,12 @@ from .core import (
     activate,
     create,
     deactivate,
+    emit_span,
     enabled,
     get,
     install_listeners,
     instrument_jit,
+    interval,
     jit_label,
     memory_snapshot,
     validate_event,
@@ -48,10 +53,10 @@ from .core import (
 
 __all__ = [
     "blackbox", "core", "goodput", "metrics", "report", "sidecar",
-    "slo", "steptrace", "trace",
+    "slo", "steptrace", "trace", "witness",
     "SCHEMA", "SCHEMA_MINOR", "SCHEMA_VERSION",
     "NewerSchema", "NullTelemetry", "Telemetry", "UnknownKind",
-    "activate", "create", "deactivate", "enabled", "get",
-    "install_listeners", "instrument_jit", "jit_label",
+    "activate", "create", "deactivate", "emit_span", "enabled", "get",
+    "install_listeners", "instrument_jit", "interval", "jit_label",
     "memory_snapshot", "validate_event",
 ]

@@ -10,8 +10,9 @@ and stage/epoch/checkpoint boundaries.
 
 Design constraints, in order:
 
-1. **The hot path must stay hot.** Spans are two ``perf_counter`` calls
-   and a dict update; events buffer in memory and flush at boundaries
+1. **The hot path must stay hot.** A step or a batch stamps each mark
+   once (``steptrace``, ``trace``) and its event carries the marks;
+   events buffer in memory and flush at boundaries
    (epoch/stage/run) or every ``_FLUSH_EVERY`` records; device step time
    is sampled by piggybacking on the amortized finiteness fetch instead
    of a per-step ``block_until_ready`` (which would serialize the async
@@ -33,6 +34,7 @@ import time
 
 from . import blackbox as _blackbox
 from . import goodput as _goodput
+from . import witness
 
 SCHEMA_VERSION = 1
 
@@ -41,7 +43,7 @@ SCHEMA_VERSION = 1
 # readers keep working); a reader seeing ``v`` with the same major but a
 # larger fractional minor (e.g. 1.2 from a newer producer) should skip
 # the record, not reject the file — see :class:`NewerSchema`.
-SCHEMA_MINOR = 6
+SCHEMA_MINOR = 7
 
 # kind -> required payload fields (beyond the {v, t, kind} envelope).
 # Extra fields are allowed everywhere: the schema pins the floor a
@@ -163,6 +165,19 @@ SCHEMA = {
     # pinned prof-budget.json band (the report flags drift=true rows as
     # anomalies)
     "profile": {"program", "seconds"},
+    # one timeline (PR 24). Every mark in the stream (``step.marks``,
+    # ``step.put``, ``trace`` events' ``marks``, ``span.t0/t1``) is a
+    # ``time.perf_counter()`` reading of this process; ``clock`` pairs one
+    # such reading with ``time.time_ns()`` taken back to back, so marks map
+    # to Unix time and so onto a profiler capture, whose events are
+    # offsets from its ``profile_start_time``. Emitted at ``activate()``,
+    # every ``stage_start`` and every serve ``warmup``
+    "clock": {"perf_counter", "time_ns"},
+    # an interval that is neither a step nor a request: set-up (``boot``,
+    # ``backend_init``, ``model_load``, ``strategy_load``, ``prepare`` and
+    # its children ``data``/``state``/``step_build``) and the stall
+    # witness (``gc``, ``stall``; telemetry.witness). Optional ``thread``
+    "span": {"name", "t0", "t1"},
 }
 
 
@@ -236,16 +251,13 @@ class NullTelemetry:
     def emit(self, kind, **fields):
         pass
 
-    def span(self, name):
-        return contextlib.nullcontext()
-
-    def add_phase(self, name, seconds):
+    def clock(self):
         pass
 
     def add_count(self, name, value):
         pass
 
-    def step_event(self, step, **fields):
+    def step_event(self, step, phases=None, **fields):
         pass
 
     def counts(self):
@@ -262,7 +274,7 @@ class NullTelemetry:
 
 
 class Telemetry:
-    """JSONL event sink with a span/phase API.
+    """JSONL event sink.
 
     ``path=None`` keeps events in memory only (``self.events``) — used by
     bench.py and tests; a path appends JSON lines to that file.
@@ -294,7 +306,6 @@ class Telemetry:
         self._fd = None
         self._size = None
         self._max_bytes = int(env.get_float("RMD_TELEMETRY_MAX_MB") * 2 ** 20)
-        self._phases = {}
         self._step_counters = {}
         self._counts = {}
         self._dropped = 0
@@ -422,38 +433,28 @@ class Telemetry:
         with self._lock:
             return self._dropped
 
-    # -- phases / steps ----------------------------------------------------
+    # -- clock / steps -----------------------------------------------------
 
-    @contextlib.contextmanager
-    def span(self, name):
-        """Accumulate wall time under ``name`` for the current step."""
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.add_phase(name, time.perf_counter() - t0)
-
-    def add_phase(self, name, seconds):
-        """Externally-timed phase contribution (e.g. from the prefetch
-        worker thread — attribution runs up to ``depth`` batches ahead,
-        the aggregate breakdown is what matters)."""
-        with self._lock:
-            self._phases[name] = self._phases.get(name, 0.0) + seconds
+    def clock(self):
+        """One ``clock`` event: ``perf_counter`` and Unix ns, back to back."""
+        return self.emit("clock", perf_counter=time.perf_counter(),
+                         time_ns=time.time_ns())
 
     def add_count(self, name, value):
         """Per-step scalar counter (e.g. ``wire_bytes``, the host→device
-        transfer volume): accumulates like a phase and drains into the
-        next ``step`` event under ``counters``."""
+        transfer volume): accumulates and drains into the next ``step``
+        event under ``counters``."""
         with self._lock:
             self._step_counters[name] = self._step_counters.get(name, 0) + value
 
-    def step_event(self, step, **fields):
-        """Close out one optimizer step: drain accumulated phases and
-        counters, update the throughput EMA, emit the ``step`` record."""
+    def step_event(self, step, phases=None, **fields):
+        """Close out one optimizer step: drain the counters, update the
+        throughput EMA, emit the ``step`` record. ``phases`` are the
+        caller's, computed from the step's marks (``steptrace``); the
+        marks themselves ride in ``fields`` (``marks``, ``put``)."""
         now = time.perf_counter()
+        phases = dict(phases or {})
         with self._lock:
-            phases = self._phases
-            self._phases = {}
             counters = self._step_counters
             self._step_counters = {}
         if self._last_step_t is None:
@@ -493,20 +494,90 @@ def get():
 
 def activate(sink):
     """Install ``sink`` as the process-wide telemetry target and hook the
-    jax.monitoring compile/cache events into it. Returns the sink."""
-    global _active
+    jax.monitoring compile/cache events into it. An enabled sink also gets
+    a ``clock`` event, the spans taken before any sink existed (``boot``
+    first), and the stall witness. Returns the sink."""
+    global _active, _hold_early
+    now = time.perf_counter()
     _active = sink
+    early, _hold_early = list(_early), False
+    _early.clear()
     if sink.enabled:
         _install_listeners()
+        sink.clock()
+        for fields in early:
+            sink.emit("span", **fields)
+        _emit_boot(now)
+        witness.start()
     return sink
 
 
 def deactivate():
     """Swap back to the null sink (closing the old one)."""
     global _active
+    witness.stop()
     old, _active = _active, NullTelemetry()
     old.close()
     return old
+
+
+# -- spans: intervals that are neither a step nor a request -----------------
+
+# spans taken before the process's first ``activate()`` (the device
+# selection, a model loaded by a caller that activates later) wait here
+# and are delivered by it; afterwards a span without an enabled sink is
+# dropped like any other event
+_early = collections.deque(maxlen=64)
+_hold_early = True
+_boot_done = False
+
+
+def process_start():
+    """This process's start on the ``perf_counter`` clock (from
+    ``/proc/self/stat`` and the boot time of ``/proc/stat``), or None."""
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/stat") as f:
+            btime = next(int(line.split()[1]) for line in f
+                         if line.startswith("btime"))
+    except (OSError, ValueError, IndexError, StopIteration):
+        return None
+    age = time.time() - (btime + ticks / os.sysconf("SC_CLK_TCK"))
+    return time.perf_counter() - max(0.0, age)
+
+
+def emit_span(name, t0, t1, **fields):
+    """One ``span`` event: ``[t0, t1]`` on ``perf_counter``."""
+    _emit_boot(t0)
+    fields = dict(fields, name=name, t0=round(t0, 6), t1=round(t1, 6))
+    if _active.enabled:
+        _active.emit("span", **fields)
+    elif _hold_early:
+        _early.append(fields)
+
+
+def _emit_boot(until):
+    """Once per process, the span ``boot``: process start to the first
+    thing the program marks (its first span's start or its first
+    ``activate()``): interpreter start-up and imports."""
+    global _boot_done
+    if _boot_done:
+        return
+    _boot_done = True
+    start = process_start()
+    if start is not None:
+        emit_span("boot", start, until)
+
+
+@contextlib.contextmanager
+def interval(name, **fields):
+    """Emit the ``span`` ``name`` around the block."""
+    t0 = time.perf_counter()
+    try:
+        yield
+    finally:
+        emit_span(name, t0, time.perf_counter(), **fields)
 
 
 def create(path=None, nonblocking=False):

@@ -10,6 +10,7 @@ milliseconds go, and did anything anomalous happen?*
 import json
 import logging
 
+from . import trace as _trace
 from .core import NewerSchema, UnknownKind, validate_event
 
 # a compile this many optimizer steps after its stage started is a
@@ -689,6 +690,40 @@ def trace_stats(events, decile=0.9):
     }
 
 
+def timeline_stats(events):
+    """What lies on the one timeline (``clock``/``span`` events and the
+    marks of ``step`` and ``trace`` events; all optional, files older than
+    schema 1.7 have none): spans by name (count, total and longest, in
+    seconds), the drift between the run's ``clock`` events, and the mean
+    of each interval between the dispatch thread's batch marks."""
+    spans = {}
+    for e in events:
+        if e["kind"] == "span":
+            s = spans.setdefault(e["name"], {"count": 0, "total": 0.0,
+                                             "max": 0.0})
+            s["count"] += 1
+            s["total"] += e["t1"] - e["t0"]
+            s["max"] = max(s["max"], e["t1"] - e["t0"])
+    offsets = [e["time_ns"] - e["perf_counter"] * 1e9 for e in events
+               if e["kind"] == "clock"]
+    batches = [e["marks"] for e in events if e["kind"] == "trace"
+               and e.get("event") == "batch" and e.get("marks")]
+    legs = {}
+    for m in batches:
+        hit = [k for k in _trace.BATCH_MARKS if k in m]
+        for a, b in zip(hit, hit[1:]):
+            legs.setdefault(f"{a}->{b}", []).append(m[b] - m[a])
+    if not (spans or offsets or batches):
+        return None
+    return {
+        "spans": spans, "clocks": len(offsets),
+        "drift_us": (max(offsets) - min(offsets)) / 1e3 if offsets else None,
+        "marked_steps": sum(1 for e in events
+                            if e["kind"] == "step" and e.get("marks")),
+        "batch_legs_s": {k: sum(v) / len(v) for k, v in legs.items()},
+    }
+
+
 def sharding_stats(events):
     """Per-stage SPMD placement summaries from ``sharding`` events: mesh
     shape and the per-chip vs. replicated byte accounting the partitioner
@@ -992,6 +1027,26 @@ def render(events, errors=(), warmup_steps=DEFAULT_WARMUP_STEPS,
             f"slowest decile ({tail['count']} requests, mean "
             f"{tail['total_s'] * 1e3:.1f} ms): {breakdown or '-'} "
             f"[dominant: {tail['dominant'] or '-'}]")
+
+    timeline = timeline_stats(events)
+    if timeline:
+        lines.append("")
+        lines.append("== timeline ==")
+        drift = ("-" if timeline["drift_us"] is None
+                 else f"{timeline['drift_us']:.1f} us")
+        lines.append(
+            f"clock events: {timeline['clocks']} (perf_counter to Unix "
+            f"time; drift over the run {drift}); steps with marks: "
+            f"{timeline['marked_steps']}")
+        for name, sp in sorted(timeline["spans"].items(),
+                               key=lambda kv: -kv[1]["total"]):
+            lines.append(
+                f"  span {name:<14} {sp['count']:>4d} x, total "
+                f"{sp['total']:.3f} s, longest {sp['max']:.3f} s")
+        if timeline["batch_legs_s"]:
+            lines.append("dispatch thread, mean per batch: " + ", ".join(
+                f"{k} {v * 1e3:.2f} ms"
+                for k, v in timeline["batch_legs_s"].items()))
 
     slo = slo_stats(events)
     if slo:

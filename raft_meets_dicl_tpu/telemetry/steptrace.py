@@ -12,15 +12,18 @@ that one clock, so they telescope *exactly* to the step total — no
 residual, no second clock, and crucially **no host↔device sync**: the
 ``device`` phase is simply how long the loop blocked on the amortized
 finite-check fetch (zero on the steps in between, where ``synced``
-lands immediately after ``dispatched``).
+lands immediately after ``dispatched``). The marks are the record: the
+``step`` event carries them absolute (``marks``), next to ``put``, the
+``[t0, t1]`` of the wire encode + ``device_put`` of the batch this step
+consumed, so a step can be laid on a timeline beside a device trace.
 
 ========== ============================================================
 phase      wall time between
 ========== ============================================================
 data_wait  start → data: blocked on the (prefetched) input queue
 host_prep  data → prep: host-side batch prep, schedules, callbacks
-device_put prep → put: consumer-side transfer cost (≈0 when the
-           prefetch worker already staged the batch)
+device_put prep → put: consumer-side transfer cost (≈0: the batch was
+           staged before the pull returned; its cost is ``put``)
 dispatch   put → dispatched: the async ``step_fn`` dispatch call
 device     dispatched → synced: blocked on the finite-check fetch
            (only at the amortized cadence)
@@ -50,11 +53,15 @@ STARVED_SHARE = 0.5
 class StepTrace:
     """Timestamps of one training step on a single perf_counter clock."""
 
-    __slots__ = ("step", "marks")
+    __slots__ = ("step", "marks", "put", "put_inline")
 
     def __init__(self, step=None):
         self.step = step
         self.marks = {}
+        self.put = None     # (t0, t1) of this batch's put, same clock
+        # the put ran on the loop's own thread, inside the pull
+        # (RMD_PREFETCH=0): its time is then part of the step total
+        self.put_inline = False
 
     def mark(self, name, t=None):
         if name not in MARKS:
@@ -82,13 +89,33 @@ class StepTrace:
             out[PHASES[MARKS.index(m0)]] = t1 - t0
         return out
 
+    def step_phases(self):
+        """The phases as the ``step`` event reports them: ``device_put``
+        is the batch's ``put`` (wire encode + transfer initiation). On the
+        prefetch worker's thread it lies outside the step, the one phase
+        that is not part of the telescoping sum; with ``RMD_PREFETCH=0``
+        it ran inside the pull and is taken out of ``data_wait``, so the
+        sum stays the step's total."""
+        out = self.phases()
+        if self.put is not None:
+            p0, p1 = self.put
+            if self.put_inline and "data_wait" in out:
+                out["data_wait"] -= p1 - p0
+            out["device_put"] = out.get("device_put", 0.0) + (p1 - p0)
+        return out
+
     def record(self):
         phases = self.phases()
-        return {
+        rec = {
             "step": self.step,
             "phases": {k: round(v, 6) for k, v in phases.items()},
             "total": round(self.total() or sum(phases.values()), 6),
+            "marks": {m: round(self.marks[m], 6)
+                      for m in MARKS if m in self.marks},
         }
+        if self.put is not None:
+            rec["put"] = [round(self.put[0], 6), round(self.put[1], 6)]
+        return rec
 
 
 def _percentile(sorted_vals, q):

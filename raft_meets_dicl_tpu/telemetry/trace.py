@@ -3,28 +3,44 @@
 Every admitted request carries a :class:`RequestTrace` from admission to
 release; every dispatch gets a :class:`BatchTrace` linking the batch
 span to its member request spans (and to the compiled program that ran
-it). The marks telescope into an **exact** critical-path decomposition:
+it). A request's marks telescope into an **exact** critical-path
+decomposition:
 
 ====================  ===================================================
-phase                 interval
+phase                 interval (request marks)
 ====================  ===================================================
 ``admission``         submit → enqueue (validate + quantize + wire-encode)
-``queue``             enqueue → dispatch pull (batcher lane wait)
-``batch_form``        dispatch pull → program launched (fan-in: decode
-                      faults culled, pad-tile assemble, ladder pick, run)
-``device``            program launched → result fetched (device + D2H)
-``respond``           fetched → ticket released (crop + sticky-order
-                      release)
+``queue``             enqueue → dispatch (batcher lane wait)
+``batch_form``        dispatch → launched. ``launched`` is the batch's
+                      ``ready`` mark: the session's run call has returned
+                      *and* blocked until the result was ready on the
+                      device. So this phase holds fan-in (decode faults
+                      culled, pad-tile assemble, ladder pick), the
+                      program call and the device's execution
+``device``            launched → fetched: the result's device-to-host
+                      fetch only (the execution is in ``batch_form``)
+``respond``           fetched → released (crop + sticky-order release)
 ====================  ===================================================
 
 The phases are differences of one monotonic clock at consecutive marks,
 so ``sum(phases) == total`` to float precision — a tail request always
-attributes its full latency, nothing hides between phases. Completed
-requests feed a bounded :class:`TraceSummary` whose :meth:`snapshot`
-gives per-class p50/p99 and the slowest-decile phase breakdown the
-``/statusz`` endpoint and BENCH_SERVE report serve live.
+attributes its full latency, nothing hides between phases. The dispatch
+thread's own marks are the batch's (``BATCH_MARKS``):
 
-Host-side only: two ``perf_counter`` calls per mark, no jax.
+``wait`` (the loop went back to ``batcher.take``) → ``dispatch`` (a batch
+was pulled) → ``assembled`` → ``called`` (the program call returned:
+inputs handed over, execution enqueued) → ``ready``
+(``block_until_ready`` returned) → ``fetched`` → ``completed`` (the last
+member's ticket was released).
+
+Both events carry their marks absolute (``perf_counter``), so batches
+and requests can be laid on a timeline beside a device trace (the
+``clock`` event maps the clock to Unix time). Completed requests feed a
+bounded :class:`TraceSummary` whose :meth:`snapshot` gives per-class
+p50/p99 and the slowest-decile phase breakdown the ``/statusz`` endpoint
+and BENCH_SERVE report serve live.
+
+Host-side only: one ``perf_counter`` call per mark, no jax.
 """
 
 import itertools
@@ -35,6 +51,14 @@ from collections import deque
 # mark order defines the telescoping phase decomposition
 MARKS = ("submit", "enqueue", "dispatch", "launched", "fetched", "released")
 PHASES = ("admission", "queue", "batch_form", "device", "respond")
+# the dispatch thread's marks for one batch, in order
+BATCH_MARKS = ("wait", "dispatch", "assembled", "called", "ready", "fetched",
+               "completed")
+# the latency spans a FlowResult and the ``serve``/``request`` event carry,
+# as (name, from mark, to mark): the same marks under their older names
+SPANS = (("admission", "submit", "enqueue"), ("queue", "enqueue", "dispatch"),
+         ("dispatch", "dispatch", "launched"),
+         ("device", "launched", "fetched"), ("total", "submit", "released"))
 
 _req_ids = itertools.count(1)
 _batch_ids = itertools.count(1)
@@ -75,6 +99,13 @@ class RequestTrace:
             return self.marks["released"] - self.marks["submit"]
         return None
 
+    def spans(self):
+        """``SPANS`` between the marks hit so far: what ``FlowResult.spans``
+        and the ``serve``/``request`` event report."""
+        return {name: self.marks[m1] - self.marks[m0]
+                for name, m0, m1 in SPANS
+                if m0 in self.marks and m1 in self.marks}
+
     def record(self):
         """The completed-request record ``slo``/``TraceSummary``/the
         ``trace`` event all share."""
@@ -87,15 +118,18 @@ class RequestTrace:
                        if self.bucket else None),
             "phases": {k: round(v, 6) for k, v in phases.items()},
             "total": round(self.total() or sum(phases.values()), 6),
+            "marks": {m: round(self.marks[m], 6)
+                      for m in MARKS if m in self.marks},
         }
 
 
 class BatchTrace:
     """One dispatch span: which requests fanned in, on which compiled
-    program (bucket/class/fingerprint)."""
+    program (bucket/class/fingerprint), and the dispatch thread's marks
+    (``BATCH_MARKS``) from going back to wait to the last release."""
 
     __slots__ = ("batch_id", "bucket", "klass", "size", "fill",
-                 "program", "members", "t_start", "t_end")
+                 "program", "members", "marks")
 
     def __init__(self, bucket, klass, program=None):
         self.batch_id = f"batch-{next(_batch_ids):06d}"
@@ -105,8 +139,14 @@ class BatchTrace:
         self.size = 0
         self.fill = 0
         self.members = []
-        self.t_start = time.perf_counter()
-        self.t_end = None
+        self.marks = {}
+
+    def mark(self, name, t=None):
+        if name not in BATCH_MARKS:
+            raise ValueError(f"unknown batch mark {name!r} "
+                             f"(one of {'/'.join(BATCH_MARKS)})")
+        self.marks[name] = time.perf_counter() if t is None else t
+        return self.marks[name]
 
     def link(self, request_trace):
         request_trace.batch_id = self.batch_id
@@ -114,11 +154,8 @@ class BatchTrace:
         self.size = len(self.members)
         return request_trace
 
-    def finish(self):
-        self.t_end = time.perf_counter()
-        return self
-
     def record(self):
+        hit = [self.marks[m] for m in BATCH_MARKS[1:] if m in self.marks]
         return {
             "batch": self.batch_id,
             "bucket": f"{self.bucket[0]}x{self.bucket[1]}",
@@ -127,8 +164,10 @@ class BatchTrace:
             "fill": self.fill,
             "program": self.program,
             "members": list(self.members),
-            "seconds": round(
-                (self.t_end or time.perf_counter()) - self.t_start, 6),
+            # dispatch to the last mark hit
+            "seconds": round(hit[-1] - hit[0], 6) if hit else 0.0,
+            "marks": {m: round(self.marks[m], 6)
+                      for m in BATCH_MARKS if m in self.marks},
         }
 
 

@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 
 from raft_meets_dicl_tpu import telemetry
-from raft_meets_dicl_tpu.telemetry import report
+from raft_meets_dicl_tpu.telemetry import core, report, steptrace, witness
 
 
 def _base(kind, **fields):
@@ -61,10 +61,8 @@ def test_sink_writes_schema_valid_jsonl(tmp_path):
     sink = telemetry.Telemetry(path)
 
     sink.emit("stage_start", stage=0, step=0)
-    with sink.span("dispatch"):
-        pass
-    sink.add_phase("data_wait", 0.025)
-    ev = sink.step_event(0, stage=0, epoch=0)
+    ev = sink.step_event(0, phases={"dispatch": 0.001, "data_wait": 0.025},
+                         stage=0, epoch=0)
     assert ev["phases"]["data_wait"] == pytest.approx(0.025)
     sink.emit("epoch_end", stage=0, epoch=0, step=1)
     sink.close()
@@ -72,18 +70,17 @@ def test_sink_writes_schema_valid_jsonl(tmp_path):
     events, errors = report.load_events(path)
     assert not errors
     assert [e["kind"] for e in events] == ["stage_start", "step", "epoch_end"]
-    # phases drained into the step event
+    # the caller's phases ride in the step event
     assert set(events[1]["phases"]) == {"dispatch", "data_wait"}
 
 
 def test_step_event_throughput_ema():
     sink = telemetry.Telemetry()  # memory-only
     for i in range(3):
-        sink.add_phase("dispatch", 0.01)
-        sink.step_event(i)
+        sink.step_event(i, phases={"dispatch": 0.01})
     assert len(sink.events) == 3
     assert all(e["throughput_ema"] > 0 for e in sink.events)
-    # phases reset between steps
+    # each step's phases are its own
     assert sink.events[-1]["phases"] == {"dispatch": 0.01}
 
 
@@ -119,10 +116,9 @@ def test_kill_switch(tmp_path, monkeypatch):
 
     sink = telemetry.create(tmp_path / "events.jsonl")
     assert isinstance(sink, telemetry.NullTelemetry)
-    with sink.span("dispatch"):
-        pass
-    sink.add_phase("x", 1.0)
-    sink.step_event(0)
+    sink.clock()
+    sink.add_count("x", 1)
+    sink.step_event(0, phases={"dispatch": 0.1})
     sink.emit("nonfinite", step=0)
     sink.close()
     assert not (tmp_path / "events.jsonl").exists()
@@ -210,6 +206,37 @@ def test_report_flags_anomalies_and_renders():
     assert "train_step" in text
     assert "device peak 7.50 GiB" in text
     assert "anomalies (" in text
+
+
+def test_report_renders_the_timeline_when_a_file_has_one():
+    """``clock``/``span`` events and ``marks`` are optional: a file from
+    before schema 1.7 renders as it did, a new one gains a section."""
+    old = [_base("step", step=i, phases={"dispatch": 0.01}, step_time=0.1,
+                 throughput_ema=10.0) for i in range(3)]
+    assert report.timeline_stats(old) is None
+    assert "== timeline ==" not in report.render(old)
+
+    new = old + [
+        _base("clock", perf_counter=100.0, time_ns=1_000_000_000_000),
+        _base("clock", perf_counter=200.0, time_ns=1_100_000_050_000),
+        _base("span", name="boot", t0=90.0, t1=100.0),
+        _base("span", name="gc", t0=150.0, t1=150.25, generation=2),
+        _base("span", name="gc", t0=160.0, t1=160.05, generation=1),
+        _base("step", step=3, phases={"dispatch": 0.01}, step_time=0.1,
+              throughput_ema=10.0, marks={"start": 1.0, "done": 1.1}),
+        _base("trace", event="batch", marks={
+            "wait": 1.0, "dispatch": 1.2, "assembled": 1.21, "called": 1.22,
+            "ready": 1.45, "fetched": 1.46, "completed": 1.48}),
+    ]
+    stats = report.timeline_stats(new)
+    assert stats["clocks"] == 2 and stats["drift_us"] == pytest.approx(50.0)
+    assert stats["spans"]["gc"] == {"count": 2, "total": pytest.approx(0.3),
+                                    "max": pytest.approx(0.25)}
+    assert stats["marked_steps"] == 1
+    assert stats["batch_legs_s"]["called->ready"] == pytest.approx(0.23)
+    text = report.render(new)
+    assert "== timeline ==" in text and "span gc" in text
+    assert "called->ready 230.00 ms" in text
 
 
 def test_report_clean_run_no_flags():
@@ -304,11 +331,17 @@ def test_smoke_train_emits_schema_valid_events(tmp_path, monkeypatch):
 
     steps = [e for e in events if e["kind"] == "step"]
     for ev in steps:
-        assert {"dispatch", "host"} <= set(ev["phases"])
+        # the phases are the step trace's, computed from the marks the
+        # event carries (test_step_marks_phases_and_put pins the sums)
+        assert set(ev["phases"]) == set(steptrace.PHASES)
+        assert set(ev["marks"]) == set(steptrace.MARKS)
         assert ev["stage"] == 0
-    # the prefetch pipeline phases land on at least one step
-    all_phases = set().union(*(e["phases"] for e in steps))
-    assert {"data_wait", "device_put"} <= all_phases
+    # one clock: at activate() and at the stage's start; set-up has spans
+    clocks = [e for e in events if e["kind"] == "clock"]
+    assert len(clocks) >= 2
+    names = [e["name"] for e in events if e["kind"] == "span"]
+    for name in ("data", "state", "step_build", "prepare"):
+        assert names.count(name) == 1, names
 
     compiles = [e for e in events if e["kind"] == "compile"]
     assert any(e["label"] == "train_step" for e in compiles)
@@ -324,6 +357,214 @@ def test_smoke_train_emits_schema_valid_events(tmp_path, monkeypatch):
     text = report.render(events)
     assert "step phase breakdown" in text
     assert "train_step" in text
+
+
+@pytest.mark.parametrize("prefetch", [True, False],
+                         ids=["prefetch-depth2", "sync"])
+def test_step_marks_phases_and_put(tmp_path, monkeypatch, prefetch):
+    """The step event carries the loop thread's marks, absolute and in
+    order; its phases are differences of those marks and telescope to the
+    step's total; ``put`` is the interval of this step's own batch: ahead
+    of the pull on the worker's thread, inside it with RMD_PREFETCH=0."""
+    from test_strategy import _make_context, _make_stage
+
+    monkeypatch.setenv("RMD_PREFETCH", "1" if prefetch else "0")
+    monkeypatch.setenv("RMD_PREFETCH_DEPTH", "2")
+    sink = telemetry.activate(telemetry.Telemetry())
+    try:
+        ctx, _ = _make_context(tmp_path, [_make_stage(epochs=1)])
+        ctx.run()
+    finally:
+        telemetry.deactivate()
+
+    steps = [e for e in sink.events if e["kind"] == "step"]
+    assert [e["step"] for e in steps] == [0, 1]
+    for ev in steps:
+        telemetry.validate_event(ev)
+        marks = [ev["marks"][m] for m in steptrace.MARKS]
+        assert marks == sorted(marks)
+        total = ev["marks"]["done"] - ev["marks"]["start"]
+        p0, p1 = ev["put"]
+        assert p0 <= p1
+        assert ev["phases"]["device_put"] == pytest.approx(p1 - p0, abs=5e-6)
+        on_loop = sum(ev["phases"].values())
+        if prefetch:
+            # the worker's put lies outside the step: staged before the
+            # pull returned, and the one phase not part of the sum
+            assert p1 <= ev["marks"]["data"]
+            on_loop -= ev["phases"]["device_put"]
+        else:
+            assert ev["marks"]["start"] <= p0 and p1 <= ev["marks"]["data"]
+        assert on_loop == pytest.approx(total, abs=1e-5)
+    # each step got its own batch's put, in the loader's order
+    assert steps[0]["put"][1] <= steps[1]["put"][0]
+    # the window events come from the same records
+    assert any(e["kind"] == "steptrace" for e in sink.events) or \
+        ctx.steptraces.snapshot()["count"] == 2
+    # and no second set of timers: nothing else feeds step phases
+    assert not hasattr(sink, "span") and not hasattr(sink, "add_phase")
+
+
+def test_put_rides_with_its_own_batch():
+    """Under prefetch depth 2 the worker runs ahead of the consumer; the
+    put interval that comes out with batch i is the one taken around
+    batch i's put, not the newest one."""
+    import time
+
+    from raft_meets_dicl_tpu.strategy.training import _device_prefetch
+
+    seen = {}
+
+    def put(batch):
+        seen[int(batch[0][0])] = time.perf_counter()
+        time.sleep(0.002)
+        return batch
+
+    items = [(np.full((1,), i), None, None, None, [i]) for i in range(5)]
+    stream = _device_prefetch(iter(items), put, depth=2)
+    first = next(stream)
+    time.sleep(0.05)          # the worker fills the queue meanwhile
+    for host, _dev, meta, (t0, t1) in [first, *stream]:
+        i = meta[0]
+        assert t0 <= seen[i] <= t1
+        assert all(not (t0 <= seen[j] <= t1) for j in seen if j != i)
+
+
+def test_early_spans_are_delivered_on_activate(monkeypatch):
+    """A span taken before the process's first sink waits in a small
+    bounded list and comes out of ``activate()``, ``boot`` first; after
+    that a span without an enabled sink is dropped."""
+    monkeypatch.setattr(core, "_hold_early", True)
+    monkeypatch.setattr(core, "_boot_done", False)
+    core._early.clear()
+    try:
+        telemetry.emit_span("backend_init", 10.0, 12.5)
+        with telemetry.interval("model_load", thread="main"):
+            pass
+        assert [f["name"] for f in core._early] == [
+            "boot", "backend_init", "model_load"]
+        sink = telemetry.activate(telemetry.Telemetry())
+        spans = [e for e in sink.events if e["kind"] == "span"]
+        assert [e["name"] for e in spans] == ["boot", "backend_init",
+                                              "model_load"]
+        # boot: process start to the first thing the program marked
+        assert spans[0]["t1"] == 10.0
+        assert (spans[1]["t0"], spans[1]["t1"]) == (10.0, 12.5)
+        assert spans[2]["thread"] == "main"
+        assert sink.events[0]["kind"] == "clock"
+        for ev in sink.events:
+            telemetry.validate_event(ev)
+        assert not core._early
+    finally:
+        telemetry.deactivate()
+    # the first activate() is past: nothing is held any more
+    telemetry.emit_span("model_load", 1.0, 2.0)
+    assert not core._early
+    # and the list is bounded while it holds
+    monkeypatch.setattr(core, "_hold_early", True)
+    for i in range(200):
+        telemetry.emit_span("data", float(i), float(i) + 1)
+    assert len(core._early) == core._early.maxlen
+    core._early.clear()
+
+
+def test_clock_event_pairs_the_two_clocks():
+    import time
+
+    sink = telemetry.Telemetry()
+    before = time.perf_counter(), time.time_ns()
+    ev = sink.clock()
+    after = time.perf_counter(), time.time_ns()
+    telemetry.validate_event(ev)
+    assert before[0] <= ev["perf_counter"] <= after[0]
+    assert before[1] <= ev["time_ns"] <= after[1]
+
+
+def test_gc_span_on_a_forced_collection(monkeypatch):
+    import gc
+
+    monkeypatch.setattr(witness, "GC_MIN_S", 0.0)
+    sink = telemetry.activate(telemetry.Telemetry())
+    try:
+        gc.collect()
+    finally:
+        telemetry.deactivate()
+    spans = [e for e in sink.events
+             if e["kind"] == "span" and e["name"] == "gc"]
+    assert spans and spans[-1]["generation"] == 2
+    assert spans[-1]["t0"] <= spans[-1]["t1"]
+    assert isinstance(spans[-1]["collected"], int)
+    telemetry.validate_event(spans[-1])
+    # the hook is gone with the sink
+    assert witness._on_gc not in gc.callbacks
+
+
+def test_ticker_lateness_arithmetic_on_an_injected_clock():
+    # due at 10.02; 30 ms late is within tolerance, 51 ms is a stall
+    assert witness.late_span(10.0, 10.05) is None
+    assert witness.late_span(10.0, 10.069) is None
+    assert witness.late_span(10.0, 10.0711) == (10.02, 10.0711)
+    assert witness.late_span(0.0, 2.0, tick=0.5, late=1.0) == (0.5, 2.0)
+
+    # the loop itself, on a clock that jumps 1.5 s across one sleep
+    times = iter([100.0, 100.02, 100.02, 101.54, 101.54])
+
+    class Stop:
+        calls = 0
+
+        def wait(self, timeout):
+            Stop.calls += 1
+            return Stop.calls > 2
+
+    sink = telemetry.activate(telemetry.Telemetry())
+    try:
+        witness._tick(Stop(), clock=lambda: next(times))
+    finally:
+        telemetry.deactivate()
+    stalls = [e for e in sink.events
+              if e["kind"] == "span" and e["name"] == "stall"]
+    assert [(e["t0"], e["t1"]) for e in stalls] == [(100.04, 101.54)]
+    assert stalls[0]["thread"] == "witness-ticker"
+    # what the process did meanwhile: CPU seconds, major faults, preemptions
+    assert stalls[0]["cpu_s"] >= 0.0
+    assert stalls[0]["majflt"] >= 0 and stalls[0]["nivcsw"] >= 0
+
+
+def test_kill_switch_starts_no_thread_and_no_gc_hook(monkeypatch):
+    import gc
+    import threading
+
+    monkeypatch.setenv("RMD_TELEMETRY", "0")
+    telemetry.activate(telemetry.create())
+    try:
+        assert witness.running() == (False, False)
+        assert witness._on_gc not in gc.callbacks
+        assert not [t for t in threading.enumerate()
+                    if t.name == "witness-ticker"]
+        telemetry.emit_span("model_load", 1.0, 2.0)   # dropped, not held
+        assert not core._early
+    finally:
+        telemetry.deactivate()
+    monkeypatch.delenv("RMD_TELEMETRY")
+    telemetry.activate(telemetry.create())
+    try:
+        assert witness.running() == (True, True)
+    finally:
+        telemetry.deactivate()
+    assert witness.running() == (False, False)
+
+
+def test_schema_7_knows_clock_and_span():
+    from raft_meets_dicl_tpu.analysis import telemetrykinds
+
+    assert telemetry.SCHEMA_MINOR == 7
+    assert telemetry.SCHEMA["clock"] == {"perf_counter", "time_ns"}
+    assert telemetry.SCHEMA["span"] == {"name", "t0", "t1"}
+    assert {"clock", "span"} <= set(telemetrykinds._schema())
+    telemetry.validate_event(_base("clock", perf_counter=1.0, time_ns=2))
+    telemetry.validate_event(_base("span", name="gc", t0=1.0, t1=2.0))
+    with pytest.raises(ValueError):
+        telemetry.validate_event(_base("span", name="gc", t0=1.0))
 
 
 def test_training_disabled_telemetry_runs_clean(tmp_path, monkeypatch):

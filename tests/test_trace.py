@@ -155,12 +155,17 @@ def test_batch_trace_links_members():
     for rt in members:
         bt.link(rt)
     bt.fill = 4
-    rec = bt.finish().record()
+    bt.mark("dispatch", 5.0)
+    bt.mark("completed", 5.5)
+    rec = bt.record()
     assert rec["size"] == 3 and rec["fill"] == 4
     assert rec["bucket"] == "32x48" and rec["program"] == "prog@abc"
     assert rec["members"] == [rt.trace_id for rt in members]
     assert all(rt.batch_id == bt.batch_id for rt in members)
-    assert rec["seconds"] >= 0
+    assert rec["seconds"] == 0.5
+    assert rec["marks"] == {"dispatch": 5.0, "completed": 5.5}
+    with pytest.raises(ValueError, match="unknown batch mark"):
+        bt.mark("teleport")
 
 
 def test_trace_summary_snapshot_and_tail():
@@ -361,6 +366,89 @@ def test_scheduler_emits_linked_trace_events(_obs_hygiene):
     # the live aggregate saw the same record
     snap = sched.trace_summary.snapshot()
     assert snap["count"] == 1 and snap["tail"]["count"] == 1
+
+
+class MarkedSession(FakeSession):
+    """A stand-in that hands back ``run_marks`` like the real session."""
+
+    run_marks = None
+
+    def run(self, img1, img2):
+        flow = (img1 + img2)[..., :2]
+        called = time.perf_counter()
+        time.sleep(0.003)            # the device's execution
+        self.run_marks = (called, time.perf_counter())
+        return flow
+
+
+@pytest.mark.parametrize("session_cls", [FakeSession, MarkedSession],
+                         ids=["no-run-marks", "run-marks"])
+def test_batch_marks_ordered_and_request_phases_unchanged(_obs_hygiene,
+                                                          session_cls):
+    """The dispatch thread's marks of a batch are ordered wait ≤ dispatch
+    ≤ assembled ≤ called ≤ ready ≤ fetched ≤ completed; ``called`` and
+    ``ready`` are the session's own (no new sync); request phases keep
+    their names and sum, and the older spans are the same marks."""
+    buckets = ShapeBuckets([(16, 24), (32, 48)])
+    session = session_cls(buckets, batch_size=2)
+    sched = Scheduler(session, batch_size=2, max_wait_ms=2.0,
+                      queue_limit=64).start()
+    try:
+        tickets = [sched.submit(*_pair((14, 20), seed=i), client=f"c{i}")
+                   for i in range(4)]
+        results = [t.result(timeout=10.0) for t in tickets]
+    finally:
+        sched.stop(drain=True)
+
+    batches = _trace_events(_obs_hygiene, "batch")
+    reqs = {e["trace"]: e for e in _trace_events(_obs_hygiene, "request")}
+    assert batches and len(reqs) == 4
+    for b in batches:
+        core.validate_event(b)
+        marks = [b["marks"][m] for m in trace_mod.BATCH_MARKS]
+        assert marks == sorted(marks), b["marks"]
+        if session_cls is MarkedSession:
+            assert b["marks"]["ready"] - b["marks"]["called"] >= 0.003
+        else:
+            assert b["marks"]["ready"] == b["marks"]["called"]
+        assert b["seconds"] == pytest.approx(
+            b["marks"]["completed"] - b["marks"]["dispatch"], abs=5e-6)
+        for member in b["members"]:
+            r = reqs[member]
+            # a request's dispatch/launched/fetched are its batch's
+            # dispatch/ready/fetched: one stamp each
+            assert r["marks"]["dispatch"] == b["marks"]["dispatch"]
+            assert r["marks"]["launched"] == b["marks"]["ready"]
+            assert r["marks"]["fetched"] == b["marks"]["fetched"]
+            assert r["marks"]["released"] <= b["marks"]["completed"] + 1e-6
+    # a later batch's wait follows the earlier one's completion
+    ordered = sorted(batches, key=lambda b: b["marks"]["dispatch"])
+    for a, b in zip(ordered, ordered[1:]):
+        assert a["marks"]["completed"] <= b["marks"]["wait"] + 1e-6
+
+    for r in reqs.values():
+        assert list(r["marks"]) == list(trace_mod.MARKS)
+        assert set(r["phases"]) == set(trace_mod.PHASES)
+        assert sum(r["phases"].values()) == pytest.approx(r["total"],
+                                                          abs=1e-5)
+        assert r["total"] == pytest.approx(
+            r["marks"]["released"] - r["marks"]["submit"], abs=5e-6)
+    # FlowResult.spans and the serve/request event's spans: same marks
+    by_rid = {e["rid"]: e for e in _obs_hygiene.events
+              if e["kind"] == "serve" and e["event"] == "request"}
+    traces = {e["rid"]: e for e in reqs.values()}
+    for res in results:
+        m = traces[res.rid]["marks"]
+        assert set(res.spans) == {"admission", "queue", "dispatch",
+                                  "device", "total"}
+        assert res.spans["queue"] == pytest.approx(
+            m["dispatch"] - m["enqueue"], abs=5e-6)
+        assert res.spans["dispatch"] == pytest.approx(
+            m["launched"] - m["dispatch"], abs=5e-6)
+        assert res.spans["total"] == pytest.approx(
+            m["released"] - m["submit"], abs=5e-6)
+        assert by_rid[res.rid]["seconds"] == pytest.approx(
+            res.spans["total"], abs=5e-6)
 
 
 def test_scheduler_metrics_counters(_obs_hygiene):
