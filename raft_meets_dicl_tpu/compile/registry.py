@@ -76,9 +76,6 @@ class ProgramKey:
     def canonical(self):
         return repr((self.kind, self.model, self.flags))
 
-    def describe(self):
-        return f"{self.kind}[{self.model}]"
-
 
 def mosaic_calls(compiled):
     """Mosaic (Pallas TPU) custom calls in a compiled executable's HLO.

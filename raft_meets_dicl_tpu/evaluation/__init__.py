@@ -189,6 +189,12 @@ def make_eval_fn(model, model_args=None, mesh=None, wire=None,
     ``wire`` (models.wire.WireFormat) accepts compact-dtype un-normalized
     images and decodes + normalizes them on device.
 
+    ``raw_output`` is a program output like any other: a caller that
+    reads ``final_flow`` alone (the serve session) passes
+    ``{"final_only": True}`` in ``model_args`` and gets ``raw_output ==
+    [final_flow]`` — its own program, its own key; ``evaluate`` hands
+    ``raw_output`` on and keeps the full form.
+
     ``model_id`` names the model stably (config id string): the program
     then dedupes process-wide in the compile registry — the eval CLI, the
     warmup pass, and training validation all get the *same* program for
@@ -271,6 +277,19 @@ def make_eval_fn(model, model_args=None, mesh=None, wire=None,
     return _cache(step)
 
 
+def _rung_model_args(model_args):
+    """Caller's model arguments for a rung/warm program: minus what the
+    builder sets itself, plus ``final_only`` — the program returns
+    (final flow, state), so the model is asked for no other
+    full-resolution flow (part of the key's ``args`` flag)."""
+    model_args = dict(model_args or {})
+    for reserved in ("iterations", "flow_init", "hidden_init",
+                     "return_state", "quant", "quant_clip"):
+        model_args.pop(reserved, None)
+    model_args["final_only"] = True
+    return model_args
+
+
 def make_rung_fn(model, iterations, cont=False, mesh=None, wire=None,
                  variables_sharding=None, model_id=None, model_args=None,
                  quant=None):
@@ -286,7 +305,9 @@ def make_rung_fn(model, iterations, cont=False, mesh=None, wire=None,
 
     ``state`` is ``{"flow", "hidden", "delta"}`` — coarse-grid carry
     arrays (left on device; hand them to the next rung unfetched) plus a
-    per-sample convergence norm the host reads *between* programs. Each
+    per-sample convergence norm the host reads *between* programs. The
+    model is asked for the final flow only (``final_only``, in the key's
+    ``args`` flag): Up8 runs on the last iteration, batch b. Each
     (iterations, cont) pair is its own ``ProgramKey`` flag variant
     (kind ``rung_step``), so rungs dedupe process-wide, AOT-export, and
     prefetch like any other program; ``serve --prebuild`` exports the
@@ -312,10 +333,7 @@ def make_rung_fn(model, iterations, cont=False, mesh=None, wire=None,
     quant = quant_ops.normalize_mode(quant)
     quant_clip = (float(env.get_float("RMD_QUANT_CLIP"))
                   if quant is not None else 1.0)
-    model_args = dict(model_args or {})
-    for reserved in ("iterations", "flow_init", "hidden_init",
-                     "return_state", "quant", "quant_clip"):
-        model_args.pop(reserved, None)
+    model_args = _rung_model_args(model_args)
 
     base = _cache_key(model, model_args, mesh, wire, variables_sharding)
     key = (None if base is None
@@ -453,10 +471,7 @@ def make_warm_fn(model, iterations, mesh=None, wire=None,
     quant = quant_ops.normalize_mode(quant)
     quant_clip = (float(env.get_float("RMD_QUANT_CLIP"))
                   if quant is not None else 1.0)
-    model_args = dict(model_args or {})
-    for reserved in ("iterations", "flow_init", "hidden_init",
-                     "return_state", "quant", "quant_clip"):
-        model_args.pop(reserved, None)
+    model_args = _rung_model_args(model_args)
 
     base = _cache_key(model, model_args, mesh, wire, variables_sharding)
     key = (None if base is None

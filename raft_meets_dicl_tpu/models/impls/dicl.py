@@ -185,7 +185,7 @@ class DiclModule(nn.Module):
 
     @nn.compact
     def __call__(self, img1, img2, train=False, frozen_bn=False, raw=False,
-                 dap=True, ctx=True, context_scale=None):
+                 dap=True, ctx=True, context_scale=None, final_only=False):
         context_scale = context_scale or {
             f"level-{lvl}": 1.0 for lvl in self.levels
         }

@@ -140,7 +140,7 @@ class RaftClModule(nn.Module):
     @nn.compact
     def __call__(self, img1, img2, train=False, frozen_bn=False,
                  iterations=12, upnet=True, flow_init=None,
-                 corr_loss_examples=False):
+                 corr_loss_examples=False, final_only=False):
         hdim = cdim = 128
 
         fnet = FeatureEncoderGa(depth=6, out_levels=(2, 3, 4, 5), heads=False)

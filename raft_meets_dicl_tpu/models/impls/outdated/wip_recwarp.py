@@ -62,7 +62,7 @@ class WipRecWarpModule(nn.Module):
 
     @nn.compact
     def __call__(self, img1, img2, train=False, frozen_bn=False,
-                 iterations=(1,) * _LEVELS, dap=True):
+                 iterations=(1,) * _LEVELS, dap=True, final_only=False):
         fnet = FeatureEncoderGa(output_dim=self.feature_channels, depth=6,
                                 out_levels=(1, 2, 3, 4, 5))
         f1, f2 = fnet((img1, img2), train, frozen_bn)  # finest-first

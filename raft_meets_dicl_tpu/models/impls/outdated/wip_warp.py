@@ -115,7 +115,7 @@ class WipWarpModule(nn.Module):
 
     @nn.compact
     def __call__(self, img1, img2, train=False, frozen_bn=False,
-                 corr_loss_examples=False):
+                 corr_loss_examples=False, final_only=False):
         fnet = FeatureEncoderGa(output_dim=self.feat_channels, depth=6,
                                 out_levels=(1, 2, 3, 4, 5))
         f1, f2 = fnet((img1, img2), train, frozen_bn)  # finest-first, 1/4..1/64

@@ -326,6 +326,44 @@ class Up8Network(nn.Module):
         return convex_upsample_8x(flow, mask, temperature=self.temperature)
 
 
+def upsample_flows(flows, hiddens, carry, full_shape, dtype=None, upnet=True,
+                   final_only=False):
+    """Scan outputs → list of full-resolution flows: the tail every
+    RAFT-family ``__call__`` shares (call it inside ``@nn.compact``).
+
+    ``flows``/``hiddens`` are the scan's stacked per-iteration outputs,
+    ``(iterations, B, H/8, W/8, ·)``; ``carry`` is its final ``(hidden,
+    flow)``. By default the convex 8x upsampling runs once over all
+    ``iterations * B`` samples, outside the scan (one large einsum +
+    pixel shuffle instead of 12 rematerialized ones): what the sequence
+    loss needs. ``final_only`` — static, set by the builders of programs
+    that return ``result.final()`` alone — upsamples the carry instead,
+    batch ``B``: a one-element list holding the same final flow (same
+    arithmetic on the same operands), and nothing of ``hiddens`` is read,
+    so the stack leaves the compiled loop.
+    """
+    if final_only:
+        hiddens, flows = carry[0][None], carry[1][None]
+    n, b, hc, wc, _ = flows.shape
+    flows_flat = flows.reshape(n * b, hc, wc, 2)
+    hiddens_flat = hiddens.reshape(n * b, hc, wc, hiddens.shape[-1])
+
+    # always *called* so its params exist regardless of ``upnet``.
+    # remat'd: recomputing the two convs + softmax in the backward pass
+    # is cheaper than saving the f32 mask residuals (66MB with layout
+    # copies at the bench config)
+    # explicit name: the remat wrapper would otherwise prefix the module
+    # path ('CheckpointUp8Network_0'), breaking checkpoint compatibility
+    ups = nn.remat(Up8Network, prevent_cse=False)(
+        dtype=dtype, name="Up8Network_0")(hiddens_flat, flows_flat)
+    if not upnet:
+        ups = 8.0 * upsample2d_bilinear(flows_flat, full_shape)
+    ups = ups.reshape(n, b, *full_shape, 2)
+
+    # unstack the scan axis into per-iteration lists (protocol parity)
+    return [ups[i] for i in range(n)]
+
+
 class _RaftStep(nn.Module):
     """One GRU iteration — the nn.scan body.
 
@@ -416,7 +454,7 @@ class RaftModule(nn.Module):
     def __call__(self, img1, img2, train=False, frozen_bn=False, iterations=12,
                  flow_init=None, hidden_init=None, upnet=True, corr_flow=False,
                  corr_grad_stop=False, mask_costs=(), return_state=False,
-                 quant=None, quant_clip=1.0):
+                 quant=None, quant_clip=1.0, final_only=False):
         hdim = self.recurrent_channels
         cdim = self.context_channels
         reg_args = self.corr_reg_args or {}
@@ -516,28 +554,9 @@ class RaftModule(nn.Module):
             (h, flow), pyramid, x, coords0
         )
 
-        # convex 8x upsampling, batched over all iterations at once (one
-        # large einsum + pixel shuffle instead of 12 rematerialized ones);
-        # always *called* so its params exist regardless of ``upnet``
-        full_shape = (img1.shape[1], img1.shape[2])
-        flows_flat = flows.reshape(iterations * b, hc, wc, 2)
-        hiddens_flat = hiddens.reshape(iterations * b, hc, wc, hdim)
-
-        # remat'd: recomputing the two convs + softmax in the backward pass
-        # is cheaper than saving the f32 mask residuals (66MB with layout
-        # copies at the bench config)
-        # explicit name: the remat wrapper would otherwise prefix the module
-        # path ('CheckpointUp8Network_0'), breaking checkpoint compatibility
-        up_net = nn.remat(Up8Network, prevent_cse=False)(
-            dtype=dt, name="Up8Network_0")(hiddens_flat, flows_flat)
-        if upnet:
-            flows_up = up_net
-        else:
-            flows_up = 8.0 * upsample2d_bilinear(flows_flat, full_shape)
-        flows_up = flows_up.reshape(iterations, b, *full_shape, 2)
-
-        # unstack the scan axis into per-iteration lists (protocol parity)
-        out = [flows_up[i] for i in range(iterations)]
+        out = upsample_flows(flows, hiddens, (h, flow),
+                             (img1.shape[1], img1.shape[2]), dtype=dt,
+                             upnet=upnet, final_only=final_only)
 
         if corr_flow:
             # corr_flows is a tuple over levels of (iterations, B, H, W, 2);

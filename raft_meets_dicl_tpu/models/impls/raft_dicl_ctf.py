@@ -145,7 +145,7 @@ class RaftPlusDiclCtfModule(nn.Module):
     def __call__(self, img1, img2, train=False, frozen_bn=False,
                  iterations=None, dap=True, upnet=True, corr_flow=False,
                  prev_flow=False, corr_grad_stop=False, flow_init=None,
-                 hidden_init=None, return_state=False):
+                 hidden_init=None, return_state=False, final_only=False):
         hdim = self.recurrent_channels
         cdim = self.context_channels
         b, h, w = img1.shape[0], img1.shape[1], img1.shape[2]

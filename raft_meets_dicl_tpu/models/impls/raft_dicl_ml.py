@@ -16,7 +16,6 @@ import jax
 import jax.numpy as jnp
 
 from ...ops.pool import avg_pool2d, max_pool2d
-from ...ops.upsample import interpolate_bilinear
 from ..common.blocks.dicl import DisplacementAwareProjection, MatchingNet
 from ..common.blocks.raft import ResidualBlock, kaiming_normal
 from ..common.corr.common import (
@@ -31,7 +30,8 @@ from ..common.norm import Norm2d
 from ..common.util import identity_1x1_init
 from ..config import register_model
 from ..model import Model, ModelAdapter
-from .raft import BasicUpdateBlock, RaftAdapter, Up8Network, make_flow_regression
+from .raft import (BasicUpdateBlock, RaftAdapter, make_flow_regression,
+                   upsample_flows)
 
 
 class _OutputNet(nn.Module):
@@ -316,7 +316,7 @@ class RaftPlusDiclMlModule(nn.Module):
     def __call__(self, img1, img2, train=False, frozen_bn=False, iterations=12,
                  dap=True, upnet=True, corr_flow=False, corr_grad_stop=False,
                  flow_init=None, hidden_init=None, mask_costs=(),
-                 return_state=False):
+                 return_state=False, final_only=False):
         hdim = self.recurrent_channels
         cdim = self.context_channels
         dt = jnp.bfloat16 if self.mixed_precision else None
@@ -378,9 +378,6 @@ class RaftPlusDiclMlModule(nn.Module):
                                    self.corr_radius,
                                    **(self.corr_reg_args or {}))
         update = BasicUpdateBlock(hdim, dtype=dt)
-        # remat'd, pinned name (the wrapper would otherwise prefix the path)
-        upnet8 = nn.remat(Up8Network, prevent_cse=False)(
-            dtype=dt, name="Up8Network_0")
 
         # one (remat-wrapped) step body serves both realizations: the
         # lax.scan (default) or a python-unrolled loop (`unroll=True`,
@@ -442,17 +439,9 @@ class RaftPlusDiclMlModule(nn.Module):
                 fmap1, fmap2, x, coords0,
             )
 
-        # convex 8x upsampling, batched over all iterations at once
-        full_shape = (img1.shape[1], img1.shape[2])
-        flows_flat = flows.reshape(iterations * b, hc, wc, 2)
-        hiddens_flat = hiddens.reshape(iterations * b, hc, wc, hdim)
-
-        ups = upnet8(hiddens_flat, flows_flat)
-        if not upnet:
-            ups = 8.0 * interpolate_bilinear(flows_flat, full_shape)
-        ups = ups.reshape(iterations, b, *full_shape, 2)
-
-        out = [ups[i] for i in range(iterations)]
+        out = upsample_flows(flows, hiddens, (h, flow),
+                             (img1.shape[1], img1.shape[2]), dtype=dt,
+                             upnet=upnet, final_only=final_only)
 
         if corr_flow:
             out_corr = [

@@ -19,13 +19,12 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ...ops.upsample import interpolate_bilinear
 from ..common import corr as corr_mod
 from ..common import encoders
 from ..common.grid import coordinate_grid
 from ..config import register_model
 from ..model import Model, ModelAdapter
-from .raft import BasicUpdateBlock, RaftAdapter, Up8Network
+from .raft import BasicUpdateBlock, RaftAdapter, upsample_flows
 from .raft_dicl_ctf import _CtfStep
 
 
@@ -54,7 +53,8 @@ class RaftPlusDiclModule(nn.Module):
     @nn.compact
     def __call__(self, img1, img2, train=False, frozen_bn=False, iterations=12,
                  dap=True, upnet=True, corr_flow=False, corr_grad_stop=False,
-                 flow_init=None, hidden_init=None, return_state=False):
+                 flow_init=None, hidden_init=None, return_state=False,
+                 final_only=False):
         hdim = self.recurrent_channels
         cdim = self.context_channels
         dt = jnp.bfloat16 if self.mixed_precision else None
@@ -101,8 +101,6 @@ class RaftPlusDiclModule(nn.Module):
             **(self.corr_reg_args or {}),
         )
         update = BasicUpdateBlock(hdim, dtype=dt)
-        upnet8 = nn.remat(Up8Network, prevent_cse=False)(
-            dtype=dt, name="Up8Network_0")
 
         # one (remat-wrapped) step body serves both realizations; scan
         # unless batch norm is actually training (the lifted scan
@@ -150,17 +148,9 @@ class RaftPlusDiclModule(nn.Module):
                 fmap1, fmap2, x, coords0,
             )
 
-        # convex 8x upsampling, batched over all iterations at once
-        full_shape = (img1.shape[1], img1.shape[2])
-        flows_flat = flows.reshape(iterations * b, hc, wc, 2)
-        hiddens_flat = hiddens.reshape(iterations * b, hc, wc, hdim)
-
-        ups = upnet8(hiddens_flat, flows_flat)
-        if not upnet:
-            ups = 8.0 * interpolate_bilinear(flows_flat, full_shape)
-        ups = ups.reshape(iterations, b, *full_shape, 2)
-
-        out = [ups[i] for i in range(iterations)]
+        out = upsample_flows(flows, hiddens, (h, flow),
+                             (img1.shape[1], img1.shape[2]), dtype=dt,
+                             upnet=upnet, final_only=final_only)
 
         if corr_flow:
             out = [[readouts[i] for i in range(iterations)], out]

@@ -21,12 +21,11 @@ from ...ops import quant as quant_ops
 from ...ops.corr import correlation_volume, lookup_pyramid_levels
 from ...ops.pallas import windowed_corr_pyramid
 from ...ops.pool import avg_pool2d
-from ...ops.upsample import interpolate_bilinear
 from ..common import encoders
 from ..common.grid import coordinate_grid
 from ..config import register_model
 from ..model import Model, ModelAdapter
-from .raft import BasicUpdateBlock, RaftAdapter, Up8Network
+from .raft import BasicUpdateBlock, RaftAdapter, upsample_flows
 
 
 def volume_level_split(coarse_shape, corr_levels, itemsize, budget_gib=None):
@@ -171,7 +170,7 @@ class RaftFsModule(nn.Module):
     def __call__(self, img1, img2, train=False, frozen_bn=False,
                  iterations=12, flow_init=None, hidden_init=None, upnet=True,
                  mask_costs=(), return_state=False, quant=None,
-                 quant_clip=1.0):
+                 quant_clip=1.0, final_only=False):
         hdim = self.recurrent_channels
         cdim = self.context_channels
         dt = jnp.bfloat16 if self.mixed_precision else None
@@ -277,27 +276,15 @@ class RaftFsModule(nn.Module):
         (h, flow), (flows, hiddens) = step((h, flow), fmap1,
                                            tuple(pyramid), x, coords0)
 
-        # convex 8x upsampling hoisted out of the remat'd scan and batched
-        # over all iterations, exactly like raft/baseline (raft.py): inside
-        # the scan its full-resolution intermediates are rematerialized
-        # per iteration in the backward pass — the step's largest cost at
-        # high resolution. Explicit name keeps a stable param path going
-        # forward; checkpoints from before the hoist (params under the
-        # scan-body subtree) are migrated at load time by
+        # convex 8x upsampling hoisted out of the remat'd scan, exactly
+        # like raft/baseline (raft.upsample_flows). The explicit module
+        # name keeps a stable param path going forward; checkpoints from
+        # before the hoist (params under the scan-body subtree) are
+        # migrated at load time by
         # strategy.checkpoint._remap_legacy_model_state.
-        full_shape = (img1.shape[1], img1.shape[2])
-        flows_flat = flows.reshape(iterations * b, hc, wc, 2)
-        hiddens_flat = hiddens.reshape(iterations * b, hc, wc, hdim)
-
-        up_net = nn.remat(Up8Network, prevent_cse=False)(
-            dtype=dt, name="Up8Network_0")(hiddens_flat, flows_flat)
-        if upnet:
-            flows_up = up_net
-        else:
-            flows_up = 8.0 * interpolate_bilinear(flows_flat, full_shape)
-        flows_up = flows_up.reshape(iterations, b, *full_shape, 2)
-
-        out = [flows_up[i] for i in range(iterations)]
+        out = upsample_flows(flows, hiddens, (h, flow),
+                             (img1.shape[1], img1.shape[2]), dtype=dt,
+                             upnet=upnet, final_only=final_only)
 
         if return_state:
             final = flows[-1]

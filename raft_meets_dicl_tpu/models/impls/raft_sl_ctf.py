@@ -84,7 +84,7 @@ class RaftSlCtfModule(nn.Module):
     @nn.compact
     def __call__(self, img1, img2, train=False, frozen_bn=False,
                  iterations=None, upnet=True, corr_flow=False,
-                 corr_grad_stop=False):
+                 corr_grad_stop=False, final_only=False):
         hdim = self.recurrent_channels
         cdim = self.context_channels
         b, h, w = img1.shape[0], img1.shape[1], img1.shape[2]

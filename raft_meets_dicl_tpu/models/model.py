@@ -110,6 +110,15 @@ class Model:
         hand to the next rung program plus a per-sample convergence norm.
         The tuple passes through here untouched; rung programs
         (``evaluation.make_rung_fn``) unpack it themselves.
+
+        Final-flow protocol: every impl accepts a static ``final_only``
+        switch, set by the builders of inference programs that return
+        ``Result.final()`` alone (never by the train step, never from a
+        config). With it an impl may leave out intermediates nobody
+        reads — the RAFT family upsamples the last iteration only, so
+        its output is the one-element list ``[final]`` — while
+        ``final()`` and ``state`` stay what they are without it; an impl
+        with nothing to leave out ignores it.
         """
         args = self.arguments | kwargs
         frozen = self.frozen_batchnorm

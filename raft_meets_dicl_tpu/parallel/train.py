@@ -362,8 +362,12 @@ def make_eval_step(model, mesh=None, model_args=None, wire=None,
     inside the step; None keeps them replicated. ``key`` registers the
     step under a stable ``compile.ProgramKey`` (dedupe + AOT), as in
     ``make_train_step``.
+
+    The program returns ``result.final()`` alone, so it asks the model
+    for the final flow only (``final_only``, see ``Model.apply``); the
+    switch is part of the effective arguments the key encodes.
     """
-    model_args = dict(model_args or {})
+    model_args = dict(model_args or {}) | {"final_only": True}
 
     # a caller-provided key must encode the *effective* model arguments
     # (config defaults merged under explicit overrides, exactly how

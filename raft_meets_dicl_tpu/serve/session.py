@@ -72,8 +72,12 @@ class ServeSession:
         self.mesh = mesh
 
         self.variables = self._init_variables(checkpoint)
+        # a request reads the final flow alone (run() drops ``out``), so
+        # the program asks the model for nothing else: Up8 runs on the
+        # last iteration, batch b, and ``out == [final]``
         self.eval_fn = evaluation.make_eval_fn(
-            self.model, None, mesh=mesh, wire=wire, model_id=spec.id)
+            self.model, {"final_only": True}, mesh=mesh, wire=wire,
+            model_id=spec.id)
 
         # iteration ladder (ladder.LadderSpec): one registered rung
         # program per (iterations, cont) — base rung, continuation
@@ -361,8 +365,10 @@ class ServeSession:
 
     def program_fingerprint(self, klass=""):
         """Stable identity of the compiled program a batch of ``klass``
-        rides (registry ProgramKey canonical form) — the batch-trace
-        field that lets a tail batch be tied to one executable."""
+        rides (registry ProgramKey canonical form, flags included: the
+        ``args`` flag says whether the final-only form ran) — the
+        batch-trace field that lets a tail batch be tied to one
+        executable."""
         fn = self.eval_fn
         if klass and self.ladder is not None:
             lad = self.ladder
@@ -370,5 +376,5 @@ class ServeSession:
             fn = self._rung_fns.get((rung, False), fn)
         key = getattr(fn, "key", None)
         if key is not None:
-            return key.describe()
+            return key.canonical()
         return getattr(fn, "telemetry_label", "eval_step")

@@ -329,3 +329,118 @@ def test_ladder_session_serves_all_classes_without_compiling():
         assert res.flow.shape == (30, 44, 2)
     # every class — including balanced escalation — rode warm programs
     assert session.compiles() == c0
+
+
+# -- final-flow programs: what serving builds upsamples one flow --------------
+
+_FINAL_ITS, _FINAL_B = 5, 2     # 5 * 2 = 10: no other dimension of the toy
+
+
+@pytest.fixture(scope="module")
+def final_programs():
+    """Every builder whose program returns the final flow alone, at
+    ``_FINAL_ITS`` iterations: ``{name: (program, takes)}``."""
+    from raft_meets_dicl_tpu import parallel
+
+    cfg = dict(TINY_LADDER_MODEL, id="final-tiny")
+    cfg["model"] = dict(cfg["model"], arguments={"iterations": _FINAL_ITS})
+    spec = models.load(cfg)
+    session = ServeSession(spec, ShapeBuckets([(32, 48)]),
+                           batch_size=_FINAL_B, video=True,
+                           ladder=LadderSpec(rungs=(_FINAL_ITS, 8)))
+    step_key = programs.ProgramKey(kind="eval_step", model=spec.id)
+    return spec, session, {
+        "session": (session.eval_fn, "pair"),
+        "rung": (session._rung_fns[(_FINAL_ITS, False)], "pair"),
+        "cont": (evaluation.make_rung_fn(spec.model, _FINAL_ITS, cont=True,
+                                         model_id=spec.id), "carry"),
+        "warm": (session._warm_fn, "flow"),
+        "eval_step": (parallel.make_eval_step(spec.model, key=step_key),
+                      "pair"),
+    }
+
+
+def _lower(final_programs, name):
+    import jax
+    import jax.numpy as jnp
+
+    spec, session, progs = final_programs
+    prog, takes = progs[name]
+    f32 = jnp.float32
+    img = jax.ShapeDtypeStruct((_FINAL_B, 32, 48, 3), f32)
+    flow = jax.ShapeDtypeStruct((_FINAL_B, 4, 6, 2), f32)
+    hidden = jax.ShapeDtypeStruct((_FINAL_B, 4, 6, 16), f32)
+    extra = {"pair": (), "flow": (flow,), "carry": (flow, hidden)}[takes]
+    return prog, prog.lower(session.variables, img, img, *extra)
+
+
+def _stacked_over_iterations(text):
+    """StableHLO tensor types whose leading dimension is ``iterations *
+    b`` at or above the coarse grid: Up8's batch when it runs on every
+    iteration."""
+    import re
+
+    hits = set()
+    for dims in re.findall(r"tensor<((?:\d+x)+)[a-z]", text):
+        d = [int(x) for x in dims.split("x") if x]
+        if (len(d) >= 3 and d[0] == _FINAL_ITS * _FINAL_B
+                and d[1] >= 4 and d[2] >= 6):
+            hits.add(tuple(d))
+    return hits
+
+
+@pytest.mark.parametrize("name", ["session", "rung", "cont", "warm",
+                                  "eval_step"])
+def test_final_flow_programs_upsample_one_flow(final_programs, name):
+    import jax
+
+    prog, lowered = _lower(final_programs, name)
+    # no operand of leading dimension iterations * b from the coarse
+    # grid up: Up8 (mask head, combine, pixel shuffle) runs on batch b
+    assert _stacked_over_iterations(lowered.as_text()) == set()
+
+    # the outputs hold the final flow and no other full-resolution one
+    # (the session's eval program returns it as ``out == [final]`` too)
+    full_res = [leaf.shape for leaf in jax.tree.leaves(lowered.out_info)
+                if tuple(leaf.shape[1:3]) == (32, 48)]
+    assert full_res == [(_FINAL_B, 32, 48, 2)] * (2 if name == "session"
+                                                  else 1)
+
+    # the switch is part of the program's identity: the AOT store and the
+    # registry can never hand this program a twelve-flow executable
+    assert "('final_only', 'True')" in dict(prog.key.flags)["args"]
+
+
+def test_final_flow_detector_sees_the_full_form(final_programs):
+    # positive control, and the key contract: evaluate()'s program (no
+    # switch) still upsamples every iteration and is another program
+    spec, session, _ = final_programs
+    full = evaluation.make_eval_fn(spec.model, None, model_id=spec.id)
+    import jax
+    import jax.numpy as jnp
+
+    img = jax.ShapeDtypeStruct((_FINAL_B, 32, 48, 3), jnp.float32)
+    lowered = full.lower(session.variables, img, img)
+    assert _stacked_over_iterations(lowered.as_text())
+    out, final = lowered.out_info
+    assert len(out) == _FINAL_ITS
+
+    assert full is not session.eval_fn and full.key != session.eval_fn.key
+    assert "final_only" not in dict(full.key.flags)["args"]
+    # what every trace/batch event carries as ``program``
+    assert "('final_only', 'True')" in session.program_fingerprint()
+    assert "final_only" in session.program_fingerprint("quality")
+
+
+def test_final_flow_program_drops_the_hidden_stack(final_programs):
+    # compiled, the loop no longer carries the per-iteration hiddens
+    # (nobody reads them once Up8 takes the final carry), nor anything
+    # else stacked over the iterations at the coarse grid
+    import re
+
+    _, lowered = _lower(final_programs, "session")
+    text = lowered.compile().as_text()
+    assert re.search(r"\[%d,%d,4,6,\d+\]" % (_FINAL_ITS, _FINAL_B),
+                     text) is None
+    assert re.search(r"\[%d,4,6,\d+\]" % (_FINAL_ITS * _FINAL_B),
+                     text) is None

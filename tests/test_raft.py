@@ -211,3 +211,143 @@ def test_sequence_loss_matches_torch_semantics(ord, include_invalid):
                      ord=ord, gamma=gamma, include_invalid=include_invalid))
 
     assert got == pytest.approx(expected, rel=1e-5)
+
+
+# -- final-flow protocol: ``final_only`` (see Model.apply) --------------------
+
+# the four impls that share raft.upsample_flows: tiny parameters each
+FINAL_FAMILY = {
+    "raft/baseline": {"corr-levels": 2, "corr-radius": 2,
+                      "corr-channels": 16, "context-channels": 8,
+                      "recurrent-channels": 8},
+    "raft/fs": {"corr-levels": 2, "corr-radius": 2, "corr-channels": 16,
+                "context-channels": 8, "recurrent-channels": 8},
+    "raft+dicl/sl": {"corr-radius": 2, "corr-channels": 8,
+                     "context-channels": 8, "recurrent-channels": 8,
+                     "corr-args": {"mnet_scale": 0.125}},
+    "raft+dicl/ml": {"corr-levels": 2, "corr-radius": 2, "corr-channels": 8,
+                     "context-channels": 8, "recurrent-channels": 8},
+}
+
+# every other registered type: what ``final_only`` must leave alone.
+# (parameters, forward arguments, input size)
+_DISP = {f"level-{lvl}": [1, 1] for lvl in (2, 3, 4, 5, 6)}
+_SL_CTF = {"corr-radius": 2, "corr-channels": 16, "context-channels": 8,
+           "recurrent-channels": 8}
+_CTF = {"corr-radius": 2, "corr-channels": 8, "context-channels": 8,
+        "recurrent-channels": 8, "corr-args": {"mnet_scale": 0.125}}
+FINAL_OTHERS = {
+    "raft/sl": ({"corr-radius": 2, "corr-channels": 16,
+                 "context-channels": 8, "recurrent-channels": 8},
+                {"iterations": 2}, (64, 96)),
+    "raft+dicl/sl-ca": ({"corr-radius": 2, "corr-channels": 8,
+                         "context-channels": 8, "recurrent-channels": 8,
+                         "embedding-channels": 8},
+                        {"iterations": 2}, (64, 96)),
+    "raft/sl-ctf-l2": (_SL_CTF, {"iterations": (2, 1)}, (64, 96)),
+    "raft/sl-ctf-l3": (_SL_CTF, {"iterations": (1, 1, 1)}, (64, 96)),
+    "raft/sl-ctf-l4": (_SL_CTF, {"iterations": (1, 1, 1, 1)}, (128, 128)),
+    "raft+dicl/ctf-l2": (_CTF, {"iterations": (2, 1)}, (64, 96)),
+    "raft+dicl/ctf-l3": (_CTF, {"iterations": (1, 1, 1)}, (128, 128)),
+    "raft+dicl/ctf-l4": (_CTF, {"iterations": (1, 1, 1, 1)}, (128, 128)),
+    "dicl/baseline": ({"displacement-range": _DISP, "feature-channels": 4},
+                      {}, (128, 128)),
+    "dicl/64to8": ({"displacement-range": {k: v for k, v in _DISP.items()
+                                           if k != "level-2"},
+                    "feature-channels": 4}, {}, (128, 128)),
+    "raft/cl": ({"corr-radius": 2}, {"iterations": 2}, (128, 128)),
+    "wip/warp/1": ({"disp-range": [2, 2]}, {}, (128, 128)),
+    "wip/warp/2": ({"feature-channels": 8, "disp-range": [[2, 2]] * 5},
+                   {}, (128, 128)),
+}
+
+
+def test_final_only_tables_cover_the_registry():
+    # a newly registered model has to take the keyword too: list it here
+    assert (set(FINAL_FAMILY) | set(FINAL_OTHERS)
+            == set(models.config.model_types()))
+
+
+@pytest.fixture(scope="module", params=list(FINAL_FAMILY))
+def family_model(request):
+    m = models.config.load_model({"type": request.param,
+                                  "parameters": FINAL_FAMILY[request.param]})
+    rs = np.random.RandomState(3)
+    img1 = jnp.asarray(rs.rand(2, 32, 48, 3), jnp.float32)
+    img2 = jnp.asarray(rs.rand(2, 32, 48, 3), jnp.float32)
+    v = jax.jit(lambda: m.init(jax.random.PRNGKey(0), img1, img2,
+                               iterations=1))()
+    hdim = FINAL_FAMILY[request.param]["recurrent-channels"]
+    carry = {"flow_init": jnp.asarray(rs.randn(2, 4, 6, 2), jnp.float32),
+             "hidden_init": jnp.asarray(rs.randn(2, 4, 6, hdim),
+                                        jnp.float32)}
+    return m, v, img1, img2, carry
+
+
+@pytest.mark.parametrize("case", ["upnet", "bilinear", "reentry", "state"])
+def test_final_only_returns_the_last_flow_alone(family_model, case):
+    """``final_only=True`` gives the one-element ``[flow]`` that the full
+    form ends with (Up8 on the last iteration's carry: the same
+    arithmetic, batch b instead of iterations * b), and the same
+    ``state``."""
+    m, v, img1, img2, carry = family_model
+    kw = {"iterations": 3}
+    if case == "bilinear":
+        kw["upnet"] = False
+    if case in ("reentry", "state"):
+        kw |= carry
+    if case == "state":
+        kw["return_state"] = True
+
+    # one program for both forms: the shared encoders and loop compile once
+    full, fin = jax.jit(lambda v: (
+        m.apply(v, img1, img2, **kw),
+        m.apply(v, img1, img2, final_only=True, **kw)))(v)
+
+    if case == "state":
+        (full, state_full), (fin, state_fin) = full, fin
+        assert sorted(state_fin) == ["delta", "flow", "hidden"]
+        for k in state_full:
+            np.testing.assert_array_equal(np.asarray(state_fin[k]),
+                                          np.asarray(state_full[k]))
+    assert len(full) == 3 and len(fin) == 1
+    assert fin[0].shape == (2, 32, 48, 2)
+    np.testing.assert_allclose(np.asarray(fin[0]), np.asarray(full[-1]),
+                               rtol=1e-5, atol=1e-5)
+
+    adapter = m.get_adapter()
+    np.testing.assert_array_equal(
+        np.asarray(adapter.wrap_result(fin, (32, 48)).final()),
+        np.asarray(fin[0]))
+
+
+@pytest.mark.parametrize("ty", list(FINAL_OTHERS))
+def test_final_only_is_accepted_by_every_other_impl(ty):
+    """Builders pass the switch to whatever model they are given, so every
+    registered impl takes it; one with nothing to leave out traces the
+    very same program for ``final()`` (raft/sl and raft+dicl/sl-ca wrap
+    family modules and honour it)."""
+    from raft_meets_dicl_tpu.analysis import hlo
+
+    params, args, (h, w) = FINAL_OTHERS[ty]
+    m = models.config.load_model({"type": ty, "parameters": params})
+    img = jax.ShapeDtypeStruct((1, h, w, 3), jnp.float32)
+    rngs = {"permute": jax.random.PRNGKey(1)}
+    v = jax.eval_shape(
+        lambda a, b: m.init(jax.random.PRNGKey(0), a, b, **args), img, img)
+
+    def final(**kw):
+        def fn(v, a, b):
+            out = m.apply(v, a, b, rngs=rngs, **args, **kw)
+            return m.get_adapter().wrap_result(out, (h, w)).final()
+        return jax.jit(fn).lower(v, img, img)
+
+    full, fin = final(), final(final_only=True)
+    assert fin.out_info.shape == full.out_info.shape == (1, h, w, 2)
+    if ty in ("raft/sl", "raft+dicl/sl-ca"):
+        out = jax.eval_shape(lambda v, a, b: m.apply(
+            v, a, b, final_only=True, **args), v, img, img)
+        assert len(out) == 1
+    else:
+        assert (hlo.fingerprint(fin.as_text())
+                == hlo.fingerprint(full.as_text()))
