@@ -27,8 +27,10 @@ from ..utils import env
 
 _MAGIC = "RMDP1"
 # bump to invalidate every existing artifact when the program contract
-# changes (arg order, aux layout, ...)
-_LAYOUT_VERSION = 2
+# changes (arg order, aux layout, ...); 3: the record holds the program's
+# trace-time counts, and the version is part of the artifact's name, so
+# that two layouts share a store without overwriting each other
+_LAYOUT_VERSION = 3
 
 _state = {"on": False, "dir": None}
 
@@ -88,7 +90,8 @@ def fingerprint():
 
 def artifact_path(key, sig):
     digest = hashlib.sha256(
-        (key.canonical() + "\0" + repr(sig)).encode()).hexdigest()
+        (f"layout={_LAYOUT_VERSION}\0" + key.canonical() + "\0"
+         + repr(sig)).encode()).hexdigest()
     return os.path.join(programs_dir(), f"{digest}.rmdp")
 
 
@@ -189,10 +192,12 @@ def fetch(src, dest=None):
     return _copy_artifacts(src, dest or programs_dir(), "fetch")
 
 
-def save(path, key, sig, compiled):
+def save(path, key, sig, compiled, trace_counts=None):
     """Serialize ``compiled`` (a jax.stages.Compiled) to ``path``
-    atomically. Returns (nbytes, seconds); raises on failure — callers
-    treat a failed save as cosmetic."""
+    atomically, with the counts its trace noted (``telemetry.note_trace``:
+    a boot that loads the executable never traces, and reads them from
+    here). Returns (nbytes, seconds); raises on failure — callers treat a
+    failed save as cosmetic."""
     from jax.experimental import serialize_executable
 
     t0 = time.perf_counter()
@@ -211,6 +216,7 @@ def save(path, key, sig, compiled):
                     compiled.runtime_executable().local_devices()],
         "in_tree": in_tree,
         "out_tree": out_tree,
+        "trace_counts": dict(trace_counts or {}),
     }
     buf = io.BytesIO()
     pickle.dump(record, buf, protocol=pickle.HIGHEST_PROTOCOL)
@@ -230,7 +236,8 @@ def load(path, key, sig):
     Returns ``(compiled, status, info)`` where status is one of
     ``hit`` (compiled is live), ``missing``, ``corrupt``, ``version``
     (fingerprint mismatch — stale jax/backend), or ``error``; ``info``
-    carries {bytes, seconds} on a hit and a reason string otherwise.
+    carries {bytes, seconds, trace_counts} on a hit and a reason string
+    otherwise.
     Never raises.
     """
     t0 = time.perf_counter()
@@ -269,6 +276,7 @@ def load(path, key, sig):
         return compiled, "hit", {
             "bytes": len(data),
             "seconds": time.perf_counter() - t0,
+            "trace_counts": dict(record.get("trace_counts") or {}),
         }
     except Exception as e:  # noqa: BLE001 - artifacts must never break boot
         return None, "error", f"{type(e).__name__}: {str(e)[:160]}"

@@ -19,6 +19,7 @@ such programs still dedupe within the process and still count compiles,
 but never touch the artifact store.
 """
 
+import math
 import os
 import threading
 from dataclasses import dataclass, field
@@ -136,6 +137,9 @@ class Program:
         self.aot_saves = 0
         self.aot_fallbacks = 0
         self._compiled = {}
+        # what the running trace has noted (telemetry.note_trace),
+        # keyed by (site, name); reset before each lowering
+        self._trace_notes = {}
         self._lock = threading.Lock()
         # callers may pin objects their pyid: key components reference so
         # the ids stay unique for the program's lifetime
@@ -146,6 +150,40 @@ class Program:
     def record_compile(self, seconds):
         self.compiles += 1
         self.compile_seconds += seconds
+
+    # -- trace-time counts (telemetry.note_trace) ---------------------------
+
+    def note_trace(self, name, value, site=()):
+        """One count from the trace that is running. Inside a
+        ``telemetry.trace_site`` the count is kept once per site and
+        name (the tracer may visit a scan's body twice) times the
+        site's repeats; outside any site counts add up."""
+        key = (tuple(label for label, _ in site), name)
+        if site:
+            self._trace_notes[key] = value * math.prod(n for _, n in site)
+        else:
+            self._trace_notes[key] = self._trace_notes.get(key, 0) + value
+
+    def trace_counts(self):
+        """The running (or last) trace's counts by name, over its sites."""
+        counts = {}
+        for (_, name), value in self._trace_notes.items():
+            counts[name] = counts.get(name, 0) + value
+        return counts
+
+    def _hand_counts(self, counts):
+        """The program's counts into the next ``step`` event's counters:
+        once per executable this boot got, traced or loaded."""
+        tele = telemetry.get()
+        for name, value in counts.items():
+            tele.add_count(name, value)
+
+    def _take_counts(self):
+        """Close the running trace's account: its counts, handed on."""
+        counts = self.trace_counts()
+        self._trace_notes = {}
+        self._hand_counts(counts)
+        return counts
 
     # -- call paths --------------------------------------------------------
 
@@ -175,7 +213,11 @@ class Program:
                                reason=f"call: {type(e).__name__}: "
                                       f"{str(e)[:160]}")
         with telemetry.jit_label(self.label, self):
-            return self._fn(*args)
+            out = self._fn(*args)
+        if self._trace_notes:
+            # this call traced: its counts go to the step that ran it
+            self._take_counts()
+        return out
 
     def _ensure(self, sig, args):
         """Resolve one shape signature: load its artifact, or compile
@@ -199,7 +241,9 @@ class Program:
             if compiled is not None:
                 self.aot_hits += 1
                 self._emit("hit", compiled, bytes=info["bytes"],
-                           seconds=round(info["seconds"], 4))
+                           seconds=round(info["seconds"], 4),
+                           **info["trace_counts"])
+                self._hand_counts(info["trace_counts"])
                 self._compiled[sig] = compiled
                 return compiled
 
@@ -235,8 +279,10 @@ class Program:
             # a program that does not fit) fails the same way through
             # plain jit: it raises
             c0 = self.compiles
+            self._trace_notes = {}
             with telemetry.jit_label(self.label, self):
                 compiled = lower(*args).compile()
+            counts = self._take_counts()
 
             if self.compiles == c0:
                 # the compile was served from the persistent XLA cache:
@@ -248,14 +294,15 @@ class Program:
                 # cache; the artifact gets written by whichever boot
                 # pays the real compile.
                 self._emit("skip_save", compiled,
-                           reason="compile served from persistent cache")
+                           reason="compile served from persistent cache",
+                           **counts)
             else:
                 try:
                     nbytes, seconds = aot.save(path, self.key, sig,
-                                               compiled)
+                                               compiled, counts)
                     self.aot_saves += 1
                     self._emit("save", compiled, bytes=nbytes,
-                               seconds=round(seconds, 4))
+                               seconds=round(seconds, 4), **counts)
                 except Exception as e:  # noqa: BLE001 - save is cosmetic
                     self._emit("fallback",
                                reason=f"save: {type(e).__name__}: "

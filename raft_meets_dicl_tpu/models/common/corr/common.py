@@ -47,15 +47,19 @@ def sample_window_fast(f2, coords, radius):
 def record_matching_bytes(*arrays):
     """Trace-time accounting of the matching volumes fed to the cost nets.
 
-    Called while the model traces (once per compile): the byte count lands
-    in the next ``step`` event's counters as ``matching_volume_bytes``, so
+    Called while the model traces: the byte count lands in the next
+    ``step`` event's counters as ``matching_volume_bytes``, so
     events.jsonl shows the window/volume footprint the matching path moves
     per step — and the drop when the unstacked/bf16 fast path is active.
+    The program that owns the trace keeps the count with its executable
+    (``telemetry.note_trace``), so a boot that loads the program from the
+    store reports it too; a model that runs the matching inside a
+    ``telemetry.trace_site`` gets its forward pass's bytes per step.
     """
     from .... import telemetry
 
     n = sum(int(a.size) * a.dtype.itemsize for a in arrays)
-    telemetry.get().add_count("matching_volume_bytes", n)
+    telemetry.note_trace("matching_volume_bytes", n)
     return n
 
 

@@ -10,6 +10,7 @@ apply the displacement-aware projection.
 from typing import Any
 
 import flax.linen as nn
+import jax
 
 from ..blocks.dicl import DisplacementAwareProjection, MatchingNet
 from .common import (
@@ -39,7 +40,10 @@ class CorrelationModule(nn.Module):
     def __call__(self, f1, f2, coords, dap=True, train=False, frozen_bn=False):
         b, h, w, _ = f1.shape
 
-        window = sample_window_fast(f2, coords, self.radius)
+        # scopes: a device trace tells sampler, cost net and projection
+        # apart by the name stack of their operations
+        with jax.named_scope("matching/sampler"):
+            window = sample_window_fast(f2, coords, self.radius)
         # unstacked pair: MatchingNet's first conv computes the f1 half
         # once and broadcasts it over the (2r+1)² displacements — the
         # (B, du, dv, H, W, 2C) stacked volume's f1 copies never exist
@@ -51,14 +55,16 @@ class CorrelationModule(nn.Module):
         if not self.is_initializing():
             record_matching_bytes(f1, window)
 
-        cost = MatchingNet(norm_type=self.norm_type, scale=self.mnet_scale,
-                           dtype=self.dtype)(
-            (f1, window), train, frozen_bn
-        )  # (B, H, W, du, dv) float32
+        with jax.named_scope("matching/mnet"):
+            cost = MatchingNet(norm_type=self.norm_type,
+                               scale=self.mnet_scale, dtype=self.dtype)(
+                (f1, window), train, frozen_bn
+            )  # (B, H, W, du, dv) float32
 
         if dap:
-            cost = DisplacementAwareProjection(
-                (self.radius, self.radius), init=self.dap_init
-            )(cost)
+            with jax.named_scope("matching/dap"):
+                cost = DisplacementAwareProjection(
+                    (self.radius, self.radius), init=self.dap_init
+                )(cost)
 
         return cost.reshape(b, h, w, self.output_dim)

@@ -26,6 +26,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ... import telemetry
 from ...ops.upsample import interpolate_bilinear, upsample_flow_2x
 from ..common import corr as corr_mod
 from ..common import encoders, hsup
@@ -89,7 +90,8 @@ class _CtfStep(nn.Module):
         if self.corr_grad_stop:
             corr = jax.lax.stop_gradient(corr)
 
-        h, d = self.update(h, x, corr, prev)
+        with jax.named_scope("update"):
+            h, d = self.update(h, x, corr, prev)
         coords1 = coords1 + d
         flow = coords1 - coords0
 
@@ -292,41 +294,48 @@ class RaftPlusDiclCtfModule(nn.Module):
                 train=train, frozen_bn=frozen_bn,
             )
 
-            if unrolled:
-                # python loop over the same step module — sequential
-                # batch-stat updates, identical parameter paths
-                step = body(**shared)
-                carry = (h_state, flow)
-                flows, hiddens, readouts, prevs = [], [], [], []
-                for _ in range(n_iter):
-                    carry, (fl, hi, ro, pv) = step(
-                        carry, jnp.zeros((0,), dtype=jnp.bfloat16),
+            # one scope and one trace site a level: the device trace names
+            # the level's operations, and the counts the matching notes
+            # while it traces (sampler path, matching bytes) stand for the
+            # level's ``n_iter`` iterations, once, however often the
+            # tracer visits the scan's body
+            with jax.named_scope(f"level{lvl}"), \
+                    telemetry.trace_site(f"level{lvl}", n_iter):
+                if unrolled:
+                    # python loop over the same step module — sequential
+                    # batch-stat updates, identical parameter paths
+                    step = body(**shared)
+                    carry = (h_state, flow)
+                    flows, hiddens, readouts, prevs = [], [], [], []
+                    for _ in range(n_iter):
+                        carry, (fl, hi, ro, pv) = step(
+                            carry, jnp.zeros((0,), dtype=jnp.bfloat16),
+                            f1[fine_idx], f2[fine_idx], x, coords0,
+                        )
+                        flows.append(fl)
+                        hiddens.append(hi)
+                        readouts.append(ro)
+                        prevs.append(pv)
+                    h_state, flow = carry
+
+                    flows = jnp.stack(flows)
+                    hiddens = jnp.stack(hiddens)
+                    readouts = jnp.stack(readouts)
+                    prevs = jnp.stack(prevs)
+                else:
+                    step = nn.scan(
+                        body,
+                        variable_broadcast=["params", "batch_stats"],
+                        split_rngs={"params": False, "dropout": True},
+                        in_axes=(0, nn.broadcast, nn.broadcast, nn.broadcast,
+                                 nn.broadcast),
+                        out_axes=0,
+                    )(**shared)
+
+                    (h_state, flow), (flows, hiddens, readouts, prevs) = step(
+                        (h_state, flow), jnp.zeros((n_iter, 0), dtype=jnp.bfloat16),
                         f1[fine_idx], f2[fine_idx], x, coords0,
                     )
-                    flows.append(fl)
-                    hiddens.append(hi)
-                    readouts.append(ro)
-                    prevs.append(pv)
-                h_state, flow = carry
-
-                flows = jnp.stack(flows)
-                hiddens = jnp.stack(hiddens)
-                readouts = jnp.stack(readouts)
-                prevs = jnp.stack(prevs)
-            else:
-                step = nn.scan(
-                    body,
-                    variable_broadcast=["params", "batch_stats"],
-                    split_rngs={"params": False, "dropout": True},
-                    in_axes=(0, nn.broadcast, nn.broadcast, nn.broadcast,
-                             nn.broadcast),
-                    out_axes=0,
-                )(**shared)
-
-                (h_state, flow), (flows, hiddens, readouts, prevs) = step(
-                    (h_state, flow), jnp.zeros((n_iter, 0), dtype=jnp.bfloat16),
-                    f1[fine_idx], f2[fine_idx], x, coords0,
-                )
 
             flow = flows[-1]
 

@@ -1126,9 +1126,16 @@ def _sw_fits_vmem(f2, coords, radius):
     return total <= 64 * 1024 * 1024
 
 
+def _sw_takes_kernel(f2, coords, radius):
+    """Which form a sampler call traces: the Mosaic kernel on the TPU
+    where the shapes fit VMEM, the XLA reference everywhere else."""
+    return jax.default_backend() == "tpu" and _sw_fits_vmem(f2, coords,
+                                                            radius)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def _sw(f2, coords, radius):
-    if jax.default_backend() == "tpu" and _sw_fits_vmem(f2, coords, radius):
+    if _sw_takes_kernel(f2, coords, radius):
         return _sw_fwd_tpu(f2, coords, radius)
     return _sw_reference(f2, coords, radius)
 
@@ -1139,7 +1146,7 @@ def _sw_vjp_fwd(f2, coords, radius):
 
 def _sw_vjp_bwd(radius, res, dout):
     f2, coords = res
-    if jax.default_backend() == "tpu" and _sw_fits_vmem(f2, coords, radius):
+    if _sw_takes_kernel(f2, coords, radius):
         df2 = _sw_bwd_tpu(f2, coords, dout, radius)
     else:
         def f(f2_):
@@ -1165,9 +1172,21 @@ def sample_window_fused(f2, coords, radius=4):
     kernel computes in f32 and rounds once on write. Coordinates are
     treated as non-differentiable (zero gradient): callers inside the
     recurrent estimators detach the lookup centers.
+
+    Which form the call traced is counted (``sw_fused_calls`` /
+    ``sw_fallback_calls``, ``telemetry.note_trace``): the fallback is
+    silent in the arithmetic and costs an order of magnitude in time, so
+    the program's ``compile`` and ``aot`` events say which one it holds.
     """
-    return _per_shard(lambda a, c: _sw(a, c, radius))(
-        f2, coords).astype(f2.dtype)
+    from .. import telemetry
+
+    def sample(a, c):
+        telemetry.note_trace(
+            "sw_fused_calls" if _sw_takes_kernel(a, c, radius)
+            else "sw_fallback_calls", 1)
+        return _sw(a, c, radius)
+
+    return _per_shard(sample)(f2, coords).astype(f2.dtype)
 
 
 def convex_combine_8x(mask_logits, win, temperature=4.0):

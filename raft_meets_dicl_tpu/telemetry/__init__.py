@@ -48,6 +48,8 @@ from .core import (
     interval,
     jit_label,
     memory_snapshot,
+    note_trace,
+    trace_site,
     validate_event,
 )
 
@@ -58,5 +60,5 @@ __all__ = [
     "NewerSchema", "NullTelemetry", "Telemetry", "UnknownKind",
     "activate", "create", "deactivate", "emit_span", "enabled", "get",
     "install_listeners", "instrument_jit", "interval", "jit_label",
-    "memory_snapshot", "validate_event",
+    "memory_snapshot", "note_trace", "trace_site", "validate_event",
 ]

@@ -60,6 +60,9 @@ SCHEMA = {
     # how long the host blocked, and the loss of the step it resolved —
     # with epoch_end's, the only loss values in the stream
     "device_sync": {"step", "seconds"},
+    # a backend compile; with the counts the owning Program's trace
+    # noted (``note_trace``: sw_fused_calls, sw_fallback_calls,
+    # matching_volume_bytes) when it noted any
     "compile": {"label", "seconds"},
     "cache": {"event"},
     "memory": {"host_rss_gib", "live_arrays"},
@@ -86,7 +89,10 @@ SCHEMA = {
     # events that hold an executable (hit | save | skip_save) carry
     # mosaic_calls, the Pallas TPU custom calls in its HLO: the kernels
     # give way to their XLA references at trace time without a word,
-    # and this is how the run shows which form it got.
+    # and this is how the run shows which form it got. They carry the
+    # program's trace-time counts too (see "compile"): a hit reads them
+    # from the artifact, so a boot that never traces still says which
+    # path each sampler call took.
     "aot": {"event"},
     # boot configuration: the effective persistent compile-cache and AOT
     # program directories (instead of silently defaulting), plus the
@@ -625,6 +631,41 @@ def instrument_jit(label, fn):
     return wrapped
 
 
+# -- trace-time counts --------------------------------------------------------
+
+_trace_sites = threading.local()
+
+
+@contextlib.contextmanager
+def trace_site(label, repeat=1):
+    """Name a part of the program being traced that runs ``repeat`` times
+    an execution (a scan's body: ``repeat`` is its length). The tracer
+    may visit such a body more than once (flax's lifted scan traces it
+    twice), so a count noted inside a site is kept once per site and
+    name, whatever the number of visits, and multiplied by ``repeat``:
+    what one execution of the program does, not what its tracing did."""
+    stack = getattr(_trace_sites, "stack", ())
+    _trace_sites.stack = stack + ((str(label), int(repeat)),)
+    try:
+        yield
+    finally:
+        _trace_sites.stack = stack
+
+
+def note_trace(name, value):
+    """A count known while a program traces (which path a kernel's
+    dispatch took, the bytes a shape makes a layer move). It belongs to
+    the registry Program whose trace is running (the ``jit_label``
+    scope), which carries it in its ``compile`` and ``aot`` events,
+    stores it with the executable and hands it to the next ``step``
+    event's counters on every boot, traced or loaded. Outside a
+    Program's trace (``model.init``, an eager apply) the count describes
+    no program and is dropped."""
+    program = getattr(_jit_label, "program", None)
+    if program is not None:
+        program.note_trace(name, value, getattr(_trace_sites, "stack", ()))
+
+
 def install_listeners():
     """Register the process-wide jax.monitoring forwarders (idempotent).
 
@@ -671,9 +712,10 @@ def install_listeners():
             program.record_compile(float(duration))
         if not _active.enabled:
             return
+        counts = program.trace_counts() if program is not None else {}
         _active.emit("compile",
                      label=getattr(_jit_label, "value", None) or "jit",
-                     seconds=round(float(duration), 6))
+                     seconds=round(float(duration), 6), **counts)
 
     monitoring.register_event_listener(on_event)
     monitoring.register_event_duration_secs_listener(on_duration)

@@ -277,6 +277,7 @@ def test_pair_embedding_unstacked_matches_stacked():
 
 
 def test_matching_volume_bytes_counter():
+    from raft_meets_dicl_tpu import compile as programs
     from raft_meets_dicl_tpu import telemetry
 
     sink = telemetry.create()  # memory-only
@@ -286,7 +287,10 @@ def test_matching_volume_bytes_counter():
         m = MlCorrelationModule(feature_dim=6, levels=2, radius=1,
                                 share=True, dtype=jnp.bfloat16)
         v = m.init(RNG, fmap1[:2], fmap2[:2], coords)
-        m.apply(v, fmap1[:2], fmap2[:2], coords)
+        # counts belong to the program whose trace notes them
+        step = programs.register_step("probe", jax.jit(
+            lambda v: m.apply(v, fmap1[:2], fmap2[:2], coords)))
+        step(v)
         sink.step_event(0)
         steps = [e for e in sink.events if e["kind"] == "step"]
         counters = steps[-1].get("counters", {})
