@@ -4,7 +4,9 @@ Capability parity with the reference inspector stack
 (src/inspect/summary.py:48-663), redesigned for the jitted training loop:
 
 - train-batch metrics read the train step's aux outputs (loss, final flow,
-  optionally gradients) instead of live module state,
+  optionally gradients) instead of live module state; they are launched on
+  the device with the step and read one step later, once the next step is
+  launched too, so that the loop never waits for the step it just started,
 - validation runs a memoized jitted forward+loss step per stage and reduces
   metrics host-side, then triggers ``CheckpointManager.create`` — the only
   place checkpoints are born during training, like the reference
@@ -31,7 +33,10 @@ from .writer import SummaryWriter
 
 class MetricsGroup:
     """Frequency-gated accumulate-and-reduce over train batches
-    (src/inspect/summary.py:48-93)."""
+    (src/inspect/summary.py:48-93). ``compute`` launches a micro-batch's
+    metrics and keeps their scalars where they are computed; ``take``
+    hands the step's unread scalars over, and ``reduce`` takes them back
+    once they are fetched."""
 
     @classmethod
     def from_config(cls, cfg):
@@ -47,6 +52,13 @@ class MetricsGroup:
         self.metrics = mtx
         self.values = [defaultdict(list) for _ in self.metrics]
 
+        # the metrics that are functions of the step's outputs alone run
+        # as one program: one launch and one transfer of target and valid
+        # a micro-batch, whatever their number
+        self._traced = [m for m in self.metrics if m.traceable]
+        self._launch = telemetry.instrument_jit(
+            "train_metrics", jax.jit(self._compute_traced))
+
     def get_config(self):
         return {
             "frequency": self.frequency,
@@ -61,15 +73,30 @@ class MetricsGroup:
     def reset(self):
         self.values = [defaultdict(list) for _ in self.metrics]
 
+    def _compute_traced(self, estimate, target, valid, loss):
+        return [m.compute(None, estimate, target, valid, loss)
+                for m in self._traced]
+
     def compute(self, ctx_m, estimate, target, valid, loss):
+        traced = iter(self._launch(estimate, target, valid, loss)
+                      if self._traced else ())
         for i, metric in enumerate(self.metrics):
-            for k, v in metric(ctx_m, estimate, target, valid, loss).items():
+            vals = (next(traced) if metric.traceable
+                    else metric.compute(ctx_m, estimate, target, valid, loss))
+            for k, v in vals.items():
                 self.values[i][k].append(v)
 
-    def reduce(self):
+    def take(self):
+        """The scalars launched since the last ``take``, unread."""
+        values = [OrderedDict(v) for v in self.values]
+        self.reset()
+        return values
+
+    def reduce(self, values):
+        """{tag: float} of one step from its fetched ``take``."""
         result = OrderedDict()
-        for i, values in enumerate(self.values):
-            for k, v in self.metrics[i].reduce(values).items():
+        for metric, vals in zip(self.metrics, values):
+            for k, v in metric.reduce(vals).items():
                 result[f"{self.prefix}{k}"] = v
         return result
 
@@ -532,6 +559,9 @@ class SummaryInspector(Inspector):
 
         self.batch_index = 0
         self._capture_fns = {}
+        # the newest closed step's scalars, launched and not yet read:
+        # (step, format arguments, each group's ``take``)
+        self._unread = None
 
     @property
     def wants_gradients(self):
@@ -671,11 +701,35 @@ class SummaryInspector(Inspector):
         for m in self.metrics:
             m.reset()
 
+    def _write_scalars(self, counter):
+        """Read the held step's scalars in one fetch and write them under
+        that step's own index and format arguments."""
+        unread, self._unread = self._unread, None
+        if unread is None:
+            return
+        step, fmtargs, taken = unread
+        taken = metrics.functional.fetch_scalars(taken)
+
+        current = self.writer.fmt.fmtargs
+        self.writer.set_fmtargs(fmtargs)
+        for m, values in zip(self.metrics, taken):
+            for k, v in m.reduce(values).items():
+                self.writer.add_scalar(k, v, step)
+        self.writer.set_fmtargs(current)
+        telemetry.get().add_count(counter, 1)
+
+    def flush(self):
+        self._write_scalars("scalars_flushed")
+        # down to the event file: a run that stops here leaves its last
+        # steps readable
+        self.writer.flush()
+
     def on_step_end(self, log, ctx, stage, epoch, i):
-        for m in self.metrics:
-            for k, v in m.reduce().items():
-                self.writer.add_scalar(k, v, ctx.step)
-            m.reset()
+        # this step's program is launched, so reading the step before it
+        # stalls nothing; this step's own scalars wait for the next one
+        self._write_scalars("scalars_late")
+        self._unread = (ctx.step, self.writer.fmt.fmtargs,
+                        [m.take() for m in self.metrics])
 
         # mirror the newest telemetry step record into the TB scalars, so
         # phase timings sit next to the training curves without opening
@@ -694,6 +748,7 @@ class SummaryInspector(Inspector):
         due = [v for v in self.val_step
                if ctx.step > 0 and ctx.step % v.frequency == 0]
         if due:
+            self.flush()
             self._pre_validation(log, ctx)
             for val in due:
                 val.run(log, ctx, self.writer, self.checkpoints, stage, epoch)

@@ -13,6 +13,8 @@ from typing import Any, List, Optional
 
 import numpy as np
 
+from . import functional as F
+
 
 @dataclass
 class MetricContext:
@@ -29,6 +31,11 @@ class MetricContext:
 
 class Metric:
     type = None
+
+    # whether ``compute`` is a pure function of (estimate, target, valid,
+    # loss): the inspector's MetricsGroup then traces it into the one
+    # program it launches a step
+    traceable = False
 
     @classmethod
     def _typecheck(cls, cfg):
@@ -63,12 +70,18 @@ class Metric:
         raise NotImplementedError
 
     def compute(self, ctx, estimate, target, valid, loss):
-        """Compute {key: float}. ``estimate``/``target`` are NHWC flow
-        arrays (batched or single), ``valid`` the matching mask."""
+        """Launch the computation and return {key: scalar} without
+        waiting for it: a scalar is an on-device array that may still be
+        in flight, or a float where the host has the value already.
+        ``estimate``/``target`` are NHWC flow arrays (batched or single),
+        ``valid`` the matching mask."""
         raise NotImplementedError
 
     def __call__(self, ctx, estimate, target, valid, loss):
-        return self.compute(ctx, estimate, target, valid, loss)
+        """{key: float}: ``compute``, then one fetch of all its scalars
+        (validation and evaluation, which read every value at once)."""
+        return F.fetch_scalars(OrderedDict(
+            self.compute(ctx, estimate, target, valid, loss)))
 
     def reduce(self, values):
         """Reduce accumulated per-step value lists {key: [floats]}."""

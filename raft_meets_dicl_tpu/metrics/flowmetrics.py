@@ -14,6 +14,7 @@ from .common import Metric
 
 class EndPointError(Metric):
     type = "epe"
+    traceable = True
 
     @classmethod
     def from_config(cls, cfg):
@@ -30,9 +31,7 @@ class EndPointError(Metric):
         return {"type": self.type, "key": self.key, "distances": self.distances}
 
     def compute(self, ctx, estimate, target, valid, loss):
-        # one batched device->host fetch for mean + every distance bucket
-        vals = F.fetch_scalars(
-            F.end_point_error(estimate, target, valid, self.distances))
+        vals = F.end_point_error(estimate, target, valid, self.distances)
 
         result = OrderedDict()
         result[f"{self.key}mean"] = vals["mean"]
@@ -43,6 +42,7 @@ class EndPointError(Metric):
 
 class FlAll(Metric):
     type = "fl-all"
+    traceable = True
 
     @classmethod
     def from_config(cls, cfg):
@@ -56,7 +56,7 @@ class FlAll(Metric):
         return {"type": self.type, "key": self.key}
 
     def compute(self, ctx, estimate, target, valid, loss):
-        return {self.key: float(F.fl_all(estimate, target, valid))}
+        return {self.key: F.fl_all(estimate, target, valid)}
 
 
 class AverageAngularError(Metric):
@@ -65,6 +65,7 @@ class AverageAngularError(Metric):
     the reference's unmasked semantics."""
 
     type = "aae"
+    traceable = True
 
     @classmethod
     def from_config(cls, cfg):
@@ -81,7 +82,7 @@ class AverageAngularError(Metric):
 
     def compute(self, ctx, estimate, target, valid, loss):
         v = valid if self.masked else None
-        return {self.key: float(F.average_angular_error(estimate, target, v))}
+        return {self.key: F.average_angular_error(estimate, target, v)}
 
 
 class FlowMagnitude(Metric):
@@ -89,6 +90,7 @@ class FlowMagnitude(Metric):
     AverageAngularError)."""
 
     type = "flow-magnitude"
+    traceable = True
 
     @classmethod
     def from_config(cls, cfg):
@@ -108,4 +110,4 @@ class FlowMagnitude(Metric):
 
     def compute(self, ctx, estimate, target, valid, loss):
         v = valid if self.masked else None
-        return {self.key: float(F.flow_magnitude(estimate, self.ord, v))}
+        return {self.key: F.flow_magnitude(estimate, self.ord, v)}

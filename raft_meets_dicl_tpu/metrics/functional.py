@@ -110,10 +110,12 @@ def tree_named_leaves(tree):
 
 
 def fetch_scalars(scalars):
-    """One device→host transfer for a whole dict of on-device scalars —
-    per-leaf ``float()`` fetches would serialize the device pipeline."""
+    """One device→host transfer for a whole pytree of scalars, on-device
+    or already floats — per-leaf ``float()`` fetches would serialize the
+    device pipeline. A plain dict comes back with its keys sorted, an
+    OrderedDict as it was."""
     host = jax.device_get(scalars)  # graftlint: disable=host-sync -- the sanctioned batched fetch point for metric scalars
-    return {k: float(v) for k, v in host.items()}  # graftlint: disable=host-sync -- values already on host (device_get above)
+    return jax.tree.map(float, host)
 
 
 def tree_norm(tree, ord=2):

@@ -17,6 +17,7 @@ from .common import Metric
 
 class Loss(Metric):
     type = "loss"
+    traceable = True
 
     @classmethod
     def from_config(cls, cfg):
@@ -30,7 +31,7 @@ class Loss(Metric):
         return {"type": self.type, "key": self.key}
 
     def compute(self, ctx, estimate, target, valid, loss):
-        return {self.key: float(loss)}
+        return {self.key: loss}
 
 
 class LearningRate(Metric):

@@ -12,6 +12,13 @@ class Inspector:
         host images to normalized f32 when this returns True."""
         return False
 
+    def flush(self):
+        """Write out whatever the inspector holds back (a step's scalars
+        that it reads one step late). The loop calls this wherever it
+        stops stepping or somebody may read what was written: when an
+        epoch's loop ends, before a failed-state dump and a rollback."""
+        pass
+
     def on_step_start(self, log, ctx, stage, epoch, i):
         pass
 

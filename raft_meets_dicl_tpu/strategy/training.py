@@ -843,6 +843,10 @@ class TrainingContext:
                 break
 
         self.log = log
+        # the loop has stopped stepping (epoch end, step limit, requested
+        # stop): nothing is launched behind the last step any more, and
+        # validation, checkpoints and the run's end read what was written
+        self.inspector.flush()
         self._flush_finite_check(log)
 
         # memory watermarks: RMD_DEBUG_MEM's ad-hoc print, promoted to a
@@ -968,6 +972,7 @@ class TrainingContext:
 
     def _rollback(self, log, stage, epoch):
         """Restore the last valid checkpoint after persistent trips."""
+        self.inspector.flush()
         self._nf_rollbacks += 1
         if self._nf_rollbacks > self.nonfinite.max_rollbacks:
             self._dump_failed(log, stage, epoch)
@@ -1258,6 +1263,7 @@ class TrainingContext:
 
     def _dump_failed(self, log, stage, epoch):
         log.error("detected non-finite values in final flow field")
+        self.inspector.flush()
         # auto-flushes the sink (nonfinite is a boundary event): the run
         # is about to die and the JSONL must survive for the post-mortem.
         # The recent sample-id window makes the trip reproducible offline

@@ -2,6 +2,7 @@
 checkpoints, hooks, and the grad-accum skip realignment."""
 
 import numpy as np
+import pytest
 
 import raft_meets_dicl_tpu.inspect as inspect_
 import raft_meets_dicl_tpu.models as models
@@ -148,6 +149,209 @@ def test_summary_inspector_end_to_end(tmp_path):
     assert "Train:S0:test.s0/flow-est" in tags
     assert "Validation:S0:test.s0:fake/EndPointError/mean" in tags
     assert "Validation:S0:test.s0:fake/i0/flow-est" in tags
+
+
+# -- a step's scalars are launched with the step and read one step late ------
+
+LATE_CFG = {
+    "metrics": [{
+        "prefix": "Train:S{n_stage}:{id_stage}/",
+        "frequency": 1,
+        "metrics": [
+            {"type": "epe"},
+            {"type": "fl-all"},
+            {"type": "loss"},
+            {"type": "learning-rate"},
+            {"type": "flow-magnitude"},
+        ],
+    }],
+    "checkpoints": INSPECT_CFG["checkpoints"],
+    "tensorboard": {"path": "tb.{id_model}"},
+}
+
+
+class Recorder:
+    """The inspector, plus a record of what each micro-batch computed
+    (which the late scalars are compared with) and a stop on request.
+    Everything not overridden forwards."""
+
+    def __init__(self, inner, stop_after=None):
+        self._inner = inner
+        self._stop_after = stop_after
+        self.batches = []       # (step, stage, final, target, valid, loss, lr)
+
+    def __getattr__(self, name):
+        return getattr(self._inner, name)
+
+    def on_batch(self, log, ctx, stage, epoch, i, img1, img2, target, valid,
+                 meta, result, loss):
+        self.batches.append((ctx.step, stage, np.asarray(result.final()),
+                             target, valid, float(loss), ctx.last_lr))
+        return self._inner.on_batch(log, ctx, stage, epoch, i, img1, img2,
+                                    target, valid, meta, result, loss)
+
+    def on_step_end(self, log, ctx, stage, epoch, i):
+        out = self._inner.on_step_end(log, ctx, stage, epoch, i)
+        if ctx.step == self._stop_after:
+            ctx.request_stop("test")
+        return out
+
+
+def _expected_scalars(batches):
+    """{(tag, step): value} by a direct computation on what the steps'
+    micro-batches put out: means over a step's micro-batches, the last
+    learning rate."""
+    from raft_meets_dicl_tpu.metrics import functional as F
+
+    steps = {}
+    for step, stage, final, target, valid, loss, lr in batches:
+        epe = F.end_point_error(final, target, valid)
+        vals = {f"EndPointError/{k}": float(v) for k, v in epe.items()}
+        vals["Fl-all"] = float(F.fl_all(final, target, valid))
+        vals["Loss"] = loss
+        vals["FlowMagnitude"] = float(F.flow_magnitude(final))
+        steps.setdefault(step, (stage, [], []))
+        steps[step][1].append(vals)
+        steps[step][2].append(lr)
+
+    out = {}
+    for step, (stage, vals, lrs) in steps.items():
+        pfx = f"Train:S{stage.index}:{stage.id.replace('/', '.')}/"
+        for k in vals[0]:
+            out[pfx + k, step] = float(np.mean([v[k] for v in vals]))
+        out[pfx + "LearningRate", step] = lrs[-1]
+    return out
+
+
+def _long_stage(id="test/s0", epochs=1, accumulate=1, validation=False):
+    stage = _make_stage(epochs=epochs, accumulate=accumulate)
+    stage.id = id
+    stage.data = strategy.spec.DataSpec(FlowSource(8), epochs=epochs,
+                                        batch_size=2)
+    if validation:
+        stage.validation = [strategy.spec.ValidationSpec(
+            name="fake", source=FlowSource(2), batch_size=1, images=set())]
+    return stage
+
+
+ENDINGS = {
+    # name: (stages, inspector config, step limit, stop after step, steps run)
+    "epoch-validation": (
+        lambda: [_long_stage(epochs=2, validation=True)],
+        LATE_CFG | {"validation": INSPECT_CFG["validation"]}, None, None, 8),
+    "request-stop": (lambda: [_long_stage()], LATE_CFG, None, 1, 2),
+    "step-limit": (lambda: [_long_stage(epochs=2)], LATE_CFG, 5, None, 5),
+    "stage-change": (
+        lambda: [_long_stage("test/s0"), _long_stage("test/s1")],
+        LATE_CFG, None, None, 8),
+    "accumulate-2": (lambda: [_long_stage(accumulate=2)], LATE_CFG, None,
+                     None, 2),
+}
+
+
+@pytest.mark.parametrize("ending", sorted(ENDINGS))
+def test_late_scalars_every_step_once(tmp_path, ending):
+    """However the loop ends, every step has each scalar tag exactly once,
+    under its own index and stage, with the value a direct computation on
+    that step's outputs gives."""
+    stages, cfg, step_limit, stop_after, steps = ENDINGS[ending]
+    ctx, _, inspector = _make_inspected_context(tmp_path, stages(), cfg)
+    ctx.step_limit = step_limit
+    ctx.inspector = rec = Recorder(inspector, stop_after)
+    ctx.run()
+    assert ctx.step == steps
+
+    # read before the writer is closed: the flush where the loop stopped
+    # has taken the last step's scalars down to the event file
+    written = [(t, s, v) for t, s, v in _read_events(tmp_path / "tb.tiny")
+               if t.startswith("Train:")]
+    inspector.writer.close()
+    expected = _expected_scalars(rec.batches)
+
+    assert sorted((t, s) for t, s, _ in written) == sorted(expected)
+    assert {s for _, s in expected} == set(range(steps))
+    for tag, step, value in written:
+        assert value == pytest.approx(expected[tag, step], rel=1e-5,
+                                      abs=1e-7), (tag, step)
+
+
+def test_scalars_are_read_after_the_next_launch(tmp_path, monkeypatch):
+    """Between the launch of a step that is neither a finite-check nor an
+    image step and the launch of the next one, the only fetch is the one
+    for the step before: its own scalars are read once the next step is
+    launched. ``scalars_late`` + ``scalars_flushed`` count every step."""
+    from raft_meets_dicl_tpu import telemetry
+    from raft_meets_dicl_tpu.metrics import functional as F
+    from raft_meets_dicl_tpu.strategy import training
+
+    monkeypatch.setenv("RMD_FINITE_CHECK_EVERY", "3")
+    trail = []      # ("launch",) | ("fetch",) | ("write", step)
+
+    real_fetch, real_make = F.fetch_scalars, training.make_train_step
+
+    def fetch_scalars(scalars):
+        trail.append(("fetch",))
+        return real_fetch(scalars)
+
+    def make_train_step(*args, **kwargs):
+        step_fn = real_make(*args, **kwargs)
+
+        def launch(*a, **kw):
+            trail.append(("launch",))
+            return step_fn(*a, **kw)
+
+        return launch
+
+    monkeypatch.setattr(F, "fetch_scalars", fetch_scalars)
+    monkeypatch.setattr(training, "make_train_step", make_train_step)
+
+    ctx, _, inspector = _make_inspected_context(
+        tmp_path, [_long_stage(epochs=2)], LATE_CFG)
+    add_scalar = inspector.writer.add_scalar
+    monkeypatch.setattr(
+        inspector.writer, "add_scalar",
+        lambda key, value, step=None: (
+            # the metric groups' scalars, not the telemetry mirror's
+            trail.append(("write", step)) if key.startswith("Train:")
+            else None, add_scalar(key, value, step)))
+
+    sink = telemetry.activate(telemetry.Telemetry())
+    counted = {}
+    add_count = sink.add_count
+    monkeypatch.setattr(
+        sink, "add_count",
+        lambda name, n: (counted.update({name: counted.get(name, 0) + n}),
+                         add_count(name, n)))
+    try:
+        ctx.run()
+    finally:
+        telemetry.deactivate()
+    assert ctx.step == 8
+
+    launches = [k for k, ev in enumerate(trail) if ev == ("launch",)]
+    assert len(launches) == 8
+    for step, (a, b) in enumerate(zip(launches, launches[1:])):
+        between = trail[a + 1:b]
+        writes = {ev[1] for ev in between if ev[0] == "write"}
+        if step in (0, 4):
+            # the first step of an epoch: nothing is held from before it
+            # (the epoch's end flushed the last step's)
+            assert between == []
+        elif step == 3:
+            # the first epoch's last step: the loop stops stepping, and
+            # the flush at the epoch's end reads it too
+            assert between.count(("fetch",)) == 2
+            assert writes == {2, 3}
+        else:
+            assert between.count(("fetch",)) == 1
+            assert writes == {step - 1}
+
+    # an epoch's last step (3 and 7) is written by the flush at its end
+    assert counted["scalars_late"] == 6
+    assert counted["scalars_flushed"] == 2
+    late = [ev["counters"].get("scalars_late", 0) for ev in sink.events
+            if ev["kind"] == "step"]
+    assert late == [0, 1, 1, 1, 0, 1, 1, 1]
 
 
 class SometimesInvalidSource(Collection):

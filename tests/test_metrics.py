@@ -156,6 +156,83 @@ def test_grad_param_metrics_selection():
     assert m(ctx, None, None, None, 0.0)["LearningRate"] == pytest.approx(1e-4)
 
 
+METRIC_CFGS = [
+    {"type": "epe"},
+    {"type": "fl-all"},
+    {"type": "aae"},
+    {"type": "aae", "masked": True},
+    {"type": "flow-magnitude"},
+    {"type": "flow-magnitude", "masked": True},
+    {"type": "loss"},
+    {"type": "learning-rate"},
+    {"type": "grad-norm", "parameters": "all"},
+    {"type": "grad-mean"},
+    {"type": "grad-minmax"},
+    {"type": "param-norm"},
+    {"type": "param-mean", "parameters": "all"},
+    {"type": "param-minmax"},
+]
+
+
+@pytest.mark.parametrize(
+    "cfg", METRIC_CFGS,
+    ids=lambda c: c["type"] + ("-masked" if c.get("masked") else ""))
+def test_deferred_value_equals_immediate(cfg):
+    """``compute`` launches and leaves its scalars where they are (the
+    traceable metrics', unfetched, on the device); fetched later, alone or
+    through the inspector's group, they are the values ``__call__`` reads
+    at once."""
+    import jax
+    import jax.numpy as jnp
+
+    from raft_meets_dicl_tpu.inspect.summary import MetricsGroup
+
+    est, tgt, valid = _random_flow(8)
+    rng = np.random.RandomState(9)
+    tree = {"enc": {"k": rng.randn(4, 4).astype(np.float32)},
+            "head": {"b": rng.randn(4).astype(np.float32)}}
+    ctx = MetricContext(lr=2.5e-4, params=tree, grads=tree)
+    est, loss = jnp.asarray(est), jnp.float32(1.75)
+
+    m = metrics.Metric.from_config(cfg)
+    immediate = m(ctx, est, tgt, valid, loss)
+    assert immediate and all(type(v) is float for v in immediate.values())
+
+    launched = m.compute(ctx, est, tgt, valid, loss)
+    if m.traceable:
+        assert all(isinstance(v, jax.Array) for v in launched.values())
+    assert F.fetch_scalars(launched) == immediate
+
+    group = MetricsGroup(1, "T/", [m])
+    group.compute(ctx, est, tgt, valid, loss)
+    taken = group.take()
+    assert group.take() == [{}]         # handed over once
+    late = group.reduce(F.fetch_scalars(taken))
+    want = m.reduce({k: [v] for k, v in immediate.items()})
+    assert list(late) == [f"T/{k}" for k in want]
+    for k, v in want.items():
+        assert late[f"T/{k}"] == pytest.approx(v, rel=1e-6, abs=1e-7)
+
+
+def test_validation_metric_reads_at_once():
+    """Validation accumulates floats, the same numbers as before: the mean
+    over batches of what the functional form gives."""
+    from raft_meets_dicl_tpu.inspect.summary import ValidationMetric
+
+    vm = ValidationMetric(metrics.Metric.from_config({"type": "epe"}),
+                          "mean", True)
+    want = []
+    for seed in (10, 11):
+        est, tgt, valid = _random_flow(seed)
+        vm.add(MetricContext(), est, tgt, valid, 0.5)
+        want.append(float(F.end_point_error(est, tgt, valid)["mean"]))
+    assert all(type(v) is float for vs in vm.values.values() for v in vs)
+    res = dict(vm.result())
+    assert res["EndPointError/mean"] == float(np.mean(want))
+    assert set(res) == {"EndPointError/mean", "EndPointError/1px",
+                        "EndPointError/3px", "EndPointError/5px"}
+
+
 def test_metrics_group_and_collectors():
     est, tgt, valid = _random_flow(6)
     ms = metrics.Metrics.from_config(
