@@ -3,7 +3,7 @@
 ``analysis.cost`` (graftcost) *predicts* per-program, per-op-class
 FLOP/byte totals from the lowered StableHLO; this module *measures*
 them. It parses the capture directories the existing surfaces already
-write (``train --profile``, ``/profilez``, ``scripts/profile_bench.py``)
+write (``train --profile``, ``/profilez``)
 — trace-event JSON always, ``.xplane.pb`` where a TF protobuf reader is
 installed — attributes device time to the PR-7 registry's programs, and
 buckets every op into graftcost's op classes plus the two runtime-only
@@ -21,8 +21,8 @@ Two attribution modes, because module names are not unique:
   three ladder rungs lower to ``module @jit_step`` and would be
   indistinguishable in one mixed capture. The segment manifest records
   key, fingerprint and predicted costs next to the raw trace.
-- **post-hoc attribution** (``attribute_trace``, used by ``/profilez``,
-  ``train --profile`` and bench): an existing unsegmented capture is
+- **post-hoc attribution** (``attribute_trace``, used by ``/profilez``
+  and ``train --profile``): an existing unsegmented capture is
   aggregated per ``hlo_module`` and op class, and module names are
   matched back to registered programs only where the mapping is
   unambiguous.

@@ -8,7 +8,7 @@ Public surface:
 - ``enable_aot()`` / ``disable_aot()`` / ``aot_enabled()`` /
   ``programs_dir()`` — the serialized-executable store that lets a
   repeat boot of the same config start stepping with zero compiles
-  (``aot`` module). CLI and bench entry points call ``enable_aot()``;
+  (``aot`` module). CLI entry points call ``enable_aot()``;
   ``RMD_AOT=0`` opts out, ``RMD_AOT_DIR`` relocates the store.
 """
 
@@ -19,15 +19,16 @@ from .aot import (
     programs_dir,
 )
 from .registry import (
-    Program, ProgramKey, ProgramRegistry, flag_items, register_step,
-    registry, reset, shape_signature, unstable,
+    Program, ProgramKey, ProgramRegistry, effective_args_key, flag_items,
+    inference_key, register_step, registry, reset, shape_signature,
+    static_args_key, unstable,
 )
 
 __all__ = [
     "aot",
     "Program", "ProgramKey", "ProgramRegistry",
-    "flag_items", "register_step", "registry", "reset",
-    "shape_signature", "unstable",
+    "effective_args_key", "flag_items", "inference_key", "register_step",
+    "registry", "reset", "shape_signature", "static_args_key", "unstable",
     "aot_enabled", "artifact_path", "disable_aot", "enable_aot",
     "fetch", "publish",
     "fingerprint", "programs_dir",

@@ -44,7 +44,7 @@ def default_dir():
 
 
 def enable_aot(path=None):
-    """Turn the AOT program store on (CLI/bench boots call this, mirroring
+    """Turn the AOT program store on (CLI boots call this, mirroring
     ``compcache.enable_persistent_cache``); ``RMD_AOT=0`` wins. Returns
     the effective programs directory, or None when disabled."""
     if not env.get_bool("RMD_AOT"):

@@ -43,9 +43,39 @@ def unstable(obj):
 def flag_items(**kwargs):
     """Normalize keyword policy flags into the sorted (name, repr) tuple
     a ProgramKey stores. Values must repr deterministically — the
-    ``evaluation.static_args_key`` discipline; callers pass
-    ``unstable(obj)`` for anything that doesn't."""
+    :func:`static_args_key` discipline; callers pass ``unstable(obj)``
+    for anything that doesn't."""
     return tuple(sorted((k, repr(v)) for k, v in kwargs.items()))
+
+
+def static_args_key(args):
+    """Repr-key an argument dict for memoizing jitted fns, or None when any
+    value can't be keyed exactly.
+
+    Array-valued args (e.g. ``flow_init``) are traced into the jit as
+    constants, and their reprs truncate — two different arrays could share a
+    key. Such calls must bypass the registry instead. Shared by every
+    program key in the framework (inference, validation, intermediates
+    capture).
+    """
+    parts = []
+    for k, v in sorted(args.items()):
+        if hasattr(v, "shape") or (
+            isinstance(v, (list, tuple)) and any(hasattr(x, "shape") for x in v)
+        ):
+            return None
+        parts.append((k, repr(v)))
+    return tuple(parts)
+
+
+def effective_args_key(model, model_args):
+    """:func:`static_args_key` of the arguments the model will run with:
+    its *config-default* arguments merged under the explicit overrides,
+    exactly how ``Model.apply`` resolves them at call time. Two models
+    with the same id but different config defaults (e.g. ``iterations``)
+    must NOT share a program or an AOT artifact; keys made of the
+    explicit arguments alone silently collided."""
+    return static_args_key(dict(getattr(model, "arguments", {})) | model_args)
 
 
 @dataclass(frozen=True)
@@ -76,6 +106,32 @@ class ProgramKey:
 
     def canonical(self):
         return repr((self.kind, self.model, self.flags))
+
+
+def inference_key(kind, model, model_args, mesh=None, wire=None,
+                  variables_sharding=None, model_id=None, **flags):
+    """Identity of an inference program, or None when something cannot be
+    keyed exactly: an array-valued argument, or a sharding pytree for
+    the variables (no stable value key). Such a program is built fresh
+    each call.
+
+    Stable when the caller names the model (``model_id``, a config id
+    string); otherwise pinned to this model object, which the program
+    must then keep alive (``Program._refs``) so that its id stays
+    unique. ``flags`` are the variant's own (``iterations``, ``cont``,
+    ``warm``, ``quant``...), beside the ``args``, ``mesh`` and ``wire``
+    every inference program carries.
+    """
+    args_key = effective_args_key(model, model_args)
+    if args_key is None or variables_sharding is not None:
+        return None
+    mesh_key = None if mesh is None else tuple(d.id for d in mesh.devices.flat)
+    wire_key = None if wire is None else (
+        wire.images, wire.flow, wire.pack_valid, wire.clip, wire.range)
+    return ProgramKey(
+        kind=kind, model=model_id or unstable(model),
+        flags=flag_items(args=args_key, mesh=mesh_key, wire=wire_key,
+                         **flags))
 
 
 def mosaic_calls(compiled):
@@ -389,7 +445,7 @@ def registry():
 
 
 def reset():
-    """Drop every registered program (tests / bench cold runs)."""
+    """Drop every registered program (tests)."""
     _registry.clear()
 
 

@@ -16,9 +16,8 @@ Three consumers share the renderer:
   host pipeline just replays the generator on CPU; the samples are fully
   determined by ``(seed, index)``).
 - ``render_sequence`` — coherent multi-frame motion for the streaming
-  video path (BENCH_VIDEO): layers move along constant affine velocity,
-  so warm-start benchmarks get realistic temporal coherence instead of
-  constant-shift toys.
+  video path: layers move along constant affine velocity, so warm-start
+  runs get realistic temporal coherence instead of constant-shift toys.
 - ``perturb`` / ``perturbation_suite`` — standing robustness eval suites
   (fog / blur / noise / low-light at graded severities) over the same
   underlying scenes, with the exact valid masks preserved so metrics

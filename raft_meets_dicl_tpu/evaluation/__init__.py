@@ -15,7 +15,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import compile as programs
 from .. import telemetry, utils
+from ..ops import quant as quant_ops
+from ..ops import warp
+from ..parallel.train import inference_step
+from ..utils import env
 
 
 @dataclass
@@ -34,51 +39,6 @@ class EvalSample:
     final: np.ndarray
     output: Any
     meta: Any
-
-
-# eval programs memoized per (model, args) so repeated evaluate() calls —
-# e.g. a validation pass every N training steps — hit the same registered
-# program instead of re-tracing the full forward pass each time. Bounded
-# FIFO (evicting an entry drops its closure + compiled executables) so
-# long-lived processes sweeping many models don't pin every one forever.
-# This is the fast in-module layer; cross-caller dedupe (training
-# validation vs the eval CLI, same (model, bucket, wire) triple) lives in
-# the process-wide compile.registry keyed by stable model id.
-_EVAL_FN_CACHE = {}
-_EVAL_FN_CACHE_MAX = 8
-
-
-def static_args_key(args):
-    """Repr-key an argument dict for memoizing jitted fns, or None when any
-    value can't be keyed exactly.
-
-    Array-valued args (e.g. ``flow_init``) are traced into the jit as
-    constants, and their reprs truncate — two different arrays could share a
-    key. Such calls must bypass the cache instead. Shared by every jit-fn
-    cache in the framework (here, validation, intermediates capture).
-    """
-    parts = []
-    for k, v in sorted(args.items()):
-        if hasattr(v, "shape") or (
-            isinstance(v, (list, tuple)) and any(hasattr(x, "shape") for x in v)
-        ):
-            return None
-        parts.append((k, repr(v)))
-    return tuple(parts)
-
-
-def _cache_key(model, model_args, mesh=None, wire=None,
-               variables_sharding=None):
-    if variables_sharding is not None:
-        # a sharding pytree has no stable value key; bypass the cache
-        return None
-    args_key = static_args_key(model_args)
-    if args_key is None:
-        return None
-    mesh_key = None if mesh is None else tuple(d.id for d in mesh.devices.flat)
-    wire_key = None if wire is None else (
-        wire.images, wire.flow, wire.pack_valid, wire.clip, wire.range)
-    return (id(model), args_key, mesh_key, wire_key)
 
 
 @dataclass
@@ -198,96 +158,73 @@ def make_eval_fn(model, model_args=None, mesh=None, wire=None,
     ``model_id`` names the model stably (config id string): the program
     then dedupes process-wide in the compile registry — the eval CLI, the
     warmup pass, and training validation all get the *same* program for
-    the same (model, bucket, wire) triple — and, when the AOT store is
-    enabled, its per-shape executables round-trip through serialized
-    artifacts so a repeat boot compiles nothing. Without it the program
-    is keyed by object identity (process-local dedupe only).
+    the same (model, bucket, wire) triple, and repeated ``evaluate()``
+    calls (a validation pass every N training steps) never re-trace the
+    forward pass — and, when the AOT store is enabled, its per-shape
+    executables round-trip through serialized artifacts so a repeat boot
+    compiles nothing. Without it the program is keyed by object identity
+    (process-local dedupe only). What cannot be keyed exactly (see
+    ``compile.inference_key``) is built fresh each call.
     """
-    from .. import compile as programs
-    from ..parallel import partition
-    from ..parallel.mesh import traced_under
-
     model_args = dict(model_args or {})
-    key = _cache_key(model, model_args, mesh, wire, variables_sharding)
-    if key is not None and key in _EVAL_FN_CACHE:
-        return _EVAL_FN_CACHE[key]
 
-    def _cache(step):
-        if key is not None:
-            while len(_EVAL_FN_CACHE) >= _EVAL_FN_CACHE_MAX:
-                _EVAL_FN_CACHE.pop(next(iter(_EVAL_FN_CACHE)))
-            _EVAL_FN_CACHE[key] = step
-        return step
-
-    # registry identity: stable when the caller names the model and every
-    # policy component reprs exactly; otherwise pinned to this model
-    # object (the _refs reference keeps its id unique while cached).
-    # The key hashes the model's *config-default* arguments merged under
-    # the explicit overrides — Model.apply merges them the same way at
-    # call time, so two models with the same id but different config
-    # defaults (e.g. ``iterations``) must NOT share a program/AOT
-    # artifact. Explicit-args-only keys silently collided here.
-    pkey = None
-    args_key = static_args_key(
-        dict(getattr(model, "arguments", {})) | model_args)
-    if args_key is not None and variables_sharding is None:
-        mesh_key = (None if mesh is None
-                    else tuple(d.id for d in mesh.devices.flat))
-        wire_key = None if wire is None else (
-            wire.images, wire.flow, wire.pack_valid, wire.clip, wire.range)
-        pkey = programs.ProgramKey(
-            kind="eval_step",
-            model=model_id or programs.unstable(model),
-            flags=programs.flag_items(
-                args=args_key, mesh=mesh_key, wire=wire_key))
-        existing = programs.registry().get(pkey)
-        if existing is not None:
-            return _cache(existing)
-
-    adapter = model.get_adapter()
-    gather = (mesh is not None and variables_sharding is not None
-              and partition.is_sharded(variables_sharding))
-    repl_one = partition.replicated(mesh) if mesh is not None else None
-
-    def step(variables, img1, img2):
-        if gather:
-            variables = jax.lax.with_sharding_constraint(
-                variables, repl_one)
-        if wire is not None:
-            img1, img2, _, _ = wire.decode(img1, img2)
+    def body(variables, img1, img2):
         out = model.apply(variables, img1, img2, train=False, **model_args)
-        result = adapter.wrap_result(out, img1.shape[1:3])
+        result = model.get_adapter().wrap_result(out, img1.shape[1:3])
         return out, result.final()
 
-    if mesh is None:
-        step = jax.jit(step)
-    else:
-        data = partition.data_sharding(mesh)
-        variables_in = (variables_sharding if variables_sharding is not None
-                        else partition.replicated(mesh))
-        step = traced_under(mesh, jax.jit(
-            step, in_shardings=(variables_in, data, data)))
-
-    # registry Program: compile events attribute to 'eval_step', compiles
-    # count per-program (warmup/stats read them), AOT artifacts for
-    # stable keys; the raw jit stays reachable via __wrapped__
-    step = programs.register_step("eval_step", step, key=pkey)
-    step._refs = (model,)
-
-    return _cache(step)
+    where = dict(mesh=mesh, wire=wire, variables_sharding=variables_sharding)
+    key = programs.inference_key("eval_step", model, model_args,
+                                 model_id=model_id, **where)
+    return inference_step("eval_step", model, body, key, **where)
 
 
-def _rung_model_args(model_args):
-    """Caller's model arguments for a rung/warm program: minus what the
-    builder sets itself, plus ``final_only`` — the program returns
-    (final flow, state), so the model is asked for no other
-    full-resolution flow (part of the key's ``args`` flag)."""
+def _rung_program(model, iterations, variant, carry, extra_inputs, quant,
+                  model_args, model_id, **where):
+    """A fixed-``iterations`` program ``(variables, img1, img2, *extra)
+    -> (final_flow, state)`` of kind ``rung_step``: what
+    :func:`make_rung_fn` and :func:`make_warm_fn` share.
+
+    ``variant`` holds the flags that tell the rungs of one ladder apart
+    beside ``iterations`` (``cont``, ``warm``); ``carry(*extra)`` turns
+    the program's ``extra_inputs`` into the model's ``flow_init`` /
+    ``hidden_init`` keywords. The model is asked for the final flow
+    only (``final_only``, in the key's ``args`` flag). The ``quant``
+    flag is only present on quant programs, and the clip ratio
+    (``RMD_QUANT_CLIP``) is read at build time and keyed only when
+    non-default, so other keys, AOT artifacts and budget pins are
+    untouched.
+    """
+    iterations = int(iterations)
+    quant = quant_ops.normalize_mode(quant)
+
+    # the caller's model arguments minus what the builder sets itself
     model_args = dict(model_args or {})
     for reserved in ("iterations", "flow_init", "hidden_init",
                      "return_state", "quant", "quant_clip"):
         model_args.pop(reserved, None)
     model_args["final_only"] = True
-    return model_args
+
+    flags = {"iterations": iterations, **variant}
+    forward_args = dict(model_args, iterations=iterations, return_state=True)
+    if quant is not None:
+        quant_clip = float(env.get_float("RMD_QUANT_CLIP"))
+        forward_args.update(quant=quant, quant_clip=quant_clip)
+        flags["quant"] = quant
+        if quant_clip != 1.0:
+            flags["quant_clip"] = quant_clip
+
+    def body(variables, img1, img2, *extra):
+        out, state = model.apply(variables, img1, img2, train=False,
+                                 **forward_args, **carry(*extra))
+        result = model.get_adapter().wrap_result(out, img1.shape[1:3])
+        return result.final(), state
+
+    key = programs.inference_key("rung_step", model, model_args,
+                                 model_id=model_id, **where, **flags)
+    return inference_step(
+        "rung_step", model, body, key, extra_inputs, **where,
+        attrs={"iterations": iterations, **variant, "quant": quant})
 
 
 def make_rung_fn(model, iterations, cont=False, mesh=None, wire=None,
@@ -305,128 +242,29 @@ def make_rung_fn(model, iterations, cont=False, mesh=None, wire=None,
 
     ``state`` is ``{"flow", "hidden", "delta"}`` — coarse-grid carry
     arrays (left on device; hand them to the next rung unfetched) plus a
-    per-sample convergence norm the host reads *between* programs. The
-    model is asked for the final flow only (``final_only``, in the key's
-    ``args`` flag): Up8 runs on the last iteration, batch b. Each
-    (iterations, cont) pair is its own ``ProgramKey`` flag variant
-    (kind ``rung_step``), so rungs dedupe process-wide, AOT-export, and
-    prefetch like any other program; ``serve --prebuild`` exports the
-    whole ladder this way.
+    per-sample convergence norm the host reads *between* programs. Up8
+    runs on the last iteration, batch b. Each (iterations, cont) pair is
+    its own ``ProgramKey`` flag variant (kind ``rung_step``), so rungs
+    dedupe process-wide, AOT-export, and prefetch like any other
+    program; ``serve --prebuild`` exports the whole ladder this way.
 
     ``quant`` selects the quantized matching tier (``'u8'``/``'i8'``,
     see ``ops.quant``): the rung runs with a quantized correlation
     volume pyramid, registered as its own ``quant=...`` ProgramKey flag
-    variant of the same kind. The flag — like ``warm`` — is only
-    present on quant programs, so existing rung keys, AOT artifacts,
-    and budget pins are untouched; ``quant=None`` is byte-identical to
-    the pre-quant builder. The clip ratio (``RMD_QUANT_CLIP``) is read
-    at build time and keyed only when non-default.
+    variant of the same kind.
     """
-    from .. import compile as programs
-    from ..ops import quant as quant_ops
-    from ..parallel import partition
-    from ..parallel.mesh import traced_under
-    from ..utils import env
-
-    iterations = int(iterations)
     cont = bool(cont)
-    quant = quant_ops.normalize_mode(quant)
-    quant_clip = (float(env.get_float("RMD_QUANT_CLIP"))
-                  if quant is not None else 1.0)
-    model_args = _rung_model_args(model_args)
-
-    base = _cache_key(model, model_args, mesh, wire, variables_sharding)
-    key = (None if base is None
-           else ("rung", iterations, cont, quant, quant_clip) + base)
-    if key is not None and key in _EVAL_FN_CACHE:
-        return _EVAL_FN_CACHE[key]
-
-    def _cache(step):
-        if key is not None:
-            while len(_EVAL_FN_CACHE) >= _EVAL_FN_CACHE_MAX:
-                _EVAL_FN_CACHE.pop(next(iter(_EVAL_FN_CACHE)))
-            _EVAL_FN_CACHE[key] = step
-        return step
-
-    # same identity contract as make_eval_fn, including the config-default
-    # argument merge (the iterations/cont flags are what distinguish the
-    # rungs of one ladder)
-    pkey = None
-    args_key = static_args_key(
-        dict(getattr(model, "arguments", {})) | model_args)
-    if args_key is not None and variables_sharding is None:
-        mesh_key = (None if mesh is None
-                    else tuple(d.id for d in mesh.devices.flat))
-        wire_key = None if wire is None else (
-            wire.images, wire.flow, wire.pack_valid, wire.clip, wire.range)
-        qflags = {}
-        if quant is not None:
-            qflags["quant"] = quant
-            if quant_clip != 1.0:
-                qflags["quant_clip"] = quant_clip
-        pkey = programs.ProgramKey(
-            kind="rung_step",
-            model=model_id or programs.unstable(model),
-            flags=programs.flag_items(
-                args=args_key, iterations=iterations, cont=cont,
-                mesh=mesh_key, wire=wire_key, **qflags))
-        existing = programs.registry().get(pkey)
-        if existing is not None:
-            return _cache(existing)
-
-    adapter = model.get_adapter()
-    gather = (mesh is not None and variables_sharding is not None
-              and partition.is_sharded(variables_sharding))
-    repl_one = partition.replicated(mesh) if mesh is not None else None
-
-    forward_args = dict(model_args)
-    forward_args["iterations"] = iterations
-    forward_args["return_state"] = True
-    if quant is not None:
-        forward_args["quant"] = quant
-        forward_args["quant_clip"] = quant_clip
-
-    def _forward(variables, img1, img2, flow, hidden):
-        if gather:
-            variables = jax.lax.with_sharding_constraint(
-                variables, repl_one)
-        if wire is not None:
-            img1, img2, _, _ = wire.decode(img1, img2)
-        kwargs = dict(forward_args)
-        if flow is not None:
-            kwargs["flow_init"] = flow
-        if hidden is not None:
-            kwargs["hidden_init"] = hidden
-        out, state = model.apply(variables, img1, img2, train=False,
-                                 **kwargs)
-        result = adapter.wrap_result(out, img1.shape[1:3])
-        return result.final(), state
-
     if cont:
-        def step(variables, img1, img2, flow, hidden):
-            return _forward(variables, img1, img2, flow, hidden)
+        def carry(flow, hidden):
+            return {"flow_init": flow, "hidden_init": hidden}
     else:
-        def step(variables, img1, img2):
-            return _forward(variables, img1, img2, None, None)
+        def carry():
+            return {}
 
-    if mesh is None:
-        step = jax.jit(step)
-    else:
-        data = partition.data_sharding(mesh)
-        variables_in = (variables_sharding if variables_sharding is not None
-                        else partition.replicated(mesh))
-        shardings = (variables_in, data, data)
-        if cont:
-            shardings = shardings + (data, data)
-        step = traced_under(mesh, jax.jit(step, in_shardings=shardings))
-
-    step = programs.register_step("rung_step", step, key=pkey)
-    step._refs = (model,)
-    step.iterations = iterations
-    step.cont = cont
-    step.quant = quant
-
-    return _cache(step)
+    return _rung_program(
+        model, iterations, {"cont": cont}, carry, 2 if cont else 0, quant,
+        model_args, model_id, mesh=mesh, wire=wire,
+        variables_sharding=variables_sharding)
 
 
 def make_warm_fn(model, iterations, mesh=None, wire=None,
@@ -460,99 +298,15 @@ def make_warm_fn(model, iterations, mesh=None, wire=None,
     quant warm program stays bit-exact versus the quant base rung (the
     parity argument above is mode-independent).
     """
-    from .. import compile as programs
-    from ..ops import quant as quant_ops
-    from ..ops import warp
-    from ..parallel import partition
-    from ..parallel.mesh import traced_under
-    from ..utils import env
-
-    iterations = int(iterations)
-    quant = quant_ops.normalize_mode(quant)
-    quant_clip = (float(env.get_float("RMD_QUANT_CLIP"))
-                  if quant is not None else 1.0)
-    model_args = _rung_model_args(model_args)
-
-    base = _cache_key(model, model_args, mesh, wire, variables_sharding)
-    key = (None if base is None
-           else ("rung", iterations, "warm", quant, quant_clip) + base)
-    if key is not None and key in _EVAL_FN_CACHE:
-        return _EVAL_FN_CACHE[key]
-
-    def _cache(step):
-        if key is not None:
-            while len(_EVAL_FN_CACHE) >= _EVAL_FN_CACHE_MAX:
-                _EVAL_FN_CACHE.pop(next(iter(_EVAL_FN_CACHE)))
-            _EVAL_FN_CACHE[key] = step
-        return step
-
-    pkey = None
-    args_key = static_args_key(
-        dict(getattr(model, "arguments", {})) | model_args)
-    if args_key is not None and variables_sharding is None:
-        mesh_key = (None if mesh is None
-                    else tuple(d.id for d in mesh.devices.flat))
-        wire_key = None if wire is None else (
-            wire.images, wire.flow, wire.pack_valid, wire.clip, wire.range)
-        qflags = {}
-        if quant is not None:
-            qflags["quant"] = quant
-            if quant_clip != 1.0:
-                qflags["quant_clip"] = quant_clip
-        pkey = programs.ProgramKey(
-            kind="rung_step",
-            model=model_id or programs.unstable(model),
-            flags=programs.flag_items(
-                args=args_key, iterations=iterations, cont=False,
-                warm=True, mesh=mesh_key, wire=wire_key, **qflags))
-        existing = programs.registry().get(pkey)
-        if existing is not None:
-            return _cache(existing)
-
-    adapter = model.get_adapter()
-    gather = (mesh is not None and variables_sharding is not None
-              and partition.is_sharded(variables_sharding))
-    repl_one = partition.replicated(mesh) if mesh is not None else None
-
-    forward_args = dict(model_args)
-    forward_args["iterations"] = iterations
-    forward_args["return_state"] = True
-    if quant is not None:
-        forward_args["quant"] = quant
-        forward_args["quant_clip"] = quant_clip
-
-    def step(variables, img1, img2, flow):
-        if gather:
-            variables = jax.lax.with_sharding_constraint(
-                variables, repl_one)
-        if wire is not None:
-            img1, img2, _, _ = wire.decode(img1, img2)
+    def carry(flow):
         flow = flow.astype(jnp.float32)
         init, _ = warp.warp_backwards(flow, -flow)
-        kwargs = dict(forward_args)
-        kwargs["flow_init"] = init
-        out, state = model.apply(variables, img1, img2, train=False,
-                                 **kwargs)
-        result = adapter.wrap_result(out, img1.shape[1:3])
-        return result.final(), state
+        return {"flow_init": init}
 
-    if mesh is None:
-        step = jax.jit(step)
-    else:
-        data = partition.data_sharding(mesh)
-        variables_in = (variables_sharding if variables_sharding is not None
-                        else partition.replicated(mesh))
-        step = traced_under(mesh, jax.jit(
-            step, in_shardings=(variables_in, data, data, data)))
-
-    step = programs.register_step("rung_step", step, key=pkey)
-    step._refs = (model,)
-    step.iterations = iterations
-    step.cont = False
-    step.warm = True
-    step.quant = quant
-
-    return _cache(step)
+    return _rung_program(
+        model, iterations, {"cont": False, "warm": True}, carry, 1, quant,
+        model_args, model_id, mesh=mesh, wire=wire,
+        variables_sharding=variables_sharding)
 
 
 def _program_compile_counter(step):

@@ -1,7 +1,7 @@
 """Kill/rejoin chaos drill: the fleet's acceptance scenario.
 
-One harness, three consumers (BENCH_SERVE fleet phase, the
-``fleet-smoke`` dryrun entry, and ad-hoc CLI drills): drive a skewed
+One harness, two consumers (the ``fleet-smoke`` dryrun entry and
+ad-hoc CLI drills): drive a skewed
 request mix plus one sticky video stream through the router, hard-kill
 a replica mid-stream, and account for what the fleet *promised*:
 

@@ -2,7 +2,7 @@
 
 The router is the fleet's admission plane. It exposes the same
 ``submit(...) -> ticket`` surface as the in-process scheduler (so the
-loadgen, bench harness and CLI drive a fleet unchanged) plus an HTTP
+loadgen and the CLI drive a fleet unchanged) plus an HTTP
 front-end for real network clients, and routes every request to one of
 N replica processes:
 

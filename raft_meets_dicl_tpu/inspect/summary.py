@@ -295,8 +295,8 @@ class StrategyValidation(Validation):
         """
         from .. import compile as programs, evaluation
 
-        model_key = evaluation.static_args_key(stage.model_args)
-        loss_key = evaluation.static_args_key(stage.loss_args)
+        model_key = programs.static_args_key(stage.model_args)
+        loss_key = programs.static_args_key(stage.loss_args)
         cacheable = model_key is not None and loss_key is not None
         key = (id(ctx.model), id(ctx.loss), model_key, loss_key)
         if cacheable and key in self._val_steps:
@@ -609,7 +609,7 @@ class SummaryInspector(Inspector):
     # -- intermediates capture ----------------------------------------------
 
     def _capture_fn(self, ctx, stage):
-        from ..evaluation import static_args_key
+        from ..compile import static_args_key
 
         args_key = static_args_key(stage.model_args)
         key = (id(ctx.model), ctx.model.frozen_batchnorm, args_key)

@@ -24,7 +24,9 @@ import optax
 from flax import struct
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..compile import register_step
+from ..compile import (
+    ProgramKey, effective_args_key, flag_items, register_step, registry,
+)
 from . import partition
 from .mesh import traced_under
 
@@ -144,8 +146,6 @@ def make_train_step(model, loss_fn, tx, mesh=None, loss_args=None,
     # doesn't already carry the flag (mirrors make_eval_step's args flag)
     if (augment is not None and key is not None
             and not any(n == "augment" for n, _ in key.flags)):
-        from ..compile import ProgramKey, flag_items
-
         key = ProgramKey(kind=key.kind, model=key.model,
                          flags=key.flags
                          + flag_items(augment=augment.describe()))
@@ -350,6 +350,66 @@ def make_train_step(model, loss_fn, tx, mesh=None, loss_args=None,
     return prog
 
 
+def inference_step(kind, model, body, key, extra_inputs=0, mesh=None,
+                   wire=None, variables_sharding=None, out_shardings=False,
+                   attrs=None):
+    """The registered inference program of ``key``: the one builder behind
+    ``make_eval_step`` and ``evaluation.make_eval_fn`` / ``make_rung_fn``
+    / ``make_warm_fn``, which differ in ``body`` and in their key's flags
+    alone.
+
+    ``body(variables, img1, img2, *extra)`` runs after the prologue —
+    model-sharded variables gathered to replicated, wire-format images
+    decoded on device — and returns the program's outputs. The program
+    takes ``extra_inputs`` batch-shaped arrays after the images (a
+    previous flow, a hidden state): with a ``mesh`` they shard like the
+    images, and with ``out_shardings`` so does the (single, batch-shaped)
+    output.
+
+    A ``key`` already in the registry returns that program, ``body``
+    unused; ``key=None`` builds an anonymous program, fresh each call.
+    ``attrs`` are set on a new program (what its callers read off it:
+    ``iterations``, ``cont``, ``warm``, ``quant``).
+    """
+    if key is not None:
+        existing = registry().get(key)
+        if existing is not None:
+            return existing
+
+    gather = (mesh is not None and variables_sharding is not None
+              and partition.is_sharded(variables_sharding))
+    repl = partition.replicated(mesh) if mesh is not None else None
+
+    def step(variables, img1, img2, *extra):
+        if gather:
+            variables = jax.lax.with_sharding_constraint(variables, repl)
+        if wire is not None:
+            img1, img2, _, _ = wire.decode(img1, img2)
+        return body(variables, img1, img2, *extra)
+
+    if mesh is None:
+        step = jax.jit(step)
+    else:
+        data = partition.data_sharding(mesh)
+        variables_in = (variables_sharding if variables_sharding is not None
+                        else repl)
+        shardings = {"in_shardings": (variables_in,)
+                     + (data,) * (2 + extra_inputs)}
+        if out_shardings:
+            shardings["out_shardings"] = data
+        step = traced_under(mesh, jax.jit(step, **shardings))
+
+    # registry Program: compile events attribute to ``kind``, compiles
+    # count per-program (warmup/stats read them), AOT artifacts for
+    # stable keys; the raw jit stays reachable via __wrapped__
+    step = register_step(kind, step, key=key)
+    # a ``pyid:`` key names the model by its id: keep it unique
+    step._refs = (model,)
+    for name, value in (attrs or {}).items():
+        setattr(step, name, value)
+    return step
+
+
 def make_eval_step(model, mesh=None, model_args=None, wire=None,
                    variables_sharding=None, key=None):
     """Build the jitted inference step returning the final flow.
@@ -370,43 +430,22 @@ def make_eval_step(model, mesh=None, model_args=None, wire=None,
     model_args = dict(model_args or {}) | {"final_only": True}
 
     # a caller-provided key must encode the *effective* model arguments
-    # (config defaults merged under explicit overrides, exactly how
-    # Model.apply resolves them): without this, e.g. a non-default
-    # ``iterations`` count silently shares the default program's key —
-    # and its AOT artifact — with the default-count model
+    # (see ``compile.effective_args_key``): without this, e.g. a
+    # non-default ``iterations`` count silently shares the default
+    # program's key — and its AOT artifact — with the default-count model
     if key is not None and not any(n == "args" for n, _ in key.flags):
-        from ..compile import ProgramKey, flag_items
-        from ..evaluation import static_args_key
-
-        args_key = static_args_key(
-            dict(getattr(model, "arguments", {})) | model_args)
+        args_key = effective_args_key(model, model_args)
         if args_key is None:
             key = None  # unkeyable (array-valued) args: never dedupe
         else:
             key = ProgramKey(kind=key.kind, model=key.model,
                              flags=key.flags + flag_items(args=args_key))
 
-    gather = (mesh is not None and variables_sharding is not None
-              and partition.is_sharded(variables_sharding))
-    repl_one = partition.replicated(mesh) if mesh is not None else None
-
-    def step(variables, img1, img2):
-        if gather:
-            variables = jax.lax.with_sharding_constraint(variables, repl_one)
-        if wire is not None:
-            img1, img2, _, _ = wire.decode(img1, img2)
+    def body(variables, img1, img2):
         out = model.apply(variables, img1, img2, train=False, **model_args)
         result = model.get_adapter().wrap_result(out, img1.shape[1:3])
         return result.final()
 
-    if mesh is None:
-        return register_step("eval_step", jax.jit(step), key=key)
-
-    repl = partition.replicated(mesh)
-    data = partition.data_sharding(mesh)
-    variables_in = (variables_sharding if variables_sharding is not None
-                    else repl)
-    return register_step("eval_step", traced_under(
-        mesh,
-        jax.jit(step, in_shardings=(variables_in, data, data),
-                out_shardings=data)), key=key)
+    return inference_step("eval_step", model, body, key, mesh=mesh,
+                          wire=wire, variables_sharding=variables_sharding,
+                          out_shardings=True)

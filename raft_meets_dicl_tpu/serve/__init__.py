@@ -14,7 +14,7 @@ scheduler with bounded-queue admission control:
   program, and the warm pool of precompiled executables per
   (model, bucket, wire) triple;
 - :mod:`.loadgen` — the open-loop synthetic load generator behind
-  ``BENCH_SERVE=1`` and the ``serve`` CLI's built-in client;
+  the ``serve`` CLI's built-in client;
 - :mod:`.ladder` — iteration-ladder latency classes (PR 11): adaptive
   recurrence budgets over chained fixed-``iterations`` rung programs;
 - :mod:`.observe` — the live observability plane (PR 13): /metrics

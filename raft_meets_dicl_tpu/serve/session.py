@@ -262,6 +262,34 @@ class ServeSession:
 
     # -- warm pool ------------------------------------------------------------
 
+    def _warm_plan(self):
+        """``(rung, program, takes)`` in warm-up order: every program a
+        bucket needs, the label its outcome record carries, and which
+        entries of the carry it is fed after the images. The carry is
+        the base rung's state, so continuation rungs and the warm-start
+        program get correct coarse shapes without knowing the model's
+        hidden width or downsampling factor."""
+        plan = [(None, self.eval_fn, ())]
+        if self.ladder is not None:
+            lad = self.ladder
+            plan.append((f"base:{lad.rungs[0]}",
+                         self._rung_fns[(lad.rungs[0], False)], ()))
+            plan += [(f"cont:+{inc}", self._rung_fns[(inc, True)],
+                      ("flow", "hidden"))
+                     for inc in sorted(set(lad.increments()))]
+            plan.append((f"full:{lad.rungs[-1]}",
+                         self._rung_fns[(lad.rungs[-1], False)], ()))
+        if self.video:
+            # the cold plain-rung twin (with a ladder the base rung above
+            # already covers it), then the warm-start program
+            if self.ladder is None:
+                plan.append((f"base:{self.warm_iterations}",
+                             self._rung_fns[(self.warm_iterations, False)],
+                             ()))
+            plan.append((f"warm:{self.warm_iterations}", self._warm_fn,
+                         ("flow",)))
+        return plan
+
     def warm_pool(self):
         """Compile (or AOT-load) the program for every bucket at the
         serve batch size; returns one outcome record per (model, bucket,
@@ -275,91 +303,39 @@ class ServeSession:
         import jax
         import jax.numpy as jnp
 
-        dtype = self.image_dtype()
+        def counts(step):
+            return {name: getattr(step, name, 0)
+                    for name in ("compiles", "aot_hits", "aot_saves")}
+
+        plan = self._warm_plan()
         outcomes = []
-
-        def _counts(step):
-            return (time.perf_counter(), getattr(step, "compiles", 0),
-                    getattr(step, "aot_hits", 0),
-                    getattr(step, "aot_saves", 0))
-
-        def _record(step, bucket, rung, t0, c0, h0, s0):
-            outcome = {
-                "model": self.spec.id,
-                "bucket": bucket,
-                "wire": (self.wire.describe() if self.wire is not None
-                         else "f32 host-normalized"),
-                "batch": self.batch_size,
-                "compiles": getattr(step, "compiles", 0) - c0,
-                "aot_hits": getattr(step, "aot_hits", 0) - h0,
-                "aot_saves": getattr(step, "aot_saves", 0) - s0,
-                "seconds": round(time.perf_counter() - t0, 4),
-            }
-            if rung is not None:
-                outcome["rung"] = rung
-            if getattr(step, "quant", None):
-                outcome["quant"] = step.quant
-            outcomes.append(outcome)
-            telemetry.get().clock()
-            telemetry.get().emit("serve", event="warmup", **outcome)
-
         for h, w in self.buckets.sizes:
-            bucket = f"{h}x{w}"
-            img = jnp.zeros((self.batch_size, h, w, 3), dtype)
-
-            step = self.eval_fn
-            t0, c0, h0, s0 = _counts(step)
-            _, flow = step(self.variables, img, img)
-            jax.block_until_ready(flow)  # graftlint: disable=host-sync -- warm pool must finish before serving starts
-            _record(step, bucket, None, t0, c0, h0, s0)
-
+            img = jnp.zeros((self.batch_size, h, w, 3), self.image_dtype())
             carry = None
-            if self.ladder is not None:
-                # ladder rungs: warm the base rung first, then feed its
-                # carry to every continuation increment (correct carry
-                # shapes without knowing the model's hidden width), then
-                # the monolithic full budget
-                lad = self.ladder
-                base = self._rung_fns[(lad.rungs[0], False)]
-                t0, c0, h0, s0 = _counts(base)
-                flow, state = base(self.variables, img, img)
-                jax.block_until_ready(flow)  # graftlint: disable=host-sync -- warm pool must finish before serving starts
-                _record(base, bucket, f"base:{lad.rungs[0]}", t0, c0, h0,
-                        s0)
-                carry = state
-                for inc in sorted(set(lad.increments())):
-                    step = self._rung_fns[(inc, True)]
-                    t0, c0, h0, s0 = _counts(step)
-                    flow, _ = step(self.variables, img, img,
-                                   state["flow"], state["hidden"])
-                    jax.block_until_ready(flow)  # graftlint: disable=host-sync -- warm pool must finish before serving starts
-                    _record(step, bucket, f"cont:+{inc}", t0, c0, h0, s0)
-                step = self._rung_fns[(lad.rungs[-1], False)]
-                t0, c0, h0, s0 = _counts(step)
-                flow, _ = step(self.variables, img, img)
-                jax.block_until_ready(flow)  # graftlint: disable=host-sync -- warm pool must finish before serving starts
-                _record(step, bucket, f"full:{lad.rungs[-1]}", t0, c0, h0,
-                        s0)
-
-            if not self.video:
-                continue
-            # video variants: the cold plain-rung twin (with a ladder the
-            # base rung above already covers it), then the warm-start
-            # program fed the twin's carry (correct coarse shape without
-            # knowing the model's downsampling factor)
-            if carry is None:
-                step = self._rung_fns[(self.warm_iterations, False)]
-                t0, c0, h0, s0 = _counts(step)
-                flow, carry = step(self.variables, img, img)
-                jax.block_until_ready(flow)  # graftlint: disable=host-sync -- warm pool must finish before serving starts
-                _record(step, bucket, f"base:{self.warm_iterations}", t0,
-                        c0, h0, s0)
-            step = self._warm_fn
-            t0, c0, h0, s0 = _counts(step)
-            flow, _ = step(self.variables, img, img, carry["flow"])
-            jax.block_until_ready(flow)  # graftlint: disable=host-sync -- warm pool must finish before serving starts
-            _record(step, bucket, f"warm:{self.warm_iterations}", t0, c0,
-                    h0, s0)
+            for rung, step, takes in plan:
+                t0, before = time.perf_counter(), counts(step)
+                out = step(self.variables, img, img,
+                           *(carry[name] for name in takes))
+                jax.block_until_ready(out)  # graftlint: disable=host-sync -- warm pool must finish before serving starts
+                if rung is not None and carry is None:
+                    carry = out[1]      # the base rung's state
+                outcome = {
+                    "model": self.spec.id,
+                    "bucket": f"{h}x{w}",
+                    "wire": (self.wire.describe() if self.wire is not None
+                             else "f32 host-normalized"),
+                    "batch": self.batch_size,
+                    **{name: n - before[name]
+                       for name, n in counts(step).items()},
+                    "seconds": round(time.perf_counter() - t0, 4),
+                }
+                if rung is not None:
+                    outcome["rung"] = rung
+                if getattr(step, "quant", None):
+                    outcome["quant"] = step.quant
+                outcomes.append(outcome)
+                telemetry.get().clock()
+                telemetry.get().emit("serve", event="warmup", **outcome)
         self.ready = True
         return outcomes
 

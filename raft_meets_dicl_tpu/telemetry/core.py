@@ -283,7 +283,7 @@ class Telemetry:
     """JSONL event sink.
 
     ``path=None`` keeps events in memory only (``self.events``) — used by
-    bench.py and tests; a path appends JSON lines to that file.
+    tests; a path appends JSON lines to that file.
 
     ``nonblocking=True`` (the serve hot path) hands disk I/O to a daemon
     writer thread behind a bounded queue (``RMD_TELEMETRY_BUFFER``): a
@@ -429,7 +429,7 @@ class Telemetry:
                 self._fd = None
 
     def counts(self):
-        """Event counts by kind (cheap snapshot, used by bench summaries)."""
+        """Event counts by kind (cheap snapshot)."""
         with self._lock:
             return dict(self._counts)
 

@@ -559,7 +559,7 @@ def fleet_stats(events):
 
 def video_stats(events):
     """Aggregate the streaming-video plane (PR 15): ``video`` frame and
-    sequence events from the sequence runner / bench, ``session``
+    sequence events from the sequence runner, ``session``
     warm-start cache events, and the serving path's video batches."""
     frames = []
     sequences = []

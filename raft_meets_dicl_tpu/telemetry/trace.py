@@ -38,7 +38,7 @@ and requests can be laid on a timeline beside a device trace (the
 ``clock`` event maps the clock to Unix time). Completed requests feed a
 bounded :class:`TraceSummary` whose :meth:`snapshot` gives per-class
 p50/p99 and the slowest-decile phase breakdown the ``/statusz`` endpoint
-and BENCH_SERVE report serve live.
+serves live.
 
 Host-side only: one ``perf_counter`` call per mark, no jax.
 """
@@ -185,7 +185,7 @@ class TraceSummary:
     one dict append per request) and answers :meth:`snapshot`: per-class
     count/p50/p99 plus the slowest-decile phase breakdown with the
     dominant phase named, so a queue-dominated tail is visible at a
-    glance (``/statusz``, the obs smoke test, BENCH_SERVE columns).
+    glance (``/statusz``, the obs smoke test).
     """
 
     def __init__(self, capacity=4096):

@@ -2,7 +2,7 @@
 
 The full-width train step takes minutes to compile cold; the persistent
 cache brings a repeat compile down to a disk read. Enabled by default
-for the CLI and ``bench.py``; opt out with ``RMD_NO_COMPILE_CACHE=1``.
+for the CLI; opt out with ``RMD_NO_COMPILE_CACHE=1``.
 
 Where the cache lives, in order:
 

@@ -45,7 +45,7 @@ def fw_bw_flows(step, variables, img1, img2):
     Concatenates ``[img1; img2]`` against ``[img2; img1]`` on the batch
     axis and runs the *existing* step once — the fw/bw product costs one
     dispatch at 2x batch instead of two, and no new program kind. Use
-    offline (eval CLI, bench) where the doubled batch shape is free to
+    offline where the doubled batch shape is free to
     compile once; the serve path instead issues two same-shape calls to
     stay inside its prebuilt bucket programs.
 

@@ -11,8 +11,7 @@ Two modes:
   measured/predicted ratio per program against the pins for *this*
   machine (``platform:device_kind``).
 - **attribute-only** (``--trace-dir DIR``): parses an existing capture
-  (a ``/profilez`` artifact, a ``train --profile`` dir, a
-  ``profile_bench`` trace) and prints the per-module attribution —
+  (a ``/profilez`` artifact, a ``train --profile`` dir) and prints the per-module attribution —
   no gating, module→program matching is best-effort.
 
     python scripts/graftprof.py                     # audit vs prof-budget.json

@@ -31,8 +31,8 @@ def _release_compiled_programs():
     """Drop every compiled executable at the end of each test module.
 
     Every XLA:CPU executable holds about fifteen memory mappings for its
-    jitted code, and jax's jit caches, the program registry and the eval
-    function cache keep executables alive for the life of the process.
+    jitted code, and jax's jit caches and the program registry keep
+    executables alive for the life of the process.
     One tier-1 process compiles thousands: by ``test_partition.py`` it
     held 48,627 mappings of the kernel's 65,530 (``vm.max_map_count``),
     the next large SPMD compile failed to map its code, and the run died
@@ -42,9 +42,7 @@ def _release_compiled_programs():
     """
     yield
     from raft_meets_dicl_tpu import compile as programs
-    from raft_meets_dicl_tpu import evaluation
 
     programs.reset()
-    evaluation._EVAL_FN_CACHE.clear()
     jax.clear_caches()
     gc.collect()

@@ -355,7 +355,6 @@ def test_evaluate_bucketed_epe_parity():
     loader = spec.apply(source, buckets=buckets).jax().loader(
         batch_size=2, shuffle=False, num_workers=0, group_by_shape=True)
 
-    evaluation._EVAL_FN_CACHE.clear()
     _TRACES[0] = 0
     got = _run_eval(model, variables, loader, pad_to=2)
 
@@ -403,7 +402,6 @@ def test_evaluate_pad_to_and_warmup():
     loader = spec.apply(source, buckets=buckets).jax().loader(
         batch_size=2, shuffle=False, num_workers=0, group_by_shape=True)
 
-    evaluation._EVAL_FN_CACHE.clear()
     fn = evaluation.make_eval_fn(model, None)
     stats = evaluation.EvalRunStats(name="warm")
     evaluation.warmup_eval_fn(fn, variables, buckets.sizes, 2, stats=stats)
@@ -422,20 +420,19 @@ def test_evaluate_pad_to_and_warmup():
     assert stats.pad_waste_ratio() > 0.0
 
 
-def test_eval_fn_cache_key():
+def test_eval_fn_dedupes_by_registry_key():
     import jax
 
     from raft_meets_dicl_tpu import evaluation
 
     model = _local_model()
-    evaluation._EVAL_FN_CACHE.clear()
     a = evaluation.make_eval_fn(model, {"x": 1})
     b = evaluation.make_eval_fn(model, {"x": 1})
     c = evaluation.make_eval_fn(model, {"x": 2})
-    assert a is b          # same model + args hit the cache
+    assert a is b          # same model + args: the registry's program
     assert a is not c      # different static args miss
 
-    # array-valued args cannot be keyed exactly: bypass the cache
+    # array-valued args cannot be keyed exactly: built fresh each call
     d = evaluation.make_eval_fn(model, {"x": np.zeros(3)})
     e = evaluation.make_eval_fn(model, {"x": np.zeros(3)})
     assert d is not e

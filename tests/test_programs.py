@@ -20,6 +20,7 @@ import pytest
 from raft_meets_dicl_tpu import compile as programs
 from raft_meets_dicl_tpu import evaluation, parallel, telemetry
 import raft_meets_dicl_tpu.models as models
+from raft_meets_dicl_tpu.models.wire import WireFormat
 
 
 @pytest.fixture
@@ -131,19 +132,117 @@ def test_program_counts_compiles_without_telemetry_sink():
     programs.reset()
 
 
+_ARGS = "(('final_only', 'True'), ('iterations', '2'))"
+
+
+def _rung_with_quant_clip(model, monkeypatch):
+    monkeypatch.setenv("RMD_QUANT_CLIP", "0.9")
+    return evaluation.make_rung_fn(model, 2, model_id="tiny-prog",
+                                   quant="u8")
+
+
+def _eval_step_with_caller_key(model, _):
+    key = programs.ProgramKey("eval_step", "tiny-prog",
+                              programs.flag_items(mesh=None))
+    return parallel.make_eval_step(model, key=key)
+
+
+# (build, canonical form) of the inference keys no budget pin covers, as
+# commit ebb2877 (PR 28) produced them: a stored executable is found by
+# this string, so a builder that renames or reorders a flag orphans every
+# artifact of that form
+_INFERENCE_KEYS = {
+    "full": (
+        lambda m, _: evaluation.make_eval_fn(
+            m, {"iterations": 3}, model_id="tiny-prog"),
+        "('eval_step', 'tiny-prog', (('args', \"(('iterations', '3'),)\"),"
+        " ('mesh', 'None'), ('wire', 'None')))"),
+    "wire": (
+        lambda m, _: evaluation.make_eval_fn(
+            m, {"final_only": True}, wire=WireFormat.from_config("u8"),
+            model_id="tiny-prog"),
+        f"('eval_step', 'tiny-prog', (('args', \"{_ARGS}\"),"
+        " ('mesh', 'None'), ('wire', \"('u8', 'f16', True, (0.0, 1.0),"
+        " (-1.0, 1.0))\")))"),
+    "mesh": (
+        lambda m, _: evaluation.make_eval_fn(
+            m, {"final_only": True}, mesh=parallel.data_mesh(2),
+            model_id="tiny-prog"),
+        f"('eval_step', 'tiny-prog', (('args', \"{_ARGS}\"),"
+        " ('mesh', '(0, 1)'), ('wire', 'None')))"),
+    "pyid": (
+        lambda m, _: evaluation.make_eval_fn(m, {"final_only": True}),
+        f"('eval_step', 'pyid:<id>', (('args', \"{_ARGS}\"),"
+        " ('mesh', 'None'), ('wire', 'None')))"),
+    "rung_quant_clip": (
+        _rung_with_quant_clip,
+        f"('rung_step', 'tiny-prog', (('args', \"{_ARGS}\"),"
+        " ('cont', 'False'), ('iterations', '2'), ('mesh', 'None'),"
+        " ('quant', \"'u8'\"), ('quant_clip', '0.9'), ('wire', 'None')))"),
+    # a caller key that lacks ``args`` gets them appended, unsorted
+    "eval_step_caller_key": (
+        _eval_step_with_caller_key,
+        f"('eval_step', 'tiny-prog', (('mesh', 'None'),"
+        f" ('args', \"{_ARGS}\")))"),
+}
+
+
+@pytest.mark.parametrize("case", list(_INFERENCE_KEYS))
+def test_inference_keys_are_the_stored_ones(case, monkeypatch):
+    build, want = _INFERENCE_KEYS[case]
+    programs.reset()
+    model = models.load(TINY_EVAL_MODEL).model
+    got = build(model, monkeypatch).key.canonical()
+    assert got.replace(str(id(model)), "<id>") == want
+    programs.reset()
+
+
+def test_registry_reset_alone_forgets_an_eval_program():
+    """The registry is the only cache in front of the builders: after
+    ``programs.reset()`` the same (model, args) builds a new program."""
+    programs.reset()
+    model = models.load(TINY_EVAL_MODEL).model
+    first = evaluation.make_eval_fn(model, {"iterations": 2})
+    assert evaluation.make_eval_fn(model, {"iterations": 2}) is first
+    programs.reset()
+    assert evaluation.make_eval_fn(model, {"iterations": 2}) is not first
+    programs.reset()
+
+
+def test_parallel_does_not_import_evaluation():
+    """``evaluation`` builds on ``parallel`` and ``compile``; nothing
+    points back (the step builders share ``parallel.train.inference_step``
+    and the keys of ``compile``). The package's own ``__init__`` imports
+    every layer, so the child puts a bare package in its place and sees
+    what ``parallel`` itself pulls in."""
+    import subprocess
+    import sys
+
+    import raft_meets_dicl_tpu
+
+    code = (
+        "import sys, types\n"
+        "pkg = types.ModuleType('raft_meets_dicl_tpu')\n"
+        f"pkg.__path__ = {list(raft_meets_dicl_tpu.__path__)!r}\n"
+        "sys.modules['raft_meets_dicl_tpu'] = pkg\n"
+        "import raft_meets_dicl_tpu.parallel\n"
+        "assert 'raft_meets_dicl_tpu.compile' in sys.modules\n"
+        "assert 'raft_meets_dicl_tpu.evaluation' not in sys.modules\n")
+    subprocess.run([sys.executable, "-c", code], check=True, timeout=120,
+                   env=dict(os.environ, JAX_PLATFORMS="cpu"))
+
+
 def test_eval_fn_dedupes_across_validation_and_cli_paths():
     """The same (model, bucket, wire) triple builds ONE program whether
     it is requested through the eval-CLI path or the training-validation
     path — both name the model by its stable config id."""
     programs.reset()
-    evaluation._EVAL_FN_CACHE.clear()
     m_cli = models.load(TINY_EVAL_MODEL).model
     m_val = models.load(TINY_EVAL_MODEL).model  # a distinct object
     assert m_cli is not m_val
 
     cli = evaluation.make_eval_fn(m_cli, {"iterations": 2},
                                   model_id="tiny-prog")
-    evaluation._EVAL_FN_CACHE.clear()  # module cache out of the way
     val = evaluation.make_eval_fn(m_val, {"iterations": 2},
                                   model_id="tiny-prog")
     assert cli is val
@@ -172,7 +271,6 @@ def test_val_step_matches_fused_reference():
     from raft_meets_dicl_tpu.inspect.summary import StrategyValidation
 
     programs.reset()
-    evaluation._EVAL_FN_CACHE.clear()
     spec = models.load(TINY_EVAL_MODEL)
     model, loss_fn = spec.model, spec.loss
     variables = model.init(jax.random.PRNGKey(0),
@@ -508,7 +606,6 @@ def test_warmup_compiles_not_overcounted_when_warm(aot_store):
     """Second warmup over the same shapes reports 0 compiles — with the
     telemetry sink disabled, where the pre-PR-7 fallback guessed 1 per
     shape."""
-    evaluation._EVAL_FN_CACHE.clear()
     model = models.load(TINY_EVAL_MODEL).model
     variables = model.init(jax.random.PRNGKey(0),
                            jnp.zeros((1, 32, 48, 3)),

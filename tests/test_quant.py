@@ -302,7 +302,6 @@ def test_aot_prepared_replica_serves_quant_classes_zero_compile(tmp_path):
     programs.enable_aot(str(tmp_path))
     try:
         programs.reset()
-        evaluation._EVAL_FN_CACHE.clear()
         s1 = ServeSession(models.load(cfg), ShapeBuckets(buckets),
                           batch_size=1, ladder=lad, quant="u8")
         out1 = s1.warm_pool()
@@ -311,7 +310,6 @@ def test_aot_prepared_replica_serves_quant_classes_zero_compile(tmp_path):
 
         # fresh replica: only the exported artifacts remain
         programs.reset()
-        evaluation._EVAL_FN_CACHE.clear()
         s2 = ServeSession(models.load(cfg), ShapeBuckets(buckets),
                           batch_size=1, ladder=lad, quant="u8")
         out2 = s2.warm_pool()

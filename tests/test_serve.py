@@ -18,7 +18,7 @@ import numpy as np
 import pytest
 
 import raft_meets_dicl_tpu.models as models
-from raft_meets_dicl_tpu import evaluation, serve, telemetry
+from raft_meets_dicl_tpu import serve, telemetry
 from raft_meets_dicl_tpu import compile as programs
 from raft_meets_dicl_tpu.models.input import ShapeBuckets
 from raft_meets_dicl_tpu.models.wire import WireFormat
@@ -395,7 +395,6 @@ def test_warm_pool_prebuild_then_zero_compile_replica(tmp_path,
     programs.enable_aot(str(tmp_path))
     try:
         programs.reset()
-        evaluation._EVAL_FN_CACHE.clear()
         s1 = ServeSession(models.load(cfg), ShapeBuckets(buckets),
                           wire=WireFormat.from_config("u8"), batch_size=2)
         out1 = s1.warm_pool()
@@ -405,7 +404,6 @@ def test_warm_pool_prebuild_then_zero_compile_replica(tmp_path,
         # "new replica": drop every in-process program and model object;
         # only the exported artifacts remain
         programs.reset()
-        evaluation._EVAL_FN_CACHE.clear()
         s2 = ServeSession(models.load(cfg), ShapeBuckets(buckets),
                           wire=WireFormat.from_config("u8"), batch_size=2)
         out2 = s2.warm_pool()

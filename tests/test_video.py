@@ -573,7 +573,6 @@ def test_video_warm_pool_prebuild_then_zero_compile_replica(tmp_path,
     programs.enable_aot(str(tmp_path))
     try:
         programs.reset()
-        evaluation._EVAL_FN_CACHE.clear()
         s1 = ServeSession(models.load(cfg), ShapeBuckets(buckets),
                           batch_size=1, video=True)
         out1 = s1.warm_pool()
@@ -581,7 +580,6 @@ def test_video_warm_pool_prebuild_then_zero_compile_replica(tmp_path,
         assert sum(o["aot_saves"] for o in out1) == 3
 
         programs.reset()
-        evaluation._EVAL_FN_CACHE.clear()
         s2 = ServeSession(models.load(cfg), ShapeBuckets(buckets),
                           batch_size=1, video=True)
         out2 = s2.warm_pool()
