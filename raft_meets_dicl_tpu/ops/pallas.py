@@ -939,27 +939,50 @@ def windowed_corr_pyramid(f1, f2_levels, coords, radius=4, mask_costs=(),
 # readout like the windowed correlation above, but the raw (k, k, C) window
 # the MatchingNet then convolves. The XLA form gathers one (k+1)² integer
 # patch per position through HBM (a giant take_along_axis) and materializes
-# it before the two lerps; this kernel reuses the proven 8-aligned-slab
-# machinery of the windowed correlation (``_wcp_window`` / ``_x_select`` /
-# ``_wcp_pads``) to keep the patch and both separable lerps in VMEM: per
-# position it reads one (k+1, _XW, C) slab, lerps y as a static row pair,
-# resolves x per static dx via the arithmetic lane-selection matrix, and
-# writes the (k², C) window row — nothing patch-sized ever touches HBM.
+# it before the two lerps; this kernel keeps the patch and both separable
+# lerps in VMEM and writes the (k², C) window row — nothing patch-sized
+# ever touches HBM.
 #
-# The custom VJP accumulates the window gradient back into the padded f2
-# map (transpose of the two lerps), mirroring ``_wcp_bwd_df2_kernel``.
-# Coordinates get a zero gradient: every caller (the corr modules inside
-# the RAFT iteration) stop-gradients the lookup centers, exactly like the
-# windowed-correlation kernel's contract.
+# A position's patch is picked by addressing, not by arithmetic: the map
+# is handed over in float32 with x as the leading (untiled) axis and y on
+# the sublanes, (B, Wp, Hp, C), so column ``x0 + xi`` is a dynamic index
+# on an untiled axis and its rows ``y0 … y0+k`` a dynamic-start sublane
+# slice, which Mosaic loads at any offset on 32-bit data (on bfloat16 it
+# wants the start a proven multiple of 16, hence the widening before the
+# call). A position then costs the two lerps of its own (k+1)×(k+1)×C
+# patch: k+1 column pairs in, k stores of a dx's k dy-rows out, which is
+# the window's dx·k+dy tap order as it stands.
+#
+# The custom VJP accumulates the window gradient back into the padded map
+# (transpose of the two lerps) with the same addressing. Coordinates get a
+# zero gradient: every caller (the corr modules inside the RAFT iteration)
+# stop-gradients the lookup centers, exactly like the windowed-correlation
+# kernel's contract.
 
 
-def _x_weights(s, fx, dx):
-    """Column ``dx`` of ``_x_select`` as a (1, _XW, 1) sublane vector: the
-    bilinear weights of lanes s+dx and s+dx+1, ready to broadcast over a
-    (rows, _XW, C) slab."""
-    ix = jax.lax.broadcasted_iota(jnp.int32, (1, _XW, 1), 1)
-    return (jnp.where(ix == s + dx, 1.0 - fx, 0.0)
-            + jnp.where(ix == s + dx + 1, fx, 0.0))
+_SW_VMEM_LIMIT = 100 * 1024 * 1024
+
+
+def _sw_pads(radius):
+    """(lo, hi) zero-padding of the sampled map on both axes, so that
+    every clamped (k+1)-wide patch is a plain in-bounds slice: starts lie
+    in [0, lo + dim] after clamping centers to [-(r+1), dim+r]."""
+    return 2 * radius + 1, 2 * radius + 2
+
+
+def _sw_window(cx, cy, dim_h, dim_w, radius):
+    """Patch start indices (into the padded map) and bilinear fractions."""
+    r = radius
+    # centers whose whole window is out of bounds clamp to positions whose
+    # sampled values are all zero (padding) — grid_sample zero semantics
+    cx = jnp.clip(cx, -(r + 1.0), dim_w + r + 0.0)
+    cy = jnp.clip(cy, -(r + 1.0), dim_h + r + 0.0)
+    x0f = jnp.floor(cx)
+    y0f = jnp.floor(cy)
+    lo, _ = _sw_pads(r)
+    x0 = x0f.astype(jnp.int32) - r + lo
+    y0 = y0f.astype(jnp.int32) - r + lo
+    return x0, y0, cx - x0f, cy - y0f
 
 
 def _sw_fwd_kernel(coords_ref, f2_ref, out_ref, *, radius, dims):
@@ -970,16 +993,19 @@ def _sw_fwd_kernel(coords_ref, f2_ref, out_ref, *, radius, dims):
     def body(j, _):
         cx = coords_ref[0, 0, j, 0]
         cy = coords_ref[0, 0, j, 1]
-        x8, s, y0, fx, fy = _wcp_window(cx, cy, 0, h2, w2, radius)
+        x0, y0, fx, fy = _sw_window(cx, cy, h2, w2, radius)
 
-        slab = f2_ref[0, pl.ds(y0, k + 1), pl.ds(x8, _XW), :]
-        slab = slab.astype(jnp.float32)                 # (k+1, _XW, C)
-        t = (1.0 - fy) * slab[0:k] + fy * slab[1:k + 1]  # (k_dy, _XW, C)
+        # y-lerp of the patch's k+1 columns: rows y0… and y0+1… of each
+        t = []
+        for xi in range(k + 1):
+            upper = f2_ref[0, x0 + xi, pl.ds(y0, k), :]
+            lower = f2_ref[0, x0 + xi, pl.ds(y0 + 1, k), :]
+            t.append((1.0 - fy) * upper + fy * lower)          # (k_dy, C)
 
-        # dx-major (k², C) window rows: dx lerps lanes s+dx / s+dx+1
+        # dx-major (k², C) window rows: dx lerps columns dx / dx+1
         for dx in range(k):
-            out_ref[0, 0, j, dx * k:(dx + 1) * k, :] = jnp.sum(
-                t * _x_weights(s, fx, dx), axis=1)       # (k_dy, C)
+            out_ref[0, 0, j, dx * k:(dx + 1) * k, :] = (
+                (1.0 - fx) * t[dx] + fx * t[dx + 1])
         return 0
 
     jax.lax.fori_loop(0, n_j, body, 0)
@@ -992,6 +1018,7 @@ def _sw_bwd_kernel(coords_ref, dout_ref, df2_ref, *, radius, dims):
     k = 2 * radius + 1
     h2, w2 = dims
     n_j = dout_ref.shape[2]
+    c = dout_ref.shape[4]
     i = pl.program_id(1)
 
     @pl.when(i == 0)
@@ -1001,30 +1028,33 @@ def _sw_bwd_kernel(coords_ref, dout_ref, df2_ref, *, radius, dims):
     def body(j, _):
         cx = coords_ref[0, 0, j, 0]
         cy = coords_ref[0, 0, j, 1]
-        x8, s, y0, fx, fy = _wcp_window(cx, cy, 0, h2, w2, radius)
+        x0, y0, fx, fy = _sw_window(cx, cy, h2, w2, radius)
 
-        # transpose of the x-selection, one window row at a time: the
-        # (1, C) gradient of tap (dx, dy) spreads over lanes s+dx / s+dx+1
-        # of slab row dy as an outer product with the dx weights
-        wx = [_x_weights(s, fx, dx)[0] for dx in range(k)]   # (_XW, 1)
-        dt = []
-        for dy in range(k):
-            acc = None
-            for dx in range(k):
-                tap = dx * k + dy
-                g = dout_ref[0, 0, j, tap:tap + 1, :].astype(jnp.float32)
-                acc = wx[dx] * g if acc is None else acc + wx[dx] * g
-            dt.append(acc)                                   # (_XW, C)
-        # transpose of the y-lerp: slab row y gets (1-fy)·dt[y] + fy·dt[y-1]
-        dd = jnp.stack(
-            [(1.0 - fy) * dt[0]]
-            + [(1.0 - fy) * dt[y] + fy * dt[y - 1] for y in range(1, k)]
-            + [fy * dt[k - 1]])                              # (k+1, _XW, C)
-
-        df2_ref[0, pl.ds(y0, k + 1), pl.ds(x8, _XW), :] += dd
+        g = [dout_ref[0, 0, j, dx * k:(dx + 1) * k, :] for dx in range(k)]
+        zero = jnp.zeros((1, c), jnp.float32)
+        for xi in range(k + 1):
+            # transpose of the x-lerp: column xi hears from dx = xi, xi-1
+            if xi == 0:
+                dt = (1.0 - fx) * g[0]
+            elif xi == k:
+                dt = fx * g[k - 1]
+            else:
+                dt = (1.0 - fx) * g[xi] + fx * g[xi - 1]     # (k_dy, C)
+            # transpose of the y-lerp: row y gets (1-fy)·dt[y] + fy·dt[y-1]
+            dd = ((1.0 - fy) * jnp.concatenate([dt, zero], axis=0)
+                  + fy * jnp.concatenate([zero, dt], axis=0))
+            df2_ref[0, x0 + xi, pl.ds(y0, k + 1), :] += dd
         return 0
 
     jax.lax.fori_loop(0, n_j, body, 0)
+
+
+def _sw_pad_f2(f2, radius):
+    """The map as the kernels address it: (B, Wp, Hp, C) float32, zero
+    padded, x leading."""
+    lo, hi = _sw_pads(radius)
+    return jnp.pad(f2.astype(jnp.float32).transpose(0, 2, 1, 3),
+                   ((0, 0), (lo, hi), (lo, hi), (0, 0)))
 
 
 def _sw_fwd_tpu(f2, coords, radius, interpret=False):
@@ -1032,7 +1062,7 @@ def _sw_fwd_tpu(f2, coords, radius, interpret=False):
     c = f2.shape[-1]
     k = 2 * radius + 1
     dims = (f2.shape[1], f2.shape[2])
-    (f2p,) = _wcp_pad_f2((f2,), radius)
+    f2p = _sw_pad_f2(f2, radius)
 
     out = pl.pallas_call(
         functools.partial(_sw_fwd_kernel, radius=radius, dims=dims),
@@ -1042,14 +1072,16 @@ def _sw_fwd_tpu(f2, coords, radius, interpret=False):
         in_specs=[
             pl.BlockSpec((1, 1, n_j, 2), lambda bi, ii: (bi, ii, 0, 0),
                          memory_space=pltpu.SMEM),
+            # the map changes with b only: one buffer, not two
             pl.BlockSpec((1,) + f2p.shape[1:], lambda bi, ii: (bi, 0, 0, 0),
+                         pipeline_mode=pl.Buffered(1),
                          memory_space=pltpu.VMEM),
         ],
         out_specs=pl.BlockSpec((1, 1, n_j, k * k, c),
                                lambda bi, ii: (bi, ii, 0, 0, 0),
                                memory_space=pltpu.VMEM),
         compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=100 * 1024 * 1024),
+            vmem_limit_bytes=_SW_VMEM_LIMIT),
         interpret=interpret,
     )(coords, f2p)
     # (b, i, j, dx·k+dy, c) → the sample_window (B, du, dv, H, W, C) layout
@@ -1061,9 +1093,9 @@ def _sw_bwd_tpu(f2, coords, dout, radius, interpret=False):
     b, n_i, n_j = coords.shape[:3]
     c = f2.shape[-1]
     k = 2 * radius + 1
-    lo, _hi_y, _hi_x = _wcp_pads(radius)
-    dims = (f2.shape[1], f2.shape[2])
-    (f2p,) = _wcp_pad_f2((f2,), radius)
+    lo, hi = _sw_pads(radius)
+    h2, w2 = dims = (f2.shape[1], f2.shape[2])
+    padded = (b, w2 + lo + hi, h2 + lo + hi, c)
 
     # (B, du, dv, H, W, C) → the kernel's (b, i, j, dx·k+dy, c) row layout
     doutr = dout.astype(jnp.float32).transpose(0, 3, 4, 1, 2, 5)
@@ -1071,7 +1103,7 @@ def _sw_bwd_tpu(f2, coords, dout, radius, interpret=False):
 
     df2 = pl.pallas_call(
         functools.partial(_sw_bwd_kernel, radius=radius, dims=dims),
-        out_shape=jax.ShapeDtypeStruct(f2p.shape, jnp.float32),
+        out_shape=jax.ShapeDtypeStruct(padded, jnp.float32),
         grid=(b, n_i),
         in_specs=[
             pl.BlockSpec((1, 1, n_j, 2), lambda bi, ii: (bi, ii, 0, 0),
@@ -1080,16 +1112,16 @@ def _sw_bwd_tpu(f2, coords, dout, radius, interpret=False):
                          lambda bi, ii: (bi, ii, 0, 0, 0),
                          memory_space=pltpu.VMEM),
         ],
-        out_specs=pl.BlockSpec((1,) + f2p.shape[1:],
+        out_specs=pl.BlockSpec((1,) + padded[1:],
                                lambda bi, ii: (bi, 0, 0, 0),
                                memory_space=pltpu.VMEM),
         compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=100 * 1024 * 1024),
+            vmem_limit_bytes=_SW_VMEM_LIMIT),
         interpret=interpret,
     )(coords, doutr)
 
-    h2, w2 = dims
-    return df2[:, lo:lo + h2, lo:lo + w2, :]
+    # strip the padding, back to (B, H2, W2, C)
+    return df2[:, lo:lo + w2, lo:lo + h2, :].transpose(0, 2, 1, 3)
 
 
 def _sw_fwd_interpret(f2, coords, radius):
@@ -1111,19 +1143,27 @@ def _sw_reference(f2, coords, radius):
 
 
 def _sw_fits_vmem(f2, coords, radius):
-    """Static shape check, mirroring ``_wcp_fits_vmem``: one (b, i)-row of
-    output plus the padded f2 map must sit in VMEM, and the x-selection
-    matrix covers the alignment shift only for radius ≤ 7."""
+    """Static shape check: what the kernels hold in VMEM as Mosaic lays
+    it out (float32, rows padded to 8 sublanes, channels to 128 lanes)
+    must fit under the limit they are compiled with. Either direction
+    keeps one (b, i)-row of window taps in two pipeline buffers and the
+    padded map once (the forward asks for one buffer, and the backward's
+    accumulator is an output block that only changes with b). The bodies
+    unroll over the patch's k+1 columns and are compiled for radius ≤ 7."""
     if radius > 7:
         return False
-    lo, hi_y, hi_x = _wcp_pads(radius)
+    lo, hi = _sw_pads(radius)
     k = 2 * radius + 1
-    n_j, c = coords.shape[2], f2.shape[-1]
-    itemsize = 2 if f2.dtype == jnp.bfloat16 else 4
-    total = n_j * k * k * max(c, 128) * 4              # out row (lane-padded)
-    total += (f2.shape[1] + lo + hi_y) * (f2.shape[2] + lo + hi_x) \
-        * c * itemsize
-    return total <= 64 * 1024 * 1024
+    n_j = coords.shape[2]
+    lanes = -(-f2.shape[-1] // 128) * 128
+
+    def tiled(leading, rows):
+        return leading * (-(-rows // 8) * 8) * lanes * 4
+
+    row = tiled(n_j, k * k)
+    padded = tiled(f2.shape[2] + lo + hi, f2.shape[1] + lo + hi)
+    # a MiB of room for what Mosaic keeps on its own stack
+    return 2 * row + padded + (1 << 20) <= _SW_VMEM_LIMIT
 
 
 def _sw_takes_kernel(f2, coords, radius):

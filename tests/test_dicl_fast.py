@@ -35,15 +35,84 @@ def _inputs(seed=0, b=2, h2=13, w2=17, c=5, h=6, w=7, spread=12.0,
 
 # -- Pallas window sampler vs XLA sample_window ------------------------------
 
+# What the kernel's addressing could get wrong, one geometry each: the map
+# rides with x leading and y on the sublanes, a position's patch is a
+# dynamic column index and a dynamic-start sublane slice.
+_GEOMETRIES = ("fractions", "residuals", "outside", "coarse")
 
-@pytest.mark.parametrize("radius", [1, 3])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_sampler_kernel_forward_parity(radius, dtype):
-    f2, coords = _inputs(seed=1, dtype=dtype)
-    ref = np.asarray(sample_window(f2, coords, radius), np.float32)
+
+def _geometry(kind, n_j, radius, dtype, c=32):
+    """(f2, coords): three rows of ``n_j`` centres over a (9, n_j) map,
+    or over one of half the resolution for ``coarse``."""
+    rs = np.random.RandomState(_GEOMETRIES.index(kind) * 100 + n_j + radius)
+    h, w = 3, n_j
+    h2, w2 = (5, n_j // 2) if kind == "coarse" else (9, n_j)
+    f2 = jnp.asarray(rs.randn(1, h2, w2, c), dtype)
+    yy, xx = np.meshgrid(np.arange(h), np.arange(w), indexing="ij")
+    if kind == "fractions":
+        # zero and almost-one fractions, all four pairings
+        almost = 1.0 - 2.0 ** -10
+        cx = xx + np.where(xx % 2 == 0, 0.0, almost)
+        cy = 3 * yy + np.where((xx // 2) % 2 == 0, 0.0, almost)
+    elif kind == "residuals":
+        # patch starts on every residual of 8, along x and along y
+        cx = xx - 3 + 0.37
+        cy = (xx + 3 * yy) % 9 - 2 + 0.6
+        for start in (np.floor(cx) - radius, np.floor(cy) - radius):
+            assert set(np.unique(start.astype(int) % 8)) == set(range(8))
+    elif kind == "outside":
+        # a whole window beyond each border, and one column or row short
+        # of it, in every pairing of x and y
+        r = radius
+        xs = np.array([-(r + 1) - 2.5, -(r + 1) + 0.25, 0.5 * w2,
+                       w2 + r - 0.25, w2 + r + 2.5])
+        ys = np.array([-(r + 1) - 2.5, -(r + 1) + 0.25, 0.5 * h2,
+                       h2 + r - 0.25, h2 + r + 2.5])
+        cx = xs[xx % 5]
+        cy = ys[(xx // 5 + yy) % 5]
+    else:
+        # a map coarser than the centres (raft+dicl/ml: h >> lvl)
+        cx = 0.5 * xx + rs.randn(h, w)
+        cy = 0.5 * yy + rs.randn(h, w)
+    coords = jnp.asarray(np.stack((cx, cy), -1)[None], jnp.float32)
+    return f2, coords
+
+
+def _sampler_cases(spread):
+    """The kernel-parity cases: ``spread`` (random centres far past the
+    borders, small C) as pytest params, then every geometry at C = 32
+    with 22 and 88 centres a row, radius 1, 4 and 7, both map dtypes."""
+    cases = list(spread)
+    for kind in _GEOMETRIES:
+        for n_j in (22, 88):
+            for radius in (1, 4, 7):
+                for dtype in (jnp.float32, jnp.bfloat16):
+                    cases.append(pytest.param(
+                        kind, n_j, radius, dtype,
+                        id=f"{kind}-j{n_j}-r{radius}-"
+                           f"{jnp.dtype(dtype).name}"))
+    return cases
+
+
+def _sampler_inputs(kind, n_j, radius, dtype):
+    if kind == "spread":
+        return _inputs(seed=n_j, dtype=dtype)       # n_j carries the seed
+    return _geometry(kind, n_j, radius, dtype)
+
+
+@pytest.mark.parametrize("kind, n_j, radius, dtype", _sampler_cases(
+    pytest.param("spread", 1, radius, dtype,
+                 id=f"spread-r{radius}-{jnp.dtype(dtype).name}")
+    for radius in (1, 3) for dtype in (jnp.float32, jnp.bfloat16)))
+def test_sampler_kernel_forward_parity(kind, n_j, radius, dtype):
+    f2, coords = _sampler_inputs(kind, n_j, radius, dtype)
+    # the kernel widens the map and lerps in float32: so does the reference
+    ref = np.asarray(pk._sw_reference(f2.astype(jnp.float32), coords, radius))
     out = np.asarray(pk._sw_fwd_interpret(f2, coords, radius))
-    atol = 1e-5 if dtype == jnp.float32 else 5e-2
-    np.testing.assert_allclose(out, ref, atol=atol)
+    assert out.dtype == np.float32
+    np.testing.assert_allclose(out, ref, atol=1e-5)
+    if kind == "outside":
+        assert (ref == 0).any() and (ref != 0).any()
 
 
 def test_sampler_kernel_zero_padding_out_of_bounds():
@@ -60,20 +129,67 @@ def test_sampler_kernel_zero_padding_out_of_bounds():
     np.testing.assert_allclose(out, ref, atol=1e-5)
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_sampler_kernel_backward_parity(dtype):
-    radius = 2
-    f2, coords = _inputs(seed=3, dtype=dtype)
-    ref = sample_window(f2.astype(jnp.float32), coords, radius)
+@pytest.mark.parametrize("kind, n_j, radius, dtype", _sampler_cases(
+    pytest.param("spread", 3, 2, dtype,
+                 id=f"spread-r2-{jnp.dtype(dtype).name}")
+    for dtype in (jnp.float32, jnp.bfloat16)))
+def test_sampler_kernel_backward_parity(kind, n_j, radius, dtype):
+    f2, coords = _sampler_inputs(kind, n_j, radius, dtype)
+    wide = f2.astype(jnp.float32)
+    ref, vjp = jax.vjp(lambda m: pk._sw_reference(m, coords, radius), wide)
     dout = jnp.asarray(np.random.RandomState(4).randn(*ref.shape),
                        jnp.float32)
-
-    df_ref = jax.grad(
-        lambda m: (sample_window(m, coords, radius) * dout).sum()
-    )(f2.astype(jnp.float32))
+    (df_ref,) = vjp(dout)
     df = np.asarray(pk._sw_bwd_interpret(f2, coords, dout, radius))
-    np.testing.assert_allclose(df, np.asarray(df_ref),
-                               atol=1e-5 if dtype == jnp.float32 else 5e-2)
+    assert df.shape == f2.shape and df.dtype == np.float32
+    np.testing.assert_allclose(df, np.asarray(df_ref), atol=1e-4)
+
+
+# The benchmark tells the sampler's calls by their result: the forward's
+# is the float32 window (b, i, j, k·k, c), the backward's a float32 padded
+# map with that window among its operands (benchmark/harness/sw_kernel.py).
+# A kernel that returns anything else reads as no sampler at all.
+_CTF3_LEVELS = [(48, 88), (24, 44), (12, 22)]
+
+
+def _pallas_calls(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _pallas_calls(sub)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("h, w", _CTF3_LEVELS)
+def test_sampler_forward_call_returns_the_float32_window(h, w, dtype):
+    b, c, radius = 6, 32, 4
+    f2 = jax.ShapeDtypeStruct((b, h, w, c), dtype)
+    coords = jax.ShapeDtypeStruct((b, h, w, 2), jnp.float32)
+    traced = jax.make_jaxpr(lambda a, cc: pk._sw_fwd_tpu(a, cc, radius))(
+        f2, coords)
+    (call,) = _pallas_calls(traced.jaxpr)
+    (out,) = call.outvars
+    assert out.aval.shape == (b, h, w, 81, c)
+    assert out.aval.dtype == jnp.float32
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("h, w", _CTF3_LEVELS)
+def test_sampler_backward_call_takes_the_float32_window(h, w, dtype):
+    b, c, radius = 6, 32, 4
+    f2 = jax.ShapeDtypeStruct((b, h, w, c), dtype)
+    coords = jax.ShapeDtypeStruct((b, h, w, 2), jnp.float32)
+    dout = jax.ShapeDtypeStruct((b, 9, 9, h, w, c), dtype)
+    traced = jax.make_jaxpr(
+        lambda a, cc, d: pk._sw_bwd_tpu(a, cc, d, radius))(f2, coords, dout)
+    (call,) = _pallas_calls(traced.jaxpr)
+    (out,) = call.outvars
+    assert out.aval.ndim == 4 and out.aval.dtype == jnp.float32
+    assert (out.aval.shape[0], out.aval.shape[3]) == (b, c)
+    windows = [v.aval for v in call.invars if v.aval.ndim == 5]
+    assert [(a.shape, a.dtype) for a in windows] == [
+        ((b, h, w, 81, c), jnp.float32)]
 
 
 def test_sample_window_fused_dispatch_and_grads():

@@ -50,6 +50,7 @@ def compile_for_v5e(fn, *specs):
             for shape, dtype in specs]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    return compiled
 
 
 # Up8 combine in the raft/baseline train step: b6 400x720, 12 iterations
@@ -74,14 +75,50 @@ for band in (True, False):
         f1, coords, dout, *f2)
 
 # fused DICL window sampler, raft+dicl/ml: b6 384x704, C=32, 4 levels
+# (the maps coarser than the centres here; level 0 is ctf3's 48x88 below)
 b, h, w, c = 6, 48, 88, 32
-for lvl in range(4):
+for lvl in range(1, 4):
     for dtype in (f32, bf16):
         f2 = ((b, h >> lvl, w >> lvl, c), dtype)
         compile_for_v5e(lambda a, cc: K._sw_fwd_tpu(a, cc, 4),
                         f2, ((b, h, w, 2), f32))
         compile_for_v5e(lambda a, cc, d: K._sw_bwd_tpu(a, cc, d, 4),
                         f2, ((b, h, w, 2), f32), ((b, 9, 9, h, w, c), f32))
+
+# ... and at the three shapes ctf3-train-things dispatches (maps and centres
+# alike): the benchmark tells the sampler's calls by their instruction text
+# as compiled, and a call it cannot tell leaves sw_ms and sw_roofline unread
+from benchmark.harness import sw_kernel
+
+
+def mosaic_call(compiled):
+    (line,) = [ln for ln in compiled.as_text().splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln]
+    return sw_kernel.call(line.strip())
+
+
+for h, w in ((48, 88), (24, 44), (12, 22)):
+    for dtype in (f32, bf16):
+        f2 = ((b, h, w, c), dtype)
+        fwd = compile_for_v5e(lambda a, cc: K._sw_fwd_tpu(a, cc, 4),
+                              f2, ((b, h, w, 2), f32))
+        direction, window, _ = mosaic_call(fwd)
+        assert (direction, window) == ("forward", (b, h, w, 81, c)), fwd
+        bwd = compile_for_v5e(lambda a, cc, d: K._sw_bwd_tpu(a, cc, d, 4),
+                              f2, ((b, h, w, 2), f32),
+                              ((b, 9, 9, h, w, c), f32))
+        direction, window, _ = mosaic_call(bwd)
+        assert (direction, window) == ("backward", (b, h, w, 81, c)), bwd
+
+# ... and at the edge of what _sw_fits_vmem admits: the gate promises the
+# compiler's verdict, and a shape it admits wrongly fails the whole step
+f2 = jax.ShapeDtypeStruct((1, 248, 440, c), f32)
+assert K._sw_fits_vmem(f2, jax.ShapeDtypeStruct((1, 248, 440, 2), f32), 4)
+compile_for_v5e(lambda a, cc: K._sw_fwd_tpu(a, cc, 4),
+                ((1, 248, 440, c), f32), ((1, 248, 440, 2), f32))
+compile_for_v5e(lambda a, cc, d: K._sw_bwd_tpu(a, cc, d, 4),
+                ((1, 248, 440, c), f32), ((1, 248, 440, 2), f32),
+                ((1, 9, 9, 248, 440, c), f32))
 
 # Under an SPMD mesh Mosaic refuses to partition a kernel automatically:
 # the step builders publish their mesh (parallel.mesh.traced_under) and

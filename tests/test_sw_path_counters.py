@@ -130,7 +130,9 @@ def test_counts_outside_a_site_add_up_and_outside_a_program_are_dropped(sink):
 
 @pytest.mark.parametrize("shape, radius, fused", [
     ((6, 48, 88, 32), 4, True),        # the cell's finest level
-    ((6, 48, 88, 32), 8, False),       # the x-selection covers radius <= 7
+    ((6, 48, 88, 32), 8, False),       # the bodies unroll for radius <= 7
+    ((1, 248, 440, 32), 4, True),      # the largest 9:16 map Mosaic takes
+    ((1, 255, 455, 32), 4, False),     # ... and the first it refuses
     ((1, 1600, 2400, 32), 4, False),   # the padded map exceeds VMEM
 ])
 def test_a_call_that_fails_the_vmem_check_is_a_fallback(monkeypatch, shape,
