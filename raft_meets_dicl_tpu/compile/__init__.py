@@ -20,15 +20,16 @@ from .aot import (
 )
 from .registry import (
     Program, ProgramKey, ProgramRegistry, effective_args_key, flag_items,
-    inference_key, register_step, registry, reset, shape_signature,
-    static_args_key, unstable,
+    inference_key, notes_flag, register_step, registry, reset,
+    shape_signature, static_args_key, unstable,
 )
 
 __all__ = [
     "aot",
     "Program", "ProgramKey", "ProgramRegistry",
-    "effective_args_key", "flag_items", "inference_key", "register_step",
-    "registry", "reset", "shape_signature", "static_args_key", "unstable",
+    "effective_args_key", "flag_items", "inference_key", "notes_flag",
+    "register_step", "registry", "reset", "shape_signature",
+    "static_args_key", "unstable",
     "aot_enabled", "artifact_path", "disable_aot", "enable_aot",
     "fetch", "publish",
     "fingerprint", "programs_dir",

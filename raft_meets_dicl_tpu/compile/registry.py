@@ -108,6 +108,14 @@ class ProgramKey:
         return repr((self.kind, self.model, self.flags))
 
 
+def notes_flag(model):
+    """``{"notes": n}`` for a model that counts its trace-time notes'
+    revisions (``Model.notes_revision``), else nothing: a key without
+    the flag is byte for byte what it was."""
+    revision = getattr(model, "notes_revision", None)
+    return {} if revision is None else {"notes": revision}
+
+
 def inference_key(kind, model, model_args, mesh=None, wire=None,
                   variables_sharding=None, model_id=None, **flags):
     """Identity of an inference program, or None when something cannot be
@@ -131,7 +139,7 @@ def inference_key(kind, model, model_args, mesh=None, wire=None,
     return ProgramKey(
         kind=kind, model=model_id or unstable(model),
         flags=flag_items(args=args_key, mesh=mesh_key, wire=wire_key,
-                         **flags))
+                         **notes_flag(model), **flags))
 
 
 def mosaic_calls(compiled):

@@ -15,6 +15,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ... import telemetry
 from ...ops.pool import avg_pool2d, max_pool2d
 from ..common.blocks.dicl import DisplacementAwareProjection, MatchingNet
 from ..common.blocks.raft import ResidualBlock, kaiming_normal
@@ -163,15 +164,25 @@ class MlCorrelationModule(nn.Module):
                             (self.radius, self.radius), init=self.dap_init)
                         for _ in range(self.levels)]
 
+        # the levels are apart only here: one scope and one trace site a
+        # sampler call, so that the device trace names the level and each
+        # call's path (``sw_fused_calls``) is counted beside the others'
         sample = sample_window_fast if fast else sample_window
-        windows = [sample(f2, coords / 2 ** i, self.radius)
-                   for i, f2 in enumerate(fmap2)]
+        windows = []
+        for i, f2 in enumerate(fmap2):
+            with jax.named_scope(f"level{i}"), \
+                    telemetry.trace_site(f"level{i}"):
+                windows.append(sample(f2, coords / 2 ** i, self.radius))
         fmap1 = list(fmap1)
         if self.dtype is not None:
             fmap1 = [f1.astype(self.dtype) for f1 in fmap1]
             windows = [win.astype(self.dtype) for win in windows]
         if not self.is_initializing():
             record_matching_bytes(*fmap1, *windows)
+            # how many levels one MatchingNet evaluation covers: a
+            # property of the program, not a count over its iterations
+            telemetry.note_trace("matching_levels_batched",
+                                 self.levels if fast else 1, scale=False)
 
         if fast:
             costs = self._batched_costs(mnets, fmap1, windows, train,
@@ -273,9 +284,10 @@ class _MlStep(nn.Module):
         flow = jax.lax.stop_gradient(flow)
         coords1 = coords0 + flow
 
-        corr = self.cvol(fmap1, fmap2, coords1, dap=self.dap,
-                         mask_costs=self.mask_costs, train=self.train,
-                         frozen_bn=self.frozen_bn)
+        with jax.named_scope("matching"):
+            corr = self.cvol(fmap1, fmap2, coords1, dap=self.dap,
+                             mask_costs=self.mask_costs, train=self.train,
+                             frozen_bn=self.frozen_bn)
         corr = checkpoint_name(corr, "corr_features")
 
         corr_flows = tuple(flow + d for d in self.reg(corr))
@@ -283,7 +295,8 @@ class _MlStep(nn.Module):
         if self.corr_grad_stop:
             corr = jax.lax.stop_gradient(corr)
 
-        h, d = self.update(h, x, corr, flow)
+        with jax.named_scope("update"):
+            h, d = self.update(h, x, corr, flow)
         coords1 = coords1 + d
         flow = coords1 - coords0
 
@@ -322,44 +335,48 @@ class RaftPlusDiclMlModule(nn.Module):
         dt = jnp.bfloat16 if self.mixed_precision else None
 
         # asymmetric encoders (reference :173-236)
-        if self.encoder_type == "raft-cnn":
-            base = FeatureEncoderS3(output_dim=256, norm_type=self.encoder_norm,
-                                    dropout=0, dtype=dt)
-            b1, b2 = base((img1, img2), train, frozen_bn)
-            b1 = b1.astype(jnp.float32)
-            b2 = b2.astype(jnp.float32)
+        with jax.named_scope("encoders"):
+            if self.encoder_type == "raft-cnn":
+                base = FeatureEncoderS3(output_dim=256,
+                                        norm_type=self.encoder_norm,
+                                        dropout=0, dtype=dt)
+                b1, b2 = base((img1, img2), train, frozen_bn)
+                b1 = b1.astype(jnp.float32)
+                b2 = b2.astype(jnp.float32)
 
-            fmap1 = StackEncoder(self.corr_channels, self.corr_levels,
-                                 self.encoder_norm)(b1, train, frozen_bn)
-            fmap2 = PyramidEncoder(self.corr_channels, self.corr_levels,
-                                   self.encoder_norm)(b2, train, frozen_bn)
-            fmap1 = (fmap1,) if self.corr_levels == 1 else fmap1
-            fmap2 = (fmap2,) if self.corr_levels == 1 else fmap2
-        elif self.encoder_type in ("raft-avgpool", "raft-maxpool"):
-            pool = avg_pool2d if self.encoder_type.endswith("avgpool") else max_pool2d
-            base = FeatureEncoderS3(output_dim=self.corr_channels,
-                                    norm_type=self.encoder_norm, dropout=0,
-                                    dtype=dt)
-            f1, f2 = base((img1, img2), train, frozen_bn)
-            f1 = f1.astype(jnp.float32)
-            f2 = f2.astype(jnp.float32)
+                fmap1 = StackEncoder(self.corr_channels, self.corr_levels,
+                                     self.encoder_norm)(b1, train, frozen_bn)
+                fmap2 = PyramidEncoder(self.corr_channels, self.corr_levels,
+                                       self.encoder_norm)(b2, train, frozen_bn)
+                fmap1 = (fmap1,) if self.corr_levels == 1 else fmap1
+                fmap2 = (fmap2,) if self.corr_levels == 1 else fmap2
+            elif self.encoder_type in ("raft-avgpool", "raft-maxpool"):
+                pool = (avg_pool2d if self.encoder_type.endswith("avgpool")
+                        else max_pool2d)
+                base = FeatureEncoderS3(output_dim=self.corr_channels,
+                                        norm_type=self.encoder_norm, dropout=0,
+                                        dtype=dt)
+                f1, f2 = base((img1, img2), train, frozen_bn)
+                f1 = f1.astype(jnp.float32)
+                f2 = f2.astype(jnp.float32)
 
-            fmap1 = tuple([f1] * self.corr_levels)
-            pyramid = [f2]
-            for _ in range(1, self.corr_levels):
-                pyramid.append(pool(pyramid[-1], 2))
-            fmap2 = tuple(pyramid)
-        else:
-            raise ValueError(f"unknown encoder type: '{self.encoder_type}'")
+                fmap1 = tuple([f1] * self.corr_levels)
+                pyramid = [f2]
+                for _ in range(1, self.corr_levels):
+                    pyramid.append(pool(pyramid[-1], 2))
+                fmap2 = tuple(pyramid)
+            else:
+                raise ValueError(
+                    f"unknown encoder type: '{self.encoder_type}'")
 
-        cnet = FeatureEncoderS3(output_dim=hdim + cdim,
-                                norm_type=self.context_norm,
-                                dropout=self.dropout, dtype=dt)
-        ctx = cnet(img1, train, frozen_bn)
-        h = jnp.tanh(ctx[..., :hdim])
-        x = nn.relu(ctx[..., hdim:])
-        if hidden_init is not None:
-            h = hidden_init.astype(h.dtype)
+            cnet = FeatureEncoderS3(output_dim=hdim + cdim,
+                                    norm_type=self.context_norm,
+                                    dropout=self.dropout, dtype=dt)
+            ctx = cnet(img1, train, frozen_bn)
+            h = jnp.tanh(ctx[..., :hdim])
+            x = nn.relu(ctx[..., hdim:])
+            if hidden_init is not None:
+                h = hidden_init.astype(h.dtype)
 
         b, hc, wc, _ = fmap1[0].shape
         coords0 = coordinate_grid(b, hc, wc)
@@ -396,52 +413,60 @@ class RaftPlusDiclMlModule(nn.Module):
             train=train, frozen_bn=frozen_bn,
         )
 
-        if self.unroll:
-            step = body(**shared)
-            carry = (h, flow)
-            flows, hiddens, corr_flows = [], [], []
-            for _ in range(iterations):
-                carry, (fl, hi, cf) = step(
-                    carry, jnp.zeros((0,), dtype=jnp.bfloat16), fmap1, fmap2, x, coords0)
-                flows.append(fl)
-                hiddens.append(hi)
-                corr_flows.append(cf)
-            h, flow = carry
+        # one trace site for the iterations: what the matching notes while
+        # it traces (sampler path, matching bytes) stands for the
+        # ``iterations`` trips of the body, once, however often the tracer
+        # visits it (flax's lifted scan does so twice)
+        with telemetry.trace_site("iteration", iterations):
+            if self.unroll:
+                step = body(**shared)
+                carry = (h, flow)
+                flows, hiddens, corr_flows = [], [], []
+                for _ in range(iterations):
+                    carry, (fl, hi, cf) = step(
+                        carry, jnp.zeros((0,), dtype=jnp.bfloat16),
+                        fmap1, fmap2, x, coords0)
+                    flows.append(fl)
+                    hiddens.append(hi)
+                    corr_flows.append(cf)
+                h, flow = carry
 
-            flows = jnp.stack(flows)
-            hiddens = jnp.stack(hiddens)
-            corr_flows = tuple(
-                jnp.stack([cf[lvl] for cf in corr_flows])
-                for lvl in range(self.corr_levels)
-            )
-        else:
-            # train-mode batch norm mutates running stats every iteration;
-            # carrying the batch_stats collection through the scan keeps
-            # the sequential-update semantics of the unrolled loop while
-            # compiling ONE step body — the 12x-unrolled train graph of
-            # this model (12 iterations x 4 MatchingNets) is what crashed
-            # the TPU compiler service at the reference Things config
-            # (b6/384x704; see PERF.md round 5)
-            live_bn = train and not frozen_bn
-            step = nn.scan(
-                body,
-                variable_broadcast=(["params"] if live_bn
-                                    else ["params", "batch_stats"]),
-                variable_carry=["batch_stats"] if live_bn else [],
-                split_rngs={"params": False, "dropout": True},
-                in_axes=(0, nn.broadcast, nn.broadcast, nn.broadcast,
-                         nn.broadcast),
-                out_axes=0,
-            )(**shared)
+                flows = jnp.stack(flows)
+                hiddens = jnp.stack(hiddens)
+                corr_flows = tuple(
+                    jnp.stack([cf[lvl] for cf in corr_flows])
+                    for lvl in range(self.corr_levels)
+                )
+            else:
+                # train-mode batch norm mutates running stats every
+                # iteration; carrying the batch_stats collection through
+                # the scan keeps the sequential-update semantics of the
+                # unrolled loop while compiling ONE step body — the
+                # 12x-unrolled train graph of this model (12 iterations x
+                # 4 MatchingNets) is what crashed the TPU compiler service
+                # at the reference Things config (b6/384x704; see PERF.md
+                # round 5)
+                live_bn = train and not frozen_bn
+                step = nn.scan(
+                    body,
+                    variable_broadcast=(["params"] if live_bn
+                                        else ["params", "batch_stats"]),
+                    variable_carry=["batch_stats"] if live_bn else [],
+                    split_rngs={"params": False, "dropout": True},
+                    in_axes=(0, nn.broadcast, nn.broadcast, nn.broadcast,
+                             nn.broadcast),
+                    out_axes=0,
+                )(**shared)
 
-            (h, flow), (flows, hiddens, corr_flows) = step(
-                (h, flow), jnp.zeros((iterations, 0), dtype=jnp.bfloat16),
-                fmap1, fmap2, x, coords0,
-            )
+                (h, flow), (flows, hiddens, corr_flows) = step(
+                    (h, flow), jnp.zeros((iterations, 0), dtype=jnp.bfloat16),
+                    fmap1, fmap2, x, coords0,
+                )
 
-        out = upsample_flows(flows, hiddens, (h, flow),
-                             (img1.shape[1], img1.shape[2]), dtype=dt,
-                             upnet=upnet, final_only=final_only)
+        with jax.named_scope("up8"):
+            out = upsample_flows(flows, hiddens, (h, flow),
+                                 (img1.shape[1], img1.shape[2]), dtype=dt,
+                                 upnet=upnet, final_only=final_only)
 
         if corr_flow:
             out_corr = [
@@ -471,6 +496,10 @@ class RaftPlusDiclMl(Model):
     """``raft+dicl/ml`` (reference raft_dicl_ml.py:448-582)."""
 
     type = "raft+dicl/ml"
+    # 1: the iterations are one trace site and each level's sampler call
+    # its own (``sw_fused_calls`` levels x iterations, the matching's
+    # bytes times the iterations), ``matching_levels_batched`` is noted
+    notes_revision = 1
 
     @classmethod
     def from_config(cls, cfg):

@@ -65,6 +65,14 @@ class Model:
 
     type = None
 
+    # A stored executable carries what its trace noted
+    # (``telemetry.note_trace``) and is found again by configuration, not
+    # by source. A model whose notes change their meaning under an
+    # unchanged configuration counts this up; it is part of the model's
+    # program keys, so that no boot loads the older notes with an older
+    # executable. None leaves the keys as they were.
+    notes_revision = None
+
     @classmethod
     def _typecheck(cls, cfg):
         if cfg["type"] != cls.type:

@@ -737,8 +737,10 @@ class TrainingContext:
                         tuple(d.id for d in self.mesh.devices.flat))
         # the augment flag exists only on the augmented variant: with
         # device augmentation off, the key (and thus program identity,
-        # AOT artifact, and budget pin) stays byte-identical to before
-        aflags = {}
+        # AOT artifact, and budget pin) stays byte-identical to before;
+        # likewise the notes flag, for a model that counts revisions of
+        # its trace-time notes
+        aflags = programs.notes_flag(self.model)
         if self.augment is not None:
             aflags["augment"] = self.augment.describe()
         return programs.ProgramKey(

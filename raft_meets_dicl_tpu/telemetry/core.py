@@ -62,7 +62,7 @@ SCHEMA = {
     "device_sync": {"step", "seconds"},
     # a backend compile; with the counts the owning Program's trace
     # noted (``note_trace``: sw_fused_calls, sw_fallback_calls,
-    # matching_volume_bytes) when it noted any
+    # matching_volume_bytes, matching_levels_batched) when it noted any
     "compile": {"label", "seconds"},
     "cache": {"event"},
     "memory": {"host_rss_gib", "live_arrays"},
@@ -652,7 +652,7 @@ def trace_site(label, repeat=1):
         _trace_sites.stack = stack
 
 
-def note_trace(name, value):
+def note_trace(name, value, scale=True):
     """A count known while a program traces (which path a kernel's
     dispatch took, the bytes a shape makes a layer move). It belongs to
     the registry Program whose trace is running (the ``jit_label``
@@ -660,10 +660,15 @@ def note_trace(name, value):
     stores it with the executable and hands it to the next ``step``
     event's counters on every boot, traced or loaded. Outside a
     Program's trace (``model.init``, an eager apply) the count describes
-    no program and is dropped."""
+    no program and is dropped. ``scale=False`` notes a property of the
+    traced part (how many levels one evaluation covers) and not a count
+    of what it does: the enclosing sites' repeats leave it as it is."""
     program = getattr(_jit_label, "program", None)
     if program is not None:
-        program.note_trace(name, value, getattr(_trace_sites, "stack", ()))
+        site = getattr(_trace_sites, "stack", ())
+        if not scale:
+            site = tuple((label, 1) for label, _ in site)
+        program.note_trace(name, value, site)
 
 
 def install_listeners():

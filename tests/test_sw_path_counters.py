@@ -144,3 +144,122 @@ def test_a_call_that_fails_the_vmem_check_is_a_fallback(monkeypatch, shape,
     assert not pk._sw_takes_kernel(f2, coords, radius)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert pk._sw_takes_kernel(f2, coords, radius) is fused
+
+
+# -- raft+dicl/ml: four levels in every iteration of one scan ----------------
+
+ML_ITERATIONS, ML_LEVELS, ML_RADIUS, ML_CHANNELS = 3, 4, 2, 8
+ML_SHAPE = (1, 64, 128)
+
+
+def _ml_train_step(share, key=None):
+    """The multi-level model's train step at toy widths, through the
+    builder the training loop uses, with its state and one batch."""
+    import optax
+
+    from raft_meets_dicl_tpu import models, parallel
+
+    spec = models.load({
+        "name": "toy ml", "id": "toy/ml",
+        "model": {"type": "raft+dicl/ml",
+                  "parameters": {"corr-radius": ML_RADIUS,
+                                 "corr-channels": ML_CHANNELS,
+                                 "share-dicl": share},
+                  "arguments": {"iterations": ML_ITERATIONS}},
+        "loss": {"type": "raft/sequence"},
+        "input": {"clip": [0, 1], "range": [-1, 1]}})
+    model = spec.model
+    model.frozen_batchnorm = True
+    b, h, w = ML_SHAPE
+    variables = model.init(jax.random.PRNGKey(0), jnp.zeros((1, h, w, 3)),
+                           jnp.zeros((1, h, w, 3)), iterations=1)
+    tx = optax.adam(1e-3)
+    state = parallel.TrainState.create(variables, tx)
+    step = parallel.make_train_step(model, spec.loss, tx, donate=False,
+                                    key=key)
+    rs = np.random.RandomState(0)
+    batch = (jnp.asarray(rs.rand(b, h, w, 3), jnp.float32),
+             jnp.asarray(rs.rand(b, h, w, 3), jnp.float32),
+             jnp.asarray(rs.randn(b, h, w, 2), jnp.float32),
+             jnp.ones((b, h, w), bool))
+    # an iteration feeds the nets frame one's stack and one window a level
+    # (float32 here: the toy model states no bf16 policy)
+    b, h8, w8 = b, h // 8, w // 8
+    volume = 4 * ML_LEVELS * b * h8 * w8 * ML_CHANNELS * (
+        1 + (2 * ML_RADIUS + 1) ** 2)
+    return step, state, batch, ML_ITERATIONS * volume
+
+
+ML_NOTES = ("sw_fused_calls", "sw_fallback_calls", "matching_volume_bytes",
+            "matching_levels_batched")
+
+
+@pytest.mark.parametrize("share, want", [
+    # off the TPU the shared net takes the fast path (its sampler calls the
+    # XLA reference), the per-level nets the loop and the plain sampler
+    (True, {"sw_fallback_calls": ML_LEVELS * ML_ITERATIONS,
+            "matching_levels_batched": ML_LEVELS}),
+    (False, {"matching_levels_batched": 1}),
+])
+def test_ml_train_step_notes_stand_for_every_iteration_and_survive_a_load(
+        aot_store, sink, share, want):
+    key = programs.ProgramKey("train_step", f"toy-ml-share{share}")
+    step, state, batch, volume = _ml_train_step(share, key)
+    want = {n: want.get(n) for n in ML_NOTES} | {
+        "matching_volume_bytes": volume}
+    _, cold = step(state, *batch)
+    sink.step_event(0)
+    compiles = [e for e in sink.events if e["kind"] == "compile"
+                and e["label"] == "train_step"]
+    assert len(compiles) == 1
+    assert {n: compiles[0].get(n) for n in ML_NOTES} == want
+    counters = [e for e in sink.events if e["kind"] == "step"][-1]["counters"]
+    assert counters["matching_volume_bytes"] == volume
+    assert counters["matching_levels_batched"] == want[
+        "matching_levels_batched"]
+
+    # "second boot": the executable and its notes come from the store
+    programs.reset()
+    del sink.events[:]
+    step2, state, batch, _ = _ml_train_step(share, key)
+    _, warm = step2(state, *batch)
+    sink.step_event(1)
+    assert step2.aot_hits == 1 and step2.compiles == 0
+    hits = [e for e in sink.events if e["kind"] == "aot"
+            and e["event"] == "hit"]
+    assert len(hits) == 1 and {n: hits[0].get(n) for n in ML_NOTES} == want
+    counters = [e for e in sink.events if e["kind"] == "step"][-1]["counters"]
+    assert counters["matching_levels_batched"] == want[
+        "matching_levels_batched"]
+    assert float(cold["loss"]) == float(warm["loss"])
+
+
+def test_ml_train_step_traced_for_the_tpu_takes_the_kernel_on_every_level(
+        monkeypatch):
+    """What the chip's program notes, from its trace alone: the dispatch
+    asks ``jax.default_backend`` while it traces, and nothing compiles."""
+    step, state, batch, volume = _ml_train_step(share=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with telemetry.jit_label(step.label, step):
+        jax.eval_shape(step.__wrapped__, state, *batch)
+    assert step.trace_counts() == {
+        "sw_fused_calls": ML_LEVELS * ML_ITERATIONS,
+        "matching_levels_batched": ML_LEVELS,
+        "matching_volume_bytes": volume}
+
+
+def test_a_model_that_counts_its_notes_revisions_has_them_in_its_keys():
+    from raft_meets_dicl_tpu import models
+    from raft_meets_dicl_tpu.models.impls.raft_dicl_ml import RaftPlusDiclMl
+
+    assert RaftPlusDiclMl.notes_revision == 1
+    ml = RaftPlusDiclMl()
+    key = programs.inference_key("eval_step", ml, {}, model_id="raft+dicl/ml")
+    assert ("notes", "1") in key.flags
+    # every other model's keys are byte for byte what they were
+    raft = models.load({"name": "r", "id": "r", "model": {
+        "type": "raft/baseline", "parameters": {}}, "loss": {
+        "type": "raft/sequence"}, "input": {}}).model
+    assert programs.notes_flag(raft) == {}
+    key = programs.inference_key("eval_step", raft, {}, model_id="r")
+    assert not [f for f in key.flags if f[0] == "notes"]
