@@ -29,10 +29,27 @@ class ConvBlock(nn.Module):
 
     Input may also be a pair ``(shared, per_item)`` with shared (B, H, W,
     C1) and per_item (B·N, H, W, C2): the conv then splits along its input
-    channels — conv(concat) = conv(shared) broadcast over N + conv(per_item)
+    channels — conv(concat) = conv(shared) repeated over N + conv(per_item)
     by linearity — computing the shared half once instead of N times.
     Parameters are identical to the concatenated form (kernel channels
     ordered shared-first).
+
+    How the shared half joins the other is the TPU compiler's business as
+    much as ours: it runs these convs with the item batch B·N as the minor
+    (lane) dimension (32 or 96 channels do not fill 128 lanes, 486 windows
+    nearly fill 512). Written as ``reshape`` + broadcast-add, the shared
+    half is repeated along a *factor* of the lane dimension, which no
+    fusion produces: the compiler writes it out at the activation's full
+    size, transposes that to item-minor, and transposes the activation's
+    gradient back to sum it over N (three copies of the activation a
+    call, more device time than the halved conv). So the repeat is a
+    contraction over B with a one-hot selection matrix: the compiler
+    lowers it to a convolution that writes item-minor directly and takes
+    the per-item half as its fused addend, and its transpose, the sum
+    over N, reads the item-minor gradient as it lies. A row of the
+    selection holds one 1 and the MXU accumulates in float32, so the
+    selected value is the shared half to the bit
+    (tests/test_matching_compile.py holds the compiler to this).
     """
 
     c_out: int
@@ -65,9 +82,14 @@ class ConvBlock(nn.Module):
 
             ys = conv(shared, kernel[:, :, :c1])       # (B, h', w', c_out)
             yp = conv(per_item, kernel[:, :, c1:])     # (B·N, h', w', c_out)
-            n = yp.shape[0] // ys.shape[0]
-            x = (yp.reshape(ys.shape[0], n, *yp.shape[1:])
-                 + ys[:, None]).reshape(yp.shape)
+            b = ys.shape[0]
+            sel = jnp.repeat(jnp.eye(b, dtype=ys.dtype), yp.shape[0] // b,
+                             axis=0)                   # (B·N, B), one 1 a row
+            # float32 operands would cross the MXU as one bfloat16 pass
+            exact = (jax.lax.Precision.HIGHEST if ys.dtype == jnp.float32
+                     else None)
+            x = yp + jnp.einsum("nb,bhwc->nhwc", sel, ys, precision=exact,
+                                preferred_element_type=ys.dtype)
         else:
             # explicit torch-convention padding (flax 'SAME' shifts strided
             # convs by one pixel on even inputs)
@@ -167,10 +189,11 @@ class MatchingNet(nn.Module):
     Alternatively input may be the pair ``(f1, window)`` with f1
     (B, H, W, C) and window (B, du, dv, H, W, C) *unstacked*: the first
     conv then splits along its input channels — the f1 half is computed
-    once and broadcast over displacements instead of convolving the same
-    f1 values du·dv times (half the first conv's FLOPs, and the
-    (B, du, dv, H, W, C) f1 broadcast never materializes). Parameters are
-    identical to the stacked form.
+    once and repeated over displacements instead of convolving the same
+    f1 values du·dv times (half the first conv's FLOPs; ``ConvBlock``
+    says how the repeat is written so that the TPU compiler adds no array
+    of the activation's size for it). Parameters are identical to the
+    stacked form, which stays as the tests' reference.
     """
 
     norm_type: str = "batch"

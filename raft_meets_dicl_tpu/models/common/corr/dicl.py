@@ -45,10 +45,11 @@ class CorrelationModule(nn.Module):
         with jax.named_scope("matching/sampler"):
             window = sample_window_fast(f2, coords, self.radius)
         # unstacked pair: MatchingNet's first conv computes the f1 half
-        # once and broadcasts it over the (2r+1)² displacements — the
-        # (B, du, dv, H, W, 2C) stacked volume's f1 copies never exist
-        # (channel order f1-first matches ``stack_pair``, so parameters
-        # and checkpoints are unchanged)
+        # once and selects it for each of the (2r+1)² displacements by a
+        # contraction over the batch, so that neither the stacked
+        # (B, du, dv, H, W, 2C) volume nor a repeated f1 half is written
+        # out (``ConvBlock``; channel order f1-first matches
+        # ``stack_pair``, so parameters and checkpoints are unchanged)
         if self.dtype is not None:
             f1 = f1.astype(self.dtype)
             window = window.astype(self.dtype)

@@ -100,8 +100,9 @@ class MlCorrelationModule(nn.Module):
     (reference raft_dicl_ml.py:236-345).
 
     Matching runs through the shared fast path by default: the fused
-    window sampler, the unstacked ``(f1, window)`` MatchingNet form (the
-    stacked (B, du, dv, H, W, 2C) volume never materializes), matching in
+    window sampler, the unstacked ``(f1, window)`` MatchingNet form (no
+    stacked (B, du, dv, H, W, 2C) volume; the f1 half of the first layer
+    joins the window's by a contraction, see ``ConvBlock``), matching in
     ``dtype`` when set, and ONE batched MatchingNet evaluation per GRU
     iteration instead of a python loop of ``levels`` hourglass calls —
     all levels share the 1/8 output resolution and channel count, so they

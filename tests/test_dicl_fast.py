@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from raft_meets_dicl_tpu.models.common.blocks.dicl import ConvBlock, MatchingNet
 from raft_meets_dicl_tpu.models.common.corr.common import (
     sample_window,
     sample_window_fast,
@@ -282,21 +283,66 @@ def test_ml_live_bn_falls_back_to_sequential_loop():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6)
 
 
-def test_ml_gradients_match_loop():
+def _ml_stacked(m, v, fmap1, fmap2, coords):
+    """The module's costs before DAP by the reference form: every level's
+    MatchingNet on the stacked (B, du, dv, H, W, 2C) volume."""
+    net = MatchingNet(norm_type=m.norm_type, dtype=m.dtype)
+    out = []
+    for i, (f1, f2) in enumerate(zip(fmap1, fmap2)):
+        window = sample_window(f2, coords / 2 ** i, m.radius)
+        if m.dtype is not None:
+            f1, window = f1.astype(m.dtype), window.astype(m.dtype)
+        name = "MatchingNet_0" if m.share else f"MatchingNet_{i}"
+        vs = {col: tree[name] for col, tree in v.items() if name in tree}
+        cost = net.apply(vs, stack_pair(f1, window), True, True)
+        out.append(cost.reshape(*cost.shape[:3], -1))
+    return jnp.concatenate(out, axis=-1)
+
+
+def _assert_trees_close(got, want, tol):
+    """Every leaf within ``tol`` of the reference leaf's largest entry (or
+    of 1, for a leaf that is small throughout)."""
+    got, want = jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)
+    assert len(got) == len(want)
+    for a, b in zip(got, want):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        np.testing.assert_allclose(a, b, rtol=0,
+                                   atol=tol * max(1.0, np.abs(b).max()))
+
+
+@pytest.mark.parametrize("dtype", [None, jnp.bfloat16])
+@pytest.mark.parametrize("share", [False, True])
+def test_ml_gradients_match_loop(share, dtype):
+    """Values and gradients (every parameter, both feature pyramids) of
+    the level-batched pair form, the per-level pair form and the stacked
+    reference form agree."""
     fmap1, fmap2, coords = _ml_inputs(seed=2)
-    m = MlCorrelationModule(feature_dim=6, levels=3, radius=1, share=False)
+    m = MlCorrelationModule(feature_dim=6, levels=3, radius=1, share=share,
+                            dtype=dtype)
     v = m.init(RNG, fmap1, fmap2, coords)
+    g = jnp.asarray(np.random.RandomState(8).randn(2, 8, 12, 27), jnp.float32)
 
-    def loss(params, fast):
-        out = m.apply({**v, "params": params}, fmap1, fmap2, coords,
-                      train=True, frozen_bn=True, fast=fast)
-        return jnp.abs(out).mean()
+    def loss(params, fmap1, fmap2, form):
+        vs = {**v, "params": params}
+        if form == "stacked":
+            out = _ml_stacked(m, vs, fmap1, fmap2, coords)
+        else:
+            out = m.apply(vs, fmap1, fmap2, coords, dap=False, train=True,
+                          frozen_bn=True, fast=form == "fast")
+        return (out * g).mean(), out
 
-    ga = jax.grad(loss)(v["params"], True)
-    gb = jax.grad(loss)(v["params"], False)
-    for a, b in zip(jax.tree_util.tree_leaves(ga),
-                    jax.tree_util.tree_leaves(gb)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
+    grad = jax.value_and_grad(loss, (0, 1, 2), has_aux=True)
+    fast = grad(v["params"], fmap1, fmap2, "fast")
+    loop = grad(v["params"], fmap1, fmap2, "loop")
+    stacked = grad(v["params"], fmap1, fmap2, "stacked")
+
+    vtol, gtol = (1e-5, 1e-4) if dtype is None else (5e-2, 5e-2)
+    for got, want in ((fast, loop), (fast, stacked), (loop, stacked)):
+        _assert_trees_close(got[0], want[0], vtol)
+        _assert_trees_close(got[1], want[1], gtol)
+    # the stacked form has no gradient the pair forms lack
+    assert all(np.abs(np.asarray(a, np.float32)).max() > 0
+               for a in jax.tree_util.tree_leaves(stacked[1][1:]))
 
 
 # -- checkpoint param-path stability -----------------------------------------
@@ -342,6 +388,65 @@ def test_ml_checkpoint_param_paths_stable(share):
 
 
 # -- unstacked matching forms (parity vs stack_pair reference) ---------------
+
+
+def _pair_inputs(dtype, b=2, h=6, w=10, c=5, r=2, seed=9):
+    rs = np.random.RandomState(seed)
+    k = 2 * r + 1
+    f1 = jnp.asarray(rs.randn(b, h, w, c), dtype)
+    window = jnp.asarray(rs.randn(b, k, k, h, w, c), dtype)
+    return f1, window
+
+
+@pytest.mark.parametrize("train", [False, True], ids=["eval", "frozen"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_matching_net_pair_matches_stacked(dtype, train):
+    """``MatchingNet((f1, window))`` against ``MatchingNet(stack_pair(f1,
+    window))``: the cost and its gradients with respect to f1, the window
+    and every parameter."""
+    f1, window = _pair_inputs(dtype)
+    m = MatchingNet(dtype=None if dtype == jnp.float32 else dtype)
+    v = m.init(RNG, stack_pair(f1, window))
+    g = jnp.asarray(np.random.RandomState(10).randn(2, 6, 10, 5, 5),
+                    jnp.float32)
+
+    def loss(params, f1, window, pair):
+        mvol = (f1, window) if pair else stack_pair(f1, window)
+        cost = m.apply({**v, "params": params}, mvol, train, train)
+        assert cost.dtype == jnp.float32
+        return (cost * g).mean(), cost
+
+    grad = jax.value_and_grad(loss, (0, 1, 2), has_aux=True)
+    pair = grad(v["params"], f1, window, True)
+    stacked = grad(v["params"], f1, window, False)
+    vtol, gtol = (1e-5, 1e-4) if dtype == jnp.float32 else (5e-2, 5e-2)
+    _assert_trees_close(pair[0], stacked[0], vtol)
+    _assert_trees_close(pair[1], stacked[1], gtol)
+    assert all(a.dtype == b.dtype for a, b in zip(
+        jax.tree_util.tree_leaves(pair[1]),
+        jax.tree_util.tree_leaves(stacked[1])))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_pair_form_selects_the_shared_half_bit_for_bit(dtype):
+    """The shared half reaches every one of its items unrounded: with a
+    per-item half of zeros the pair form is the shared half's own block,
+    to the bit."""
+    f1, _ = _pair_inputs(dtype)
+    b, n = f1.shape[0], 25
+    items = jnp.zeros((b * n, *f1.shape[1:3], 3), dtype)
+    block = ConvBlock(8, dtype=None if dtype == jnp.float32 else dtype)
+    v = block.init(RNG, (f1, items))
+
+    alone = {"params": {**v["params"], "Conv_0": {
+        "kernel": v["params"]["Conv_0"]["kernel"][:, :, :f1.shape[-1]]}},
+        "batch_stats": v["batch_stats"]}
+    want = np.asarray(block.apply(alone, f1).astype(jnp.float32))
+    assert (want > 0).any()
+    got = np.asarray(block.apply(v, (f1, items)).astype(jnp.float32))
+    np.testing.assert_array_equal(
+        got.reshape(b, n, *want.shape[1:]),
+        np.broadcast_to(want[:, None], (b, n, *want.shape[1:])))
 
 
 def test_matching_net_1x1_unstacked_matches_stacked():
