@@ -17,11 +17,12 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ... import telemetry
 from ...ops import quant as quant_ops
 from ...ops.corr import correlation_volume, lookup_pyramid_levels
 from ...ops.pallas import windowed_corr_pyramid
 from ...ops.pool import avg_pool2d
-from ..common import encoders
+from ..common.encoders.raft import FeatureEncoderS3
 from ..common.grid import coordinate_grid
 from ..config import register_model
 from ..model import Model, ModelAdapter
@@ -68,6 +69,25 @@ def volume_level_split(coarse_shape, corr_levels, itemsize, budget_gib=None):
     return n_windowed
 
 
+def _keep_convs_and_stats(prim, *_, **__):
+    """Remat policy of the two encoders: of an encoder's forward pass the
+    backward keeps the convolutions' outputs (compact, in the compute
+    dtype) and the norms' statistics (``reduce_sum``: a few numbers a
+    channel), and recomputes the float32 chains between them.
+
+    Left to itself autodiff keeps every norm's full-size float32
+    intermediates, for all three encoder passes and across the whole
+    recurrence: at the resolutions this model exists for they, not the
+    scan, decide the step's peak memory, and reading them back costs
+    more than computing them again from the convolution's output. At b1
+    1088x1920 under the bf16 policy (PERF.md section 6, PR 35, one v5e):
+    15.28 GiB and 996 ms a step with nothing recomputed, 9.40 GiB and
+    1078 ms with the encoders recomputed whole, 8.72 GiB and 827 ms with
+    this policy (the encoders' backward pass 145 ms where it was 333).
+    """
+    return prim.name in ("conv_general_dilated", "reduce_sum")
+
+
 class _FsStep(nn.Module):
     """One GRU iteration — nn.scan body; carry is (hidden, flow).
 
@@ -90,18 +110,13 @@ class _FsStep(nn.Module):
     n_windowed: int = 0
     dtype: Any = None
 
-    @nn.compact
-    def __call__(self, carry, fmap1, pyramid, x, coords0):
-        h, flow = carry
-        flow = jax.lax.stop_gradient(flow)
-        coords1 = coords0 + flow
-
+    def _lookup(self, fmap1, pyramid, coords1):
         n_win = self.n_windowed
         if n_win == 0:
             # small-enough shapes: ``pyramid`` is the materialized volume
             # pyramid, amortized across iterations — same math (pooling
-            # commutes with the dot product), ~4x the throughput of the
-            # per-step windowed computation at training crops
+            # commutes with the dot product), and cheaper than the
+            # per-step windowed computation wherever it fits
             corr = lookup_pyramid_levels(pyramid, coords1,
                                          self.corr_radius,
                                          mask_costs=self.mask_costs)
@@ -131,11 +146,21 @@ class _FsStep(nn.Module):
                 pyramid[n_win:], coords1, self.corr_radius,
                 mask_costs=self.mask_costs, first_level=n_win,
             )
+        return corr
+
+    @nn.compact
+    def __call__(self, carry, fmap1, pyramid, x, coords0):
+        h, flow = carry
+        flow = jax.lax.stop_gradient(flow)
+        coords1 = coords0 + flow
+
+        with jax.named_scope("lookup"):
+            corr = self._lookup(fmap1, pyramid, coords1)
 
         # named so the remat policy saves the correlation output: without
         # it the windowed Pallas kernel's forward runs a second time in
-        # the backward pass (profiled ~90 ms/step at 1080p), and the
-        # volume-lookup einsums recompute likewise
+        # the backward pass, and the volume-lookup einsums recompute
+        # likewise
         from jax.ad_checkpoint import checkpoint_name
 
         if isinstance(corr, list):
@@ -143,8 +168,9 @@ class _FsStep(nn.Module):
         else:
             corr = checkpoint_name(corr, "corr_features")
 
-        h, d = BasicUpdateBlock(self.recurrent_channels, dtype=self.dtype)(
-            h, x, corr, flow)
+        with jax.named_scope("update"):
+            h, d = BasicUpdateBlock(self.recurrent_channels,
+                                    dtype=self.dtype)(h, x, corr, flow)
 
         coords1 = coords1 + d
         flow = coords1 - coords0
@@ -175,16 +201,23 @@ class RaftFsModule(nn.Module):
         cdim = self.context_channels
         dt = jnp.bfloat16 if self.mixed_precision else None
 
-        fnet = encoders.make_encoder_s3(
-            "raft", output_dim=self.corr_channels,
-            norm_type=self.encoder_norm, dropout=self.dropout, dtype=dt,
+        # both encoders are rematerialised, keeping their convolutions'
+        # outputs: see _keep_convs_and_stats. Module names and parameter
+        # paths are the plain encoders' (checkpoints load unchanged)
+        encoder = nn.remat(FeatureEncoderS3, static_argnums=(2, 3),
+                           policy=_keep_convs_and_stats)
+        fnet = encoder(
+            output_dim=self.corr_channels, norm_type=self.encoder_norm,
+            dropout=self.dropout, dtype=dt, name="FeatureEncoderS3_0",
         )
-        cnet = encoders.make_encoder_s3(
-            "raft", output_dim=hdim + cdim,
-            norm_type=self.context_norm, dropout=self.dropout, dtype=dt,
+        cnet = encoder(
+            output_dim=hdim + cdim, norm_type=self.context_norm,
+            dropout=self.dropout, dtype=dt, name="FeatureEncoderS3_1",
         )
 
-        fmap1, fmap2 = fnet((img1, img2), train, frozen_bn)
+        with jax.named_scope("encoders"):
+            fmap1, fmap2 = fnet((img1, img2), train, frozen_bn)
+            ctx = cnet(img1, train, frozen_bn)
         if dt is None:
             fmap1 = fmap1.astype(jnp.float32)
             fmap2 = fmap2.astype(jnp.float32)
@@ -195,46 +228,51 @@ class RaftFsModule(nn.Module):
         # strategy dispatch: the windowed computation exists so the
         # O(H²W²) volume never has to — but where a level's volume DOES
         # fit, materializing it once and looking it up per iteration is
-        # ~4x faster (the windowed kernel is gather-bound). Identical
-        # math either way (pooling/bilinear commute with the dot
-        # product). The decision is PER LEVEL, greedy from the coarsest:
-        # each level's volume is 4x smaller than the previous, so at
-        # 1080p the coarse suffix (levels 1-3, ~1.2 GB) fits while
-        # level 0 (3.7 GB) cannot — moving 3 of 4 levels off the
-        # serialized kernel. The estimate charges 2x for the backward's
-        # volume-gradient accumulation and is per chip (the global-batch
-        # shapes seen at trace time are divided by the SPMD data-parallel
-        # degree). RMD_FS_VOLUME_GIB tunes the budget (0 forces the
-        # windowed path everywhere).
+        # faster (the windowed kernel walks its positions one by one).
+        # Identical math either way (pooling/bilinear commute with the
+        # dot product). The decision is PER LEVEL, greedy from the
+        # coarsest: each level's volume is 4x smaller than the previous,
+        # so at 1088x1920 under the bf16 policy the coarse suffix
+        # (levels 1-3, 0.70 GB) fits while level 0 (2.13 GB) cannot —
+        # moving 3 of 4 levels off the serialized kernel. The estimate
+        # charges 2x for the backward's volume-gradient accumulation and
+        # is per chip (the global-batch shapes seen at trace time are
+        # divided by the SPMD data-parallel degree). RMD_FS_VOLUME_GIB
+        # tunes the budget (0 forces the windowed path everywhere).
         b0, hc0, wc0, _ = fmap1.shape
         itemsize = 2 if dt is not None else 4
         n_windowed = volume_level_split(
             (b0, hc0, wc0), self.corr_levels, itemsize)
+        telemetry.note_trace("wcp_levels_windowed", n_windowed, scale=False)
 
-        # avg-pooled second-frame feature pyramid (raft_fs.py:26-31);
-        # the coarse suffix becomes materialized volumes against the
-        # same pooled maps (so both dispatch paths correlate against
-        # bit-identical f2 levels)
-        f2_pyramid = [fmap2]
-        for _ in range(1, self.corr_levels):
-            f2_pyramid.append(avg_pool2d(f2_pyramid[-1], 2))
-        # quantized matching tier (ops.quant): the materialized coarse
-        # suffix is stored at the quantized width and dequantized
-        # in-register by the lookup einsums. The windowed prefix never
-        # materializes a volume, so there is nothing to quantize there —
-        # both modes reduce to storage quantization here (the int8
-        # feature-dot construction is a RaftModule path).
-        qmode = quant_ops.normalize_mode(quant)
-        volumes = [
-            correlation_volume(fmap1, f2, dtype=dt, normalize=False)
-            for f2 in f2_pyramid[n_windowed:]
-        ]
-        if qmode is not None:
-            volumes = quant_ops.quantize_pyramid(volumes, qmode,
-                                                 clip=quant_clip)
+        with jax.named_scope("pyramid"):
+            # avg-pooled second-frame feature pyramid (raft_fs.py:26-31);
+            # the coarse suffix becomes materialized volumes against the
+            # same pooled maps (so both dispatch paths correlate against
+            # bit-identical f2 levels)
+            f2_pyramid = [fmap2]
+            for _ in range(1, self.corr_levels):
+                f2_pyramid.append(avg_pool2d(f2_pyramid[-1], 2))
+            # quantized matching tier (ops.quant): the materialized coarse
+            # suffix is stored at the quantized width and dequantized
+            # in-register by the lookup einsums. The windowed prefix never
+            # materializes a volume, so there is nothing to quantize
+            # there — both modes reduce to storage quantization here (the
+            # int8 feature-dot construction is a RaftModule path).
+            qmode = quant_ops.normalize_mode(quant)
+            volumes = [
+                correlation_volume(fmap1, f2, dtype=dt, normalize=False)
+                for f2 in f2_pyramid[n_windowed:]
+            ]
+            if qmode is not None:
+                volumes = quant_ops.quantize_pyramid(volumes, qmode,
+                                                     clip=quant_clip)
+        telemetry.note_trace(
+            "corr_volume_bytes",
+            sum(v.size * v.dtype.itemsize
+                for v in jax.tree_util.tree_leaves(volumes)))
         pyramid = f2_pyramid[:n_windowed] + volumes
 
-        ctx = cnet(img1, train, frozen_bn)
         h = jnp.tanh(ctx[..., :hdim])
         x = nn.relu(ctx[..., hdim:])
         if hidden_init is not None:
@@ -273,8 +311,9 @@ class RaftFsModule(nn.Module):
             dtype=dt,
         )
 
-        (h, flow), (flows, hiddens) = step((h, flow), fmap1,
-                                           tuple(pyramid), x, coords0)
+        with telemetry.trace_site("iteration", iterations):
+            (h, flow), (flows, hiddens) = step((h, flow), fmap1,
+                                               tuple(pyramid), x, coords0)
 
         # convex 8x upsampling hoisted out of the remat'd scan, exactly
         # like raft/baseline (raft.upsample_flows). The explicit module
@@ -282,9 +321,10 @@ class RaftFsModule(nn.Module):
         # before the hoist (params under the scan-body subtree) are
         # migrated at load time by
         # strategy.checkpoint._remap_legacy_model_state.
-        out = upsample_flows(flows, hiddens, (h, flow),
-                             (img1.shape[1], img1.shape[2]), dtype=dt,
-                             upnet=upnet, final_only=final_only)
+        with jax.named_scope("up8"):
+            out = upsample_flows(flows, hiddens, (h, flow),
+                                 (img1.shape[1], img1.shape[2]), dtype=dt,
+                                 upnet=upnet, final_only=final_only)
 
         if return_state:
             final = flows[-1]
@@ -307,6 +347,11 @@ class RaftFs(Model):
     """``raft/fs`` (reference raft_fs.py:173-268)."""
 
     type = "raft/fs"
+    # the program's trace-time notes (wcp_fused_calls, wcp_fallback_calls,
+    # wcp_levels_windowed, corr_volume_bytes) are stored with its
+    # executable: a revision in the program keys keeps a run from loading
+    # an executable stored before the notes existed (ROADMAP D12)
+    notes_revision = 1
 
     @classmethod
     def from_config(cls, cfg):

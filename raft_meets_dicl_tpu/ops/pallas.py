@@ -868,10 +868,17 @@ def _wcp_fits_vmem(f1, f2_levels, radius):
     return total <= 64 * 1024 * 1024
 
 
+def _wcp_takes_kernel(f1, f2_levels, radius):
+    """Which form a windowed-correlation call traces: the Mosaic kernels
+    on the TPU where the shapes fit VMEM, the XLA composition everywhere
+    else."""
+    return jax.default_backend() == "tpu" and _wcp_fits_vmem(f1, f2_levels,
+                                                            radius)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _wcp(f1, f2_levels, coords, radius):
-    if jax.default_backend() == "tpu" and _wcp_fits_vmem(f1, f2_levels,
-                                                        radius):
+    if _wcp_takes_kernel(f1, f2_levels, radius):
         return _wcp_fwd_tpu(f1, f2_levels, coords, radius)
     return _wcp_reference(f1, f2_levels, coords, radius)
 
@@ -882,8 +889,7 @@ def _wcp_vjp_fwd(f1, f2_levels, coords, radius):
 
 def _wcp_vjp_bwd(radius, res, dout):
     f1, f2_levels, coords = res
-    if jax.default_backend() == "tpu" and _wcp_fits_vmem(f1, f2_levels,
-                                                        radius):
+    if _wcp_takes_kernel(f1, f2_levels, radius):
         df1, df2 = _wcp_bwd_tpu(f1, f2_levels, coords, dout, radius)
     else:
         def f(f1_, f2_):
@@ -912,14 +918,29 @@ def windowed_corr_pyramid(f1, f2_levels, coords, radius=4, mask_costs=(),
     ``lookup_pyramid(correlation_pyramid(all_pairs_correlation(f1, f2)))``
     without ever building the volume. ``mask_costs`` zeroes whole levels
     by pyramid level id (l + 3), like the reference (raft.py:86).
+
+    Which form the call traced is counted (``wcp_fused_calls`` /
+    ``wcp_fallback_calls``, ``telemetry.note_trace``), as the window
+    sampler's is: the fallback is silent in the arithmetic and not in the
+    time. The scope gives the three kernels' device operations (forward,
+    backward to ``f1``, backward to each map) a name of their own,
+    ``wcp.N``, in the compiled program and in a device trace.
     """
+    from .. import telemetry
+
     c = f1.shape[-1]
     k = 2 * radius + 1
     if normalize:
         f1 = (f1 / jnp.sqrt(jnp.asarray(c, jnp.float32))).astype(f1.dtype)
 
-    out = _per_shard(lambda a, b, c: _wcp(a, b, c, radius))(
-        f1, tuple(f2_levels), coords)
+    def correlate(a, b, c):
+        telemetry.note_trace(
+            "wcp_fused_calls" if _wcp_takes_kernel(a, b, radius)
+            else "wcp_fallback_calls", 1)
+        return _wcp(a, b, c, radius)
+
+    with jax.named_scope("wcp"):
+        out = _per_shard(correlate)(f1, tuple(f2_levels), coords)
 
     if mask_costs:
         keep = jnp.concatenate([

@@ -13,7 +13,9 @@ the family's own ``_*_reference`` evaluated at highest matmul precision:
 - ``wcp`` — the windowed correlation pyramid of ``raft/fs`` at
   cfg/strategy/highres/raft-fs.hd1k-1080p.yaml: b1 1072x2560, C=256,
   r=4, band and per-position forms, with all 4 levels on the kernel and
-  with the prefix the volume/windowed dispatch leaves on it;
+  with the prefix the volume/windowed dispatch leaves on it; and the
+  benchmark cell's shape, b1 1088x1920 (136x240), where that prefix is
+  level 0 alone;
 - ``sw`` — the fused DICL window sampler at ``raft+dicl/ml``'s reference
   shape: b6 384x704, C=32, r=4, levels 48x88 down to 6x11.
 
@@ -231,6 +233,12 @@ def cases(families):
         for levels in sorted({4, n_win} - {0}):
             yield f"wcp/levels{levels}/band", case_wcp, (levels, True)
             yield f"wcp/levels{levels}/position", case_wcp, (levels, False)
+        # the cell fs-train-1080p: b1 1088x1920, level 0 alone (levels
+        # 1-3 are materialised volumes there)
+        n_win = volume_level_split((1, 136, 240), 4, 2)
+        for band, form in ((True, "band"), (False, "position")):
+            yield f"wcp/136x240/levels{n_win}/{form}", case_wcp, (
+                n_win, band, jnp.bfloat16, 136, 240)
     if "sw" in families:
         yield "sw/f32", case_sw, (jnp.float32,)
         yield "sw/bf16", case_sw, (jnp.bfloat16,)

@@ -74,6 +74,20 @@ for band in (True, False):
         lambda a, cc, d, *bb: K._wcp_bwd_tpu(a, bb, cc, d, 4, band=band),
         f1, coords, dout, *f2)
 
+# ... and the hybrid call of the cell fs-train-1080p: level 0 alone at
+# 136x240 (1088x1920), the coarser levels being materialised volumes
+h, w = 136, 240
+f1 = f2 = ((1, h, w, c), bf16)
+coords = ((1, h, w, 2), f32)
+dout = ((1, h, w, 81), f32)
+for band in (True, False):
+    compile_for_v5e(
+        lambda a, cc, b: K._wcp_fwd_tpu(a, (b,), cc, 4, band=band),
+        f1, coords, f2)
+    compile_for_v5e(
+        lambda a, cc, d, b: K._wcp_bwd_tpu(a, (b,), cc, d, 4, band=band),
+        f1, coords, dout, f2)
+
 # fused DICL window sampler, raft+dicl/ml: b6 384x704, C=32, 4 levels
 # (the maps coarser than the centres here; level 0 is ctf3's 48x88 below)
 b, h, w, c = 6, 48, 88, 32
