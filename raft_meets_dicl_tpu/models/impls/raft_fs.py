@@ -350,8 +350,10 @@ class RaftFs(Model):
     # the program's trace-time notes (wcp_fused_calls, wcp_fallback_calls,
     # wcp_levels_windowed, corr_volume_bytes) are stored with its
     # executable: a revision in the program keys keeps a run from loading
-    # an executable stored before the notes existed (ROADMAP D12)
-    notes_revision = 1
+    # an executable stored before the notes existed (ROADMAP D12). 2: the
+    # windowed-correlation kernels' block form (PR 36), for the same
+    # reason: the store is keyed by the configuration, not the program
+    notes_revision = 2
 
     @classmethod
     def from_config(cls, cfg):

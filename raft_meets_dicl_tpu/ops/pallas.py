@@ -248,36 +248,78 @@ _combine.defvjp(_combine_fwd, _combine_bwd)
 # Channel order of the output: (level, dx, dy) — identical to
 # ops.corr.lookup_pyramid and the reference CorrBlock (raft.py:57-92).
 
-# The slab's x-start is rounded down to a multiple of 8 (Mosaic requires
-# statically-provable sublane alignment for dynamic slices); the kernel
-# reads a widened 8-aligned slab and folds the residual shift s = x0 - x8
-# into a small per-position selection matrix built from iotas. _XW is the
-# widened slab width: ceil((k+1) + 7, 8) for r=4 → 24.
+# Two forms. The per-position kernels (``RMD_WCP_BAND=0``) walk a grid row
+# one position at a time; the block kernels below them, the default, work
+# on lane-wide blocks of positions.
+#
+# Per-position form: the slab's x-start is rounded down to a multiple of 8
+# (Mosaic requires statically-provable sublane alignment for dynamic
+# slices); the kernel reads a widened 8-aligned slab and folds the
+# residual shift s = x0 - x8 into a small per-position selection matrix
+# built from iotas. _XW is the widened slab width: ceil((k+1) + 7, 8) for
+# r=4 → 24.
 _XW = 24
 
 
-# Band-sharing chunk parameters: _PB consecutive positions share one
-# (k+9, _XBW, C) slab read + one MXU contraction when their windows
-# overlap enough (the flow-smooth case); otherwise the chunk falls back
-# to the per-position path. _XBW covers the (k+1)-lane window + ≤7-lane
-# alignment residual + ≤8 lanes of x-spread for radius ≤ 7.
-_XBW = 32
-_PB = 8
+# Block form. A block is _PBLK consecutive positions of one grid row. One
+# pass over a block reads ONE slab of the padded map, (k+8) rows by _XS
+# columns, and contracts it with the block's f1 rows on the MXU in the
+# features' own type: (_PBLK, C) x (C, (k+8)·_XS) -> f32, the slab's
+# columns on the 128 lanes and the block's positions on the sublanes.
+# Everything after the contraction runs on full (8, 128) registers, eight
+# positions at a time: a position's k+1 rows are picked out of the slab's
+# k+8 by a three-stage shifter on the bits of its row offset, the y-lerp
+# and the x-lerp (the x+1 neighbour is one lane rotate) follow, and a
+# lane gather by address puts tap (dx, dy) of the window that starts at
+# column sx on lane dx·k + dy. The costs leave flat on the lanes,
+# f32[b, n_i, n_j, L·k·k], and their cotangent enters the two backward
+# kernels in the same form, where the same chain runs transposed (the
+# gather the other way round, the shifter upwards) and the two
+# contractions take the spread cotangent as a bf16 pair (high part and
+# remainder) when the features are bf16.
+#
+# Why these numbers. _XS is the lane width: a slab's columns fill a
+# register and a position's window is a lane gather inside one register.
+# The slab's first column is a multiple of _XA (a bf16 sublane tile), so a
+# window may start up to _XA - 1 columns into it; _PBLK = 80 positions at
+# zero flow span 80 + k + 1 columns, which leaves 128 - 90 - 15 = 23
+# columns for the flow to differ inside a block, and 240 and 320
+# (136x240, 134x320) are whole blocks. The slab's k+8 rows serve window
+# rows that start up to _YSPREAD - 1 = 7 rows apart.
+#
+# A pass serves the positions whose windows lie inside its slab
+# (``_wcp_pass``); the slab is anchored on the topmost, then leftmost,
+# window still to do, so a pass serves at least one position, and a block
+# is passed over until every position is served: once where the flow is
+# smooth across the block (``wcp_shared_share`` counts those), twice where
+# an object's edge cuts it, and in the worst case once a position. The
+# result is exact for any centres whatever.
+_PBLK = 80
+_XS = 128
+_XA = 16
+_YSPREAD = 8
 
 
-def _wcp_pads(radius):
-    """(lo, hi_y, hi_x) zero-padding of the f2 maps so every clamped,
-    8-aligned window is a plain in-bounds slice: x-starts lie in
-    [0, lo + dim] after clamping centers to [-(r+1), dim+r], and the
-    widened slab extends _XW (per-position) / _XBW with k+9 rows
-    (band-shared) past the start."""
-    lo = 2 * radius + 1
-    return lo, 2 * radius + 10, _XBW
+def _round_up(n, m):
+    return -(-n // m) * m
 
 
-def _wcp_window(cx, cy, lvl, dim_h, dim_w, radius):
-    """Clamped window start indices (into the padded map), the 8-aligned
-    x-start + residual shift, and the bilinear fractions."""
+def _wcp_pads(radius, dim_w):
+    """(lo, hi_y, hi_x) zero-padding of an f2 map so every clamped window
+    is a plain in-bounds slice: window starts lie in [0, lo + dim] after
+    clamping centers to [-(r+1), dim+r]; the per-position slab extends
+    k+1 rows and _XW columns past its start, a block's slab k+8 rows and
+    _XS columns, its first column clamped so that it ends with the padded
+    map (whose width is therefore a multiple of _XA and at least _XS)."""
+    k = 2 * radius + 1
+    lo = k
+    wp = max(_XS, _round_up(lo + dim_w + _XW, _XA))
+    return lo, k + _YSPREAD, wp - lo - dim_w
+
+
+def _wcp_window_start(cx, cy, lvl, dim_h, dim_w, radius):
+    """Clamped window start indices (into the padded map) and the
+    bilinear fractions, for scalar or vector centers."""
     scale = float(2 ** lvl)
     r = radius
     cx = cx / scale
@@ -291,8 +333,15 @@ def _wcp_window(cx, cy, lvl, dim_h, dim_w, radius):
     lo = 2 * r + 1
     x0 = x0f.astype(jnp.int32) - r + lo
     y0 = y0f.astype(jnp.int32) - r + lo
+    return x0, y0, cx - x0f, cy - y0f
+
+
+def _wcp_window(cx, cy, lvl, dim_h, dim_w, radius):
+    """The per-position form of the window: the 8-aligned x-start, the
+    residual shift, the y-start and the bilinear fractions."""
+    x0, y0, fx, fy = _wcp_window_start(cx, cy, lvl, dim_h, dim_w, radius)
     x8 = pl.multiple_of((x0 // 8) * 8, 8)
-    return x8, x0 - x8, y0, cx - x0f, cy - y0f
+    return x8, x0 - x8, y0, fx, fy
 
 
 def _x_select(s, fx, k):
@@ -333,92 +382,6 @@ def _wcp_fwd_kernel(coords_ref, f1_ref, *f2_refs_and_out, radius, dims):
     jax.lax.fori_loop(0, n_j, body, 0)
 
 
-def _wcp_fwd_band_kernel(coords_ref, f1_ref, *f2_refs_and_out, radius,
-                        dims):
-    """Band-shared forward: chunks of _PB consecutive positions.
-
-    Shared path per chunk·level — the bandwidth fix for the per-position
-    kernel (PERF.md round 4: slab reads were 8x redundant for smooth
-    flow):
-      1. ONE (k+9, _XBW, C) slab read;
-      2. ONE MXU contraction against the chunk's stacked f1 rows
-         ((k+9)·_XBW, C) x (C, _PB);
-      3. bilinear windows resolved with arithmetic selection masks —
-         y as a pair-lerp plus pure row-selection (static dy loop), x as
-         the lerped lane-selection (static dx loop) — no dynamic lane
-         slicing, the constraint that killed the round-4 j-vectorization
-         attempts.
-    The per-position fallback (identical math to _wcp_fwd_kernel) runs
-    whenever the chunk's window spread exceeds the shared slab.
-    """
-    f2_refs = f2_refs_and_out[:-1]
-    out_ref = f2_refs_and_out[-1]
-    k = 2 * radius + 1
-    yb = k + 9
-    n_c = f1_ref.shape[2]
-
-    def chunk(ci, _):
-        f1c = f1_ref[0, 0, ci].astype(jnp.float32)          # (_PB, C)
-
-        for lvl, f2_ref in enumerate(f2_refs):
-            h2, w2 = dims[lvl]
-            xs, ys, fxs, fys, xb8, ymin, fits = _wcp_band_params(
-                coords_ref, ci, lvl, h2, w2, radius)
-
-            def shared(lvl=lvl, f2_ref=f2_ref, xs=xs, ys=ys, fxs=fxs,
-                       fys=fys, xb8=xb8, ymin=ymin):
-                slab = f2_ref[0, pl.ds(ymin, yb), pl.ds(xb8, _XBW), :]
-                s2 = slab.astype(jnp.float32).reshape(yb * _XBW, -1)
-                d = jax.lax.dot_general(
-                    s2, f1c, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)     # (yb*_XBW, _PB)
-                d3 = d.reshape(yb, _XBW, _PB)
-
-                fyv = jnp.stack(fys).reshape(1, 1, _PB)
-                t = (1.0 - fyv) * d3[0:yb - 1] + fyv * d3[1:yb]
-
-                syv = jnp.stack([y - ymin for y in ys]).reshape(1, 1, _PB)
-                iy = jax.lax.broadcasted_iota(jnp.int32, (yb - 1, 1, _PB), 0)
-                e = jnp.stack([
-                    jnp.sum(jnp.where(iy == syv + dy, t, 0.0), axis=0)
-                    for dy in range(k)
-                ])                                          # (k_dy, _XBW, _PB)
-
-                sxv = jnp.stack([x - xb8 for x in xs]).reshape(1, 1, _PB)
-                fxv = jnp.stack(fxs).reshape(1, 1, _PB)
-                ix = jax.lax.broadcasted_iota(jnp.int32, (1, _XBW, _PB), 1)
-                return jnp.stack([
-                    jnp.sum(((ix == sxv + dx) * (1.0 - fxv)
-                             + (ix == sxv + dx + 1) * fxv) * e, axis=1)
-                    for dx in range(k)
-                ])                                          # (k_dx, k_dy, _PB)
-
-            def fallback(lvl=lvl, f2_ref=f2_ref, xs=xs, ys=ys, fxs=fxs,
-                         fys=fys):
-                vs = []
-                for p in range(_PB):
-                    x8p = pl.multiple_of((xs[p] // 8) * 8, 8)
-                    sp = xs[p] - x8p
-                    slab = f2_ref[0, pl.ds(ys[p], k + 1),
-                                  pl.ds(x8p, _XW), :]
-                    dd = jnp.sum(
-                        slab.astype(jnp.float32)
-                        * f1c[p:p + 1, :][None, :, :], axis=-1)
-                    t = (1.0 - fys[p]) * dd[0:k, :] + fys[p] * dd[1:k + 1, :]
-                    m = _x_select(sp, fxs[p], k)
-                    v = jnp.sum(t[:, :, None] * m[None, :, :], axis=1)
-                    vs.append(v.T)                          # (k_dx, k_dy)
-                return jnp.stack(vs, axis=-1)               # (k, k, _PB)
-
-            v = jax.lax.cond(fits, shared, fallback)
-            for p in range(_PB):
-                out_ref[0, 0, ci * _PB + p,
-                        lvl * k:(lvl + 1) * k, :] = v[:, :, p]
-        return 0
-
-    jax.lax.fori_loop(0, n_c, chunk, 0)
-
-
 def _unlerp(dout_ref, j, lvl, s, fx, fy, radius):
     """Transpose of the window lerps: spread the (dy, dx) cost gradient of
     position j at level lvl onto the widened (k+1, _XW) slab."""
@@ -431,167 +394,300 @@ def _unlerp(dout_ref, j, lvl, s, fx, fy, radius):
             + fy * jnp.concatenate([zr, dt], axis=0))     # (k+1, _XW)
 
 
-def _wcp_band_params(coords_ref, ci, lvl, h2, w2, radius):
-    """Per-chunk window parameters + the shared-slab fit predicate."""
+# planes of a block pass's per-position parameters, each broadcast over
+# the lanes so the eight-position groups load them as plain registers
+_SX, _SY, _FX, _FY, _NOW = range(5)
+
+
+def _wcp_pass(x0, y0, todo, wp, radius):
+    """One pass over a block: where its slab starts and whom it serves.
+
+    ``x0``, ``y0``: the block's window starts, (_PBLK, 1) int32; ``todo``:
+    the positions no earlier pass served; ``wp``: the padded map's width.
+    The slab's first row is the topmost window's still to do, its first
+    column the _XA-aligned start of the leftmost window among those within
+    _YSPREAD rows of that (clamped so the slab ends with the map); served
+    are the windows that lie inside it, the anchor's always among them.
+    Pure ``jnp``: the kernels trace it and ``wcp_shared_share`` maps it.
+    """
     k = 2 * radius + 1
-    xs, ys, fxs, fys = [], [], [], []
-    for p in range(_PB):
-        cx = coords_ref[0, 0, ci * _PB + p, 0]
-        cy = coords_ref[0, 0, ci * _PB + p, 1]
-        x8, s, y0, fx, fy = _wcp_window(cx, cy, lvl, h2, w2, radius)
-        xs.append(x8 + s)
-        ys.append(y0)
-        fxs.append(fx)
-        fys.append(fy)
-    xmin = functools.reduce(jnp.minimum, xs)
-    xmax = functools.reduce(jnp.maximum, xs)
-    ymin = functools.reduce(jnp.minimum, ys)
-    ymax = functools.reduce(jnp.maximum, ys)
-    xb8 = pl.multiple_of((xmin // 8) * 8, 8)
-    fits = jnp.logical_and(xmax - xb8 <= _XBW - 1 - (k + 1),
-                           ymax - ymin <= 8)
-    return xs, ys, fxs, fys, xb8, ymin, fits
+    far = jnp.int32(1 << 30)
+    ytop = jnp.min(jnp.where(todo, y0, far))
+    near = todo & (y0 - ytop < _YSPREAD)
+    xmin = jnp.min(jnp.where(near, x0, far))
+    xb = jnp.minimum((xmin // _XA) * _XA, wp - _XS)
+    now = near & (x0 - xb <= _XS - (k + 1))
+    return ytop, xb, now
 
 
-def _wcp_band_dv(dout_ref, ci, lvl, radius):
-    """The chunk's (k_dx, k_dy, _PB) output-gradient stack."""
-    k = 2 * radius + 1
-    return jnp.stack([
-        dout_ref[0, 0, ci * _PB + p, lvl * k:(lvl + 1) * k, :]
-        for p in range(_PB)
-    ], axis=-1)
+def _wcp_block_passes(coords_ref, j0, lvl, dim, wp, radius, prm_ref, serve):
+    """Pass over the block at ``j0`` until every position is served:
+    ``serve(ytop, xb)`` runs once a pass with the pass's parameters in
+    ``prm_ref``."""
+    cx = coords_ref[0, 0, pl.ds(j0, _PBLK), 0:1]
+    cy = coords_ref[0, 0, pl.ds(j0, _PBLK), 1:2]
+    x0, y0, fx, fy = _wcp_window_start(cx, cy, lvl, dim[0], dim[1], radius)
 
+    def lanes(v):
+        return jnp.broadcast_to(v.astype(jnp.float32), (_PBLK, _XS))
 
-def _wcp_band_dD3(dv, xs, ys, fxs, fys, xb8, ymin, radius):
-    """Transpose of the band forward's selection/lerp chain: spread the
-    (k, k, _PB) cost gradients onto the shared (k+9, _XBW) slab grid."""
-    k = 2 * radius + 1
-    yb = k + 9
+    prm_ref[_FX] = lanes(fx)
+    prm_ref[_FY] = lanes(fy)
 
-    sxv = jnp.stack([x - xb8 for x in xs]).reshape(1, 1, _PB)
-    fxv = jnp.stack(fxs).reshape(1, 1, _PB)
-    ix = jax.lax.broadcasted_iota(jnp.int32, (1, _XBW, _PB), 1)
-    de = sum(
-        ((ix == sxv + dx) * (1.0 - fxv) + (ix == sxv + dx + 1) * fxv)
-        * dv[dx][:, None, :]
-        for dx in range(k)
-    )                                               # (k_dy, _XBW, _PB)
+    def one_pass(todo):
+        ytop, xb, now = _wcp_pass(x0, y0, todo > 0, wp, radius)
+        xb = pl.multiple_of(xb, _XA)
+        prm_ref[_SX] = lanes(x0 - xb)
+        prm_ref[_SY] = lanes(y0 - ytop)
+        prm_ref[_NOW] = lanes(now)
+        serve(ytop, xb)
+        return jnp.where(now, 0, todo)
 
-    syv = jnp.stack([y - ymin for y in ys]).reshape(1, 1, _PB)
-    iy = jax.lax.broadcasted_iota(jnp.int32, (yb - 1, 1, _PB), 0)
-    dt = sum(
-        jnp.where(iy == syv + dy, de[dy][None, :, :], 0.0)
-        for dy in range(k)
-    )                                               # (yb-1, _XBW, _PB)
+    # the first pass serves the whole block wherever the flow is smooth
+    # across it: the loop (a pipeline drain a turn) is for the others
+    todo = one_pass(jnp.ones((_PBLK, 1), jnp.int32))
 
-    fyv = jnp.stack(fys).reshape(1, 1, _PB)
-    zr = jnp.zeros((1, _XBW, _PB), jnp.float32)
-    return ((1.0 - fyv) * jnp.concatenate([dt, zr], axis=0)
-            + fyv * jnp.concatenate([zr, dt], axis=0))  # (yb, _XBW, _PB)
-
-
-def _wcp_bwd_df1_band_kernel(coords_ref, dout_ref, *f2_refs_and_out,
-                             radius, dims):
-    """Band-shared df1: per chunk·level ONE slab read and ONE MXU
-    contraction dD3^T(yb*_XBW, _PB) x slab(yb*_XBW, C) -> (_PB, C)."""
-    f2_refs = f2_refs_and_out[:-1]
-    df1_ref = f2_refs_and_out[-1]
-    k = 2 * radius + 1
-    yb = k + 9
-    n_c = df1_ref.shape[2]
-
-    def chunk(ci, _):
-        acc = jnp.zeros((_PB, f2_refs[0].shape[-1]), jnp.float32)
-        for lvl, f2_ref in enumerate(f2_refs):
-            h2, w2 = dims[lvl]
-            xs, ys, fxs, fys, xb8, ymin, fits = _wcp_band_params(
-                coords_ref, ci, lvl, h2, w2, radius)
-            dv = _wcp_band_dv(dout_ref, ci, lvl, radius)
-
-            def shared(f2_ref=f2_ref, xs=xs, ys=ys, fxs=fxs, fys=fys,
-                       xb8=xb8, ymin=ymin, dv=dv):
-                dd3 = _wcp_band_dD3(dv, xs, ys, fxs, fys, xb8, ymin,
-                                    radius)
-                slab = f2_ref[0, pl.ds(ymin, yb), pl.ds(xb8, _XBW), :]
-                s2 = slab.astype(jnp.float32).reshape(yb * _XBW, -1)
-                return jax.lax.dot_general(
-                    dd3.reshape(yb * _XBW, _PB), s2,
-                    (((0,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)     # (_PB, C)
-
-            def fallback(f2_ref=f2_ref, xs=xs, ys=ys, fxs=fxs, fys=fys,
-                         dv=dv, lvl=lvl):
-                outs = []
-                for p in range(_PB):
-                    x8p = pl.multiple_of((xs[p] // 8) * 8, 8)
-                    sp = xs[p] - x8p
-                    m = _x_select(sp, fxs[p], k)
-                    dvp = dv[:, :, p].T                     # (k_dy, k_dx)
-                    dt = jnp.sum(dvp[:, None, :] * m[None, :, :], axis=2)
-                    zr = jnp.zeros((1, _XW), jnp.float32)
-                    dd = ((1.0 - fys[p])
-                          * jnp.concatenate([dt, zr], axis=0)
-                          + fys[p] * jnp.concatenate([zr, dt], axis=0))
-                    slab = f2_ref[0, pl.ds(ys[p], k + 1),
-                                  pl.ds(x8p, _XW), :]
-                    part = jnp.sum(dd[:, :, None]
-                                   * slab.astype(jnp.float32), axis=(0, 1))
-                    outs.append(part)
-                return jnp.stack(outs)                      # (_PB, C)
-
-            acc = acc + jax.lax.cond(fits, shared, fallback)
-        df1_ref[0, 0, ci] = acc
-        return 0
-
-    jax.lax.fori_loop(0, n_c, chunk, 0)
-
-
-def _wcp_bwd_df2_band_kernel(coords_ref, f1_ref, dout_ref, df2_ref, *,
-                             radius, lvl, dims):
-    """Band-shared df2 for ONE level: per chunk ONE MXU outer product
-    dD3(yb*_XBW, _PB) x f1c(_PB, C) accumulated into the shared slab."""
-    k = 2 * radius + 1
-    yb = k + 9
-    n_c = f1_ref.shape[2]
-    h2, w2 = dims
-    i = pl.program_id(1)
-
-    @pl.when(i == 0)
+    @pl.when(jnp.max(todo) > 0)
     def _():
-        df2_ref[:] = jnp.zeros_like(df2_ref)
+        jax.lax.while_loop(lambda todo: jnp.max(todo) > 0, one_pass, todo)
 
-    def chunk(ci, _):
-        f1c = f1_ref[0, 0, ci].astype(jnp.float32)          # (_PB, C)
-        xs, ys, fxs, fys, xb8, ymin, fits = _wcp_band_params(
-            coords_ref, ci, lvl, h2, w2, radius)
-        dv = _wcp_band_dv(dout_ref, ci, 0, radius)
 
-        def shared():
-            dd3 = _wcp_band_dD3(dv, xs, ys, fxs, fys, xb8, ymin, radius)
-            ds2 = jax.lax.dot_general(
-                dd3.reshape(yb * _XBW, _PB), f1c,
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)         # (yb*_XBW, C)
-            df2_ref[0, pl.ds(ymin, yb), pl.ds(xb8, _XBW), :] += (
-                ds2.reshape(yb, _XBW, -1))
+def _wcp_taps(lvl, v, k):
+    """What lane register ``v`` of the flat costs holds of level ``lvl``:
+    (is a tap of the level, its dx, its dy), each (8, 128)."""
+    m = (jax.lax.broadcasted_iota(jnp.int32, (8, _XS), 1)
+         + (_XS * v - lvl * k * k))
+    # m // k by a float product: exact for these few hundred integers
+    dx = ((m.astype(jnp.float32) + 0.5) * (1.0 / k)).astype(jnp.int32)
+    return (m >= 0) & (m < k * k), dx, m - dx * k
 
-        def fallback():
-            for p in range(_PB):
-                x8p = pl.multiple_of((xs[p] // 8) * 8, 8)
-                sp = xs[p] - x8p
-                m = _x_select(sp, fxs[p], k)
-                dvp = dv[:, :, p].T                         # (k_dy, k_dx)
-                dt = jnp.sum(dvp[:, None, :] * m[None, :, :], axis=2)
-                zr = jnp.zeros((1, _XW), jnp.float32)
-                dd = ((1.0 - fys[p]) * jnp.concatenate([dt, zr], axis=0)
-                      + fys[p] * jnp.concatenate([zr, dt], axis=0))
-                df2_ref[0, pl.ds(ys[p], k + 1), pl.ds(x8p, _XW), :] += (
-                    dd[:, :, None] * f1c[p:p + 1, :][None, :, :])
 
-        jax.lax.cond(fits, shared, fallback)
+def _wcp_lane_regs(lvl, kk):
+    """The lane registers of the flat costs that level ``lvl`` touches."""
+    return range(lvl * kk // _XS, (lvl * kk + kk - 1) // _XS + 1)
+
+
+def _wcp_group_prm(prm_ref, r0):
+    rows = pl.ds(r0, 8)
+    return (prm_ref[_SX, rows, :].astype(jnp.int32),
+            prm_ref[_SY, rows, :].astype(jnp.int32),
+            prm_ref[_FX, rows, :], prm_ref[_FY, rows, :],
+            prm_ref[_NOW, rows, :] > 0.0)
+
+
+def _wcp_select(d_ref, prm_ref, out_ref, j0, lvl, n_out, radius, unroll):
+    """Windows out of a pass's product: ``d_ref`` (_PBLK, (k+8)·_XS) holds
+    every position's dot product with every slab element, row-major over
+    (slab row, slab column); the served positions' lerped (dx, dy) taps
+    go to level ``lvl``'s lanes of ``out_ref``, eight positions at a time."""
+    k = 2 * radius + 1
+    taps = {v: _wcp_taps(lvl, v, k) for v in _wcp_lane_regs(lvl, k * k)}
+
+    def group(g, _):
+        r0 = pl.multiple_of(g * 8, 8)
+        sx, sy, fx, fy, now = _wcp_group_prm(prm_ref, r0)
+        rows = [d_ref[pl.ds(r0, 8), y * _XS:(y + 1) * _XS]
+                for y in range(k + _YSPREAD)]
+        # rows sy .. sy+k of the slab's k+8, by the bits of sy
+        for shift in (4, 2, 1):
+            bit = (sy & shift) != 0
+            rows = [jnp.where(bit, rows[y + shift], rows[y])
+                    for y in range(len(rows) - shift)]
+        t = [rows[dy] + fy * (rows[dy + 1] - rows[dy]) for dy in range(k)]
+        # x-lerp against the next column (one lane up), then the window's
+        # columns sx .. sx+k-1 to the taps' lanes by address
+        t = [a + fx * (pltpu.roll(a, _XS - 1, 1) - a) for a in t]
+        for v, (valid, dx, dy) in taps.items():
+            idx = jnp.clip(sx + dx, 0, _XS - 1)
+            val = jnp.zeros((8, _XS), jnp.float32)
+            for i in range(k):
+                val = jnp.where(dy == i,
+                                jnp.take_along_axis(t[i], idx, axis=1), val)
+            w = min(_XS, n_out - _XS * v)
+            at = (0, 0, pl.ds(pl.multiple_of(j0 + r0, 8), 8),
+                  slice(_XS * v, _XS * v + w))
+            keep = (valid & now)[:, :w]
+            out_ref[at] = jnp.where(keep, val[:, :w], out_ref[at])
         return 0
 
-    jax.lax.fori_loop(0, n_c, chunk, 0)
+    # unrolled on the chip: the groups are independent and the scheduler
+    # interleaves them (rolled, one group's chain ran at a time, 1.6 times
+    # slower); the interpreter keeps the loop, which traces several times
+    # faster
+    jax.lax.fori_loop(0, _PBLK // 8, group, 0, unroll=unroll)
+
+
+def _wcp_spread(dout_ref, prm_ref, dd_ref, dv_ref, j0, lvl, n_out, radius,
+                unroll):
+    """Transpose of ``_wcp_select``: the served positions' cost cotangents
+    (level ``lvl``'s lanes of ``dout_ref``) spread over the pass's slab,
+    into ``dd_ref`` (_PBLK, (k+8)·_XS); unserved positions get zeros."""
+    k = 2 * radius + 1
+    kk = k * k
+    regs = list(_wcp_lane_regs(lvl, kk))
+    lane = jax.lax.broadcasted_iota(jnp.int32, (8, _XS), 1)
+    zero = jnp.zeros((8, _XS), jnp.float32)
+
+    def group(g, _):
+        r0 = pl.multiple_of(g * 8, 8)
+        sx, sy, fx, fy, now = _wcp_group_prm(prm_ref, r0)
+        rows8 = pl.ds(pl.multiple_of(j0 + r0, 8), 8)
+        dv = {}
+        for v in regs:
+            w = min(_XS, n_out - _XS * v)
+            if w == _XS:
+                reg = dout_ref[0, 0, rows8, _XS * v:_XS * (v + 1)]
+            else:
+                # the costs' last register is short: widen it over zeros
+                dv_ref[:, 0:w] = dout_ref[0, 0, rows8, _XS * v:_XS * v + w]
+                reg = dv_ref[...]
+            dv[v] = jnp.where(now, reg, 0.0)
+
+        rel = lane - sx                       # slab column - window start
+        in_win = (rel >= 0) & (rel < k)
+        dt = []
+        for dy in range(k):
+            m = jnp.clip(lvl * kk + rel * k + dy, 0, n_out - 1)
+            g0 = zero
+            for v in regs:
+                got = jnp.take_along_axis(dv[v], m & (_XS - 1), axis=1)
+                g0 = got if len(regs) == 1 else jnp.where(
+                    (m >> 7) == v, got, g0)
+            g0 = jnp.where(in_win, g0, 0.0)
+            # x-unlerp: column x takes (1-fx) of tap x-sx and fx of the
+            # tap before it
+            dt.append(g0 + fx * (pltpu.roll(g0, 1, 1) - g0))
+        a = [fy * d for d in dt]
+        b = [d - e for d, e in zip(dt, a)]
+        rows = [b[0]] + [a[i - 1] + b[i] for i in range(1, k)] + [a[k - 1]]
+        # down by sy rows: the shifter of _wcp_select the other way
+        for shift in (1, 2, 4):
+            bit = (sy & shift) != 0
+            n = len(rows)
+            rows = [jnp.where(bit,
+                              rows[y - shift] if y >= shift else zero,
+                              rows[y] if y < n else zero)
+                    for y in range(n + shift)]
+        for y, row in enumerate(rows):
+            dd_ref[pl.ds(r0, 8), y * _XS:(y + 1) * _XS] = row
+        return 0
+
+    # unrolled on the chip: the groups are independent and the scheduler
+    # interleaves them (rolled, one group's chain ran at a time, 1.6 times
+    # slower); the interpreter keeps the loop, which traces several times
+    # faster
+    jax.lax.fori_loop(0, _PBLK // 8, group, 0, unroll=unroll)
+
+
+def _wcp_dot_f32(a, b, dims):
+    """``a`` (float32, a spread cotangent) contracted with ``b`` (features)
+    into float32. Bfloat16 features meet ``a`` as a bfloat16 pair, its
+    high part and the remainder (sixteen bits of mantissa between them),
+    so the MXU runs at the features' width; float32 features meet it as
+    it is."""
+    def dot(x):
+        return jax.lax.dot_general(x, b, dims,
+                                   preferred_element_type=jnp.float32)
+
+    if b.dtype != jnp.bfloat16:
+        return dot(a)
+    hi = a.astype(jnp.bfloat16)
+    return dot(hi) + dot((a - hi.astype(jnp.float32)).astype(jnp.bfloat16))
+
+
+def _wcp_fwd_block_kernel(coords_ref, f1_ref, *rest, radius, dims, unroll):
+    """Block forward: the costs of one grid row, every level, flat."""
+    n_lvl = len(dims)
+    f2_refs = rest[:n_lvl]
+    out_ref, d_ref, prm_ref = rest[n_lvl:]
+    k = 2 * radius + 1
+    ys = k + _YSPREAD
+    n_out = n_lvl * k * k
+
+    def block(bi, _):
+        j0 = pl.multiple_of(bi * _PBLK, _PBLK)
+        f1b = f1_ref[0, 0, pl.ds(j0, _PBLK), :]
+        for lvl, f2_ref in enumerate(f2_refs):
+            def serve(ytop, xb, lvl=lvl, f2_ref=f2_ref):
+                slab = f2_ref[0, pl.ds(ytop, ys), pl.ds(xb, _XS), :]
+                d_ref[...] = jax.lax.dot_general(
+                    f1b, slab.reshape(ys * _XS, -1),
+                    (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                _wcp_select(d_ref, prm_ref, out_ref, j0, lvl, n_out, radius,
+                            unroll)
+
+            _wcp_block_passes(coords_ref, j0, lvl, dims[lvl],
+                              f2_ref.shape[2], radius, prm_ref, serve)
+        return 0
+
+    jax.lax.fori_loop(0, f1_ref.shape[2] // _PBLK, block, 0)
+
+
+def _wcp_bwd_df1_block_kernel(coords_ref, dout_ref, *rest, radius, dims,
+                              unroll):
+    """Block df1: per pass the cotangent spread over the slab, contracted
+    with the slab, (_PBLK, (k+8)·_XS) x ((k+8)·_XS, C)."""
+    n_lvl = len(dims)
+    f2_refs = rest[:n_lvl]
+    df1_ref, dd_ref, prm_ref, dv_ref, acc_ref = rest[n_lvl:]
+    k = 2 * radius + 1
+    ys = k + _YSPREAD
+    n_out = n_lvl * k * k
+    dv_ref[...] = jnp.zeros_like(dv_ref)
+
+    def block(bi, _):
+        j0 = pl.multiple_of(bi * _PBLK, _PBLK)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        for lvl, f2_ref in enumerate(f2_refs):
+            def serve(ytop, xb, lvl=lvl, f2_ref=f2_ref):
+                _wcp_spread(dout_ref, prm_ref, dd_ref, dv_ref, j0, lvl,
+                            n_out, radius, unroll)
+                slab = f2_ref[0, pl.ds(ytop, ys), pl.ds(xb, _XS), :]
+                acc_ref[...] += _wcp_dot_f32(
+                    dd_ref[...], slab.reshape(ys * _XS, -1),
+                    (((1,), (0,)), ((), ())))
+
+            _wcp_block_passes(coords_ref, j0, lvl, dims[lvl],
+                              f2_ref.shape[2], radius, prm_ref, serve)
+        df1_ref[0, 0, pl.ds(j0, _PBLK), :] = acc_ref[...].astype(
+            df1_ref.dtype)
+        return 0
+
+    jax.lax.fori_loop(0, df1_ref.shape[2] // _PBLK, block, 0)
+
+
+def _wcp_bwd_df2_block_kernel(coords_ref, f1_ref, dout_ref, df2_ref, dd_ref,
+                              prm_ref, dv_ref, *, radius, lvl, n_lvl, dims,
+                              unroll):
+    """Block df2 for ONE level: per pass the spread cotangent contracted
+    with the block's f1 rows, ((k+8)·_XS, _PBLK) x (_PBLK, C), added into
+    the slab's place in the map (resident across the grid rows)."""
+    k = 2 * radius + 1
+    ys = k + _YSPREAD
+    n_out = n_lvl * k * k
+    dv_ref[...] = jnp.zeros_like(dv_ref)
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        df2_ref[...] = jnp.zeros_like(df2_ref)
+
+    def block(bi, _):
+        j0 = pl.multiple_of(bi * _PBLK, _PBLK)
+        f1b = f1_ref[0, 0, pl.ds(j0, _PBLK), :]
+
+        def serve(ytop, xb):
+            _wcp_spread(dout_ref, prm_ref, dd_ref, dv_ref, j0, lvl, n_out,
+                        radius, unroll)
+            ds2 = _wcp_dot_f32(dd_ref[...], f1b, (((0,), (0,)), ((), ())))
+            df2_ref[0, pl.ds(ytop, ys), pl.ds(xb, _XS), :] += ds2.reshape(
+                ys, _XS, -1)
+
+        _wcp_block_passes(coords_ref, j0, lvl, dims, df2_ref.shape[2],
+                          radius, prm_ref, serve)
+        return 0
+
+    jax.lax.fori_loop(0, f1_ref.shape[2] // _PBLK, block, 0)
 
 
 def _wcp_bwd_df1_kernel(coords_ref, dout_ref, *f2_refs_and_out, radius,
@@ -651,10 +747,10 @@ def _wcp_bwd_df2_kernel(coords_ref, f1_ref, dout_ref, df2_ref, *, radius,
 
 
 def _wcp_pad_f2(f2_levels, radius):
-    lo, hi_y, hi_x = _wcp_pads(radius)
+    pads = [_wcp_pads(radius, f2.shape[2]) for f2 in f2_levels]
     return tuple(
         jnp.pad(f2, ((0, 0), (lo, hi_y), (lo, hi_x), (0, 0)))
-        for f2 in f2_levels
+        for f2, (lo, hi_y, hi_x) in zip(f2_levels, pads)
     )
 
 
@@ -670,6 +766,42 @@ def _wcp_bwd_interpret(f1, f2_levels, coords, dout, radius, band=None):
                         interpret=True, band=band)
 
 
+_WCP_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=100 * 1024 * 1024)
+
+
+def _wcp_row_spec(n_j, *minor, memory_space=pltpu.VMEM):
+    """One (batch, grid row) of a (b, n_i, n_j, ...) operand a step."""
+    zeros = (0,) * (1 + len(minor))
+    return pl.BlockSpec((1, 1, n_j) + minor, lambda bi, ii: (bi, ii) + zeros,
+                        memory_space=memory_space)
+
+
+def _wcp_map_spec(f2):
+    """A whole padded map, resident across a sample's grid rows."""
+    return pl.BlockSpec((1,) + f2.shape[1:], lambda bi, ii: (bi, 0, 0, 0),
+                        memory_space=pltpu.VMEM)
+
+
+def _wcp_block_pad(n_j, *rows):
+    """The position axis padded to whole blocks: zeros, and for the
+    centres (last) the row's last centre again, so that the padding joins
+    its neighbours' pass; padded positions are sliced off forward and
+    carry a zero cotangent backward."""
+    n_jp = _round_up(n_j, _PBLK)
+    if n_jp == n_j:
+        return (n_jp,) + rows
+    pad = ((0, 0), (0, 0), (0, n_jp - n_j), (0, 0))
+    return (n_jp,) + tuple(jnp.pad(x, pad) for x in rows[:-1]) + (
+        jnp.pad(rows[-1], pad, mode="edge"),)
+
+
+def _wcp_block_scratch(radius):
+    """A pass's product (or spread cotangent) and its parameters."""
+    k = 2 * radius + 1
+    return [pltpu.VMEM((_PBLK, (k + _YSPREAD) * _XS), jnp.float32),
+            pltpu.VMEM((5, _PBLK, _XS), jnp.float32)]
+
+
 def _wcp_fwd_tpu(f1, f2_levels, coords, radius, interpret=False,
                  band=None):
     b, n_i, n_j, c = f1.shape
@@ -681,52 +813,37 @@ def _wcp_fwd_tpu(f1, f2_levels, coords, radius, interpret=False,
         band = _wcp_band_enabled()
 
     if band:
-        # pad the position axis to whole chunks; padded positions sample
-        # around coord 0 (in-bounds garbage) and are sliced off below
-        n_jp = -(-n_j // _PB) * _PB
-        if n_jp != n_j:
-            f1 = jnp.pad(f1, ((0, 0), (0, 0), (0, n_jp - n_j), (0, 0)))
-            coords = jnp.pad(coords,
-                             ((0, 0), (0, 0), (0, n_jp - n_j), (0, 0)))
-        f1r = f1.reshape(b, n_i, n_jp // _PB, _PB, c)
-        kernel = functools.partial(_wcp_fwd_band_kernel, radius=radius,
-                                   dims=dims)
-        f1_spec = pl.BlockSpec((1, 1, n_jp // _PB, _PB, c),
-                               lambda bi, ii: (bi, ii, 0, 0, 0),
-                               memory_space=pltpu.VMEM)
-    else:
-        n_jp = n_j
-        # j rides an untiled axis (the dummy sublane dim keeps the
-        # last-two dims static so per-position dynamic indexing is legal)
-        f1r = f1.reshape(b, n_i, n_j, 1, c)
-        kernel = functools.partial(_wcp_fwd_kernel, radius=radius,
-                                   dims=dims)
-        f1_spec = pl.BlockSpec((1, 1, n_j, 1, c),
-                               lambda bi, ii: (bi, ii, 0, 0, 0),
-                               memory_space=pltpu.VMEM)
+        # the costs leave flat, (level, dx, dy) on the lanes: no reshape
+        n_jp, f1, coords = _wcp_block_pad(n_j, f1, coords)
+        out = pl.pallas_call(
+            functools.partial(_wcp_fwd_block_kernel, radius=radius,
+                              dims=dims, unroll=not interpret),
+            out_shape=jax.ShapeDtypeStruct((b, n_i, n_jp, n_lvl * k * k),
+                                           jnp.float32),
+            grid=(b, n_i),
+            in_specs=[_wcp_row_spec(n_jp, 2), _wcp_row_spec(n_jp, c)]
+            + [_wcp_map_spec(f2) for f2 in f2p],
+            out_specs=_wcp_row_spec(n_jp, n_lvl * k * k),
+            scratch_shapes=_wcp_block_scratch(radius),
+            compiler_params=_WCP_PARAMS,
+            interpret=interpret,
+        )(coords, f1, *f2p)
+        return out if n_jp == n_j else out[:, :, :n_j]
 
+    # j rides an untiled axis (the dummy sublane dim keeps the last-two
+    # dims static so per-position dynamic indexing is legal)
     out = pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((b, n_i, n_jp, n_lvl * k, k),
+        functools.partial(_wcp_fwd_kernel, radius=radius, dims=dims),
+        out_shape=jax.ShapeDtypeStruct((b, n_i, n_j, n_lvl * k, k),
                                        jnp.float32),
         grid=(b, n_i),
-        in_specs=[
-            pl.BlockSpec((1, 1, n_jp, 2), lambda bi, ii: (bi, ii, 0, 0),
-                         memory_space=pltpu.SMEM),
-            f1_spec,
-        ] + [
-            pl.BlockSpec((1,) + f2.shape[1:], lambda bi, ii: (bi, 0, 0, 0),
-                         memory_space=pltpu.VMEM)
-            for f2 in f2p
-        ],
-        out_specs=pl.BlockSpec((1, 1, n_jp, n_lvl * k, k),
-                               lambda bi, ii: (bi, ii, 0, 0, 0),
-                               memory_space=pltpu.VMEM),
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=100 * 1024 * 1024),
+        in_specs=[_wcp_row_spec(n_j, 2, memory_space=pltpu.SMEM),
+                  _wcp_row_spec(n_j, 1, c)]
+        + [_wcp_map_spec(f2) for f2 in f2p],
+        out_specs=_wcp_row_spec(n_j, n_lvl * k, k),
+        compiler_params=_WCP_PARAMS,
         interpret=interpret,
-    )(coords, f1r, *f2p)
-    out = out[:, :, :n_j]
+    )(coords, f1.reshape(b, n_i, n_j, 1, c), *f2p)
     # (level, dx, dy) channel flatten — (L*k, k) row-major is exactly that
     return out.reshape(b, n_i, n_j, n_lvl * k * k)
 
@@ -737,10 +854,15 @@ def _wcp_band_enabled():
     return env.get_bool("RMD_WCP_BAND")
 
 
+def _wcp_strip(df2_l, dim, radius):
+    """A padded map's gradient without its padding."""
+    lo = 2 * radius + 1
+    return df2_l[:, lo:lo + dim[0], lo:lo + dim[1], :]
+
+
 def _wcp_bwd_tpu(f1, f2_levels, coords, dout, radius, interpret=False,
                  band=None):
     b, n_i, n_j, c = f1.shape
-    lo, _hi_y, _hi_x = _wcp_pads(radius)
     f2p = _wcp_pad_f2(f2_levels, radius)
     dims = tuple((f2.shape[1], f2.shape[2]) for f2 in f2_levels)
     if band is None:
@@ -750,83 +872,79 @@ def _wcp_bwd_tpu(f1, f2_levels, coords, dout, radius, interpret=False,
     n_lvl = len(f2_levels)
 
     if band:
-        # whole-chunk padding; padded positions carry zero dout and
-        # coords 0 (in-bounds), so they contribute nothing to df1/df2
-        n_jp = -(-n_j // _PB) * _PB
+        # the cotangent enters flat, as the costs left; df1 leaves in the
+        # features' type
+        n_jp, f1, dout, coords = _wcp_block_pad(n_j, f1, dout, coords)
+        coords_spec = _wcp_row_spec(n_jp, 2)
+        dout_spec = _wcp_row_spec(n_jp, n_lvl * k * k)
+        f1_spec = _wcp_row_spec(n_jp, c)
+        scratch = _wcp_block_scratch(radius) + [
+            pltpu.VMEM((8, _XS), jnp.float32)]
+
+        df1 = pl.pallas_call(
+            functools.partial(_wcp_bwd_df1_block_kernel, radius=radius,
+                              dims=dims, unroll=not interpret),
+            out_shape=jax.ShapeDtypeStruct((b, n_i, n_jp, c), f1.dtype),
+            grid=(b, n_i),
+            in_specs=[coords_spec, dout_spec]
+            + [_wcp_map_spec(f2) for f2 in f2p],
+            out_specs=f1_spec,
+            scratch_shapes=scratch + [pltpu.VMEM((_PBLK, c), jnp.float32)],
+            compiler_params=_WCP_PARAMS,
+            interpret=interpret,
+        )(coords, dout, *f2p)
         if n_jp != n_j:
-            pad = ((0, 0), (0, 0), (0, n_jp - n_j), (0, 0))
-            f1 = jnp.pad(f1, pad)
-            coords = jnp.pad(coords, pad)
-            dout = jnp.pad(dout, pad)
-        f1r = f1.reshape(b, n_i, n_jp // _PB, _PB, c)
-        row_spec = pl.BlockSpec((1, 1, n_jp // _PB, _PB, c),
-                                lambda bi, ii: (bi, ii, 0, 0, 0),
-                                memory_space=pltpu.VMEM)
-        df1_kernel = functools.partial(_wcp_bwd_df1_band_kernel,
-                                       radius=radius, dims=dims)
-        df2_kernel = _wcp_bwd_df2_band_kernel
-        df1_shape = (b, n_i, n_jp // _PB, _PB, c)
-    else:
-        n_jp = n_j
-        f1r = f1.reshape(b, n_i, n_j, 1, c)
-        row_spec = pl.BlockSpec((1, 1, n_j, 1, c),
-                                lambda bi, ii: (bi, ii, 0, 0, 0),
-                                memory_space=pltpu.VMEM)
-        df1_kernel = functools.partial(_wcp_bwd_df1_kernel, radius=radius,
-                                       dims=dims)
-        df2_kernel = _wcp_bwd_df2_kernel
-        df1_shape = (b, n_i, n_j, 1, c)
+            df1 = df1[:, :, :n_j]
 
-    doutr = dout.reshape(b, n_i, n_jp, n_lvl * k, k)
+        df2_out = []
+        for lvl, f2 in enumerate(f2p):
+            df2_l = pl.pallas_call(
+                functools.partial(_wcp_bwd_df2_block_kernel, radius=radius,
+                                  lvl=lvl, n_lvl=n_lvl, dims=dims[lvl],
+                                  unroll=not interpret),
+                out_shape=jax.ShapeDtypeStruct(f2.shape, jnp.float32),
+                grid=(b, n_i),
+                in_specs=[coords_spec, f1_spec, dout_spec],
+                out_specs=_wcp_map_spec(f2),
+                scratch_shapes=scratch,
+                compiler_params=_WCP_PARAMS,
+                interpret=interpret,
+            )(coords, f1, dout)
+            df2_out.append(_wcp_strip(df2_l, dims[lvl], radius))
+        return df1, tuple(df2_out)
 
-    coords_spec = pl.BlockSpec((1, 1, n_jp, 2),
-                               lambda bi, ii: (bi, ii, 0, 0),
-                               memory_space=pltpu.SMEM)
-    dout_spec = pl.BlockSpec((1, 1, n_jp, n_lvl * k, k),
-                             lambda bi, ii: (bi, ii, 0, 0, 0),
-                             memory_space=pltpu.VMEM)
+    doutr = dout.reshape(b, n_i, n_j, n_lvl * k, k)
+    coords_spec = _wcp_row_spec(n_j, 2, memory_space=pltpu.SMEM)
+    row_spec = _wcp_row_spec(n_j, 1, c)
+    f1r = f1.reshape(b, n_i, n_j, 1, c)
 
     df1 = pl.pallas_call(
-        df1_kernel,
-        out_shape=jax.ShapeDtypeStruct(df1_shape, jnp.float32),
+        functools.partial(_wcp_bwd_df1_kernel, radius=radius, dims=dims),
+        out_shape=jax.ShapeDtypeStruct((b, n_i, n_j, 1, c), jnp.float32),
         grid=(b, n_i),
-        in_specs=[coords_spec, dout_spec] + [
-            pl.BlockSpec((1,) + f2.shape[1:], lambda bi, ii: (bi, 0, 0, 0),
-                         memory_space=pltpu.VMEM)
-            for f2 in f2p
-        ],
+        in_specs=[coords_spec, _wcp_row_spec(n_j, n_lvl * k, k)]
+        + [_wcp_map_spec(f2) for f2 in f2p],
         out_specs=row_spec,
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=100 * 1024 * 1024),
+        compiler_params=_WCP_PARAMS,
         interpret=interpret,
-    )(coords, doutr, *f2p).reshape(b, n_i, n_jp, c)[:, :, :n_j]
+    )(coords, doutr, *f2p).reshape(b, n_i, n_j, c)
 
     df2_out = []
     for lvl, f2 in enumerate(f2p):
-        # pass only this level's dout columns; raise the scoped-vmem cap —
-        # the accumulated df2 block (revisited across the i-grid) plus its
-        # pipeline double-buffer exceed the default budget at level 0
+        # pass only this level's dout columns; the raised scoped-vmem cap
+        # holds the accumulated df2 block (revisited across the i-grid)
         dout_l = doutr[:, :, :, lvl * k:(lvl + 1) * k, :]
-        dout_l_spec = pl.BlockSpec((1, 1, n_jp, k, k),
-                                   lambda bi, ii: (bi, ii, 0, 0, 0),
-                                   memory_space=pltpu.VMEM)
         df2_l = pl.pallas_call(
-            functools.partial(df2_kernel, radius=radius, lvl=lvl,
+            functools.partial(_wcp_bwd_df2_kernel, radius=radius, lvl=lvl,
                               dims=dims[lvl]),
             out_shape=jax.ShapeDtypeStruct(f2.shape, jnp.float32),
             grid=(b, n_i),
-            in_specs=[coords_spec, row_spec, dout_l_spec],
-            out_specs=pl.BlockSpec((1,) + f2.shape[1:],
-                                   lambda bi, ii: (bi, 0, 0, 0),
-                                   memory_space=pltpu.VMEM),
-            compiler_params=pltpu.CompilerParams(
-                vmem_limit_bytes=100 * 1024 * 1024),
+            in_specs=[coords_spec, row_spec, _wcp_row_spec(n_j, k, k)],
+            out_specs=_wcp_map_spec(f2),
+            compiler_params=_WCP_PARAMS,
             interpret=interpret,
         )(coords, f1r, dout_l)
-
-        # strip the padding back off
-        h2, w2 = dims[lvl]
-        df2_out.append(df2_l[:, lo:lo + h2, lo:lo + w2, :])
+        df2_out.append(_wcp_strip(df2_l, dims[lvl], radius))
 
     return df1, tuple(df2_out)
 
@@ -855,14 +973,18 @@ def _wcp_fits_vmem(f1, f2_levels, radius):
     """
     if radius > 7:
         return False
-    lo, hi_y, hi_x = _wcp_pads(radius)
     k = 2 * radius + 1
-    n_lvl = len(f2_levels)
-    n_j, c = f1.shape[2], f1.shape[3]
+    n_jp = _round_up(f1.shape[2], _PBLK)
+    c = f1.shape[3]
     itemsize = 2 if f1.dtype == jnp.bfloat16 else 4
-    total = n_j * (n_lvl * k + 8) * 128 * 4        # out block (padded)
-    total += n_j * 8 * c * itemsize                # f1 row block
+    # a row's blocks, each in two buffers: the flat costs on whole lane
+    # registers, the centres (two of 128 lanes), f1
+    lanes = _round_up(len(f2_levels) * k * k, _XS)
+    total = 2 * n_jp * ((lanes + _XS) * 4 + c * itemsize)
+    # a pass's product and its parameters
+    total += _PBLK * ((k + _YSPREAD) + 5) * _XS * 4
     for f2 in f2_levels:
+        lo, hi_y, hi_x = _wcp_pads(radius, f2.shape[2])
         total += (f2.shape[1] + lo + hi_y) * (f2.shape[2] + lo + hi_x) \
             * c * itemsize
     return total <= 64 * 1024 * 1024
@@ -919,6 +1041,16 @@ def windowed_corr_pyramid(f1, f2_levels, coords, radius=4, mask_costs=(),
     without ever building the volume. ``mask_costs`` zeroes whole levels
     by pyramid level id (l + 3), like the reference (raft.py:86).
 
+    On the TPU, where the shapes fit VMEM (``_wcp_fits_vmem``), the call
+    is the block kernels above: 80 positions of a grid row against one
+    slab of a level's map a pass, the features in their own type on the
+    MXU, accumulation and both lerps in float32, the costs written flat
+    in the (level, dx, dy) order returned here, so nothing reshapes them
+    on the way to ``_WindowConv1x1``; the gradient is float32 costs in,
+    ``df1`` and every ``df2`` out in the features' type, zero for the
+    centres. ``wcp_shared_share`` gives the share of a field's blocks that
+    one pass serves (``RMD_WCP_BAND=0``: the per-position kernels).
+
     Which form the call traced is counted (``wcp_fused_calls`` /
     ``wcp_fallback_calls``, ``telemetry.note_trace``), as the window
     sampler's is: the fallback is silent in the arithmetic and not in the
@@ -950,6 +1082,35 @@ def windowed_corr_pyramid(f1, f2_levels, coords, radius=4, mask_costs=(),
         ])
         out = out * keep
     return out
+
+
+def wcp_shared_share(coords, dims, radius=4):
+    """Share of a call's block·levels that one slab serves.
+
+    ``coords``: (B, H, W, 2) level-0 window centres, as
+    ``windowed_corr_pyramid`` takes them; ``dims``: the (height, width) of
+    each level's map. A block (_PBLK consecutive positions of a row) whose
+    windows all lie inside the slab its first pass anchors takes one pass,
+    any other takes a pass more for every cluster of windows it holds. The
+    predicate is the one the kernels trace (``_wcp_window_start``,
+    ``_wcp_pass``), so the share is the path they take on these centres: 1
+    on zero flow, lower the more object edges cut the blocks.
+    """
+    b, n_i, n_j, _ = coords.shape
+    n_jp, coords = _wcp_block_pad(n_j, coords)
+    blocks = coords.reshape(b, n_i, n_jp // _PBLK, _PBLK, 2)
+    todo = jnp.ones((_PBLK, 1), bool)
+    shared = []
+    for lvl, (h2, w2) in enumerate(dims):
+        lo, _, hi_x = _wcp_pads(radius, w2)
+
+        def one_pass_serves(c, lvl=lvl, h2=h2, w2=w2, wp=lo + w2 + hi_x):
+            x0, y0, _, _ = _wcp_window_start(c[:, 0:1], c[:, 1:2], lvl, h2,
+                                             w2, radius)
+            return _wcp_pass(x0, y0, todo, wp, radius)[2].all()
+
+        shared.append(jax.vmap(jax.vmap(jax.vmap(one_pass_serves)))(blocks))
+    return jnp.mean(jnp.stack(shared).astype(jnp.float32))
 
 
 # ---------------------------------------------------------------------------

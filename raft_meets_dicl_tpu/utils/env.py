@@ -165,8 +165,8 @@ KNOBS = dict([
        "level-batched MatchingNets + fused Pallas window sampler (0 = "
        "reference loop)", "models"),
     _k("RMD_WCP_BAND", "switch", True,
-       "band-sharing windowed-correlation Pallas kernel (0 = per-row "
-       "form)", "models"),
+       "windowed-correlation Pallas kernels on blocks of 80 positions "
+       "sharing a slab (0 = per-position form)", "models"),
     _k("RMD_FS_VOLUME_GIB", "float", 4.0,
        "raft/fs correlation-volume HBM budget steering the "
        "volume/windowed dispatch (per chip)", "models"),

@@ -12,10 +12,14 @@ the family's own ``_*_reference`` evaluated at highest matmul precision:
   at b6 400x720, 12 iterations: 324,000 rows of 576 logits;
 - ``wcp`` — the windowed correlation pyramid of ``raft/fs`` at
   cfg/strategy/highres/raft-fs.hd1k-1080p.yaml: b1 1072x2560, C=256,
-  r=4, band and per-position forms, with all 4 levels on the kernel and
+  r=4, block and per-position forms, with all 4 levels on the kernel and
   with the prefix the volume/windowed dispatch leaves on it; and the
   benchmark cell's shape, b1 1088x1920 (136x240), where that prefix is
-  level 0 alone;
+  level 0 alone, the block form there on three fields of centres (zero
+  flow, a smooth field, a Things-like field of objects that move up to
+  40 grid cells against their background), each with the milliseconds a
+  call of forward, ``df1`` and ``df2`` and the share of its blocks that
+  one slab serves (``ops.pallas.wcp_shared_share``);
 - ``sw`` — the fused DICL window sampler at ``raft+dicl/ml``'s reference
   shape: b6 384x704, C=32, r=4, levels 48x88 down to 6x11.
 
@@ -24,7 +28,9 @@ not skipped. Results go to ``chiprun_out/kernels.json`` and, one line per
 case, to stdout; the exit code is 1 if any case failed. Times are printed
 for the record (one warm call each); they are not a benchmark.
 
-    chiprun -- python scripts/chip_kernels.py [combine] [wcp] [sw]
+    chiprun -- python scripts/chip_kernels.py [combine] [wcp] [sw] [PART...]
+
+(``PART``: run only the cases whose name contains one of these.)
 """
 
 import json
@@ -125,9 +131,71 @@ def case_combine(dtype, m=12 * 6 * 50 * 90):
 # -- wcp ---------------------------------------------------------------------
 
 _ROWS = 17   # reference row chunk: its (rows, W, 81, C) gathers are GBs
+_CALLS = 10  # calls between two syncs, for the milliseconds a call
 
 
-def case_wcp(levels, band, dtype=jnp.bfloat16, h=134, w=320, c=256):
+def _grid(h, w):
+    return np.meshgrid(np.arange(h, dtype=np.float64),
+                       np.arange(w, dtype=np.float64), indexing="ij")
+
+
+def _field_zero(rng, b, h, w):
+    yy, xx = _grid(h, w)
+    return np.stack((xx, yy), -1)[None].repeat(b, 0)
+
+
+def _field_smooth(rng, b, h, w):
+    """A camera's motion: a zoom of a few percent, a pan and a slow wave."""
+    yy, xx = _grid(h, w)
+    fx = 0.04 * (xx - w / 2) + 3.0 * np.sin(yy / 40.0) + 2.5
+    fy = 0.03 * (yy - h / 2) + 2.0 * np.cos(xx / 60.0) - 1.5
+    return np.stack((xx + fx, yy + fy), -1)[None].repeat(b, 0)
+
+
+def _field_things(rng, b, h, w, objects=14, reach=40.0):
+    """Piecewise smooth, as FlyingThings3D at 1/8: the smooth background
+    and on it objects (ellipses and boxes, a tenth to a half of the frame
+    across), each with an affine motion of its own whose displacement
+    reaches ``reach`` grid cells, the later drawn over the earlier, with
+    discontinuities along every edge."""
+    yy, xx = _grid(h, w)
+    out = _field_smooth(rng, b, h, w)
+    for bi in range(b):
+        for _ in range(objects):
+            cy, cx = rng.uniform(0, h), rng.uniform(0, w)
+            ry, rx = rng.uniform(h / 10, h / 2) / 2, rng.uniform(w / 10,
+                                                                w / 2) / 2
+            if rng.random() < 0.5:
+                inside = ((yy - cy) / ry) ** 2 + ((xx - cx) / rx) ** 2 < 1
+            else:
+                inside = (abs(yy - cy) < ry) & (abs(xx - cx) < rx)
+            t = rng.uniform(-reach, reach, 2)
+            a = rng.normal(0, 0.03, (2, 2))
+            fx = t[0] + a[0, 0] * (xx - cx) + a[0, 1] * (yy - cy)
+            fy = t[1] + a[1, 0] * (xx - cx) + a[1, 1] * (yy - cy)
+            out[bi][inside] = np.stack((xx + fx, yy + fy), -1)[inside]
+    return out
+
+
+def _field_noisy(rng, b, h, w):
+    return np.asarray(_coords(rng, b, h, w))
+
+
+FIELDS = {"zero": _field_zero, "smooth": _field_smooth,
+          "things": _field_things, "noisy": _field_noisy}
+
+
+def _ms_a_call(fn, *args):
+    jax.block_until_ready(fn(*args))
+    t0 = time.perf_counter()
+    for _ in range(_CALLS):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return 1e3 * (time.perf_counter() - t0) / _CALLS
+
+
+def case_wcp(levels, band, dtype=jnp.bfloat16, h=134, w=320, c=256,
+             field="noisy"):
     b, radius = 1, 4
     rng = np.random.default_rng(1)
     f1 = jnp.asarray(rng.normal(0, 1.0, (b, h, w, c)), dtype)
@@ -135,21 +203,25 @@ def case_wcp(levels, band, dtype=jnp.bfloat16, h=134, w=320, c=256):
     for _ in range(1, levels):
         f2.append(avg_pool2d(f2[-1], 2))
     f2 = tuple(f2)
-    coords = _coords(rng, b, h, w)
+    coords = jnp.asarray(FIELDS[field](rng, b, h, w), jnp.float32)
     dout = jnp.asarray(rng.normal(0, 1.0, (b, h, w, levels * 81)),
                        jnp.float32)
     if not K._wcp_fits_vmem(f1, f2, radius):
         raise RuntimeError("_wcp_fits_vmem says no: dispatch would take "
                            "the XLA path at this shape")
 
-    out, cold_f, ms_f = _timed(
-        jax.jit(lambda a, bb, cc: K._wcp_fwd_tpu(a, bb, cc, radius,
-                                                 band=band)),
-        f1, f2, coords)
-    (df1, df2), cold_b, ms_b = _timed(
-        jax.jit(lambda a, bb, cc, d: K._wcp_bwd_tpu(a, bb, cc, d, radius,
-                                                    band=band)),
-        f1, f2, coords, dout)
+    fwd = jax.jit(lambda a, bb, cc: K._wcp_fwd_tpu(a, bb, cc, radius,
+                                                   band=band))
+    bwd = jax.jit(lambda a, bb, cc, d: K._wcp_bwd_tpu(a, bb, cc, d, radius,
+                                                      band=band))
+    out, cold_f, _ = _timed(fwd, f1, f2, coords)
+    (df1, df2), cold_b, _ = _timed(bwd, f1, f2, coords, dout)
+    # each kernel alone: the other calls of the jitted pair are dead code
+    ms = {"fwd": _ms_a_call(fwd, f1, f2, coords),
+          "df1": _ms_a_call(jax.jit(lambda *a: bwd(*a)[0]),
+                            f1, f2, coords, dout),
+          "df2": _ms_a_call(jax.jit(lambda *a: bwd(*a)[1]),
+                            f1, f2, coords, dout)}
 
     f2_32 = tuple(x.astype(jnp.float32) for x in f2)
 
@@ -171,17 +243,21 @@ def case_wcp(levels, band, dtype=jnp.bfloat16, h=134, w=320, c=256):
             jnp.add, df2_sum, g2)
     jax.block_until_ready(df2_sum)
     ref_s = time.perf_counter() - t0
-    return {
+    report = {
         "shape": f"b1 {h}x{w} C={c} r={radius} levels={levels} "
-                 f"band={band}", "dtype": jnp.dtype(dtype).name,
+                 f"band={band} field={field}",
+        "dtype": jnp.dtype(dtype).name,
         "err": {"fwd": _err(out, jnp.concatenate(outs, 1)),
                 "df1": _err(df1, jnp.concatenate(df1s, 1)),
                 **{f"df2[{i}]": _err(g, w_)
                    for i, (g, w_) in enumerate(zip(df2, df2_sum))}},
         "compile_s": round(cold_f + cold_b, 2),
-        "ms": {"fwd": ms_f, "bwd": ms_b,
-               "ref_fwd_bwd_incl_compile": 1e3 * ref_s},
+        "ms": {**ms, "ref_fwd_bwd_incl_compile": 1e3 * ref_s},
     }
+    if band:
+        report["shared_share"] = round(float(K.wcp_shared_share(
+            coords, [x.shape[1:3] for x in f2], radius)), 4)
+    return report
 
 
 # -- sw ----------------------------------------------------------------------
@@ -231,21 +307,25 @@ def cases(families):
         # the prefix the dispatch leaves on the kernel at this shape
         n_win = volume_level_split((1, 134, 320), 4, 2)
         for levels in sorted({4, n_win} - {0}):
-            yield f"wcp/levels{levels}/band", case_wcp, (levels, True)
+            yield f"wcp/levels{levels}/block", case_wcp, (levels, True)
             yield f"wcp/levels{levels}/position", case_wcp, (levels, False)
         # the cell fs-train-1080p: b1 1088x1920, level 0 alone (levels
         # 1-3 are materialised volumes there)
         n_win = volume_level_split((1, 136, 240), 4, 2)
-        for band, form in ((True, "band"), (False, "position")):
-            yield f"wcp/136x240/levels{n_win}/{form}", case_wcp, (
-                n_win, band, jnp.bfloat16, 136, 240)
+        for field in ("zero", "smooth", "things"):
+            yield f"wcp/136x240/levels{n_win}/block/{field}", case_wcp, (
+                n_win, True, jnp.bfloat16, 136, 240, 256, field)
+        yield f"wcp/136x240/levels{n_win}/position", case_wcp, (
+            n_win, False, jnp.bfloat16, 136, 240)
     if "sw" in families:
         yield "sw/f32", case_sw, (jnp.float32,)
         yield "sw/bf16", case_sw, (jnp.bfloat16,)
 
 
 def main(argv):
-    families = argv or ["combine", "wcp", "sw"]
+    known = ("combine", "wcp", "sw")
+    families = [a for a in argv if a in known] or list(known)
+    only = [a for a in argv if a not in known]   # substrings of case names
     dev = jax.devices()[0]
     if dev.platform != "tpu":
         sys.exit(f"chip_kernels: needs a TPU, jax found '{dev.platform}' — "
@@ -258,6 +338,8 @@ def main(argv):
 
     results, failed = {}, []
     for name, fn, args in cases(families):
+        if only and not any(part in name for part in only):
+            continue
         try:
             rep = fn(*args)
             rep["compiled"] = True
