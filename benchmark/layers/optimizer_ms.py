@@ -1,0 +1,11 @@
+"""Device time a train step spends in everything after the gradient: clip,
+norms, the optax update, ``apply_updates``, the finiteness keep (scope
+``optimizer``), forward and backward: the traced operations whose
+instruction the program's ``owners`` record gives to the phase
+``optimizer``. Nothing where the run holds no such record or the records
+cover under 90% of the traced time: see ``_owners.table``."""
+from . import _owners
+
+
+def read(run):
+    return _owners.phase_ms(run, "train", "optimizer")

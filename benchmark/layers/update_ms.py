@@ -1,0 +1,10 @@
+"""Device time a train step spends in the update block: motion encoder, GRU,
+flow head (scope ``update``), forward and backward: the traced operations
+whose instruction the program's ``owners`` record gives to the phase
+``update``. Nothing where the run holds no such record or the records cover
+under 90% of the traced time: see ``_owners.table``."""
+from . import _owners
+
+
+def read(run):
+    return _owners.phase_ms(run, "train", "update")

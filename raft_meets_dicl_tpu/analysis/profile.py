@@ -23,9 +23,10 @@ Two attribution modes, because module names are not unique:
   key, fingerprint and predicted costs next to the raw trace.
 - **post-hoc attribution** (``attribute_trace``, used by ``/profilez``
   and ``train --profile``): an existing unsegmented capture is
-  aggregated per ``hlo_module`` and op class, and module names are
-  matched back to registered programs only where the mapping is
-  unambiguous.
+  aggregated per ``hlo_module`` and op class, and per phase of the
+  model (encoders, lookup, update ...) from the ``owners`` record of
+  the registered program whose instructions cover the module's
+  operations (``compile/owners.py``); that program names the module.
 
 The roofline prediction is deliberately crude (peak FLOP/s and
 bandwidth per device kind, no overlap model): the *ratio* is the
@@ -590,36 +591,68 @@ def audit_profiles(entries=None, budget=None, out_dir=None, repeats=2,
 # -- post-hoc attribution (unsegmented captures) ------------------------------
 
 
-def _module_map():
-    """``module name -> [program key]`` over the live registry: jax
-    names a jitted module ``jit_<fn.__name__>``, so the mapping is a
-    guess — callers only trust unambiguous (single-program) names."""
+def _program_records():
+    """``[(program key, owners record)]`` over the live registry: what
+    each executable's compiled text says of its instructions
+    (``compile/owners.py``; kept by the program when the telemetry sink
+    is on)."""
     from ..compile.registry import registry as program_registry
 
-    out = {}
+    out = []
     for prog in program_registry().programs():
-        fn = getattr(prog, "__wrapped__", None)
-        name = getattr(fn, "__name__", None) or \
-            getattr(getattr(fn, "__wrapped__", None), "__name__", None)
-        if not name:
-            continue
         key = prog.key.canonical() if prog.key else prog.label
-        out.setdefault(f"jit_{name}", []).append(key)
+        out += [(key, rec) for rec in getattr(prog, "owners", {}).values()]
     return out
+
+
+def _op_name(op):
+    """An operation's instruction name: trace JSON gives the name alone,
+    a TPU xplane the whole instruction text."""
+    return op.partition(" = ")[0].strip().lstrip("%")
+
+
+def _phases(module, ops, records):
+    """``(program key, {phase: seconds}, covered share)`` of one module's
+    operations from the record whose keys cover most of their time, or
+    ``(None, {}, 0.0)`` when none covers nine tenths (no record of this
+    program: the sink was off, or the capture is another process's)."""
+    from ..compile import owners
+
+    total = sum(ops.values())
+    best = (None, {}, 0.0)
+    for key, rec in records:
+        if module != "?" and rec.get("module") != module:
+            continue
+        by_name = {k.split(":")[0]: owner
+                   for k, owner in owners.flat(rec).items()}
+        phases, covered = {}, 0.0
+        for op, s in ops.items():
+            owner = by_name.get(_op_name(op))
+            if owner is not None:
+                covered += s
+            phase = owner[0] if owner else owners.UNOWNED
+            phases[phase] = phases.get(phase, 0.0) + s
+        share = covered / total if total else 0.0
+        if share > best[2]:
+            best = (key, phases, share)
+    return best if best[2] >= 0.9 else (None, {}, 0.0)
 
 
 def attribute_trace(trace_dir, top_ops=5):
     """Best-effort attribution of an *unsegmented* capture (the
     ``/profilez`` and ``train --profile`` artifacts): device time per
-    hlo module and op class, module names matched to registered
-    programs where the mapping is unambiguous.
+    hlo module and op class, and per phase of the model where a
+    registered program's ``owners`` record covers the module's
+    operations; that record's program names the module.
 
     Raises :class:`TraceError` on an unusable capture — callers on the
     serving path wrap this (an attribution failure must never fail the
     capture that produced the artifact).
     """
+    from ..compile import owners
+
     collected = collect_trace(trace_dir)
-    modmap = _module_map()
+    records = _program_records()
     per_module = {}
     for module, op, s in collected["ops"]:
         m = per_module.setdefault(module, {"seconds": 0.0, "classes": {},
@@ -628,19 +661,24 @@ def attribute_trace(trace_dir, top_ops=5):
         c = op_class(op)
         m["classes"][c] = m["classes"].get(c, 0.0) + s
         m["ops"][op] = m["ops"].get(op, 0.0) + s
+    order = {p: i for i, p in enumerate(owners.PHASES)}
     modules = []
     for name in sorted(per_module,
                        key=lambda n: -per_module[n]["seconds"]):
         m = per_module[name]
-        keys = modmap.get(name, [])
+        program, phases, covered = _phases(name, m["ops"], records)
         modules.append({
             "module": name,
-            "program": keys[0] if len(keys) == 1 else None,
-            "candidates": len(keys),
+            "program": program,
             "seconds": round(m["seconds"], 6),
             "classes": {c: round(s, 6)
                         for c, s in sorted(m["classes"].items(),
                                            key=lambda kv: -kv[1])},
+            "phases": {p: round(s, 6)
+                       for p, s in sorted(phases.items(),
+                                          key=lambda kv: order.get(
+                                              kv[0], len(order)))},
+            "covered": round(covered, 4),
             "top_ops": [{"op": o, "seconds": round(s, 6)}
                         for o, s in sorted(m["ops"].items(),
                                            key=lambda kv: -kv[1])
@@ -761,11 +799,12 @@ def render_attribution(summary, top_modules=6):
         who = m["module"]
         if m.get("program"):
             who += f" -> {m['program']}"
-        elif m.get("candidates", 0) > 1:
-            who += f" (ambiguous: {m['candidates']} programs)"
         classes = ", ".join(
             f"{c} {100 * s / m['seconds']:.0f}%"
             for c, s in list(m["classes"].items())[:4]) if m["seconds"] \
             else "-"
         out.append(f"  {m['seconds'] * 1e3:8.1f} ms  {who}  [{classes}]")
+        if m.get("phases"):
+            out.append("            phases: " + ", ".join(
+                f"{p} {s * 1e3:.1f} ms" for p, s in m["phases"].items()))
     return "\n".join(out)

@@ -12,7 +12,7 @@ Public surface:
   ``RMD_AOT=0`` opts out, ``RMD_AOT_DIR`` relocates the store.
 """
 
-from . import aot
+from . import aot, owners
 from .aot import (
     aot_enabled, artifact_path, disable_aot, enable_aot, fetch, fingerprint,
     publish,
@@ -25,7 +25,7 @@ from .registry import (
 )
 
 __all__ = [
-    "aot",
+    "aot", "owners",
     "Program", "ProgramKey", "ProgramRegistry",
     "effective_args_key", "flag_items", "inference_key", "notes_flag",
     "register_step", "registry", "reset", "shape_signature",

@@ -192,12 +192,15 @@ def fetch(src, dest=None):
     return _copy_artifacts(src, dest or programs_dir(), "fetch")
 
 
-def save(path, key, sig, compiled, trace_counts=None):
+def save(path, key, sig, compiled, trace_counts=None, text_facts=None):
     """Serialize ``compiled`` (a jax.stages.Compiled) to ``path``
     atomically, with the counts its trace noted (``telemetry.note_trace``:
     a boot that loads the executable never traces, and reads them from
-    here). Returns (nbytes, seconds); raises on failure — callers treat a
-    failed save as cosmetic."""
+    here) and what the saving boot read off its compiled text
+    (``text_facts``: ``mosaic_calls`` and the ``owners`` record; a boot
+    that loads the executable need not take a text of 7-16 MB to say them
+    again). Returns (nbytes, seconds); raises on failure — callers treat
+    a failed save as cosmetic."""
     from jax.experimental import serialize_executable
 
     t0 = time.perf_counter()
@@ -217,6 +220,8 @@ def save(path, key, sig, compiled, trace_counts=None):
         "in_tree": in_tree,
         "out_tree": out_tree,
         "trace_counts": dict(trace_counts or {}),
+        # additive: an artifact without it loads as before
+        "text_facts": text_facts,
     }
     buf = io.BytesIO()
     pickle.dump(record, buf, protocol=pickle.HIGHEST_PROTOCOL)
@@ -236,8 +241,8 @@ def load(path, key, sig):
     Returns ``(compiled, status, info)`` where status is one of
     ``hit`` (compiled is live), ``missing``, ``corrupt``, ``version``
     (fingerprint mismatch — stale jax/backend), or ``error``; ``info``
-    carries {bytes, seconds, trace_counts} on a hit and a reason string
-    otherwise.
+    carries {bytes, seconds, trace_counts, text_facts} on a hit and a
+    reason string otherwise.
     Never raises.
     """
     t0 = time.perf_counter()
@@ -277,6 +282,7 @@ def load(path, key, sig):
             "bytes": len(data),
             "seconds": time.perf_counter() - t0,
             "trace_counts": dict(record.get("trace_counts") or {}),
+            "text_facts": record.get("text_facts"),
         }
     except Exception as e:  # noqa: BLE001 - artifacts must never break boot
         return None, "error", f"{type(e).__name__}: {str(e)[:160]}"

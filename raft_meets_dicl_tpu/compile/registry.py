@@ -22,11 +22,12 @@ but never touch the artifact store.
 import math
 import os
 import threading
+import time
 from dataclasses import dataclass, field
 from typing import Tuple
 
 from .. import telemetry
-from . import aot
+from . import aot, owners
 
 _UNSTABLE = "pyid:"
 
@@ -142,14 +143,15 @@ def inference_key(kind, model, model_args, mesh=None, wire=None,
                          **notes_flag(model), **flags))
 
 
-def mosaic_calls(compiled):
-    """Mosaic (Pallas TPU) custom calls in a compiled executable's HLO.
+def mosaic_calls(text):
+    """Mosaic (Pallas TPU) custom calls in a compiled executable's HLO
+    text (``compiled.as_text()``).
 
     The Pallas kernels give way to their XLA references at trace time
     (off-TPU, or when a shape does not fit VMEM) without a word; this
     count, carried by the program's ``aot`` events, is how a run shows
     from the executable itself which form it got."""
-    return compiled.as_text().count('custom_call_target="tpu_custom_call"')
+    return text.count('custom_call_target="tpu_custom_call"')
 
 
 def shape_signature(args):
@@ -201,6 +203,9 @@ class Program:
         self.aot_saves = 0
         self.aot_fallbacks = 0
         self._compiled = {}
+        # per shape signature, whose instruction is whose in the
+        # executable this boot got (``owners.parse``; sink on only)
+        self.owners = {}
         # what the running trace has noted (telemetry.note_trace),
         # keyed by (site, name); reset before each lowering
         self._trace_notes = {}
@@ -304,7 +309,14 @@ class Program:
             compiled, status, info = aot.load(path, self.key, sig)
             if compiled is not None:
                 self.aot_hits += 1
-                self._emit("hit", compiled, bytes=info["bytes"],
+                facts = info["text_facts"]
+                if facts:
+                    # stored with the executable it describes: this boot
+                    # takes no text and parses nothing
+                    facts = dict(facts, owners=dict(
+                        facts["owners"], source="artifact", seconds=0.0))
+                self._emit("hit", compiled, sig, facts,
+                           bytes=info["bytes"],
                            seconds=round(info["seconds"], 4),
                            **info["trace_counts"])
                 self._hand_counts(info["trace_counts"])
@@ -357,15 +369,16 @@ class Program:
                 # the next boot. This boot is already warm through the
                 # cache; the artifact gets written by whichever boot
                 # pays the real compile.
-                self._emit("skip_save", compiled,
+                self._emit("skip_save", compiled, sig,
                            reason="compile served from persistent cache",
                            **counts)
             else:
+                facts = self._text_facts(compiled)
                 try:
                     nbytes, seconds = aot.save(path, self.key, sig,
-                                               compiled, counts)
+                                               compiled, counts, facts)
                     self.aot_saves += 1
-                    self._emit("save", compiled, bytes=nbytes,
+                    self._emit("save", compiled, sig, facts, bytes=nbytes,
                                seconds=round(seconds, 4), **counts)
                 except Exception as e:  # noqa: BLE001 - save is cosmetic
                     self._emit("fallback",
@@ -375,12 +388,40 @@ class Program:
             self._compiled[sig] = compiled
             return compiled
 
-    def _emit(self, event, compiled=None, **fields):
+    def _text_facts(self, compiled):
+        """What this boot reads off an executable's compiled text, taken
+        once: the count of Mosaic calls and the ``owners`` record (which
+        phase of the model each instruction belongs to,
+        ``compile/owners.py``). None with the sink off: no text is
+        taken."""
+        if not telemetry.get().enabled:
+            return None
+        t0 = time.perf_counter()
+        text = compiled.as_text()
+        record = owners.parse(text)
+        # what the text and its parse cost this boot's set-up
+        record.update(source="text",
+                      seconds=round(time.perf_counter() - t0, 4))
+        return {"mosaic_calls": mosaic_calls(text), "owners": record}
+
+    def _emit(self, event, compiled=None, sig=None, facts=None, **fields):
+        """One ``aot`` event; with an executable in hand (``hit``,
+        ``save``, ``skip_save``) and the sink on it carries
+        ``mosaic_calls`` and is followed by the ``owners`` event, both
+        from ``facts``: what the artifact holds of its executable's text
+        (a ``hit`` of an artifact a sink-on boot saved; ``source:
+        "artifact"``), else read off the text now (``"text"``)."""
         tele = telemetry.get()
+        record = None
         if compiled is not None and tele.enabled:
-            fields["mosaic_calls"] = mosaic_calls(compiled)
+            facts = facts or self._text_facts(compiled)
+            fields["mosaic_calls"] = facts["mosaic_calls"]
+            record = self.owners[sig] = facts["owners"]
         tele.emit("aot", event=event, program=self.key.kind,
                   model=self.key.model, **fields)
+        if record is not None:
+            tele.emit("aot", event="owners", program=self.key.kind,
+                      model=self.key.model, **record)
 
     def stats(self):
         return {

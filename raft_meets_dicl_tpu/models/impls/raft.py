@@ -400,31 +400,36 @@ class _RaftStep(nn.Module):
         # per-level list form: the flatten-to-K² + level concat the flat
         # lookup would do costs tile-padding layout copies (~30 ms/step);
         # every consumer contracts the window axes anyway
-        corr = lookup_pyramid_levels(pyramid, coords1, self.corr_radius,
-                                     self.mask_costs)
-        # named so the remat policy can save the lookup output: recomputing
-        # the windowed einsums in the backward pass costs more than the
-        # (B, H/8, W/8, L·(2r+1)²) buffer per iteration it saves
         from jax.ad_checkpoint import checkpoint_name
 
-        corr = [checkpoint_name(lvl, "corr_features") for lvl in corr]
+        # the scopes are metadata for the compiled text's readers
+        # (compile/owners.py): they change no operation
+        with jax.named_scope("lookup"):
+            corr = lookup_pyramid_levels(pyramid, coords1, self.corr_radius,
+                                         self.mask_costs)
+            # named so the remat policy can save the lookup output:
+            # recomputing the windowed einsums in the backward pass costs
+            # more than the (B, H/8, W/8, L·(2r+1)²) buffer per iteration
+            # it saves
+            corr = [checkpoint_name(lvl, "corr_features") for lvl in corr]
 
-        # always *call* the readout so its params exist regardless of the
-        # static switch (per-stage overrides / checkpoint compatibility);
-        # XLA dead-code-eliminates the unused branch
-        reg = make_flow_regression(
-            self.corr_reg_type, self.corr_levels, self.corr_radius,
-            **self.corr_reg_args,
-        )
-        corr_flows = tuple(flow + d for d in reg(corr))
-        if not self.corr_flow:
-            corr_flows = ()
+            # always *call* the readout so its params exist regardless of
+            # the static switch (per-stage overrides / checkpoint
+            # compatibility); XLA dead-code-eliminates the unused branch
+            reg = make_flow_regression(
+                self.corr_reg_type, self.corr_levels, self.corr_radius,
+                **self.corr_reg_args,
+            )
+            corr_flows = tuple(flow + d for d in reg(corr))
+            if not self.corr_flow:
+                corr_flows = ()
 
-        if self.corr_grad_stop:
-            corr = jax.lax.stop_gradient(corr)
+            if self.corr_grad_stop:
+                corr = jax.lax.stop_gradient(corr)
 
-        h, d = BasicUpdateBlock(self.recurrent_channels, dtype=self.dtype)(
-            h, x, corr, flow)
+        with jax.named_scope("update"):
+            h, d = BasicUpdateBlock(self.recurrent_channels,
+                                    dtype=self.dtype)(h, x, corr, flow)
 
         coords1 = coords1 + d
         flow = coords1 - coords0
@@ -475,10 +480,11 @@ class RaftModule(nn.Module):
             norm_type=self.context_norm, dropout=self.dropout, dtype=dt,
         )
 
-        fmap1, fmap2 = fnet((img1, img2), train, frozen_bn)
-        if dt is None:
-            fmap1 = fmap1.astype(jnp.float32)
-            fmap2 = fmap2.astype(jnp.float32)
+        with jax.named_scope("encoders"):
+            fmap1, fmap2 = fnet((img1, img2), train, frozen_bn)
+            if dt is None:
+                fmap1 = fmap1.astype(jnp.float32)
+                fmap2 = fmap2.astype(jnp.float32)
 
         # The all-pairs volume + einsum windowed lookup is the FASTEST
         # measured realization on-chip at training crops (the feature-space
@@ -493,25 +499,28 @@ class RaftModule(nn.Module):
         # einsums dequantize in-register, so the per-iteration HBM stream
         # is the quantized bytes. quant=None is the bit-exact default.
         qmode = quant_ops.normalize_mode(quant)
-        if qmode == "i8":
-            pyramid = tuple(quant_ops.correlation_pyramid_int8(
-                fmap1, fmap2, self.corr_levels, clip=quant_clip))
-        elif qmode == "u8":
-            pyramid = tuple(quant_ops.quantize_pyramid(
-                correlation_pyramid_direct(
-                    fmap1, fmap2, self.corr_levels, dtype=dt),
-                qmode, clip=quant_clip))
-        else:
-            pyramid = tuple(correlation_pyramid_direct(
-                fmap1, fmap2, self.corr_levels, dtype=dt))
+        with jax.named_scope("corr"):
+            if qmode == "i8":
+                pyramid = tuple(quant_ops.correlation_pyramid_int8(
+                    fmap1, fmap2, self.corr_levels, clip=quant_clip))
+            elif qmode == "u8":
+                pyramid = tuple(quant_ops.quantize_pyramid(
+                    correlation_pyramid_direct(
+                        fmap1, fmap2, self.corr_levels, dtype=dt),
+                    qmode, clip=quant_clip))
+            else:
+                pyramid = tuple(correlation_pyramid_direct(
+                    fmap1, fmap2, self.corr_levels, dtype=dt))
 
-        ctx = cnet(img1, train, frozen_bn)
-        h = jnp.tanh(ctx[..., :hdim])
-        x = nn.relu(ctx[..., hdim:])
-        if hidden_init is not None:
-            # continuation rung: re-enter the recurrence with the previous
-            # program's final hidden state (the context tanh is DCE'd)
-            h = hidden_init.astype(h.dtype)
+        with jax.named_scope("encoders"):
+            ctx = cnet(img1, train, frozen_bn)
+            h = jnp.tanh(ctx[..., :hdim])
+            x = nn.relu(ctx[..., hdim:])
+            if hidden_init is not None:
+                # continuation rung: re-enter the recurrence with the
+                # previous program's final hidden state (the context tanh
+                # is DCE'd)
+                h = hidden_init.astype(h.dtype)
 
         b, hc, wc, _ = fmap1.shape
         coords0 = coordinate_grid(b, hc, wc)
@@ -554,9 +563,12 @@ class RaftModule(nn.Module):
             (h, flow), pyramid, x, coords0
         )
 
-        out = upsample_flows(flows, hiddens, (h, flow),
-                             (img1.shape[1], img1.shape[2]), dtype=dt,
-                             upnet=upnet, final_only=final_only)
+        # Up8Network_0 stays the innermost scope of the combine's Mosaic
+        # call: the compiler names the call after it
+        with jax.named_scope("up8"):
+            out = upsample_flows(flows, hiddens, (h, flow),
+                                 (img1.shape[1], img1.shape[2]), dtype=dt,
+                                 upnet=upnet, final_only=final_only)
 
         if corr_flow:
             # corr_flows is a tuple over levels of (iterations, B, H, W, 2);

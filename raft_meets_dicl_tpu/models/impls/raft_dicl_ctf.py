@@ -75,20 +75,25 @@ class _CtfStep(nn.Module):
         prev = jax.lax.stop_gradient(prev)
         coords1 = coords0 + prev
 
-        corr = self.cmod(f1, f2, coords1, dap=self.dap, train=self.train,
-                         frozen_bn=self.frozen_bn)
-        # saved under the remat policy: recomputing the MatchingNet over
-        # all (2r+1)² displacements in the backward pass costs far more
-        # than the (B, H, W, (2r+1)²) cost volume it would save
-        corr = checkpoint_name(corr, "corr_features")
+        # ``lookup`` names the phase for the compiled text's readers
+        # (compile/owners.py); inside it ``matching/sampler`` stays the
+        # sampler call's innermost scope
+        with jax.named_scope("lookup"):
+            corr = self.cmod(f1, f2, coords1, dap=self.dap,
+                             train=self.train, frozen_bn=self.frozen_bn)
+            # saved under the remat policy: recomputing the MatchingNet
+            # over all (2r+1)² displacements in the backward pass costs
+            # far more than the (B, H, W, (2r+1)²) cost volume it would
+            # save
+            corr = checkpoint_name(corr, "corr_features")
 
-        # readout is always computed so the regression params exist
-        # regardless of the static corr_flow switch; XLA removes it when
-        # the output is unused
-        readout = prev + self.reg(corr)
+            # readout is always computed so the regression params exist
+            # regardless of the static corr_flow switch; XLA removes it
+            # when the output is unused
+            readout = prev + self.reg(corr)
 
-        if self.corr_grad_stop:
-            corr = jax.lax.stop_gradient(corr)
+            if self.corr_grad_stop:
+                corr = jax.lax.stop_gradient(corr)
 
         with jax.named_scope("update"):
             h, d = self.update(h, x, corr, prev)
@@ -201,11 +206,12 @@ class RaftPlusDiclCtfModule(nn.Module):
             norm_type=self.context_norm, dropout=0, **ctx_kw,
         )
 
-        f1, f2 = fnet((img1, img2), train, frozen_bn)  # finest-first tuples
-        ctx = cnet(img1, train, frozen_bn)
+        with jax.named_scope("encoders"):
+            f1, f2 = fnet((img1, img2), train, frozen_bn)  # finest-first
+            ctx = cnet(img1, train, frozen_bn)
 
-        hidden = [jnp.tanh(c[..., :hdim]) for c in ctx]
-        context = [nn.relu(c[..., hdim:]) for c in ctx]
+            hidden = [jnp.tanh(c[..., :hdim]) for c in ctx]
+            context = [nn.relu(c[..., hdim:]) for c in ctx]
 
         # shared-or-per-level submodules (reference :40-78); flax modules
         # created once are parameter-shared on repeated calls
@@ -261,15 +267,17 @@ class RaftPlusDiclCtfModule(nn.Module):
                         else jnp.zeros((b, lh, lw, 2), jnp.float32))  # graftlint: disable=f32-literal -- flow fields are f32 by convention
                 h_state = hidden_init.astype(hidden[fine_idx].dtype)
             else:
-                if flow is None:
-                    flow = jnp.zeros((b, lh, lw, 2), jnp.float32)  # graftlint: disable=f32-literal -- flow fields are f32 by convention
-                else:
-                    flow = upsample_flow_2x(flow)
+                # between levels: the bilinear 2x of flow and hidden state
+                with jax.named_scope("up8"):
+                    if flow is None:
+                        flow = jnp.zeros((b, lh, lw, 2), jnp.float32)  # graftlint: disable=f32-literal -- flow fields are f32 by convention
+                    else:
+                        flow = upsample_flow_2x(flow)
 
-                if h_state is None:
-                    h_state = hidden[fine_idx]
-                else:
-                    h_state = hups[lvl](h_state, hidden[fine_idx])
+                    if h_state is None:
+                        h_state = hidden[fine_idx]
+                    else:
+                        h_state = hups[lvl](h_state, hidden[fine_idx])
             if finest:
                 entry_flow = flow
 
@@ -344,12 +352,14 @@ class RaftPlusDiclCtfModule(nn.Module):
                 # (the raft/baseline hoist: one large einsum instead of
                 # n_iter rematerialized ones); always called so its params
                 # exist regardless of ``upnet``
-                flows_flat = flows.reshape(n_iter * b, lh, lw, 2)
-                hidden_flat = hiddens.reshape(n_iter * b, lh, lw, hdim)
-                ups = upnet8(hidden_flat, flows_flat)
-                if not upnet:
-                    ups = 8.0 * interpolate_bilinear(flows_flat, (h, w))
-                ups = ups.reshape(n_iter, b, h, w, 2)
+                # (Up8Network_0 stays the combine call's innermost scope)
+                with jax.named_scope("up8"):
+                    flows_flat = flows.reshape(n_iter * b, lh, lw, 2)
+                    hidden_flat = hiddens.reshape(n_iter * b, lh, lw, hdim)
+                    ups = upnet8(hidden_flat, flows_flat)
+                    if not upnet:
+                        ups = 8.0 * interpolate_bilinear(flows_flat, (h, w))
+                    ups = ups.reshape(n_iter, b, h, w, 2)
                 out_lvl = [ups[i] for i in range(n_iter)]
             else:
                 out_lvl = [flows[i] for i in range(n_iter)]
@@ -385,6 +395,10 @@ class _CtfModel(Model):
     """Shared config wrapper for the three registered level counts."""
 
     levels = None
+    # 1: the phase scopes of PR 37 (``compile/owners.py``): an executable
+    # stored before them, found again by configuration, would say
+    # ``other`` of its encoders and its upsampling
+    notes_revision = 1
 
     @classmethod
     def from_config(cls, cfg):

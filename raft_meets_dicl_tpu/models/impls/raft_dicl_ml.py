@@ -168,12 +168,16 @@ class MlCorrelationModule(nn.Module):
         # the levels are apart only here: one scope and one trace site a
         # sampler call, so that the device trace names the level and each
         # call's path (``sw_fused_calls``) is counted beside the others'
+        # (``level{i}`` stays the call's innermost scope; ``sampler``,
+        # ``mnet`` and ``dap`` round it and below name the owner for the
+        # compiled text's readers, compile/owners.py)
         sample = sample_window_fast if fast else sample_window
         windows = []
-        for i, f2 in enumerate(fmap2):
-            with jax.named_scope(f"level{i}"), \
-                    telemetry.trace_site(f"level{i}"):
-                windows.append(sample(f2, coords / 2 ** i, self.radius))
+        with jax.named_scope("sampler"):
+            for i, f2 in enumerate(fmap2):
+                with jax.named_scope(f"level{i}"), \
+                        telemetry.trace_site(f"level{i}"):
+                    windows.append(sample(f2, coords / 2 ** i, self.radius))
         fmap1 = list(fmap1)
         if self.dtype is not None:
             fmap1 = [f1.astype(self.dtype) for f1 in fmap1]
@@ -185,37 +189,40 @@ class MlCorrelationModule(nn.Module):
             telemetry.note_trace("matching_levels_batched",
                                  self.levels if fast else 1, scale=False)
 
-        if fast:
-            costs = self._batched_costs(mnets, fmap1, windows, train,
-                                        frozen_bn)
-        else:
-            # reference per-level loop (also the init path: creates the
-            # per-level parameters at their checkpoint paths)
-            costs = [mnets[i]((f1, win), train, frozen_bn)
-                     for i, (f1, win) in enumerate(zip(fmap1, windows))]
+        with jax.named_scope("mnet"):
+            if fast:
+                costs = self._batched_costs(mnets, fmap1, windows, train,
+                                            frozen_bn)
+            else:
+                # reference per-level loop (also the init path: creates
+                # the per-level parameters at their checkpoint paths)
+                costs = [mnets[i]((f1, win), train, frozen_bn)
+                         for i, (f1, win) in enumerate(zip(fmap1, windows))]
 
-        out = []
-        for i, cost in enumerate(costs):       # cost: (B, H, W, du, dv)
-            if i + 3 in mask_costs:
-                cost = jnp.zeros_like(cost)
+        with jax.named_scope("dap"):
+            out = []
+            for i, cost in enumerate(costs):       # cost: (B, H, W, du, dv)
+                if i + 3 in mask_costs:
+                    cost = jnp.zeros_like(cost)
 
-            if dap and self.dap_type == "separate":
-                cost = daps[i](cost)
+                if dap and self.dap_type == "separate":
+                    cost = daps[i](cost)
 
-            out.append(cost.reshape(b, h, w, k * k))
+                out.append(cost.reshape(b, h, w, k * k))
 
-        out = jnp.concatenate(out, axis=-1)
+            out = jnp.concatenate(out, axis=-1)
 
-        if self.dap_type == "full":
-            # always create the full-DAP params for config stability
-            full = nn.Conv(
-                self.levels * k * k, (1, 1), use_bias=False,
-                kernel_init=(identity_1x1_init if self.dap_init == "identity"
-                             else nn.initializers.lecun_normal()),
-            )
-            projected = full(out)
-            if dap:
-                out = projected
+            if self.dap_type == "full":
+                # always create the full-DAP params for config stability
+                full = nn.Conv(
+                    self.levels * k * k, (1, 1), use_bias=False,
+                    kernel_init=(identity_1x1_init
+                                 if self.dap_init == "identity"
+                                 else nn.initializers.lecun_normal()),
+                )
+                projected = full(out)
+                if dap:
+                    out = projected
 
         return out
 
@@ -289,12 +296,13 @@ class _MlStep(nn.Module):
             corr = self.cvol(fmap1, fmap2, coords1, dap=self.dap,
                              mask_costs=self.mask_costs, train=self.train,
                              frozen_bn=self.frozen_bn)
-        corr = checkpoint_name(corr, "corr_features")
+        with jax.named_scope("lookup"):
+            corr = checkpoint_name(corr, "corr_features")
 
-        corr_flows = tuple(flow + d for d in self.reg(corr))
+            corr_flows = tuple(flow + d for d in self.reg(corr))
 
-        if self.corr_grad_stop:
-            corr = jax.lax.stop_gradient(corr)
+            if self.corr_grad_stop:
+                corr = jax.lax.stop_gradient(corr)
 
         with jax.named_scope("update"):
             h, d = self.update(h, x, corr, flow)
@@ -499,8 +507,11 @@ class RaftPlusDiclMl(Model):
     type = "raft+dicl/ml"
     # 1: the iterations are one trace site and each level's sampler call
     # its own (``sw_fused_calls`` levels x iterations, the matching's
-    # bytes times the iterations), ``matching_levels_batched`` is noted
-    notes_revision = 1
+    # bytes times the iterations), ``matching_levels_batched`` is noted.
+    # 2: the scopes ``sampler``, ``mnet`` and ``dap`` of PR 37
+    # (``compile/owners.py``): an executable stored before them gives its
+    # matching no owner below ``matching``
+    notes_revision = 2
 
     @classmethod
     def from_config(cls, cfg):

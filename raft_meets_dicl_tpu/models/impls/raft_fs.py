@@ -154,19 +154,20 @@ class _FsStep(nn.Module):
         flow = jax.lax.stop_gradient(flow)
         coords1 = coords0 + flow
 
+        from jax.ad_checkpoint import checkpoint_name
+
         with jax.named_scope("lookup"):
             corr = self._lookup(fmap1, pyramid, coords1)
 
-        # named so the remat policy saves the correlation output: without
-        # it the windowed Pallas kernel's forward runs a second time in
-        # the backward pass, and the volume-lookup einsums recompute
-        # likewise
-        from jax.ad_checkpoint import checkpoint_name
-
-        if isinstance(corr, list):
-            corr = [checkpoint_name(lvl, "corr_features") for lvl in corr]
-        else:
-            corr = checkpoint_name(corr, "corr_features")
+            # named so the remat policy saves the correlation output:
+            # without it the windowed Pallas kernel's forward runs a second
+            # time in the backward pass, and the volume-lookup einsums
+            # recompute likewise
+            if isinstance(corr, list):
+                corr = [checkpoint_name(lvl, "corr_features")
+                        for lvl in corr]
+            else:
+                corr = checkpoint_name(corr, "corr_features")
 
         with jax.named_scope("update"):
             h, d = BasicUpdateBlock(self.recurrent_channels,
@@ -273,10 +274,11 @@ class RaftFsModule(nn.Module):
                 for v in jax.tree_util.tree_leaves(volumes)))
         pyramid = f2_pyramid[:n_windowed] + volumes
 
-        h = jnp.tanh(ctx[..., :hdim])
-        x = nn.relu(ctx[..., hdim:])
-        if hidden_init is not None:
-            h = hidden_init.astype(h.dtype)
+        with jax.named_scope("encoders"):
+            h = jnp.tanh(ctx[..., :hdim])
+            x = nn.relu(ctx[..., hdim:])
+            if hidden_init is not None:
+                h = hidden_init.astype(h.dtype)
 
         b, hc, wc, _ = fmap1.shape
         coords0 = coordinate_grid(b, hc, wc)
@@ -352,8 +354,10 @@ class RaftFs(Model):
     # executable: a revision in the program keys keeps a run from loading
     # an executable stored before the notes existed (ROADMAP D12). 2: the
     # windowed-correlation kernels' block form (PR 36), for the same
-    # reason: the store is keyed by the configuration, not the program
-    notes_revision = 2
+    # reason: the store is keyed by the configuration, not the program.
+    # 3: the phase scopes of PR 37 (``compile/owners.py``): an executable
+    # stored before them says ``other`` of most of its instructions
+    notes_revision = 3
 
     @classmethod
     def from_config(cls, cfg):

@@ -159,14 +159,19 @@ def make_train_step(model, loss_fn, tx, mesh=None, loss_args=None,
     bspec = partition.batch_spec(mesh) if mesh is not None else None
 
     def forward(params, batch_stats, img1, img2, flow, valid, keys=None):
-        if wire is not None:
-            img1, img2, flow, valid = wire.decode(img1, img2, flow, valid)
-        if augment is not None:
-            # on-device augmentation of the decoded (normalized) batch,
-            # keyed per sample — inside the grad-free data path, XLA
-            # schedules it alongside the forward's first convs
-            img1, img2, flow, valid = augment.apply(
-                keys, img1, img2, flow, valid)
+        # the scopes (here, in ``step`` and in the models) name phases for
+        # the compiled text's readers (compile/owners.py): metadata only
+        with jax.named_scope("input"):
+            if wire is not None:
+                img1, img2, flow, valid = wire.decode(img1, img2, flow,
+                                                      valid)
+            if augment is not None:
+                # on-device augmentation of the decoded (normalized)
+                # batch, keyed per sample — inside the grad-free data
+                # path, XLA schedules it alongside the forward's first
+                # convs
+                img1, img2, flow, valid = augment.apply(
+                    keys, img1, img2, flow, valid)
 
         def compute_loss(p):
             out, new_bs = model.apply(
@@ -174,7 +179,9 @@ def make_train_step(model, loss_fn, tx, mesh=None, loss_args=None,
                 img1, img2, train=True, **model_args,
             )
             result = model.get_adapter().wrap_result(out, img1.shape[1:3])
-            l = loss_fn(model, result.output(), flow, valid, **loss_args)
+            with jax.named_scope("loss"):
+                l = loss_fn(model, result.output(), flow, valid,
+                            **loss_args)
             return l, (new_bs, result.final())
 
         return jax.value_and_grad(compute_loss, has_aux=True)(params)
@@ -231,60 +238,65 @@ def make_train_step(model, loss_fn, tx, mesh=None, loss_args=None,
             loss = lsum / accumulate
             final = finals.reshape((-1,) + finals.shape[2:])
 
-        if gather:
-            # reduce the gradients back onto the param shards; from here
-            # on the optimizer update is elementwise and shard-local
-            grads = jax.lax.with_sharding_constraint(
-                grads, state_sharding.params)
+        # everything after the gradient: clip, norms, the optax update,
+        # apply_updates, the finiteness keep
+        with jax.named_scope("optimizer"):
+            if gather:
+                # reduce the gradients back onto the param shards; from
+                # here on the optimizer update is elementwise and
+                # shard-local
+                grads = jax.lax.with_sharding_constraint(
+                    grads, state_sharding.params)
 
-        updates, new_opt = tx.update(grads, state.opt_state, state.params)
-        if external_lr:
-            updates = jax.tree.map(lambda u: -lr * u, updates)
-        new_params = optax.apply_updates(state.params, updates)
+            updates, new_opt = tx.update(grads, state.opt_state,
+                                         state.params)
+            if external_lr:
+                updates = jax.tree.map(lambda u: -lr * u, updates)
+            new_params = optax.apply_updates(state.params, updates)
 
-        finite = jnp.all(jnp.isfinite(final))
-        nf_count = state.nonfinite_count
+            finite = jnp.all(jnp.isfinite(final))
+            nf_count = state.nonfinite_count
 
-        if guard:
-            # the update tree is where every poison ends up (NaN grads ->
-            # NaN moments -> NaN updates; NaN lr -> NaN updates), so one
-            # reduce over it catches grad/optimizer/lr poison before the
-            # params do — checking it alongside the flow keeps batch_stats
-            # poison (via a NaN loss/forward) covered too
-            ok = finite
-            for leaf in jax.tree.leaves(updates):
-                ok &= jnp.all(jnp.isfinite(leaf))
+            if guard:
+                # the update tree is where every poison ends up (NaN grads
+                # -> NaN moments -> NaN updates; NaN lr -> NaN updates), so
+                # one reduce over it catches grad/optimizer/lr poison before
+                # the params do — checking it alongside the flow keeps
+                # batch_stats poison (via a NaN loss/forward) covered too
+                ok = finite
+                for leaf in jax.tree.leaves(updates):
+                    ok &= jnp.all(jnp.isfinite(leaf))
 
-            def keep(new, old):
-                return jax.tree.map(
-                    lambda n, o: jnp.where(ok, n, o), new, old)
+                def keep(new, old):
+                    return jax.tree.map(
+                        lambda n, o: jnp.where(ok, n, o), new, old)
 
-            new_params = keep(new_params, state.params)
-            new_bs = keep(new_bs, state.batch_stats)
-            new_opt = keep(new_opt, state.opt_state)
-            finite = ok
-            nf_count = nf_count + jnp.where(ok, 0, 1).astype(jnp.int32)
+                new_params = keep(new_params, state.params)
+                new_bs = keep(new_bs, state.batch_stats)
+                new_opt = keep(new_opt, state.opt_state)
+                finite = ok
+                nf_count = nf_count + jnp.where(ok, 0, 1).astype(jnp.int32)
 
-        new_state = state.replace(
-            params=new_params,
-            batch_stats=new_bs,
-            opt_state=new_opt,
-            step=state.step + 1,
-            nonfinite_count=nf_count,
-        )
-        aux = {
-            "loss": loss,
-            "final": final,
-            "finite": finite,
-            "nonfinite_count": nf_count,
-            # in-step global norms: two elementwise reductions fused
-            # into the compiled step, fetched host-side only at the
-            # amortized finite-check cadence (observability gauges)
-            "grad_norm": optax.global_norm(grads),
-            "update_norm": optax.global_norm(updates),
-        }
-        if with_grads:
-            aux["grads"] = grads
+            new_state = state.replace(
+                params=new_params,
+                batch_stats=new_bs,
+                opt_state=new_opt,
+                step=state.step + 1,
+                nonfinite_count=nf_count,
+            )
+            aux = {
+                "loss": loss,
+                "final": final,
+                "finite": finite,
+                "nonfinite_count": nf_count,
+                # in-step global norms: two elementwise reductions fused
+                # into the compiled step, fetched host-side only at the
+                # amortized finite-check cadence (observability gauges)
+                "grad_norm": optax.global_norm(grads),
+                "update_norm": optax.global_norm(updates),
+            }
+            if with_grads:
+                aux["grads"] = grads
         return new_state, aux
 
     if external_lr:
@@ -384,7 +396,8 @@ def inference_step(kind, model, body, key, extra_inputs=0, mesh=None,
         if gather:
             variables = jax.lax.with_sharding_constraint(variables, repl)
         if wire is not None:
-            img1, img2, _, _ = wire.decode(img1, img2)
+            with jax.named_scope("input"):
+                img1, img2, _, _ = wire.decode(img1, img2)
         return body(variables, img1, img2, *extra)
 
     if mesh is None:

@@ -92,7 +92,14 @@ SCHEMA = {
     # and this is how the run shows which form it got. They carry the
     # program's trace-time counts too (see "compile"): a hit reads them
     # from the artifact, so a boot that never traces still says which
-    # path each sampler call took.
+    # path each sampler call took. Each of them is followed by one
+    # event='owners' (PR 37; compile/owners.py): from the same text,
+    # every instruction that runs as a device operation, keyed
+    # name:dtype[dims] as a capture shows it, grouped by phase of the
+    # model, scope and direction, with the counts instructions,
+    # inferred, unowned, the seconds text and parse took this boot and
+    # source: "text", or "artifact" where a hit hands on the record the
+    # saving boot stored with the executable (0.0 seconds, no text taken).
     "aot": {"event"},
     # boot configuration: the effective persistent compile-cache and AOT
     # program directories (instead of silently defaulting), plus the

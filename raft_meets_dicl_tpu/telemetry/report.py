@@ -392,7 +392,9 @@ def aot_stats(events):
                 "aot": e.get("aot"),
                 "prefetch": e.get("prefetch"),
             }
-        elif e["kind"] == "aot":
+        elif e["kind"] == "aot" and e.get("event") != "owners":
+            # (an ``owners`` record's seconds are its parse's, not an
+            # artifact's: the benchmark and /profilez read it)
             key = (e.get("program", "?"), e.get("model", "?"))
             agg = out["programs"].setdefault(key, {
                 "hit": 0, "miss": 0, "save": 0, "fallback": 0,

@@ -186,7 +186,101 @@ print("compiled")
 """
 
 
-def test_every_kernel_family_compiles_for_v5e():
+# Whole train steps of tiny models, for what the compiled text says of
+# its instructions (PR 37): the compiler names a Mosaic call after its
+# innermost scope, three readers of the benchmark tell their kernels by
+# that name (``wcp.N``, ``sampler.N``, ``Up8Network_0.N``), and the phase
+# scopes must not have come between; and the chip's own text, with its
+# prefetch copies and allocation calls, must leave the parser of
+# ``compile/owners.py`` few instructions without an owner. Off the TPU the
+# kernels give way to their XLA twins and there is no call to name: this
+# is the one place where that text is rehearsed without a chip.
+_CHILD_MODELS = r"""
+import os
+import sys
+import time
+sys.path.insert(0, sys.argv[1])
+import jax
+import jax.numpy as jnp
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+
+try:
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+except Exception as e:
+    print(f"no compile-only TPU topology: {type(e).__name__}: {e}")
+    sys.exit(3)
+
+import optax
+import raft_meets_dicl_tpu.models as models
+from raft_meets_dicl_tpu import parallel
+from raft_meets_dicl_tpu.compile import owners
+
+chip = SingleDeviceSharding(topo.devices[0])
+jax.default_backend = lambda: "tpu"    # trace the on-TPU dispatch
+os.environ["RMD_FS_VOLUME_GIB"] = "0"  # raft/fs: every level on the kernel
+
+
+def spec(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+
+def step_text(kind, parameters, loss, h, w, arguments):
+    cfg = {"name": "tiny", "id": "tiny", "input": None,
+           "model": {"type": kind, "parameters": parameters,
+                     "arguments": arguments},
+           "loss": loss if isinstance(loss, dict) else {"type": loss}}
+    loaded = models.load(cfg)
+    model = loaded.model
+    model.frozen_batchnorm = True
+    variables = jax.eval_shape(
+        lambda a, b: model.init(jax.random.PRNGKey(0), a, b),
+        jnp.zeros((1, h, w, 3)), jnp.zeros((1, h, w, 3)))
+    tx = optax.adamw(1e-3)
+    state = jax.eval_shape(lambda v: parallel.TrainState.create(v, tx),
+                           variables)
+    state = jax.tree.map(lambda x: spec(x.shape, x.dtype), state)
+    step = parallel.make_train_step(model, loaded.loss, tx, external_lr=True)
+    batch = (spec((2, h, w, 3), jnp.float32), spec((2, h, w, 3), jnp.float32),
+             spec((2, h, w, 2), jnp.float32), spec((2, h, w), bool))
+    return step.lower(state, spec((), jnp.float32), *batch).compile().as_text()
+
+
+tiny = {"corr-radius": 2, "corr-channels": 32, "context-channels": 16,
+        "recurrent-channels": 16, "mixed-precision": True}
+cases = [
+    ("raft/baseline", dict(tiny, **{"corr-levels": 2}), "raft/sequence",
+     64, 96, {"iterations": 2}, ("Up8Network_",), ()),
+    ("raft/fs", dict(tiny, **{"corr-levels": 2}), "raft/sequence",
+     64, 96, {"iterations": 2}, ("wcp.", "Up8Network_"), ()),
+    ("raft+dicl/ctf-l3", tiny, {"type": "raft+dicl/mlseq", "arguments": {"alpha": [0.38, 0.6, 1.0]}},
+     64, 128, {"iterations": [1, 1, 2]}, ("sampler.", "Up8Network_"),
+     ("corr",)),        # nothing is built once a step for its look-ups
+]
+for kind, parameters, loss, h, w, arguments, prefixes, absent in cases:
+    t0 = time.time()
+    text = step_text(kind, parameters, loss, h, w, arguments)
+    calls = [line.split(" = ")[0].split("%")[-1].strip()
+             for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls, f"{kind}: no Mosaic call in the compiled step"
+    for name in calls:
+        assert name.startswith(prefixes), (kind, calls)
+    for prefix in prefixes:
+        assert any(n.startswith(prefix) for n in calls), (kind, prefix, calls)
+    record = owners.parse(text)
+    assert record["unowned"] < 0.10 * record["instructions"], \
+        (kind, record["rules"])
+    named = set(record["owners"]) - {owners.OTHER, owners.UNOWNED}
+    assert named == set(owners.PHASES) - {"input", *absent}, \
+        (kind, sorted(named))
+    print(kind, calls, record["rules"], round(time.time() - t0, 1), flush=True)
+print("compiled")
+"""
+
+
+def _compile_only(child, timeout):
     # compile-only: no chip is taken, so libtpu's one-process lock (a
     # stale /tmp/libtpu_lockfile, a neighbour compiling) must not matter
     env = dict(os.environ, JAX_PLATFORMS="cpu",
@@ -194,9 +288,17 @@ def test_every_kernel_family_compiles_for_v5e():
                TPU_ACCELERATOR_TYPE="v5litepod-4",
                ALLOW_MULTIPLE_LIBTPU_LOAD="1")
     proc = subprocess.run(
-        [sys.executable, "-c", _CHILD, str(REPO)], env=env,
-        capture_output=True, text=True, timeout=600)
+        [sys.executable, "-c", child, str(REPO)], env=env,
+        capture_output=True, text=True, timeout=timeout)
     if proc.returncode == 3:
         pytest.skip(proc.stdout.strip().splitlines()[-1])
     assert proc.returncode == 0, proc.stderr[-4000:]
     assert proc.stdout.strip().endswith("compiled")
+
+
+def test_every_kernel_family_compiles_for_v5e():
+    _compile_only(_CHILD, 600)
+
+
+def test_model_steps_name_their_mosaic_calls_and_owners_for_v5e():
+    _compile_only(_CHILD_MODELS, 600)

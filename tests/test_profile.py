@@ -95,7 +95,8 @@ def test_collect_trace_canned_fixture():
     assert classes["reduce"] == pytest.approx(50e-6)
 
 
-def test_attribute_trace_canned_fixture():
+def test_attribute_trace_canned_fixture(monkeypatch):
+    monkeypatch.setattr(prof, "_program_records", lambda: [])
     summary = prof.attribute_trace(CANNED)
     assert summary["source"] == "trace-json"
     assert summary["op_events"] == 9
@@ -352,10 +353,70 @@ def test_publish_metrics_roundtrip():
         pytest.approx(0.006)
 
 
+def _canned_record():
+    """An ``owners`` record for the canned capture's ``jit_step``: every
+    operation but the last reduce has an owner."""
+    return {"module": "jit_step", "owners": {
+        "encoders": {"encoders": {"fwd": ["convolution.2:f32[8,8]"]}},
+        "lookup": {"lookup": {"fwd": ["dot.1:f32[8,8]",
+                                      "gather.4:f32[8,8]"],
+                              "bwd": ["dynamic-update-slice.8:f32[8,8]"]}},
+        "update": {"update": {"fwd": ["add_rsqrt_fusion.5:f32[8]"]}},
+        "optimizer": {"optimizer": {"fwd": ["all-reduce.3:f32[8,8]"]}},
+        "input": {"input": {"fwd": ["infeed.6:f32[8]"]}},
+    }, "inferred_keys": []}
+
+
+def test_attribute_trace_names_program_and_phases_from_the_record(
+        monkeypatch):
+    ops = {o for m, o, _ in prof.collect_trace(CANNED)["ops"]
+           if m == "jit_step"}
+    rec = _canned_record()
+    from raft_meets_dicl_tpu.compile import owners
+    assert {k.split(":")[0] for k in owners.flat(rec)} < ops
+    other = {"module": "jit_step", "owners": {"up8": {"up8": {"fwd": [
+        "convolution.2:f32[8,8]"]}}}, "inferred_keys": []}
+    monkeypatch.setattr(prof, "_program_records", lambda: [
+        ("('eval_step', 'm', ())", other), ("('train_step', 'm', ())", rec)])
+    summary = prof.attribute_trace(CANNED)
+    step, evals = summary["modules"]
+    # the record that covers the module's operations names its program;
+    # one that covers half of them, or another module's, names nothing
+    assert step["program"] == "('train_step', 'm', ())"
+    assert step["covered"] == pytest.approx(1 - 50 / 4040, abs=1e-3)
+    assert step["phases"]["encoders"] == pytest.approx(2000e-6)
+    assert step["phases"]["lookup"] == pytest.approx(1290e-6)
+    assert step["phases"]["unowned"] == pytest.approx(50e-6)
+    assert sum(step["phases"].values()) == pytest.approx(step["seconds"])
+    assert list(step["phases"])[:2] == ["input", "encoders"]
+    assert (evals["program"], evals["phases"]) == (None, {})
+    assert "candidates" not in step
+    text = prof.render_attribution(summary)
+    assert "train_step" in text and "phases: input" in text
+
+
+def test_attribute_trace_reads_the_live_registrys_records():
+    # what Program._emit keeps is what attribute_trace finds
+    from raft_meets_dicl_tpu import compile as programs
+
+    programs.reset()
+    key = programs.ProgramKey("train_step", "canned")
+    prog = programs.register_step("train_step", lambda x: x, key=key)
+    prog.owners[()] = _canned_record()
+    try:
+        assert prof._program_records() == [(key.canonical(),
+                                            _canned_record())]
+        step = prof.attribute_trace(CANNED)["modules"][0]
+        assert step["program"] == key.canonical()
+        assert step["phases"]["update"] == pytest.approx(125e-6)
+    finally:
+        programs.reset()
+
+
 def test_publish_attribution_metrics_roundtrip(monkeypatch):
-    # pin the registry guess empty: earlier test files may have left a
-    # live program named `step`, which would relabel the jit_step row
-    monkeypatch.setattr(prof, "_module_map", lambda: {})
+    # pin the registry's records empty: earlier test files may have left
+    # a live program whose record would relabel the jit_step row
+    monkeypatch.setattr(prof, "_program_records", lambda: [])
     reg = metrics_mod.MetricsRegistry()
     summary = prof.attribute_trace(CANNED)
     prof.publish_attribution_metrics(summary, reg)
@@ -405,9 +466,9 @@ def test_capture_profile_attribution_and_eviction(monkeypatch, tmp_path):
     monkeypatch.setattr("tempfile.tempdir", str(tmp_path))
     canned = {"source": "trace-json", "device_seconds": 0.004,
               "op_events": 9, "modules": [
-                  {"module": "jit_step", "program": None, "candidates": 0,
+                  {"module": "jit_step", "program": None,
                    "seconds": 0.004, "classes": {"conv": 0.002},
-                   "top_ops": []}]}
+                   "phases": {}, "covered": 0.0, "top_ops": []}]}
     monkeypatch.setattr(prof, "attribute_trace", lambda d: canned)
     reg = metrics_mod.MetricsRegistry()
     payload = sidecar.capture_profile(threading.Lock(), 0.1,

@@ -354,7 +354,8 @@ def test_aot_second_boot_emits_no_compile_events(aot_store):
                     and e["label"] == "train_step"]
         assert compiles == []
         aot_events = [e for e in sink.events if e["kind"] == "aot"]
-        assert [e["event"] for e in aot_events] == ["hit"]
+        # the hit, then what its executable's text says of its owners
+        assert [e["event"] for e in aot_events] == ["hit", "owners"]
         assert aot_events[0]["program"] == "train_step"
     finally:
         telemetry.deactivate()

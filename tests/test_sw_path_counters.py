@@ -252,10 +252,10 @@ def test_a_model_that_counts_its_notes_revisions_has_them_in_its_keys():
     from raft_meets_dicl_tpu import models
     from raft_meets_dicl_tpu.models.impls.raft_dicl_ml import RaftPlusDiclMl
 
-    assert RaftPlusDiclMl.notes_revision == 1
+    assert RaftPlusDiclMl.notes_revision == 2
     ml = RaftPlusDiclMl()
     key = programs.inference_key("eval_step", ml, {}, model_id="raft+dicl/ml")
-    assert ("notes", "1") in key.flags
+    assert ("notes", "2") in key.flags
     # every other model's keys are byte for byte what they were
     raft = models.load({"name": "r", "id": "r", "model": {
         "type": "raft/baseline", "parameters": {}}, "loss": {

@@ -186,7 +186,7 @@ def test_counts_outside_a_program_are_dropped(sink):
 
 
 def test_the_models_notes_revision_is_in_its_keys():
-    assert RaftFs.notes_revision == 2
+    assert RaftFs.notes_revision == 3
     key = programs.inference_key("eval_step", RaftFs(), {},
                                  model_id="raft/fs")
-    assert ("notes", "2") in key.flags
+    assert ("notes", "3") in key.flags
