@@ -15,10 +15,41 @@ raft/common.py) returning features at 1/8..1/64.
 from typing import Any, Tuple
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 from ..blocks.raft import ResidualBlock, kaiming_normal
 from ..norm import Norm2d
+
+
+# The TPU compiler lays a convolution's batch on the eight sublanes of a
+# tile, and rewrites every convolution whose batch is under 8 into its
+# space-to-batch form (W cut in eight and folded into the batch). Through a
+# stack of 3x3 convolutions that form pays for a halo exchange a layer, and
+# it cannot carry an instance norm's per-sample statistics at all (mean and
+# 1/sigma are broadcast to full size in float32 and relaid). Forward and
+# backward on one v5e under the bf16 policy (PERF.md section 6, PR 38): a
+# frozen-batch-norm encoder at 6x400x720 43.8 ms as a batch of 6 and 18.9 ms
+# with two images of zeros behind it (19.8 with the conversion switched off:
+# six images take a tile of eight either way); the pyramid at 6x384x704 55.4
+# and 20.1.
+_BATCH_TILE = 8
+
+
+def _fill_batch_tile(x, norm_type, train, frozen_bn):
+    """``x`` with images of zeros behind it up to a full tile of the batch,
+    where that is free: on the TPU, for a batch of 4 to 7 (at least half a
+    tile: the zeros at most double what the encoder keeps for its backward
+    pass; a 1088x1920 pair filled to 8 is twice as fast and 6.4 GiB larger),
+    and unless a live batch norm would count the zeros into its statistics
+    (every other norm here is per sample or frozen, and a convolution does
+    not mix samples: the first ``n`` results are what they were)."""
+    n = x.shape[0]
+    live_batch_stats = norm_type == "batch" and train and not frozen_bn
+    if (jax.default_backend() != "tpu" or live_batch_stats
+            or not _BATCH_TILE // 2 <= n < _BATCH_TILE):
+        return x
+    return jnp.pad(x, ((0, _BATCH_TILE - n),) + ((0, 0),) * (x.ndim - 1))
 
 
 class _Stem(nn.Module):
@@ -66,10 +97,12 @@ class FeatureEncoderS3(nn.Module):
         if paired:
             n = x[0].shape[0]
             x = jnp.concatenate(x, axis=0)
+        batch = x.shape[0]
+        x = _fill_batch_tile(x, self.norm_type, train, frozen_bn)
 
         x = _Stem(self.norm_type, dtype=self.dtype)(x, train, frozen_bn)
         x = nn.Conv(self.output_dim, (1, 1), kernel_init=kaiming_normal,
-                    dtype=self.dtype)(x)
+                    dtype=self.dtype)(x)[:batch]
         if self.dropout > 0:
             x = _drop2d(x, self.dropout, train)
 
@@ -119,6 +152,8 @@ class FeatureEncoderPyramid(nn.Module):
         if paired:
             n = x[0].shape[0]
             x = jnp.concatenate(x, axis=0)
+        batch = x.shape[0]
+        x = _fill_batch_tile(x, self.norm_type, train, frozen_bn)
 
         x = _Stem(self.norm_type, dtype=dt)(x, train, frozen_bn)  # 1/8, 128ch
 
@@ -131,7 +166,7 @@ class FeatureEncoderPyramid(nn.Module):
             out = EncoderOutputNet(self.output_dim,
                                    intermediate_dim=160 + 32 * i,
                                    norm_type=self.norm_type,
-                                   dtype=dt)(x, train, frozen_bn)
+                                   dtype=dt)(x, train, frozen_bn)[:batch]
             if self.dropout > 0:
                 out = _drop2d(out, self.dropout, train)
             outputs.append(out)

@@ -8,20 +8,81 @@ wrapper passes ``train=False``-equivalent ``use_running_average`` into
 ``Norm2d.__call__`` (see models/model.py ``Model.apply``).
 """
 
+from functools import partial
 from typing import Any
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
+from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 NORM_TYPES = ("group", "batch", "instance", "none")
+
+# name of the instance norm's per-sample, per-channel statistics (mean and
+# 1/sigma, f32[N,1,1,C]) for rematerialisation policies that keep by name
+INSTANCE_STATS = "instance_norm_stats"
+
+
+def _float32(x):
+    return x.astype(jnp.promote_types(x.dtype, jnp.float32))
+
+
+def _instance_stats(x32, epsilon):
+    """Mean and ``1/sqrt(var + epsilon)`` over the spatial axes of an NHWC
+    map as ``f32[N,1,1,C]``: float32 sums, flax's fast variance
+    (``E[x²] - E[x]²`` clamped at 0)."""
+    count = x32.shape[1] * x32.shape[2]
+    mean = jnp.sum(x32, (1, 2), keepdims=True) / count
+    mean2 = jnp.sum(x32 * x32, (1, 2), keepdims=True) / count
+    var = jnp.maximum(0.0, mean2 - mean * mean)
+    return mean, lax.rsqrt(var + epsilon)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(1, 2))
+def instance_norm(x, epsilon=1e-5, dtype=None):
+    """Non-affine instance norm of an NHWC map, in the array the
+    convolution wrote: statistics and arithmetic in float32 inside the
+    fusions, result in ``dtype`` (default: the input's).
+
+    The mathematics is flax ``GroupNorm(group_size=1, use_scale=False,
+    use_bias=False)``'s; what differs is what the backward pass is handed:
+    the input as it came, the mean and ``1/sigma``, and nothing of the
+    input's size in float32 (autodiff kept flax's ``[N,H,W,C,1]`` float32
+    squares and ``x - mean``). On the chip the compiler had already fused
+    those away: the two forms run at the same speed there (PERF.md
+    section 6, PR 38)."""
+    return _instance_norm_fwd(x, epsilon, dtype)[0]
+
+
+def _instance_norm_fwd(x, epsilon, dtype):
+    x32 = _float32(x)
+    mean, rstd = _instance_stats(x32, epsilon)
+    mean = checkpoint_name(mean, INSTANCE_STATS)
+    rstd = checkpoint_name(rstd, INSTANCE_STATS)
+    y = (x32 - mean) * rstd
+    return y.astype(dtype or x.dtype), (x, mean, rstd)
+
+
+def _instance_norm_bwd(epsilon, dtype, res, g):
+    x, mean, rstd = res
+    g32 = _float32(g)
+    xhat = (_float32(x) - mean) * rstd
+    m1 = jnp.mean(g32, (1, 2), keepdims=True)
+    m2 = jnp.mean(g32 * xhat, (1, 2), keepdims=True)
+    dx = (g32 - m1 - xhat * m2) * rstd
+    return (dx.astype(x.dtype),)
+
+
+instance_norm.defvjp(_instance_norm_fwd, _instance_norm_bwd)
 
 
 class Norm2d(nn.Module):
     """Dispatches to group/batch/instance/no normalization over NHWC maps.
 
     ``train`` only affects batch norm (running-stats update vs. use).
-    ``dtype`` is the return/compute dtype; flax norm layers compute the
-    statistics in float32 internally regardless.
+    ``dtype`` is the return/compute dtype; the statistics are computed in
+    float32 regardless (inside the flax layers, and in ``instance_norm``).
     """
 
     ty: str
@@ -59,10 +120,7 @@ class Norm2d(nn.Module):
             return bn(x)
         if self.ty == "instance":
             # per-sample, per-channel over spatial dims; non-affine like torch
-            return nn.GroupNorm(
-                num_groups=None, group_size=1, epsilon=1e-5,
-                use_scale=False, use_bias=False, dtype=self.dtype,
-            )(x)
+            return instance_norm(x, 1e-5, self.dtype)
         if self.ty == "none":
             return x
         raise ValueError(f"unknown norm type '{self.ty}'")

@@ -72,18 +72,25 @@ def volume_level_split(coarse_shape, corr_levels, itemsize, budget_gib=None):
 def _keep_convs_and_stats(prim, *_, **__):
     """Remat policy of the two encoders: of an encoder's forward pass the
     backward keeps the convolutions' outputs (compact, in the compute
-    dtype) and the norms' statistics (``reduce_sum``: a few numbers a
-    channel), and recomputes the float32 chains between them.
+    dtype: they are the instance norm's ``x``) and the norms' statistics
+    (``reduce_sum``: the two float32 sums a channel that
+    ``norm.instance_norm`` and a live batch norm take; mean and 1/sigma
+    follow from them), and recomputes the float32 chains between them.
 
-    Left to itself autodiff keeps every norm's full-size float32
-    intermediates, for all three encoder passes and across the whole
-    recurrence: at the resolutions this model exists for they, not the
-    scan, decide the step's peak memory, and reading them back costs
-    more than computing them again from the convolution's output. At b1
-    1088x1920 under the bf16 policy (PERF.md section 6, PR 35, one v5e):
-    15.28 GiB and 996 ms a step with nothing recomputed, 9.40 GiB and
-    1078 ms with the encoders recomputed whole, 8.72 GiB and 827 ms with
-    this policy (the encoders' backward pass 145 ms where it was 333).
+    Left to itself the backward pass keeps, beside each convolution's
+    output, what every layer between two convolutions wrote (the ReLU's
+    mask and result, the residual sums), for all three encoder passes and
+    across the whole recurrence: at the resolutions this model exists for
+    they, not the scan, decide the step's peak memory, and reading them
+    back costs more than computing them again from the convolution's
+    output. At b1 1088x1920 under the bf16 policy, one v5e (PERF.md
+    section 6): 15.28 GiB and 996 ms a step with nothing recomputed (PR
+    35, when the instance norm was flax's ``GroupNorm`` and its float32
+    intermediates were kept too), 9.40 GiB and 1078 ms with the encoders
+    recomputed whole, 8.72 GiB and 827 ms with this policy (the encoders'
+    backward pass 145 ms where it was 333); since PR 36's kernels 638 ms,
+    and 585 ms at 8.96 GiB since PR 38 runs the feature encoder a frame a
+    call (``encoder_ms`` 246.9 -> 194.5).
     """
     return prim.name in ("conv_general_dilated", "reduce_sum")
 
@@ -203,8 +210,9 @@ class RaftFsModule(nn.Module):
         dt = jnp.bfloat16 if self.mixed_precision else None
 
         # both encoders are rematerialised, keeping their convolutions'
-        # outputs: see _keep_convs_and_stats. Module names and parameter
-        # paths are the plain encoders' (checkpoints load unchanged)
+        # outputs and their norms' sums: see _keep_convs_and_stats. Module
+        # names and parameter paths are the plain encoders' (checkpoints
+        # load unchanged)
         encoder = nn.remat(FeatureEncoderS3, static_argnums=(2, 3),
                            policy=_keep_convs_and_stats)
         fnet = encoder(
@@ -217,7 +225,18 @@ class RaftFsModule(nn.Module):
         )
 
         with jax.named_scope("encoders"):
-            fmap1, fmap2 = fnet((img1, img2), train, frozen_bn)
+            if img1.shape[0] == 1 and self.encoder_norm == "instance":
+                # one frame a call: the TPU compiler runs the convolutions
+                # of so small a batch in their space-to-batch form, which
+                # carries the statistics of a batch of one natively and
+                # those of a pair only by broadcasting each mean and 1/sigma
+                # to full size in float32 and relaying it (encoders/raft.py).
+                # Forward and backward at 1088x1920 on one v5e: 181.6 ms
+                # for the pair, 65.3 ms a frame (PERF.md section 6, PR 38)
+                fmap1 = fnet(img1, train, frozen_bn)
+                fmap2 = fnet(img2, train, frozen_bn)
+            else:
+                fmap1, fmap2 = fnet((img1, img2), train, frozen_bn)
             ctx = cnet(img1, train, frozen_bn)
         if dt is None:
             fmap1 = fmap1.astype(jnp.float32)

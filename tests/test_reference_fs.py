@@ -179,15 +179,9 @@ def test_the_references_parameter_tree_is_the_programs(weights):
         "ScanCheckpoint_FsStep_0", "Up8Network_0"}
 
 
-@pytest.mark.parametrize("scale, channels", [(2, 64), (4, 96)],
-                         ids=["half", "quarter"])
-def test_the_encoders_backward_keeps_convolution_outputs_alone(
-        capsys, weights, scale, channels):
-    # the remat policy of raft_fs.py: of the encoders' activations the
-    # backward pass holds the five convolution outputs of a stage, in the
-    # compute dtype, for the pair (fnet) and for frame one (cnet), and no
-    # full-size float32 intermediate of a norm (without the policy there
-    # are a dozen a stage)
+def _kept_by_the_backward(capsys, weights):
+    """What autodiff keeps of a ``raft/fs`` step under the bf16 policy, as
+    ``(dtype[shape], the printed line)``."""
     from jax.ad_checkpoint import print_saved_residuals
 
     spec = _program(_config(True))
@@ -199,12 +193,47 @@ def test_the_encoders_backward_keeps_convolution_outputs_alone(
                                train=True)[0]
         return sum(jnp.abs(o).mean() for o in out)
 
+    capsys.readouterr()
     print_saved_residuals(loss, params["params"])
+    return [(ln.split()[0], ln)
+            for ln in capsys.readouterr().out.splitlines() if ln.strip()]
+
+
+@pytest.mark.parametrize("scale, channels", [(2, 64), (4, 96)],
+                         ids=["half", "quarter"])
+def test_the_encoders_backward_keeps_convolution_outputs_alone(
+        capsys, weights, scale, channels):
+    # the remat policy of raft_fs.py: of the encoders' activations the
+    # backward pass holds the five convolution outputs of a stage, in the
+    # compute dtype, for each frame (fnet, a frame a call at a batch of
+    # one) and for frame one (cnet), and no full-size float32 intermediate
+    # of a norm (without the policy there are a dozen a stage)
     h, w = SIZE[0] // scale, SIZE[1] // scale
-    kept = [ln.split()[0] for ln in capsys.readouterr().out.splitlines()
-            if f",{h},{w},{channels}]" in ln.split()[0]]
-    assert sorted(kept) == sorted(5 * [f"bf16[1,{h},{w},{channels}]"]
-                                  + 5 * [f"bf16[2,{h},{w},{channels}]"])
+    kept = [aval for aval, _ in _kept_by_the_backward(capsys, weights)
+            if f",{h},{w},{channels}]" in aval]
+    assert kept == 15 * [f"bf16[1,{h},{w},{channels}]"]
+
+
+def test_the_encoders_backward_keeps_the_norms_statistics(capsys, weights):
+    # ... and of every instance norm its two float32 sums a channel (the
+    # policy's ``reduce_sum``; mean and 1/sigma follow from them), fifteen
+    # norms a call of fnet and two calls: sixty small arrays, and nothing
+    # of a feature map's size in float32 anywhere in the encoders
+    kept = _kept_by_the_backward(capsys, weights)
+    sums = [aval for aval, _ in kept
+            if aval in ("f32[1,64]", "f32[1,96]", "f32[1,128]")]
+    assert sorted(sums) == sorted(20 * ["f32[1,64]", "f32[1,96]",
+                                        "f32[1,128]"])
+    smallest = SIZE[0] // 8 * SIZE[1] // 8 * 128
+    for aval, line in kept:
+        dtype, dims = aval.rstrip("]").split("[")
+        if "from the argument" in line or not dims:
+            continue
+        dims = [int(d) for d in dims.split(",")]
+        if (dtype == "f32" and len(dims) >= 4
+                and int(np.prod(dims)) >= smallest):
+            assert not any(src in line for src in (
+                "encoders/raft.py", "blocks/raft.py", "norm.py")), line
 
 
 def _window_costs_gather(f1, f2, centres, radius):
