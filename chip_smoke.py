@@ -12,6 +12,12 @@ from a seed and data rendered from a seed (no dataset, no network):
    (batch 4, u8 wire), answering the built-in open-loop client's 32
    requests at 50/s.
 
+3. where the host has four chips, ``main.py train`` once more with no
+   ``--device-ids``: the default ``data=4`` mesh, 12 steps of the same
+   stage at six pairs a chip (cfg/strategy/dev/synth-things-dp4.yaml,
+   a global batch of 24), with the collectives of the compiled step
+   printed. On fewer chips the leg is skipped, and says so.
+
 Each phase is one child process, one after the other: a chip belongs to
 one process at a time, so this parent never imports jax. What the phases
 did is read back from the run's own records — the telemetry event stream
@@ -47,6 +53,7 @@ REPO = Path(__file__).resolve().parent
 STEPS = 12          # > RMD_FINITE_CHECK_EVERY: one mid-run loss sample
 REQUESTS = 32
 BUDGET_S = 1150     # the whole run, compilation included
+MESH_BUDGET_S = 600  # more for the four-chip leg: its step compiles for four minutes
 
 
 class Failed(Exception):
@@ -134,15 +141,13 @@ def program_record(events, program):
     }
 
 
-def train_phase(out, deadline):
-    run_phase("train", [
-        "main.py", "train", "-m", "cfg/model/raft-baseline.yaml",
-        "-d", "cfg/strategy/dev/synth-things.yaml",
-        "-s", "cfg/seeds/fixed.yaml",
-        "--device", "tpu", "--device-ids", "0",
-        "--limit-steps", str(STEPS), "-o", str(out / "train"),
-    ], out / "train.log", deadline)
-    runs = sorted((out / "train").iterdir())
+def read_train_run(out_dir, mosaic_what):
+    """What both training legs check of a run's records: it ran on the
+    chip, every step completed, the loss is finite, the step compiled at
+    most once and holds the Up8 kernels (forward and backward,
+    ops/pallas._combine), the peak device memory was reported. Returns
+    the run's events and its record."""
+    runs = sorted(out_dir.iterdir())
     events = read_events(runs[-1] / "events.jsonl")
 
     start = first(events, "run_start")
@@ -158,10 +163,9 @@ def train_phase(out, deadline):
         f"losses sampled from the event stream: {losses}")
 
     program = program_record(events, "train_step")
-    # the Up8 convex combine, forward and backward (ops/pallas._combine)
     check(program["mosaic_calls"] >= 2,
-          f"train_step holds {program['mosaic_calls']} Mosaic calls: the "
-          f"Up8 kernels gave way to their XLA reference")
+          f"train_step holds {program['mosaic_calls']} Mosaic calls: "
+          f"{mosaic_what}")
     memory = [e for e in events if e["kind"] == "memory"]
     check(memory and memory[-1].get("device_peak_gib", 0) > 0,
           f"no peak device memory reported: {memory}")
@@ -174,7 +178,57 @@ def train_phase(out, deadline):
         "steady_step_s": round(statistics.median(steady), 4),
         "device_peak_gib": memory[-1]["device_peak_gib"],
     }
+    return events, device, record
+
+
+def train_phase(out, deadline):
+    run_phase("train", [
+        "main.py", "train", "-m", "cfg/model/raft-baseline.yaml",
+        "-d", "cfg/strategy/dev/synth-things.yaml",
+        "-s", "cfg/seeds/fixed.yaml",
+        "--device", "tpu", "--device-ids", "0",
+        "--limit-steps", str(STEPS), "-o", str(out / "train"),
+    ], out / "train.log", deadline)
+    _, device, record = read_train_run(
+        out / "train",
+        "the Up8 kernels gave way to their XLA reference")
     print(f"[train] {json.dumps(record)}", flush=True)
+    return device, record
+
+
+MESH_CHIPS = 4
+
+
+def mesh_phase(out, deadline):
+    """The data-parallel leg: the same step over every chip of the host,
+    the mesh `main.py train` builds by default."""
+    run_phase("mesh", [
+        "main.py", "train", "-m", "cfg/model/raft-baseline.yaml",
+        "-d", "cfg/strategy/dev/synth-things-dp4.yaml",
+        "-s", "cfg/seeds/fixed.yaml", "--device", "tpu",
+        "--limit-steps", str(STEPS), "-o", str(out / "mesh"),
+    ], out / "mesh.log", deadline)
+    events, device, record = read_train_run(
+        out / "mesh", "the Up8 kernels are not in the partitioned step")
+
+    check(record["devices_used"] == MESH_CHIPS,
+          f"the mesh leg used {record['devices_used']} devices")
+    sharding = first(events, "sharding")
+    check(sharding["mesh"] == {"data": MESH_CHIPS},
+          f"mesh {sharding['mesh']}, not data={MESH_CHIPS}")
+    steps = [e for e in events if e["kind"] == "step"]
+    check(all(e.get("devices") == MESH_CHIPS and e["batch"] == 24
+              for e in steps), "a step that did not feed four chips")
+    (held,) = [e for e in events if e["kind"] == "aot"
+               and e.get("program") == "train_step"
+               and e["event"] in ("hit", "save", "skip_save")]
+    said = held.get("collectives")
+    check(held.get("mesh") == {"data": MESH_CHIPS} and said
+          and said["counts"].get("all-reduce", 0) >= 1,
+          f"the mesh step says nothing of its collectives: {held}")
+
+    record.update(mesh=sharding["mesh"], collectives=said)
+    print(f"[mesh] {json.dumps(record)}", flush=True)
     return device, record
 
 
@@ -238,12 +292,20 @@ def main(argv):
         served_on, serve = serve_phase(out, deadline)
         check(served_on == device,
               f"train ran on {device}, serve on {served_on}")
+        mesh = None
+        if device["count"] >= MESH_CHIPS:
+            meshed_on, mesh = mesh_phase(out, deadline + MESH_BUDGET_S)
+            check(meshed_on == device,
+                  f"train ran on {device}, the mesh leg on {meshed_on}")
+        else:
+            print(f"[mesh] skipped: {device['count']} chip(s) here, the "
+                  f"data-parallel leg needs {MESH_CHIPS}", flush=True)
     except Failed as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
         return 1
 
     summary = {"versions": versions, "train": train, "serve": serve,
-               "wall_s": round(time.monotonic() - t0, 1)}
+               "mesh": mesh, "wall_s": round(time.monotonic() - t0, 1)}
     (out / "summary.json").write_text(json.dumps(summary, indent=1))
     print(f"[total] {summary['wall_s']} s, records in {out}", flush=True)
     print(json.dumps({"ok": True, "device": device}))

@@ -44,6 +44,15 @@ SCOPES = {
     "optimizer": "optimizer",   # everything after the gradient
 }
 
+# what the partitioner put in, not the model: every instruction whose opcode
+# is a collective, whatever name stack it inherited from its operand (a
+# gradient's all-reduce carries the backward convolution's, the pair
+# concatenation's all-to-all the encoder's). A one-chip program holds none.
+COLLECTIVE = "collective"
+_COLLECTIVE_OPS = ("all-reduce", "all-gather", "all-to-all",
+                   "reduce-scatter", "collective-permute",
+                   "collective-broadcast")
+
 OTHER = "other"         # named by the program, by no scope of the table
 UNOWNED = "unowned"     # named by nothing the rules can reach
 
@@ -95,6 +104,28 @@ def owner_of(op_name):
     return OTHER, first or "", direction
 
 
+def _base(opcode):
+    return opcode.removesuffix("-start").removesuffix("-done")
+
+
+def _is_collective(opcode):
+    return _base(opcode) in _COLLECTIVE_OPS
+
+
+def _wrapped_collective(text, spans, called):
+    """The collective an ``async-start`` / ``-done`` wraps, if it wraps
+    one: the opcode of its called computation's root."""
+    if not called or called[0] not in spans:
+        return None
+    start, end = spans[called[0]]
+    for line in text[start:end].split("\n"):
+        if line.lstrip().startswith("ROOT "):
+            op = _OPCODE.search(line.split(" = ", 1)[-1])
+            if op and _is_collective(op.group(1)):
+                return _base(op.group(1))
+    return None
+
+
 def _computations(text):
     """``{name: (start, end)}`` spans of the computations' bodies, and
     the entry's name."""
@@ -128,6 +159,11 @@ def parse(text):
     wrapped ones) a key and an owner, resolved in this order, the rule
     that fired kept as a count:
 
+    - ``collective``: an instruction whose opcode is a collective
+      (``all-reduce``, ``all-gather``, ``all-to-all``, ``reduce-scatter``,
+      ``collective-permute``, their ``-start`` / ``-done`` halves) is of
+      the phase ``collective`` whatever its name stack, its scope the
+      opcode;
     - ``own``: the instruction's own ``op_name``;
     - ``fusion``: for a fusion without one, the commonest owner among the
       instructions of the computation it calls;
@@ -179,7 +215,15 @@ def parse(text):
                 and "/" in meta.group(1) else None
             rule = "own" if owner else None
             called = _CALLED.findall(rest)
-            if opcode == "fusion" or opcode.startswith("async"):
+            wrapped = _wrapped_collective(text, spans, called) \
+                if opcode.startswith("async") else None
+            if wrapped or _is_collective(opcode):
+                # the opcode decides, not the name stack: the direction is
+                # still the operand's (a gradient's reduce is ``bwd``)
+                owner = (COLLECTIVE, wrapped or _base(opcode),
+                         owner[2] if owner else "fwd")
+                rule = "collective"
+            elif opcode == "fusion" or opcode.startswith("async"):
                 # an asynchronous slice's wrapped computation is, like a
                 # fused one, no sequence of operations of its own
                 if owner is None and called and called[0] in spans:
@@ -255,7 +299,7 @@ def parse(text):
             if owner is None:
                 owner, rule = (UNOWNED, "", "fwd"), "unowned"
             rules[rule] += 1
-            if rule not in ("own", "fusion", "unowned"):
+            if rule not in ("own", "fusion", "collective", "unowned"):
                 inferred_keys.append(key)
             phase, scope, direction = owner
             owners.setdefault(phase, {}).setdefault(scope, {}).setdefault(
@@ -278,7 +322,12 @@ def _walk(row, users, side, depth, found):
     ``row``, None for one that has none yet, looking through the free
     instructions between (a bitcast, a tuple element)."""
     for n in (users.get(row[0], ()) if side == "user" else row[5]):
-        if n[3] is not None:
+        if n[3] is not None and n[3][0] == COLLECTIVE:
+            # a collective moves a value, it does not own its neighbours:
+            # they take what lies on its other side
+            if depth:
+                _walk(n, users, side, depth - 1, found)
+        elif n[3] is not None:
             found.append(n[3])
         elif n[1] in _SEE_THROUGH and depth:
             _walk(n, users, side, depth - 1, found)

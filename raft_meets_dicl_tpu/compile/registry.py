@@ -206,6 +206,9 @@ class Program:
         # per shape signature, whose instruction is whose in the
         # executable this boot got (``owners.parse``; sink on only)
         self.owners = {}
+        # the mesh a partitioned step was built over, ``{axis: size}``
+        # (the step builders set it); None for a one-device program
+        self.mesh_axes = None
         # what the running trace has noted (telemetry.note_trace),
         # keyed by (site, name); reset before each lowering
         self._trace_notes = {}
@@ -390,10 +393,11 @@ class Program:
 
     def _text_facts(self, compiled):
         """What this boot reads off an executable's compiled text, taken
-        once: the count of Mosaic calls and the ``owners`` record (which
+        once: the count of Mosaic calls, the ``owners`` record (which
         phase of the model each instruction belongs to,
-        ``compile/owners.py``). None with the sink off: no text is
-        taken."""
+        ``compile/owners.py``) and, of a partitioned program, its
+        ``collectives`` (``analysis/collectives.py``). None with the sink
+        off: no text is taken."""
         if not telemetry.get().enabled:
             return None
         t0 = time.perf_counter()
@@ -402,13 +406,25 @@ class Program:
         # what the text and its parse cost this boot's set-up
         record.update(source="text",
                       seconds=round(time.perf_counter() - t0, 4))
-        return {"mosaic_calls": mosaic_calls(text), "owners": record}
+        facts = {"mosaic_calls": mosaic_calls(text), "owners": record}
+        if record["rules"].get("collective"):
+            # what the partitioner put in, from the same text: count and
+            # result bytes (one chip's) by kind. A one-device program
+            # holds none and says nothing
+            from ..analysis import collectives
+
+            said = collectives.summarize_schedule(
+                collectives.parse_schedule(text))
+            facts["collectives"] = {k: said[k] for k in
+                                    ("counts", "bytes", "total_bytes")}
+        return facts
 
     def _emit(self, event, compiled=None, sig=None, facts=None, **fields):
         """One ``aot`` event; with an executable in hand (``hit``,
         ``save``, ``skip_save``) and the sink on it carries
-        ``mosaic_calls`` and is followed by the ``owners`` event, both
-        from ``facts``: what the artifact holds of its executable's text
+        ``mosaic_calls`` (a partitioned program's ``collectives`` too)
+        and is followed by the ``owners`` event, all from ``facts``: what
+        the artifact holds of its executable's text
         (a ``hit`` of an artifact a sink-on boot saved; ``source:
         "artifact"``), else read off the text now (``"text"``)."""
         tele = telemetry.get()
@@ -416,7 +432,11 @@ class Program:
         if compiled is not None and tele.enabled:
             facts = facts or self._text_facts(compiled)
             fields["mosaic_calls"] = facts["mosaic_calls"]
+            if "collectives" in facts:
+                fields["collectives"] = facts["collectives"]
             record = self.owners[sig] = facts["owners"]
+        if self.mesh_axes:
+            fields["mesh"] = self.mesh_axes
         tele.emit("aot", event=event, program=self.key.kind,
                   model=self.key.model, **fields)
         if record is not None:

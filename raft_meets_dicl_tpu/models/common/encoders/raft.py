@@ -36,6 +36,19 @@ from ..norm import Norm2d
 _BATCH_TILE = 8
 
 
+def _batch_shards(n):
+    """Over how many chips a batch of ``n`` is split in the step being
+    traced (``parallel.mesh.traced_under``: the step builders split the
+    leading dimension over every mesh axis), and that mesh; ``(1, None)``
+    for a single-device step."""
+    from ....parallel.mesh import traced_mesh
+
+    mesh = traced_mesh()
+    if mesh is None or mesh.devices.size == 1 or n % mesh.devices.size:
+        return 1, None
+    return mesh.devices.size, mesh
+
+
 def _fill_batch_tile(x, norm_type, train, frozen_bn):
     """``x`` with images of zeros behind it up to a full tile of the batch,
     where that is free: on the TPU, for a batch of 4 to 7 (at least half a
@@ -43,13 +56,38 @@ def _fill_batch_tile(x, norm_type, train, frozen_bn):
     pass; a 1088x1920 pair filled to 8 is twice as fast and 6.4 GiB larger),
     and unless a live batch norm would count the zeros into its statistics
     (every other norm here is per sample or frozen, and a convolution does
-    not mix samples: the first ``n`` results are what they were)."""
-    n = x.shape[0]
+    not mix samples: the first ``n`` results are what they were).
+
+    The batch that counts is a chip's: under a mesh the trace sees the
+    global batch and the compiler converts the slice each chip is handed,
+    so every chip's slice is filled where it lies (a ``shard_map``: nothing
+    moves between chips). ``_drop_fill`` takes the zeros' results off."""
+    shards, mesh = _batch_shards(x.shape[0])
+    n = x.shape[0] // shards
     live_batch_stats = norm_type == "batch" and train and not frozen_bn
     if (jax.default_backend() != "tpu" or live_batch_stats
             or not _BATCH_TILE // 2 <= n < _BATCH_TILE):
         return x
-    return jnp.pad(x, ((0, _BATCH_TILE - n),) + ((0, 0),) * (x.ndim - 1))
+
+    def fill(x):
+        return jnp.pad(x, ((0, _BATCH_TILE - n),) + ((0, 0),) * (x.ndim - 1))
+
+    return fill(x) if mesh is None else _on_batch_shards(fill, mesh)(x)
+
+
+def _drop_fill(x, batch):
+    """The results of the ``batch`` images that ``_fill_batch_tile`` was
+    given, in their order."""
+    shards, mesh = _batch_shards(batch)
+    if mesh is None or x.shape[0] == batch:
+        return x[:batch]
+    n = batch // shards
+    return _on_batch_shards(lambda x: x[:n], mesh)(x)
+
+
+def _on_batch_shards(fn, mesh):
+    lead = jax.sharding.PartitionSpec(tuple(mesh.axis_names))
+    return jax.shard_map(fn, mesh=mesh, in_specs=lead, out_specs=lead)
 
 
 class _Stem(nn.Module):
@@ -101,8 +139,9 @@ class FeatureEncoderS3(nn.Module):
         x = _fill_batch_tile(x, self.norm_type, train, frozen_bn)
 
         x = _Stem(self.norm_type, dtype=self.dtype)(x, train, frozen_bn)
-        x = nn.Conv(self.output_dim, (1, 1), kernel_init=kaiming_normal,
-                    dtype=self.dtype)(x)[:batch]
+        x = _drop_fill(nn.Conv(self.output_dim, (1, 1),
+                               kernel_init=kaiming_normal,
+                               dtype=self.dtype)(x), batch)
         if self.dropout > 0:
             x = _drop2d(x, self.dropout, train)
 
@@ -163,10 +202,11 @@ class FeatureEncoderPyramid(nn.Module):
         # p36.py:52-55)
         outputs = []
         for i in range(self.levels):
-            out = EncoderOutputNet(self.output_dim,
-                                   intermediate_dim=160 + 32 * i,
-                                   norm_type=self.norm_type,
-                                   dtype=dt)(x, train, frozen_bn)[:batch]
+            out = _drop_fill(
+                EncoderOutputNet(self.output_dim,
+                                 intermediate_dim=160 + 32 * i,
+                                 norm_type=self.norm_type,
+                                 dtype=dt)(x, train, frozen_bn), batch)
             if self.dropout > 0:
                 out = _drop2d(out, self.dropout, train)
             outputs.append(out)

@@ -47,8 +47,8 @@ def _per_shard(fn):
             or jax.default_backend() != "tpu"):
         return fn
     lead = P(tuple(mesh.axis_names))
-    return jax.shard_map(fn, mesh=mesh, in_specs=lead, out_specs=lead,
-                         check_vma=False)
+    return jax.shard_map(_under_its_scope(fn), mesh=mesh, in_specs=lead,
+                         out_specs=lead, check_vma=False)
 
 
 _TILE = 512
@@ -1425,3 +1425,25 @@ def convex_combine_8x(mask_logits, win, temperature=4.0):
     out = _per_shard(lambda a, b: _combine(a, b, 1.0 / temperature))(
         logits2d, win2d)
     return out.reshape(*lead, _C * _S)
+
+
+def _under_its_scope(fn):
+    """``fn`` under the innermost named scope of the trace that is running,
+    stated again. A compiled kernel is called after the scope that holds
+    its ``pallas_call`` (``Up8Network_0.2``, ``wcp.27``, ``sampler.3``):
+    that is how a capture's readers tell the families apart, and inside
+    ``_per_shard``'s map that scope would be ``shard_map``. (At the end of
+    the file: the kernels' source lines are part of their compiled text, so
+    nothing is added above them for the sake of a mesh step.)"""
+    from jax.extend import source_info_util
+
+    scopes = [el.name for el in source_info_util.current_name_stack().stack
+              if type(el).__name__ == "Scope"]
+    if not scopes:
+        return fn
+
+    def named(*args):
+        with jax.named_scope(scopes[-1]):
+            return fn(*args)
+
+    return named

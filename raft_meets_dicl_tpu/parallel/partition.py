@@ -26,8 +26,10 @@ the numerically-proven pure data-parallel compute graph, with the batch
 split over every mesh device — then reduces the gradients back onto the
 param shards for the (elementwise, shard-local) optimizer update. Per
 chip, params and both Adam moments shrink by the model-axis factor at
-rest; the transient gather is one params-sized buffer that XLA overlaps
-with compute. (Letting GSPMD propagate the model axis through the conv
+rest; the transient gather is one params-sized buffer (whether the chip's
+compiler hides it behind compute has not been measured: this layout has
+only run on virtual CPU devices; the replicated 1-D layout is what the
+benchmark's four-chip cell runs, PERF.md section 5). (Letting GSPMD propagate the model axis through the conv
 compute itself was measured numerically unsafe on the XLA CPU backend —
 the partially-replicated concat/all-reduce path miscompiles — and the
 gather-compute form is what the per-chip HBM motivation needs anyway.)

@@ -189,7 +189,8 @@ def make_train_step(model, loss_fn, tx, mesh=None, loss_args=None,
     def step(state, lr, img1, img2, flow, valid, sample_ids=None,
              epoch=None):
         # ZeRO-style gather: one all-gather of the sharded params for the
-        # compute graph; XLA overlaps it with the first encoder convs
+        # compute graph (its overlap with the first encoder convs is the
+        # compiler's to schedule; not measured on chips)
         params = (jax.lax.with_sharding_constraint(state.params, repl_one)
                   if gather else state.params)
 
@@ -357,6 +358,7 @@ def make_train_step(model, loss_fn, tx, mesh=None, loss_args=None,
             out_shardings=(state_in, aux_shardings),
             donate_argnums=(0,) if donate else (),
         )), key=key)
+    prog.mesh_axes = dict(mesh.shape)
     if augment is not None:
         prog.augment = augment
     return prog
@@ -418,6 +420,8 @@ def inference_step(kind, model, body, key, extra_inputs=0, mesh=None,
     step = register_step(kind, step, key=key)
     # a ``pyid:`` key names the model by its id: keep it unique
     step._refs = (model,)
+    if mesh is not None:
+        step.mesh_axes = dict(mesh.shape)
     for name, value in (attrs or {}).items():
         setattr(step, name, value)
     return step

@@ -1203,6 +1203,10 @@ class TrainingContext:
             # the inspector's callbacks lie inside ``synced`` → ``done``
             phases, self._step_phases = self._step_phases, {}
             fields = {"put": rec["put"]} if "put" in rec else {}
+            if fields and self.mesh is not None:
+                # one put a step however many chips it feeds
+                # (``shard_batch``: one device_put with a sharded layout)
+                fields["devices"] = int(self.mesh.devices.size)
             tele.step_event(step, phases=phases, marks=rec["marks"],
                             stage=stage.index, epoch=epoch,
                             batch=stage.data.batch_size, **fields)

@@ -62,7 +62,8 @@ SCHEMA = {
     "device_sync": {"step", "seconds"},
     # a backend compile; with the counts the owning Program's trace
     # noted (``note_trace``: sw_fused_calls, sw_fallback_calls,
-    # matching_volume_bytes, matching_levels_batched) when it noted any
+    # matching_volume_bytes, matching_levels_batched) when it noted any,
+    # and the ``mesh`` ({axis: size}) of a step built over one
     "compile": {"label", "seconds"},
     "cache": {"event"},
     "memory": {"host_rss_gib", "live_arrays"},
@@ -92,7 +93,11 @@ SCHEMA = {
     # and this is how the run shows which form it got. They carry the
     # program's trace-time counts too (see "compile"): a hit reads them
     # from the artifact, so a boot that never traces still says which
-    # path each sampler call took. Each of them is followed by one
+    # path each sampler call took. Of a step built over a mesh they carry
+    # ``mesh`` ({axis: size}) and ``collectives`` (PR 39: counts, bytes
+    # (one chip's result buffers) and total_bytes by kind, from
+    # analysis/collectives.parse_schedule on the same text as the owners
+    # record, stored with the artifact). Each of them is followed by one
     # event='owners' (PR 37; compile/owners.py): from the same text,
     # every instruction that runs as a device operation, keyed
     # name:dtype[dims] as a capture shows it, grouped by phase of the
@@ -725,6 +730,10 @@ def install_listeners():
         if not _active.enabled:
             return
         counts = program.trace_counts() if program is not None else {}
+        if getattr(program, "mesh_axes", None):
+            # a partitioned step says over what (its collectives need the
+            # compiled text: on the ``aot`` event that holds the executable)
+            counts["mesh"] = program.mesh_axes
         _active.emit("compile",
                      label=getattr(_jit_label, "value", None) or "jit",
                      seconds=round(float(duration), 6), **counts)

@@ -125,6 +125,83 @@ def test_rule_fusion_takes_its_computations_commonest_owner():
     assert rec["instructions"] == 1
 
 
+REDUCER = ("%region_0.4 (a.1: f32[], b.1: f32[]) -> f32[] {\n"
+           "  %a.1 = f32[] parameter(0)\n  %b.1 = f32[] parameter(1)\n"
+           "  ROOT %add.9 = f32[] add(%a.1, %b.1)\n}")
+
+
+@pytest.mark.parametrize("line,key,scope,direction", [
+    # a gradient's all-reduce inherits the backward convolution's name stack
+    (f"ROOT %all-reduce.3 = f32[4,8]{{1,0}} all-reduce(%p0.1), channel_id=1, "
+     f"replica_groups={{{{0,1,2,3}}}}, use_global_device_ids=true, "
+     f"to_apply=%region_0.4, "
+     f"{_meta(BACK + '/encoders/Conv_0/conv_general_dilated')}",
+     "all-reduce.3:f32[4,8]", "all-reduce", "bwd"),
+    # the pair concatenation's reshard, named by the encoder
+    (f"ROOT %all-to-all.2 = f32[4,8]{{1,0}} all-to-all(%p0.1), channel_id=2, "
+     f"replica_groups={{{{0,1,2,3}}}}, dimensions={{0}}, "
+     f"{_meta(STACK + '/encoders/FeatureEncoderS3_0/concatenate')}",
+     "all-to-all.2:f32[4,8]", "all-to-all", "fwd"),
+    # the asynchronous halves, as the TPU's scheduler writes them
+    ("ROOT %all-reduce-start.1 = f32[4,8]{1,0} all-reduce-start(%p0.1), "
+     "channel_id=3, replica_groups={{0,1,2,3}}, to_apply=%region_0.4",
+     "all-reduce-start.1:f32[4,8]", "all-reduce", "fwd"),
+    ("ROOT %collective-permute-done.5 = f32[4,8]{1,0} "
+     f"collective-permute-done(%p0.1), {_meta('jit(step)/optimizer/mul')}",
+     "collective-permute-done.5:f32[4,8]", "collective-permute", "fwd"),
+    ("ROOT %all-gather.7 = f32[4,8]{1,0} all-gather(%p0.1), channel_id=4, "
+     "dimensions={0}", "all-gather.7:f32[4,8]", "all-gather", "fwd"),
+    ("ROOT %reduce-scatter.8 = f32[4,8]{1,0} reduce-scatter(%p0.1), "
+     "channel_id=5, dimensions={0}, to_apply=%region_0.4",
+     "reduce-scatter.8:f32[4,8]", "reduce-scatter", "fwd"),
+], ids=["all-reduce", "all-to-all", "all-reduce-start",
+        "collective-permute-done", "all-gather", "reduce-scatter"])
+def test_rule_collective_whatever_the_name_stack(line, key, scope, direction):
+    rec = owners.parse(_hlo([P0, line], REDUCER))
+    assert _owner(rec, key) == ("collective", scope, direction)
+    assert rec["rules"] == {"collective": 1}
+    assert (rec["instructions"], rec["inferred"], rec["unowned"]) == (1, 0, 0)
+    # the reducer's add is no operation of the program
+    assert "other" not in rec["owners"]
+
+
+def test_rule_collective_under_an_asynchronous_wrapper():
+    wrapped = ("%async_computation.2 (param_0.9: f32[4,8]) -> f32[4,8] {\n"
+               "  %param_0.9 = f32[4,8]{1,0} parameter(0)\n"
+               "  ROOT %all-to-all.4 = f32[4,8]{1,0} all-to-all(%param_0.9), "
+               "channel_id=6, replica_groups={{0,1,2,3}}, dimensions={0}\n}")
+    rec = owners.parse(_hlo([
+        P0,
+        "%async-start.1 = ((f32[4,8]), f32[4,8], u32[]) async-start(%p0.1), "
+        "calls=%async_computation.2",
+        "ROOT %async-done.1 = f32[4,8]{1,0} async-done(%async-start.1), "
+        "calls=%async_computation.2",
+    ], wrapped))
+    flat = owners.flat(rec)
+    assert flat["async-start.1:f32[4,8]"] == ("collective", "all-to-all",
+                                              "fwd")
+    assert flat["async-done.1:f32[4,8]"] == ("collective", "all-to-all",
+                                             "fwd")
+    assert rec["rules"] == {"collective": 2}
+
+
+def test_a_copy_next_to_a_collective_is_not_the_collectives():
+    # the rule is the opcode's: a neighbour still infers from its other side
+    rec = owners.parse(_hlo([
+        P0,
+        f"%mul.2 = f32[4,8]{{1,0}} multiply(%p0.1, %p0.1), "
+        f"{_meta('jit(step)/optimizer/mul')}",
+        "%all-reduce.3 = f32[4,8]{1,0} all-reduce(%mul.2), channel_id=1, "
+        "to_apply=%region_0.4",
+        "ROOT %copy.4 = f32[4,8]{0,1} copy(%all-reduce.3)",
+    ], REDUCER))
+    assert _owner(rec, "all-reduce.3:f32[4,8]")[0] == "collective"
+    assert _owner(rec, "mul.2:f32[4,8]") == ("optimizer", "optimizer", "fwd")
+    # ... and looks through the collective to what produced its operand
+    assert _owner(rec, "copy.4:f32[4,8]") == ("optimizer", "optimizer", "fwd")
+    assert rec["rules"] == {"own": 1, "collective": 1, "producer": 1}
+
+
 def test_rule_copy_belongs_to_its_user():
     rec = owners.parse(_hlo([
         P0,
