@@ -11,7 +11,7 @@ model-input adapter, nowhere else.
 """
 
 from dataclasses import dataclass, field
-from typing import Dict, List, Tuple, Union
+from typing import Dict, List, Optional, Tuple, Union
 
 
 class Collection:
@@ -83,9 +83,13 @@ class Metadata:
     validation (non-finite data); the trainer skips such batches.
     ``original_extents`` tracks the un-padded region ((y0,y1),(x0,x1)) so
     outputs can be cropped back after modulo padding.
+    ``fetch_s`` is written by the loader: the wall seconds its worker spent
+    on ``source[index]`` for this sample (render or decode, adapter and
+    all); it rides with the batch to the step that consumes it.
     """
 
     valid: bool
     dataset_id: str
     sample_id: SampleId
     original_extents: Tuple[Tuple[int, int], Tuple[int, int]]
+    fetch_s: Optional[float] = field(default=None, compare=False, repr=False)

@@ -70,12 +70,12 @@ def _draw_layers(key, h, w, layers, motion, spin, zoom):
 
 
 def _texture(p, p0y, p0x):
-    """Sinusoidal texture in layer-canonical coords (moves with the layer)."""
-    args = (2.0 * jnp.pi * (p["freq"][:, 0, None, None] * p0y[None]
-                            + p["freq"][:, 1, None, None] * p0x[None])
-            + p["phase"][:, None, None])
-    tex = p["color"][:, None, None] + p["amp"][:, None, None] * jnp.sin(args)
-    return jnp.clip(jnp.moveaxis(tex, 0, -1), 0.0, 1.0)
+    """Sinusoidal texture in layer-canonical coords (moves with the layer);
+    ``p`` holds each pixel's own parameters, ``[h, w, 3(, 2)]``."""
+    args = (2.0 * jnp.pi * (p["freq"][..., 0] * p0y[..., None]
+                            + p["freq"][..., 1] * p0x[..., None])
+            + p["phase"])
+    return jnp.clip(p["color"] + p["amp"] * jnp.sin(args), 0.0, 1.0)
 
 
 def _layer_mask(p, p0y, p0x):
@@ -104,14 +104,18 @@ def _pose(p, t):
 
 
 def _frame(bg, lay, t, h, w, layers):
-    """Render frame ``t``: per-pixel topmost-layer index and RGB image."""
+    """Render frame ``t``: per-pixel topmost-layer index and RGB image.
+
+    The owner of each pixel is decided first, with its canonical
+    coordinates; the texture is then evaluated once a pixel with the
+    owner's parameters, not once a layer over the whole frame.
+    """
     py, px = jnp.meshgrid(jnp.arange(h, dtype=jnp.float32),
                           jnp.arange(w, dtype=jnp.float32), indexing="ij")
 
     # background (index 0): translation-only motion, full coverage
-    bg0y = py - t * bg["vel"][0]
-    bg0x = px - t * bg["vel"][1]
-    img = _texture(bg, bg0y, bg0x)
+    own0y = py - t * bg["vel"][0]
+    own0x = px - t * bg["vel"][1]
     own = jnp.zeros((h, w), jnp.int32)
 
     for i in range(layers):
@@ -124,10 +128,14 @@ def _frame(bg, lay, t, h, w, layers):
         p0y = p["c0"][0] + i00 * dy + i01 * dx
         p0x = p["c0"][1] + i10 * dy + i11 * dx
         mask = _layer_mask(p, p0y, p0x)
-        img = jnp.where(mask[..., None], _texture(p, p0y, p0x), img)
+        own0y = jnp.where(mask, p0y, own0y)
+        own0x = jnp.where(mask, p0x, own0x)
         own = jnp.where(mask, i + 1, own)
 
-    return own, img
+    # the owner's texture: row 0 of the table is the background's
+    owner = {k: jnp.concatenate((bg[k][None], lay[k]))[own]
+             for k in ("freq", "phase", "color", "amp")}
+    return own, _texture(owner, own0y, own0x)
 
 
 def _flow_and_valid(bg, lay, own_t, own_next, t, h, w, layers):

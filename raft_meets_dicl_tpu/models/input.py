@@ -10,6 +10,7 @@ GIL) rather than a torch DataLoader with worker processes.
 import concurrent.futures
 import copy
 import threading
+import time
 from dataclasses import replace
 
 import numpy as np
@@ -709,6 +710,17 @@ class Loader:
                 f"{self.bad_sample_budget}): the input data is "
                 "persistently failing to decode") from error
 
+    def _source_item(self, index):
+        """``source[index]``, with the wall seconds it took written on the
+        sample's metadata (``fetch_s``): what one worker pays for one
+        sample, which the step that consumes the batch reports."""
+        t0 = time.perf_counter()
+        sample = self.source[index]
+        seconds = time.perf_counter() - t0
+        for m in sample[4]:
+            m.fetch_s = seconds
+        return sample
+
     def _fetch(self, index, fetch=None, retry_on=Exception):
         """``source[index]`` with bounded retry, then substitution.
 
@@ -721,7 +733,7 @@ class Loader:
         crash.
         """
         index = int(index)
-        fetch = fetch if fetch is not None else self.source.__getitem__
+        fetch = fetch if fetch is not None else self._source_item
         last = None
         for _ in range(self.retries + 1):
             try:

@@ -831,6 +831,9 @@ class TrainingContext:
                 break
             i, (host, dev, meta, strace.put) = nxt
             strace.put_inline = not prefetch
+            fetched = [m.fetch_s for m in meta if m.fetch_s is not None]
+            if fetched:
+                strace.fetch = sum(fetched) / len(fetched)
             strace.mark("data")
 
             log_ = log.new(f"step {self.step}", sep=", ")
@@ -1202,8 +1205,8 @@ class TrainingContext:
             # phases of all of them. Emitted once the step is closed, so
             # the inspector's callbacks lie inside ``synced`` → ``done``
             phases, self._step_phases = self._step_phases, {}
-            fields = {"put": rec["put"]} if "put" in rec else {}
-            if fields and self.mesh is not None:
+            fields = {k: rec[k] for k in ("put", "fetch", "cpu") if k in rec}
+            if "put" in fields and self.mesh is not None:
                 # one put a step however many chips it feeds
                 # (``shard_batch``: one device_put with a sharded layout)
                 fields["devices"] = int(self.mesh.devices.size)

@@ -469,7 +469,8 @@ class Telemetry:
         """Close out one optimizer step: drain the counters, update the
         throughput EMA, emit the ``step`` record. ``phases`` are the
         caller's, computed from the step's marks (``steptrace``); the
-        marks themselves ride in ``fields`` (``marks``, ``put``)."""
+        marks themselves ride in ``fields`` (``marks``, ``put``), with the
+        host's two readings (``fetch``, ``cpu``: see ``steptrace``)."""
         now = time.perf_counter()
         phases = dict(phases or {})
         with self._lock:
@@ -578,14 +579,16 @@ def emit_span(name, t0, t1, **fields):
 def _emit_boot(until):
     """Once per process, the span ``boot``: process start to the first
     thing the program marks (its first span's start or its first
-    ``activate()``): interpreter start-up and imports."""
+    ``activate()``): interpreter start-up and imports. It carries ``cpus``,
+    the CPUs this process may run on: what a step's CPU-seconds are a share
+    of."""
     global _boot_done
     if _boot_done:
         return
     _boot_done = True
     start = process_start()
     if start is not None:
-        emit_span("boot", start, until)
+        emit_span("boot", start, until, cpus=len(os.sched_getaffinity(0)))
 
 
 @contextlib.contextmanager

@@ -16,6 +16,10 @@ lands immediately after ``dispatched``). The marks are the record: the
 ``step`` event carries them absolute (``marks``), next to ``put``, the
 ``[t0, t1]`` of the wire encode + ``device_put`` of the batch this step
 consumed, so a step can be laid on a timeline beside a device trace.
+Two more readings ride along: ``fetch``, the mean wall seconds a loader
+worker spent on one sample of that batch, and ``cpu``, the process's
+``time.process_time()`` at ``start``; between two steps' ``start`` marks
+the difference is the CPU-seconds every thread of the process burnt.
 
 ========== ============================================================
 phase      wall time between
@@ -53,12 +57,14 @@ STARVED_SHARE = 0.5
 class StepTrace:
     """Timestamps of one training step on a single perf_counter clock."""
 
-    __slots__ = ("step", "marks", "put", "put_inline")
+    __slots__ = ("step", "marks", "put", "put_inline", "fetch", "cpu")
 
     def __init__(self, step=None):
         self.step = step
         self.marks = {}
         self.put = None     # (t0, t1) of this batch's put, same clock
+        self.fetch = None   # mean seconds a worker spent on one sample
+        self.cpu = None     # process_time() at ``start``
         # the put ran on the loop's own thread, inside the pull
         # (RMD_PREFETCH=0): its time is then part of the step total
         self.put_inline = False
@@ -67,6 +73,8 @@ class StepTrace:
         if name not in MARKS:
             raise ValueError(f"unknown step mark {name!r}")
         self.marks[name] = time.perf_counter() if t is None else float(t)
+        if name == "start":
+            self.cpu = time.process_time()
         return self
 
     def total(self):
@@ -115,6 +123,10 @@ class StepTrace:
         }
         if self.put is not None:
             rec["put"] = [round(self.put[0], 6), round(self.put[1], 6)]
+        if self.fetch is not None:
+            rec["fetch"] = round(self.fetch, 6)
+        if self.cpu is not None:
+            rec["cpu"] = round(self.cpu, 6)
         return rec
 
 

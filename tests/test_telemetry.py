@@ -4,7 +4,10 @@ smoke train emitting a schema-valid events.jsonl), and the satellite
 fixes riding with it (raft/fs legacy checkpoint remap, per-chip volume
 budget)."""
 
+import dataclasses
 import json
+import os
+import time
 
 import numpy as np
 import pytest
@@ -398,6 +401,14 @@ def test_step_marks_phases_and_put(tmp_path, monkeypatch, prefetch):
         assert on_loop == pytest.approx(total, abs=1e-5)
     # each step got its own batch's put, in the loader's order
     assert steps[0]["put"][1] <= steps[1]["put"][0]
+    # and its own batch's fetch: the mean seconds a worker spent on one of
+    # its samples; ``cpu`` is the process's CPU clock at ``start``
+    for ev in steps:
+        assert 0.0 < ev["fetch"] < 60.0
+    assert steps[0]["cpu"] < steps[1]["cpu"]
+    wall = steps[1]["marks"]["start"] - steps[0]["marks"]["start"]
+    assert steps[1]["cpu"] - steps[0]["cpu"] <= wall * len(
+        os.sched_getaffinity(0)) + 0.05
     # the window events come from the same records
     assert any(e["kind"] == "steptrace" for e in sink.events) or \
         ctx.steptraces.snapshot()["count"] == 2
@@ -428,6 +439,116 @@ def test_put_rides_with_its_own_batch():
         i = meta[0]
         assert t0 <= seen[i] <= t1
         assert all(not (t0 <= seen[j] <= t1) for j in seen if j != i)
+
+
+class _SlowSource:
+    """Four one-sample batches; every access takes ``seconds``."""
+
+    def __init__(self, seconds, fail_first=()):
+        self.seconds, self.failing = seconds, set(fail_first)
+
+    def __len__(self):
+        return 4
+
+    def __getitem__(self, index):
+        from raft_meets_dicl_tpu.data.collection import (Metadata, SampleArgs,
+                                                         SampleId)
+
+        time.sleep(self.seconds)
+        if index in self.failing:
+            self.failing.discard(index)
+            raise IOError(f"sample {index}")
+        img = np.full((1, 4, 4, 3), index, np.float32)
+        meta = Metadata(True, "slow", SampleId(f"s{index}", SampleArgs(),
+                                               SampleArgs()), ((0, 4), (0, 4)))
+        return img, img, np.zeros((1, 4, 4, 2), np.float32), \
+            np.ones((1, 4, 4), bool), [meta]
+
+
+@pytest.mark.parametrize("workers", [0, 2], ids=["inline", "pool"])
+def test_loader_stamps_each_samples_fetch_seconds(workers):
+    """``Loader._fetch`` times ``source[index]`` and writes the seconds on
+    the sample's metadata, so they reach the step with the batch; a retry
+    is stamped with the access that succeeded."""
+    from raft_meets_dicl_tpu.models.input import Loader
+
+    loader = Loader(_SlowSource(0.02, fail_first={1}), batch_size=2,
+                    num_workers=workers, retries=1)
+    batches = list(loader)
+    assert len(batches) == 2
+    for *_arrays, meta in batches:
+        assert len(meta) == 2
+        for m in meta:
+            assert 0.02 <= m.fetch_s < 0.02 + 0.5
+    # the reading is no part of a sample's identity
+    m = batches[0][4][0]
+    assert dataclasses.replace(m, fetch_s=9.0) == m
+    assert "fetch_s" not in repr(m)
+
+
+def test_steptrace_record_carries_fetch_and_cpu():
+    tr = steptrace.StepTrace(step=7)
+    before = time.process_time()
+    tr.mark("start", 1.0).mark("data", 1.5).mark("done", 2.0)
+    assert before <= tr.cpu <= time.process_time()
+    # no loader reading (a direct caller): the record leaves ``fetch`` out
+    rec = tr.record()
+    assert "fetch" not in rec and rec["cpu"] == round(tr.cpu, 6)
+    tr.fetch = 0.0123456789
+    assert tr.record()["fetch"] == 0.012346
+    # marks and phases are what they were
+    assert rec["phases"] == {"data_wait": 0.5, "host_prep": 0.5}
+
+
+def test_boot_span_says_how_many_cpus_the_process_may_use(monkeypatch):
+    monkeypatch.setattr(core, "_boot_done", False)
+    try:
+        sink = telemetry.activate(telemetry.Telemetry())
+        boot = [e for e in sink.events
+                if e["kind"] == "span" and e["name"] == "boot"]
+        assert len(boot) == 1
+        assert boot[0]["cpus"] == len(os.sched_getaffinity(0)) >= 1
+        telemetry.validate_event(boot[0])
+    finally:
+        telemetry.deactivate()
+
+
+def _input_run(steps, cpus):
+    """What the benchmark's readers take: the events and the window."""
+    events = [_base("span", name="boot", t0=0.0, t1=1.0, cpus=cpus)]
+    for i, (start, cpu, fetch) in enumerate(steps):
+        events.append(_base(
+            "step", step=i, phases={}, step_time=0.5, throughput_ema=2.0,
+            marks={"start": start, "done": start + 0.4}, fetch=fetch,
+            cpu=cpu))
+    return {"kind": "train", "events": events,
+            "readings": {"window_wall": (-1.0, 1.0)}}
+
+
+def test_input_readers_read_the_step_events_and_none_without_them():
+    """``fetch_ms`` and ``host_cpu_pct`` (benchmark/layers) on the events
+    this program writes; on a program that writes neither reading, the
+    parent of PR 40, both find nothing and say None."""
+    from benchmark.layers import fetch_ms, host_cpu_pct
+
+    # four steps half a second apart on a host of 8: 2, 3 and 2.5
+    # CPU-seconds between consecutive ``start`` marks
+    run = _input_run([(10.0, 100.0, 0.040), (10.5, 102.0, 0.050),
+                      (11.0, 105.0, 0.070), (11.5, 107.5, 0.060)], cpus=8)
+    assert fetch_ms.read(run) == pytest.approx(55.0)
+    assert host_cpu_pct.read(run) == pytest.approx(100 * 5.0 / 8)
+
+    def without(*keys):
+        return dict(run, events=[{k: v for k, v in e.items()
+                                  if k not in keys} for e in run["events"]])
+
+    old = without("fetch", "cpu", "cpus")
+    for ev in old["events"]:
+        telemetry.validate_event(ev)
+    assert fetch_ms.read(old) is None and host_cpu_pct.read(old) is None
+    assert host_cpu_pct.read(without("cpus")) is None
+    assert host_cpu_pct.read(without("cpu")) is None
+    assert fetch_ms.read(without("cpu", "cpus")) == pytest.approx(55.0)
 
 
 def test_early_spans_are_delivered_on_activate(monkeypatch):
