@@ -661,7 +661,8 @@ def attribute_trace(trace_dir, top_ops=5):
         c = op_class(op)
         m["classes"][c] = m["classes"].get(c, 0.0) + s
         m["ops"][op] = m["ops"].get(op, 0.0) + s
-    order = {p: i for i, p in enumerate(owners.PHASES)}
+    order = {p: i for i, p in enumerate(owners.PHASES
+                                         + owners.LADDER_PHASES)}
     modules = []
     for name in sorted(per_module,
                        key=lambda n: -per_module[n]["seconds"]):

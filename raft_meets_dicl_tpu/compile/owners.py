@@ -24,6 +24,10 @@ from collections import Counter
 # the phases every model's step is read by, in the order of a step
 PHASES = ("input", "encoders", "corr", "lookup", "update", "up8", "loss",
           "optimizer")
+# the phases of a coarse-to-fine ladder without a recurrence (DICL), beside
+# ``encoders``, ``lookup`` and ``up8``: what it does before a level's
+# matching and after it. No other model states their scopes
+LADDER_PHASES = ("warp", "context")
 
 # scope name -> phase. ``matching/sampler`` is two scopes to the name
 # stack; the innermost one found is the owner's scope
@@ -39,6 +43,8 @@ SCOPES = {
     "dap": "lookup",
     "wcp": "lookup",
     "update": "update",         # motion encoder, GRU, flow head
+    "warp": "warp",             # coarse flow 2x up, frame two's features warped
+    "context": "context",       # context network refining a level's flow
     "up8": "up8",               # mask head, convex combine, bilinear 2x
     "loss": "loss",
     "optimizer": "optimizer",   # everything after the gradient

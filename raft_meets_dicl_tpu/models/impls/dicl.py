@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ... import telemetry
 from ...ops.upsample import interpolate_bilinear, upsample_flow_2x
 from ..common import warp
 from ..common.blocks.dicl import (
@@ -30,6 +31,7 @@ from ..common.blocks.dicl import (
     DisplacementAwareProjection,
     MatchingNet,
 )
+from ..common.corr.common import record_matching_bytes
 from ..common.encoders import dicl as dicl_encoders
 from ..config import register_loss, register_model
 from ..model import Loss, Model, ModelAdapter, Result
@@ -141,29 +143,45 @@ class FlowLevel(nn.Module):
                  ctx=True, scale=1.0, train=False, frozen_bn=False):
         b, h, w, _ = feat1.shape
 
+        # scopes: a device trace tells warp, shift stack, cost net,
+        # projection and context network apart by the name stack of their
+        # operations (``compile/owners.SCOPES``)
         flow_up = None
         if flow_coarse is not None:
-            flow_up = jax.lax.stop_gradient(upsample_flow_2x(flow_coarse))
-            feat2, _mask = warp.warp_backwards(feat2, flow_up)
+            with jax.named_scope("warp"):
+                flow_up = jax.lax.stop_gradient(upsample_flow_2x(flow_coarse))
+                feat2, _mask = warp.warp_backwards(feat2, flow_up)
+            if not self.is_initializing():
+                telemetry.note_trace("warp_calls", 1)
 
         # matching cost
-        mvol = displaced_pair_volume(feat1, feat2, self.maxdisp)
-        cost = MatchingNet()(mvol, train, frozen_bn)  # (B, H, W, du, dv)
+        with jax.named_scope("matching"):
+            mvol = displaced_pair_volume(feat1, feat2, self.maxdisp)
+        if not self.is_initializing():
+            record_matching_bytes(mvol)
+        with jax.named_scope("matching/mnet"):
+            cost = MatchingNet()(mvol, train, frozen_bn)  # (B, H, W, du, dv)
         if dap:
-            cost = DisplacementAwareProjection(self.maxdisp, init=self.dap_init)(cost)
+            with jax.named_scope("matching/dap"):
+                cost = DisplacementAwareProjection(
+                    self.maxdisp, init=self.dap_init)(cost)
 
-        # raw flow via soft-argmin, plus the coarse estimate
-        flow = soft_argmin_flow(cost)
-        flow = flow + flow_up if flow_up is not None else flow
+        # raw flow via soft-argmin, plus the coarse estimate; the entropy
+        # of the same softmax for the context network
+        with jax.named_scope("matching"):
+            flow = soft_argmin_flow(cost)
+            flow = flow + flow_up if flow_up is not None else flow
+            entr = jax.lax.stop_gradient(flow_entropy(cost)) if ctx else None
         flow_raw = flow if raw else None
 
         if ctx:
-            img1 = interpolate_bilinear(img1, (h, w))
-            entr = jax.lax.stop_gradient(flow_entropy(cost))
-            ctxf = jnp.concatenate(
-                (jax.lax.stop_gradient(flow), entr, feat1, img1), axis=-1
-            )
-            flow = flow + CtfContextNet(self.level)(ctxf, train, frozen_bn) * scale
+            with jax.named_scope("context"):
+                img1 = interpolate_bilinear(img1, (h, w))
+                ctxf = jnp.concatenate(
+                    (jax.lax.stop_gradient(flow), entr, feat1, img1), axis=-1
+                )
+                flow = flow + CtfContextNet(self.level)(
+                    ctxf, train, frozen_bn) * scale
 
         return flow, flow_raw
 
@@ -197,7 +215,8 @@ class DiclModule(nn.Module):
             output_dim=self.feature_channels, depth=6,
             out_levels=tuple(lvl - 1 for lvl in sorted(self.levels)),
         )
-        f1, f2 = feature((img1, img2), train, frozen_bn)  # finest-first
+        with jax.named_scope("encoders"):
+            f1, f2 = feature((img1, img2), train, frozen_bn)  # finest-first
 
         flow = None
         out = []
@@ -213,6 +232,12 @@ class DiclModule(nn.Module):
             )
             out = [flow, flow_raw] + out
 
+        if final_only:
+            # an inference program reads ``Result.final()`` alone: the
+            # finest refined flow, without the coarser levels' flows and
+            # the ``raw`` copies as program outputs (the arithmetic that
+            # leads to it is every level's, so nothing else is left out)
+            return [flow]
         # finest first: [flow_f, flow_f_raw, ..., flow6, flow6_raw]
         return [f for f in out if f is not None]
 
@@ -222,6 +247,9 @@ class Dicl(Model):
     """``dicl/baseline`` (reference dicl.py:300-375)."""
 
     type = "dicl/baseline"
+    # 1: the ladder states its scopes (``warp``, ``matching``, ``context``)
+    # and notes ``matching_volume_bytes`` and ``warp_calls``
+    notes_revision = 1
 
     @classmethod
     def from_config(cls, cfg):
@@ -282,6 +310,7 @@ class Dicl64to8(Model):
     6..3 (reference dicl_64to8.py:154-202)."""
 
     type = "dicl/64to8"
+    notes_revision = 1
 
     @classmethod
     def from_config(cls, cfg):
@@ -354,8 +383,10 @@ class DiclResult(Result):
         _, fh, fw, _ = flow.shape
         th, tw = self.shape
 
-        flow = interpolate_bilinear(flow, (th, tw))
-        return flow * jnp.asarray([tw / fw, th / fh], dtype=flow.dtype)
+        # the model's last resize takes Up8's place in the phase table
+        with jax.named_scope("up8"):
+            flow = interpolate_bilinear(flow, (th, tw))
+            return flow * jnp.asarray([tw / fw, th / fh], dtype=flow.dtype)
 
     def intermediate_flow(self):
         return self.result

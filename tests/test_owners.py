@@ -71,6 +71,15 @@ P0 = "%p0.1 = f32[4,8]{1,0} parameter(0)"
     (f"{BACK}/up8/jvp(RaftModule)/up8/checkpoint/Up8Network_0/pallas_call",
      ("up8", "up8", "bwd")),
     ("jit(step)/jvp(loss)/reduce_sum", ("loss", "loss", "fwd")),
+    # the ladder's own phases (DICL): the warp before a level, the
+    # context network after it; its shift stack and soft-argmin are
+    # ``matching``, its last resize ``up8``
+    ("jit(step)/DiclModule/FlowLevel_3/warp/gather", ("warp", "warp", "fwd")),
+    ("jit(step)/DiclModule/FlowLevel_3/context/CtfContextNet_0/ConvBlock_2/"
+     "Conv_0/conv_general_dilated", ("context", "context", "fwd")),
+    ("jit(step)/DiclModule/FlowLevel_4/matching/concatenate",
+     ("lookup", "matching", "fwd")),
+    ("jit(step)/up8/dot_general", ("up8", "up8", "fwd")),
     ("jit(step)/optimizer/mul", ("optimizer", "optimizer", "fwd")),
     ("jit(step)/input/convert_element_type", ("input", "input", "fwd")),
     # no scope of the table: the outermost own component names it
@@ -83,7 +92,8 @@ def test_scope_table(op_name, want):
 
 
 def test_every_scope_maps_to_a_phase_of_the_vocabulary():
-    assert set(owners.SCOPES.values()) == set(owners.PHASES)
+    assert set(owners.SCOPES.values()) == set(owners.PHASES
+                                              + owners.LADDER_PHASES)
     # a level's scope is transparent: it is in no table
     assert "level0" not in owners.SCOPES
 
@@ -535,3 +545,72 @@ def test_sink_off_takes_no_text_and_keeps_no_record(aot_store, monkeypatch):
     finally:
         if before.enabled:
             telemetry.activate(before)
+
+
+# -- a program without a recurrence --------------------------------------------
+
+
+TINY_DICL = {
+    "name": "tiny-dicl-owners", "id": "tiny-dicl-owners",
+    "model": {
+        "type": "dicl/baseline",
+        "parameters": {
+            "feature-channels": 8,
+            "displacement-range": {f"level-{i}": [3, 3] for i in range(2, 7)},
+        },
+        "arguments": {"raw": True, "dap": True, "ctx": True},
+    },
+    "loss": {"type": "dicl/multiscale"},
+    "input": {"padding": {"type": "modulo", "mode": "zeros",
+                          "size": [128, 128]}},
+}
+
+
+def test_dicl_eval_program_names_warp_context_and_mnet(aot_store):
+    """The served form of ``dicl/baseline`` (final flow only, u8 wire):
+    the record of its eval program names the ladder's phases, leaves
+    little to ``other`` and ``unowned``, and its ``save`` event carries
+    what the trace noted of the matching volumes and the warps."""
+    from raft_meets_dicl_tpu import evaluation
+
+    sink = telemetry.activate(telemetry.Telemetry())
+    try:
+        spec = models.load(TINY_DICL)
+        wire = WireFormat.from_config("u8").bound(spec.input.clip,
+                                                  spec.input.range)
+        img = jnp.zeros((1, 128, 128, 3))
+        variables = spec.model.init(jax.random.PRNGKey(0), img, img)
+        fn = evaluation.make_eval_fn(spec.model, {"final_only": True},
+                                     wire=wire, model_id=spec.id)
+        frames = jnp.asarray(
+            np.random.RandomState(0).randint(0, 256, (2, 2, 128, 128, 3)),
+            jnp.uint8)
+        out, flow = fn(variables, frames[0], frames[1])
+        assert len(out) == 1 and flow.shape == (2, 128, 128, 2)
+        assert np.isfinite(np.asarray(flow)).all()
+        events = [e for e in sink.events if e["kind"] == "aot"
+                  and e.get("program") == "eval_step"]
+    finally:
+        telemetry.deactivate()
+    assert [e["event"] for e in events] == ["miss", "save", "owners"]
+    rec = events[2]
+    assert {"encoders", "warp", "lookup", "context", "up8", "input"} \
+        <= set(rec["owners"])
+    assert {"matching", "mnet", "dap"} <= set(rec["owners"]["lookup"])
+    # no backward pass, no recurrence: nothing under update or corr
+    assert not {"update", "corr", "loss", "optimizer"} & set(rec["owners"])
+    assert all(set(d) == {"fwd"} for scopes in rec["owners"].values()
+               for d in scopes.values())
+    count = {phase: sum(len(keys) for d in scopes.values()
+                        for keys in d.values())
+             for phase, scopes in rec["owners"].items()}
+    loose = count.get(owners.OTHER, 0) + count.get(owners.UNOWNED, 0)
+    assert loose < 0.10 * rec["instructions"], count
+    # five levels' stacked pairs of a batch of 2 (49 hypotheses, 2 x 8
+    # channels, float32) at 1/4 ... 1/64 of 128x128; a warp before four
+    positions = sum((128 >> lvl) ** 2 for lvl in range(2, 7))
+    assert events[1]["matching_volume_bytes"] == 2 * 49 * positions * 16 * 4
+    assert events[1]["warp_calls"] == 4
+    # the key counts the notes' revision: an older tree's stored program,
+    # which states no scope, is not handed to this one
+    assert dict(fn.key.flags)["notes"] == "1"

@@ -425,6 +425,77 @@ def test_warm_pool_prebuild_then_zero_compile_replica(tmp_path,
     assert warm[0]["aot_saves"] == 1 and warm[1]["aot_hits"] == 1
 
 
+TINY_DICL_MODEL = {
+    "name": "serve tiny dicl", "id": "serve-tiny-dicl",
+    "model": {"type": "dicl/baseline",
+              "parameters": {
+                  "feature-channels": 8,
+                  "displacement-range": {f"level-{i}": [3, 3]
+                                         for i in range(2, 7)}},
+              "arguments": {"raw": True, "dap": True, "ctx": True,
+                            "context_scale": {
+                                f"level-{i}": 2.0 ** (1 - i)
+                                for i in range(2, 7)}}},
+    "loss": {"type": "dicl/multiscale"},
+    "input": {"clip": [0, 1], "range": [-1, 1],
+              "padding": {"type": "modulo", "mode": "zeros",
+                          "size": [128, 128]}},
+}
+
+
+def test_a_model_without_iterations_serves_through_batcher_and_scheduler(
+        _serve_hygiene):
+    """``dicl/baseline``: no recurrence, no ``iterations`` argument, no
+    ladder, no video. The session builds its eval program and its warm
+    pool for it at two buckets, and what the scheduler releases is the
+    model called directly on the padded, wire-decoded pair."""
+    import jax
+
+    spec = models.load(TINY_DICL_MODEL)
+    assert "iterations" not in spec.model.arguments
+    buckets = [(128, 128), (128, 256)]
+    session = ServeSession(spec, ShapeBuckets(buckets),
+                           wire=WireFormat.from_config("u8"), batch_size=2)
+    outcomes = session.warm_pool()
+    assert [(o["bucket"], o["compiles"]) for o in outcomes] == [
+        ("128x128", 1), ("128x256", 1)]
+    assert not any("rung" in o for o in outcomes)
+    # the final-only form, in the key: its own program, its own artifact
+    assert "('final_only', 'True')" in dict(session.eval_fn.key.flags)["args"]
+    c0 = session.compiles()
+
+    shapes = [(100, 120), (120, 200)] * 3
+    sched = Scheduler(session, batch_size=2, max_wait_ms=20.0).start()
+    try:
+        pairs = [_pair(shape, seed=i) for i, shape in enumerate(shapes)]
+        tickets = [sched.submit(a, b, client=f"c{i % 2}")
+                   for i, (a, b) in enumerate(pairs)]
+        results = [t.result(timeout=120.0) for t in tickets]
+    finally:
+        sched.stop(drain=True)
+    assert session.compiles() == c0
+    batches = _serve_events(_serve_hygiene, "batch")
+    assert sum(b["size"] for b in batches) == len(shapes)
+    assert {b["bucket"] for b in batches} == {"128x128", "128x256"}
+    assert all("iterations" not in b and b["compiles"] == 0 for b in batches)
+
+    model = spec.model
+    direct = jax.jit(lambda v, a, b: model.get_adapter().wrap_result(
+        model.apply(v, a, b), a.shape[1:3]).final())
+    for (img1, img2), shape, res in zip(pairs, shapes, results):
+        bucket = (128, 128) if shape == (100, 120) else (128, 256)
+        e1, e2 = sched.batcher.encode_pair(img1, img2, bucket,
+                                           session.encode_image)
+        n1, n2, _, _ = session.wire.decode(e1[None], e2[None])
+        want = np.asarray(direct(session.variables, n1, n2))[0]
+        assert res.flow.shape == (*shape, 2)
+        # ten program outputs against one, a batch of 2 against one
+        # sample: other fusions, the same arithmetic
+        np.testing.assert_allclose(res.flow, want[:shape[0], :shape[1]],
+                                   atol=1e-4)
+        assert float(np.abs(res.flow).mean()) > 1e-3
+
+
 @pytest.mark.slow
 def test_cli_serve_smoke(tmp_path):
     import yaml
