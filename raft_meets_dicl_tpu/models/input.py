@@ -9,6 +9,7 @@ GIL) rather than a torch DataLoader with worker processes.
 
 import concurrent.futures
 import copy
+import itertools
 import threading
 import time
 from dataclasses import replace
@@ -573,49 +574,101 @@ class JaxAdapter:
                       bad_sample_budget)
 
 
+class _Assembly:
+    """One batch assembled in place: four arrays allocated once, each
+    sample's rows copied once, straight to where they will lie.
+
+    ``counts`` are the rows each sample of the chunk brings. With
+    ``shuffle`` the constructor draws the in-batch order from ``rng``, one
+    ``permutation(rows)`` a batch and none for a batch of a single row, so
+    batches are to be made in batch order. Concatenated row ``c`` (sample
+    ``j``'s row ``i`` is ``c = counts[:j].sum() + i``) lands at the row
+    ``p`` with ``perm[p] == c``, which is where ``np.concatenate`` followed
+    by ``[perm]`` would leave it. The arrays are fresh ``np.empty`` ones a
+    batch, made by the first sample placed from its frames and dtypes, and
+    never written again once ``batch()`` has handed them out. ``place``
+    may run on several threads at once, a sample each: samples write
+    disjoint rows, so only the allocation takes the lock.
+    """
+
+    def __init__(self, counts, shuffle=False, rng=None):
+        self.counts = [int(c) for c in counts]
+        starts = np.concatenate(([0], np.cumsum(self.counts))).astype(int)
+        rows = np.arange(starts[-1])
+        if shuffle and len(rows) > 1:
+            rng = rng if rng is not None else np.random
+            rows[rng.permutation(len(rows))] = np.arange(len(rows))
+        self.rows = [rows[a:b] for a, b in zip(starts, starts[1:])]
+        self.arrays = None
+        self.meta = [None] * len(rows)
+        # each sample's frame shape and metadata, for the mixed-shapes
+        # check in chunk order
+        self.frames = [None] * len(self.counts)
+        self._lock = threading.Lock()
+
+    def place(self, j, sample):
+        """Copy sample ``j`` of the chunk into its rows."""
+        img1, img2, flow, valid, meta = sample
+        if img1.shape[0] != self.counts[j]:
+            raise ValueError(
+                f"cannot place a sample of {img1.shape[0]} row(s) in a "
+                f"batch laid out for {self.counts[j]} an index: the thread "
+                "pool draws the in-batch order when a batch is submitted, "
+                "from the rows the iteration's first sample had — use "
+                "num_workers=0 for sources whose samples differ in rows")
+        if flow is None:
+            valid = None
+        arrays = (img1, img2, flow, valid)
+        self.frames[j] = (img1.shape[1:], meta)
+
+        with self._lock:
+            if self.arrays is None:
+                n = len(self.meta)
+                self.arrays = tuple(
+                    None if a is None else np.empty((n,) + a.shape[1:], a.dtype)
+                    for a in arrays)
+        if self.arrays[0].shape[1:] != img1.shape[1:]:
+            return      # mixed shapes: batch() raises, in chunk order
+
+        for i, row in enumerate(self.rows[j]):
+            for dst, src in zip(self.arrays, arrays):
+                if dst is not None:
+                    dst[row] = src[i]
+            self.meta[row] = meta[i]
+
+    def batch(self):
+        """The assembled ``(img1, img2, flow, valid, meta)``, once every
+        sample is placed; mixed shapes raise here."""
+        def describe(frame, meta):
+            ds = meta[0].dataset_id if meta and hasattr(
+                meta[0], "dataset_id") else "<unknown dataset>"
+            return f"{frame[0]}x{frame[1]} (dataset '{ds}')"
+
+        for frame, meta in self.frames[1:]:
+            if frame != self.frames[0][0]:
+                raise ValueError(
+                    "cannot batch samples of mixed shapes: "
+                    f"{describe(*self.frames[0])} vs "
+                    f"{describe(frame, meta)} — use shape buckets "
+                    "(--buckets / RMD_EVAL_BUCKETS / loader "
+                    "group_by_shape=True) or batch size 1 for "
+                    "mixed-resolution datasets")
+        return (*self.arrays, self.meta)
+
+
 def collate(samples, shuffle=False, rng=None):
-    """Concatenate pre-batched samples into one global batch.
+    """Assemble pre-batched samples into one global batch, one copy each.
 
     Sources may return more than one sample each (fw/bw pairing); the global
     batch is the concatenation, optionally shuffled within the batch so
-    paired samples don't always sit next to each other.
+    paired samples don't always sit next to each other. This is the serial
+    form of what the loader's thread pool does a sample a worker: allocate
+    once, place every sample at its (shuffled) rows (:class:`_Assembly`).
     """
-    base = samples[0][0].shape[1:]
-    for s in samples[1:]:
-        if s[0].shape[1:] != base:
-            def describe(smp, shape):
-                meta = smp[4]
-                ds = meta[0].dataset_id if meta and hasattr(
-                    meta[0], "dataset_id") else "<unknown dataset>"
-                return f"{shape[0]}x{shape[1]} (dataset '{ds}')"
-            raise ValueError(
-                "cannot batch samples of mixed shapes: "
-                f"{describe(samples[0], base)} vs "
-                f"{describe(s, s[0].shape[1:])} — use shape buckets "
-                "(--buckets / RMD_EVAL_BUCKETS / loader "
-                "group_by_shape=True) or batch size 1 for "
-                "mixed-resolution datasets")
-
-    img1 = np.concatenate([s[0] for s in samples], axis=0)
-    img2 = np.concatenate([s[1] for s in samples], axis=0)
-
-    if samples[0][2] is not None:
-        flow = np.concatenate([s[2] for s in samples], axis=0)
-        valid = np.concatenate([s[3] for s in samples], axis=0)
-    else:
-        flow, valid = None, None
-
-    meta = [m for s in samples for m in s[4]]
-
-    if shuffle and img1.shape[0] > 1:
-        rng = rng if rng is not None else np.random
-        perm = rng.permutation(img1.shape[0])
-        img1, img2 = img1[perm], img2[perm]
-        if flow is not None:
-            flow, valid = flow[perm], valid[perm]
-        meta = [meta[i] for i in perm]
-
-    return img1, img2, flow, valid, meta
+    batch = _Assembly([s[0].shape[0] for s in samples], shuffle, rng)
+    for j, sample in enumerate(samples):
+        batch.place(j, sample)
+    return batch.batch()
 
 
 class _DecodeFailed(Exception):
@@ -633,6 +686,17 @@ class Loader:
     shared-memory array transport (models.mpdecode) for pipelines whose
     pure-Python decode path is the bottleneck. ``procs=None`` reads
     ``RMD_LOADER_PROCS`` (0 or unset = thread pool).
+
+    A batch is assembled in place (:class:`_Assembly`): four fresh arrays
+    a batch, each sample copied once to the rows the in-batch shuffle
+    gives it. The thread pool's workers do that copy themselves, each for
+    the sample it fetched, and the pulling thread only draws the order
+    and waits; every other path (``num_workers=0``, ``group_by_shape``,
+    decode processes) places the samples one after the other on the
+    pulling thread (:func:`collate`). The stream is the same either way.
+    For the draw the thread pool takes the rows an index from the
+    iteration's first sample, so a source has to return the same number
+    of rows for every index there (a sample with another count raises).
 
     Shuffling uses an own Generator. Without an explicit ``seed`` it is
     derived from the global numpy RNG so run-level seeding
@@ -830,22 +894,44 @@ class Loader:
             return
 
         with concurrent.futures.ThreadPoolExecutor(self.num_workers) as pool:
-            # pipeline: submit the next batch while the consumer works
-            pending = []
+            # pipeline: submit the next batch while the consumer works.
+            # A worker fetches its sample and copies it into the batch's
+            # arrays, at the rows the in-batch order gives it; this thread
+            # draws that order when it submits the chunk (the draws come
+            # in batch order, as when ``collate`` made them) and waits.
             batches = self._batches()
+            chunk = next(batches, None)
+            if chunk is None:
+                return
+            # the draw needs the batch's rows: a source returns the same
+            # number an index, which the iteration's first sample shows
+            head = pool.submit(self._fetch, chunk[0]).result()
+            per_index = head[0].shape[0]
+            pending = []
 
-            def submit_next():
-                chunk = next(batches, None)
-                if chunk is not None:
-                    pending.append([pool.submit(self._fetch, i) for i in chunk])
+            def fetch_into(batch, j, index):
+                batch.place(j, self._fetch(index))
 
-            submit_next()
-            submit_next()
+            def submit(chunk, fetched=()):
+                batch = _Assembly([per_index] * len(chunk), self.shuffle,
+                                  self.rng)
+                for j, sample in enumerate(fetched):
+                    batch.place(j, sample)
+                pending.append((batch, [
+                    pool.submit(fetch_into, batch, j, i)
+                    for j, i in list(enumerate(chunk))[len(fetched):]]))
+
+            submit(chunk, [head])
+            del head
+            for chunk in itertools.islice(batches, 1):
+                submit(chunk)
             while pending:
-                futures = pending.pop(0)
-                samples = [f.result() for f in futures]
-                submit_next()
-                yield collate(samples, self.shuffle, self.rng)
+                batch, futures = pending.pop(0)
+                for f in futures:
+                    f.result()
+                for chunk in itertools.islice(batches, 1):
+                    submit(chunk)
+                yield batch.batch()
 
     def _iter_samples(self):
         """Single samples in epoch order, decode pipelined a window ahead
@@ -935,7 +1021,8 @@ class Loader:
     def _iter_procs(self):
         """Decode-process path: same two-batch pipelining as the thread
         pool, with samples crossing back through shared memory. Segments
-        are released right after collate copies out of them."""
+        are released right after collate copies out of them (its one
+        copy, into the batch's arrays)."""
         from . import mpdecode
 
         pool = mpdecode.DecodePool(self.source, self.procs)

@@ -5,7 +5,8 @@ cv2/numpy, but the pure-Python decode path (dataset indexing, augmentation
 glue, per-sample validation) stays single-core. This pool forks worker
 processes that run ``source[index]`` and hand the resulting arrays back
 through POSIX shared memory — one segment per sample, written once by the
-worker, read zero-copy by the consumer (``collate`` is the single copy),
+worker, read zero-copy by the consumer (``collate`` copies each sample
+once, into the batch's arrays, and that is the single copy),
 then unlinked. Only the metadata list travels through the result queue's
 pickle channel.
 
@@ -84,7 +85,7 @@ def decode_sample(payload):
     """Payload → ((img1, img2, flow, valid, meta), shm handle).
 
     The arrays are views into the segment: the caller must keep ``shm``
-    open until it has copied them out (collate does), then
+    open until it has copied them out (collate does, once a sample), then
     ``shm.close(); shm.unlink()``.
     """
     name, descr, meta = payload

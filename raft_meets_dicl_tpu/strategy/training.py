@@ -99,8 +99,8 @@ def _device_prefetch(samples, put, depth=2):
     otherwise serializes with compute. A background
     thread loads and ``put``s up to ``depth`` batches ahead (default 2:
     batch N+1 transfers while step N executes); the main loop receives
-    (host_batch, device_batch, meta, put_span) with transfers already in
-    flight. Loader exceptions re-raise at the consumption point.
+    (host_batch, device_batch, meta, put_span, pull_span) with transfers
+    already in flight. Loader exceptions re-raise at the consumption point.
 
     ``RMD_PREFETCH=0`` swaps in :func:`_sync_transfer` (identical batch
     stream, transfer left on the critical path — the A/B baseline);
@@ -109,7 +109,11 @@ def _device_prefetch(samples, put, depth=2):
     ``put_span`` is the ``perf_counter`` ``(t0, t1)`` of the worker's
     ``put`` (wire encode + transfer initiation) of *this* batch: it rides
     through the queue with its batch and lands in the ``put`` field of the
-    step that consumes it, up to ``depth`` steps later. The time the
+    step that consumes it, up to ``depth`` steps later. ``pull_span`` is
+    the ``(t0, t1)`` of the ``next()`` that handed the worker this batch
+    (the loader's pulling thread: the wait for its workers and whatever
+    it does to a batch itself) and lands in ``pull``; pull + put is the
+    period of the one thread that feeds the device. The time the
     consumer blocks on the queue (the input pipeline failing to keep
     ahead of the device) is the step trace's ``start`` → ``data``.
     """
@@ -121,39 +125,53 @@ def _device_prefetch(samples, put, depth=2):
 
     def worker():
         try:
-            for img1, img2, flow, valid, meta in samples:
+            for (img1, img2, flow, valid, meta), pull_span in _timed(samples):
                 host = (img1, img2, flow, valid)
                 t0 = time.perf_counter()
                 dev = put(host)
-                q.put((host, dev, meta, (t0, time.perf_counter())))
+                q.put((host, dev, meta, (t0, time.perf_counter()), pull_span))
         except BaseException as e:  # noqa: BLE001 - re-raised by consumer
-            q.put((_END, e, None, None))
+            q.put((_END, e, None, None, None))
             return
-        q.put((_END, None, None, None))
+        q.put((_END, None, None, None, None))
 
     t = threading.Thread(target=worker, daemon=True)
     t.start()
     while True:
-        host, dev, meta, put_span = q.get()
+        host, dev, meta, put_span, pull_span = q.get()
         if host is _END:
             if dev is not None:
                 raise dev
             return
-        yield host, dev, meta, put_span
+        yield host, dev, meta, put_span, pull_span
+
+
+def _timed(samples):
+    """Each item of ``samples`` with the ``perf_counter`` ``(t0, t1)`` of
+    the ``next()`` that produced it."""
+    samples = iter(samples)
+    while True:
+        t0 = time.perf_counter()
+        try:
+            item = next(samples)
+        except StopIteration:
+            return
+        yield item, (t0, time.perf_counter())
 
 
 def _sync_transfer(samples, put):
-    """RMD_PREFETCH=0: the same (host, dev, meta, put_span) stream as
-    :func:`_device_prefetch` with the transfer kept synchronous on the
-    critical path — the bit-identical A/B baseline for the prefetch
+    """RMD_PREFETCH=0: the same (host, dev, meta, put_span, pull_span)
+    stream as :func:`_device_prefetch` with the transfer kept synchronous
+    on the critical path — the bit-identical A/B baseline for the prefetch
     overlap, and an escape hatch for backends whose background-thread
-    ``device_put`` misbehaves. The put then lies inside the consuming
-    step's ``start`` → ``data`` and counts towards its wall time."""
-    for img1, img2, flow, valid, meta in samples:
+    ``device_put`` misbehaves. The pull and the put then lie inside the
+    consuming step's ``start`` → ``data`` and count towards its wall
+    time."""
+    for (img1, img2, flow, valid, meta), pull_span in _timed(samples):
         host = (img1, img2, flow, valid)
         t0 = time.perf_counter()
         dev = put(host)
-        yield host, dev, meta, (t0, time.perf_counter())
+        yield host, dev, meta, (t0, time.perf_counter()), pull_span
 
 
 class _StepResult:
@@ -829,7 +847,7 @@ class TrainingContext:
             nxt = next(it, None)
             if nxt is None:
                 break
-            i, (host, dev, meta, strace.put) = nxt
+            i, (host, dev, meta, strace.put, strace.pull) = nxt
             strace.put_inline = not prefetch
             fetched = [m.fetch_s for m in meta if m.fetch_s is not None]
             if fetched:
@@ -1205,7 +1223,8 @@ class TrainingContext:
             # phases of all of them. Emitted once the step is closed, so
             # the inspector's callbacks lie inside ``synced`` → ``done``
             phases, self._step_phases = self._step_phases, {}
-            fields = {k: rec[k] for k in ("put", "fetch", "cpu") if k in rec}
+            fields = {k: rec[k] for k in ("put", "pull", "fetch", "cpu")
+                      if k in rec}
             if "put" in fields and self.mesh is not None:
                 # one put a step however many chips it feeds
                 # (``shard_batch``: one device_put with a sharded layout)

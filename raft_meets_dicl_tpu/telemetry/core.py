@@ -469,8 +469,9 @@ class Telemetry:
         """Close out one optimizer step: drain the counters, update the
         throughput EMA, emit the ``step`` record. ``phases`` are the
         caller's, computed from the step's marks (``steptrace``); the
-        marks themselves ride in ``fields`` (``marks``, ``put``), with the
-        host's two readings (``fetch``, ``cpu``: see ``steptrace``)."""
+        marks themselves ride in ``fields`` (``marks``, ``put``, ``pull``),
+        with the host's two readings (``fetch``, ``cpu``: see
+        ``steptrace``)."""
         now = time.perf_counter()
         phases = dict(phases or {})
         with self._lock:

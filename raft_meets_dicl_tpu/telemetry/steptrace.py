@@ -15,7 +15,9 @@ finite-check fetch (zero on the steps in between, where ``synced``
 lands immediately after ``dispatched``). The marks are the record: the
 ``step`` event carries them absolute (``marks``), next to ``put``, the
 ``[t0, t1]`` of the wire encode + ``device_put`` of the batch this step
-consumed, so a step can be laid on a timeline beside a device trace.
+consumed, and ``pull``, the ``[t0, t1]`` of the ``next()`` that handed
+the prefetch worker that batch (its wait for the loader, before the
+``put``), so a step can be laid on a timeline beside a device trace.
 Two more readings ride along: ``fetch``, the mean wall seconds a loader
 worker spent on one sample of that batch, and ``cpu``, the process's
 ``time.process_time()`` at ``start``; between two steps' ``start`` marks
@@ -57,12 +59,14 @@ STARVED_SHARE = 0.5
 class StepTrace:
     """Timestamps of one training step on a single perf_counter clock."""
 
-    __slots__ = ("step", "marks", "put", "put_inline", "fetch", "cpu")
+    __slots__ = ("step", "marks", "put", "pull", "put_inline", "fetch",
+                 "cpu")
 
     def __init__(self, step=None):
         self.step = step
         self.marks = {}
         self.put = None     # (t0, t1) of this batch's put, same clock
+        self.pull = None    # (t0, t1) of the pull that produced the batch
         self.fetch = None   # mean seconds a worker spent on one sample
         self.cpu = None     # process_time() at ``start``
         # the put ran on the loop's own thread, inside the pull
@@ -123,6 +127,8 @@ class StepTrace:
         }
         if self.put is not None:
             rec["put"] = [round(self.put[0], 6), round(self.put[1], 6)]
+        if self.pull is not None:
+            rec["pull"] = [round(self.pull[0], 6), round(self.pull[1], 6)]
         if self.fetch is not None:
             rec["fetch"] = round(self.fetch, 6)
         if self.cpu is not None:

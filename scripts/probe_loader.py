@@ -8,17 +8,23 @@ Builds the cell's input pipeline as the program does (the synthetic source
 of ``benchmark/harness/train.py``, the model's input spec, the wire format
 and the loader arguments of the cell's environment), pulls batches for
 ``--seconds`` and prints, a line of JSON a worker count, the pairs a second
-it delivered and the CPU-seconds the process burnt a second of wall
+it delivered, the CPU-seconds the process burnt a second of wall
 (``os.times()``: every thread's, the kernel's share apart), beside the CPUs
-the machine gives the process; last, what ``collate`` of one batch costs
-with the workers gone. A cell's rate cannot pass the first number; where the second
-stands at the CPUs available the host's cores are what holds it, and where
-it stands far under them the workers wait on each other.
+the machine gives the process, and the pulling thread's share
+(``puller_busy_pct``: this thread's own CPU time, ``time.thread_time()``,
+over the wall: what the puller does to a batch itself, its waits for the
+workers left out); last, what ``collate`` of one batch costs with the
+workers gone (the serial assembly: allocate once, one copy a sample). A
+cell's rate cannot pass the first number; where the second stands at the
+CPUs available the host's cores are what holds it, and where it stands far
+under them the workers wait on each other or on the puller, which the
+third tells apart: near 100 the puller is the loader's period (PR 40's
+tree), near 0 it only waits (the workers place their samples themselves).
 
 ``--stacks`` samples every thread's Python stack through the window and
 prints where the threads sit, in threads: sixteen workers idle in the pool's
-``_worker`` mean the consumer (``collate`` on the pulling thread) is what
-the workers wait for, not each other.
+``_worker`` mean the consumer (the pulling thread, or whoever takes its
+batches) is what the workers wait for, not each other.
 
 ``--tree`` runs another checkout's program and benchmark (the parent's,
 unpacked by ``git archive``) from this one script. The process is held to
@@ -118,7 +124,7 @@ def main():
         batches = iter(loader)
         next(batches)                       # the render's compile, the pool
         sampler = _Sampler(args.tree) if args.stacks else None
-        wall0, cpu0 = time.perf_counter(), os.times()
+        wall0, cpu0, own0 = time.perf_counter(), os.times(), time.thread_time()
         pairs, fetch = 0, []
         while time.perf_counter() - wall0 < args.seconds:
             *_arrays, meta = next(batches)
@@ -126,11 +132,13 @@ def main():
             fetch += [m.fetch_s for m in meta
                       if getattr(m, "fetch_s", None) is not None]
         wall, cpu1 = time.perf_counter() - wall0, os.times()
+        own = time.thread_time() - own0
         user, system = cpu1.user - cpu0.user, cpu1.system - cpu0.system
         print(json.dumps({
             "workers": workers, "pairs_per_s": round(pairs / wall, 2),
             "cpu_s_per_s": round((user + system) / wall, 2),
             "of_it_system": round(system / wall, 2),
+            "puller_busy_pct": round(100.0 * own / wall, 1),
             "cpu_s_per_pair": round((user + system) / max(pairs, 1), 4),
             "fetch_ms": round(1e3 * sum(fetch) / len(fetch), 2)
             if fetch else None,
@@ -139,8 +147,9 @@ def main():
             sampler.report()
         batches.close()
 
-    # the puller's own work on a batch, with every worker gone: what
-    # ``collate`` costs when nothing else touches memory or the interpreter
+    # a batch assembled by one thread, with every worker gone: what
+    # ``collate`` (the serial assembly) costs when nothing else touches
+    # memory or the interpreter
     from raft_meets_dicl_tpu.models.input import collate
 
     samples = [adapter[i] for i in range(batch)]

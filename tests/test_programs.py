@@ -834,15 +834,15 @@ def test_prefetch_depth_knob(monkeypatch):
              for i in range(4)]
     got = list(_device_prefetch(iter(items), lambda b: ("dev",) + b,
                                 depth=1))
-    assert [m for *_, m, _put in got] == [[0], [1], [2], [3]]
-    assert all(dev[0] == "dev" for _, dev, _, _put in got)
+    assert [m for _, _, m, _put, _pull in got] == [[0], [1], [2], [3]]
+    assert all(dev[0] == "dev" for _, dev, _, _put, _pull in got)
     # each batch carries the interval of its own put, in order
-    puts = [put for *_, put in got]
+    puts = [put for *_, put, _pull in got]
     assert all(t0 <= t1 for t0, t1 in puts)
     assert all(a[1] <= b[0] for a, b in zip(puts, puts[1:]))
 
     got = list(_sync_transfer(iter(items), lambda b: ("dev",) + b))
-    assert [m for *_, m, _put in got] == [[0], [1], [2], [3]]
+    assert [m for _, _, m, _put, _pull in got] == [[0], [1], [2], [3]]
 
     def boom():
         yield items[0]

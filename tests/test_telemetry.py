@@ -435,7 +435,7 @@ def test_put_rides_with_its_own_batch():
     stream = _device_prefetch(iter(items), put, depth=2)
     first = next(stream)
     time.sleep(0.05)          # the worker fills the queue meanwhile
-    for host, _dev, meta, (t0, t1) in [first, *stream]:
+    for host, _dev, meta, (t0, t1), _pull in [first, *stream]:
         i = meta[0]
         assert t0 <= seen[i] <= t1
         assert all(not (t0 <= seen[j] <= t1) for j in seen if j != i)
