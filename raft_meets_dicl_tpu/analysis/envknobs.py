@@ -7,7 +7,7 @@ checks hold that together:
 
 - **env-knob (module)**: a direct ``os.environ``/``os.getenv`` *read* of
   an ``RMD_*`` name anywhere outside ``utils/env.py`` (writes — fault
-  injection, save/restore in tests and the dry run — stay legal);
+  injection, save/restore in tests — stay legal);
 - **env-knob (project)**: every ``RMD_*`` string literal in the lint
   surface must name a registered knob (catches typos like
   ``env.get("RMD_PREFTCH")``), and every registered knob must be

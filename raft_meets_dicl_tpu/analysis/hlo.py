@@ -206,9 +206,9 @@ def build_flagship_programs(n_devices=2, shape=(48, 64), mesh2d=False):
     """Register the raft-baseline tiny-shape train + eval steps on a CPU
     mesh and return ``[(program, args, audit_kwargs)]`` for auditing.
 
-    Mirrors ``__graft_entry__``'s dry-run construction (same model
-    config, tiny shapes) so the persistent compile cache and AOT store
-    warmed by earlier boots serve this audit without fresh compiles.
+    The flagship model configuration at tiny shapes, so the persistent
+    compile cache and AOT store warmed by an earlier audit serve the
+    next without fresh compiles.
     """
     import jax
     import jax.numpy as jnp

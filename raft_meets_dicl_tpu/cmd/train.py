@@ -309,8 +309,8 @@ def _train(args):
             registry=telemetry.metrics.registry())
 
     # boot configuration event: the effective compile-cache and AOT
-    # program directories (instead of silently defaulting) plus the
-    # prefetch knob — the first thing a cold-start post-mortem needs
+    # program directories (instead of silently defaulting) — the first
+    # thing a cold-start post-mortem needs
     from .. import compile as programs
     from ..utils import compcache
 
@@ -320,7 +320,6 @@ def _train(args):
         aot_dir=str(programs.programs_dir()) if programs.aot_enabled()
         else None,
         aot=programs.aot_enabled(),
-        prefetch=utils.env.get_bool("RMD_PREFETCH"),
     )
     if compcache.effective_dir():
         logging.info(

@@ -15,8 +15,8 @@ One router process fronting N serve replica processes:
 - :mod:`.client` — stdlib HTTP client with the typed transport failure
   classes (:class:`~.client.ReplicaDown` is safe to retry,
   :class:`~.client.ReplicaTimeout` is not);
-- :mod:`.drill` — the kill/rejoin chaos drill the dryrun acceptance
-  gate runs.
+- :mod:`.drill` — the kill/rejoin chaos drill (``serve --fleet N
+  --drill``).
 """
 
 from .client import ReplicaClient, ReplicaDown, ReplicaTimeout

@@ -1,8 +1,7 @@
 """Kill/rejoin chaos drill: the fleet's acceptance scenario.
 
-One harness, two consumers (the ``fleet-smoke`` dryrun entry and
-ad-hoc CLI drills): drive a skewed
-request mix plus one sticky video stream through the router, hard-kill
+One harness, one consumer (``main.py serve --fleet N --drill``): drive a
+skewed request mix plus one sticky video stream through the router, hard-kill
 a replica mid-stream, and account for what the fleet *promised*:
 
 - zero dropped accepted requests — every submitted request ends in a

@@ -248,20 +248,7 @@ _combine.defvjp(_combine_fwd, _combine_bwd)
 # Channel order of the output: (level, dx, dy) — identical to
 # ops.corr.lookup_pyramid and the reference CorrBlock (raft.py:57-92).
 
-# Two forms. The per-position kernels (``RMD_WCP_BAND=0``) walk a grid row
-# one position at a time; the block kernels below them, the default, work
-# on lane-wide blocks of positions.
-#
-# Per-position form: the slab's x-start is rounded down to a multiple of 8
-# (Mosaic requires statically-provable sublane alignment for dynamic
-# slices); the kernel reads a widened 8-aligned slab and folds the
-# residual shift s = x0 - x8 into a small per-position selection matrix
-# built from iotas. _XW is the widened slab width: ceil((k+1) + 7, 8) for
-# r=4 → 24.
-_XW = 24
-
-
-# Block form. A block is _PBLK consecutive positions of one grid row. One
+# The kernels. A block is _PBLK consecutive positions of one grid row. One
 # pass over a block reads ONE slab of the padded map, (k+8) rows by _XS
 # columns, and contracts it with the block's f1 rows on the MXU in the
 # features' own type: (_PBLK, C) x (C, (k+8)·_XS) -> f32, the slab's
@@ -298,6 +285,11 @@ _PBLK = 80
 _XS = 128
 _XA = 16
 _YSPREAD = 8
+# The padded map's margin, in columns past the last window start: a window
+# reaches k+1 columns past its start (16 at radius 7, the largest the
+# kernels take) and ``_wcp_pads`` rounds the rest up to _XA. The padded
+# maps' width, and so the compiled programs' shapes, hang on this number.
+_XMARGIN = 24
 
 
 def _round_up(n, m):
@@ -307,13 +299,14 @@ def _round_up(n, m):
 def _wcp_pads(radius, dim_w):
     """(lo, hi_y, hi_x) zero-padding of an f2 map so every clamped window
     is a plain in-bounds slice: window starts lie in [0, lo + dim] after
-    clamping centers to [-(r+1), dim+r]; the per-position slab extends
-    k+1 rows and _XW columns past its start, a block's slab k+8 rows and
-    _XS columns, its first column clamped so that it ends with the padded
-    map (whose width is therefore a multiple of _XA and at least _XS)."""
+    clamping centers to [-(r+1), dim+r]; a window extends k+1 rows and
+    columns past its start (_XMARGIN covers the columns), a block's slab
+    k+8 rows and _XS columns, its first column clamped so that it ends
+    with the padded map (whose width is therefore a multiple of _XA and at
+    least _XS)."""
     k = 2 * radius + 1
     lo = k
-    wp = max(_XS, _round_up(lo + dim_w + _XW, _XA))
+    wp = max(_XS, _round_up(lo + dim_w + _XMARGIN, _XA))
     return lo, k + _YSPREAD, wp - lo - dim_w
 
 
@@ -334,64 +327,6 @@ def _wcp_window_start(cx, cy, lvl, dim_h, dim_w, radius):
     x0 = x0f.astype(jnp.int32) - r + lo
     y0 = y0f.astype(jnp.int32) - r + lo
     return x0, y0, cx - x0f, cy - y0f
-
-
-def _wcp_window(cx, cy, lvl, dim_h, dim_w, radius):
-    """The per-position form of the window: the 8-aligned x-start, the
-    residual shift, the y-start and the bilinear fractions."""
-    x0, y0, fx, fy = _wcp_window_start(cx, cy, lvl, dim_h, dim_w, radius)
-    x8 = pl.multiple_of((x0 // 8) * 8, 8)
-    return x8, x0 - x8, y0, fx, fy
-
-
-def _x_select(s, fx, k):
-    """(_XW, k) selection-and-lerp matrix: column dx picks lanes s+dx and
-    s+dx+1 with the bilinear weights — the dynamic lane shift expressed as
-    arithmetic instead of an (unsupported) dynamic lane slice."""
-    ix = jax.lax.broadcasted_iota(jnp.int32, (_XW, k), 0)
-    dxi = jax.lax.broadcasted_iota(jnp.int32, (_XW, k), 1)
-    return (jnp.where(ix == dxi + s, 1.0 - fx, 0.0)
-            + jnp.where(ix == dxi + s + 1, fx, 0.0))
-
-
-def _wcp_fwd_kernel(coords_ref, f1_ref, *f2_refs_and_out, radius, dims):
-    f2_refs = f2_refs_and_out[:-1]
-    out_ref = f2_refs_and_out[-1]
-    k = 2 * radius + 1
-    kk = k * k
-    n_j = f1_ref.shape[2]
-
-    def body(j, _):
-        f1j = f1_ref[0, 0, j].astype(jnp.float32)      # (1, C)
-        cx = coords_ref[0, 0, j, 0]
-        cy = coords_ref[0, 0, j, 1]
-        for lvl, f2_ref in enumerate(f2_refs):
-            h2, w2 = dims[lvl]
-            x8, s, y0, fx, fy = _wcp_window(cx, cy, lvl, h2, w2, radius)
-
-            slab = f2_ref[0, pl.ds(y0, k + 1), pl.ds(x8, _XW), :]
-            d = jnp.sum(slab.astype(jnp.float32) * f1j[None, :, :],
-                        axis=-1)                       # (k+1, _XW): (y, x)
-            t = (1.0 - fy) * d[0:k, :] + fy * d[1:k + 1, :]   # (k, _XW)
-            m = _x_select(s, fx, k)                           # (_XW, k)
-            v = jnp.sum(t[:, :, None] * m[None, :, :], axis=1)  # (dy, dx)
-            vt = v.T                                            # (dx, dy)
-            out_ref[0, 0, j, lvl * k:(lvl + 1) * k, :] = vt
-        return 0
-
-    jax.lax.fori_loop(0, n_j, body, 0)
-
-
-def _unlerp(dout_ref, j, lvl, s, fx, fy, radius):
-    """Transpose of the window lerps: spread the (dy, dx) cost gradient of
-    position j at level lvl onto the widened (k+1, _XW) slab."""
-    k = 2 * radius + 1
-    dv = dout_ref[0, 0, j, lvl * k:(lvl + 1) * k, :].T  # (dy, dx)
-    m = _x_select(s, fx, k)                             # (_XW, k)
-    dt = jnp.sum(dv[:, None, :] * m[None, :, :], axis=2)  # (k, _XW)
-    zr = jnp.zeros((1, _XW), jnp.float32)
-    return ((1.0 - fy) * jnp.concatenate([dt, zr], axis=0)
-            + fy * jnp.concatenate([zr, dt], axis=0))     # (k+1, _XW)
 
 
 # planes of a block pass's per-position parameters, each broadcast over
@@ -690,62 +625,6 @@ def _wcp_bwd_df2_block_kernel(coords_ref, f1_ref, dout_ref, df2_ref, dd_ref,
     jax.lax.fori_loop(0, f1_ref.shape[2] // _PBLK, block, 0)
 
 
-def _wcp_bwd_df1_kernel(coords_ref, dout_ref, *f2_refs_and_out, radius,
-                        dims):
-    """df1 over all levels (reads the f2 maps, touches no df2 state —
-    split from the df2 kernel so each stays under the VMEM budget)."""
-    f2_refs = f2_refs_and_out[:-1]
-    df1_ref = f2_refs_and_out[-1]
-    k = 2 * radius + 1
-    n_j = df1_ref.shape[2]
-
-    def body(j, _):
-        cx = coords_ref[0, 0, j, 0]
-        cy = coords_ref[0, 0, j, 1]
-        acc = None
-        for lvl, f2_ref in enumerate(f2_refs):
-            h2, w2 = dims[lvl]
-            x8, s, y0, fx, fy = _wcp_window(cx, cy, lvl, h2, w2, radius)
-            dd = _unlerp(dout_ref, j, lvl, s, fx, fy, radius)
-
-            slab = f2_ref[0, pl.ds(y0, k + 1), pl.ds(x8, _XW), :]
-            part = jnp.sum(dd[:, :, None] * slab.astype(jnp.float32), axis=0)
-            part = jnp.sum(part, axis=0, keepdims=True)   # (1, C)
-            acc = part if acc is None else acc + part
-        df1_ref[0, 0, j] = acc
-        return 0
-
-    jax.lax.fori_loop(0, n_j, body, 0)
-
-
-def _wcp_bwd_df2_kernel(coords_ref, f1_ref, dout_ref, df2_ref, *, radius,
-                        lvl, dims):
-    """df2 for ONE pyramid level, accumulated across the i-grid (the
-    output block is indexed by b only and stays resident in VMEM).
-    ``dout_ref`` carries only this level's (k, k) channel block."""
-    k = 2 * radius + 1
-    n_j = f1_ref.shape[2]
-    h2, w2 = dims
-    i = pl.program_id(1)
-
-    @pl.when(i == 0)
-    def _():
-        df2_ref[:] = jnp.zeros_like(df2_ref)
-
-    def body(j, _):
-        f1j = f1_ref[0, 0, j].astype(jnp.float32)      # (1, C)
-        cx = coords_ref[0, 0, j, 0]
-        cy = coords_ref[0, 0, j, 1]
-        x8, s, y0, fx, fy = _wcp_window(cx, cy, lvl, h2, w2, radius)
-        dd = _unlerp(dout_ref, j, 0, s, fx, fy, radius)
-
-        df2_ref[0, pl.ds(y0, k + 1), pl.ds(x8, _XW), :] += (
-            dd[:, :, None] * f1j[None, :, :])
-        return 0
-
-    jax.lax.fori_loop(0, n_j, body, 0)
-
-
 def _wcp_pad_f2(f2_levels, radius):
     pads = [_wcp_pads(radius, f2.shape[2]) for f2 in f2_levels]
     return tuple(
@@ -754,26 +633,25 @@ def _wcp_pad_f2(f2_levels, radius):
     )
 
 
-def _wcp_fwd_interpret(f1, f2_levels, coords, radius, band=None):
+def _wcp_fwd_interpret(f1, f2_levels, coords, radius):
     """Interpreter-mode forward (kernel correctness tests off-TPU)."""
-    return _wcp_fwd_tpu(f1, tuple(f2_levels), coords, radius,
-                        interpret=True, band=band)
+    return _wcp_fwd_tpu(f1, tuple(f2_levels), coords, radius, interpret=True)
 
 
-def _wcp_bwd_interpret(f1, f2_levels, coords, dout, radius, band=None):
+def _wcp_bwd_interpret(f1, f2_levels, coords, dout, radius):
     """Interpreter-mode backward (kernel correctness tests off-TPU)."""
     return _wcp_bwd_tpu(f1, tuple(f2_levels), coords, dout, radius,
-                        interpret=True, band=band)
+                        interpret=True)
 
 
 _WCP_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=100 * 1024 * 1024)
 
 
-def _wcp_row_spec(n_j, *minor, memory_space=pltpu.VMEM):
+def _wcp_row_spec(n_j, *minor):
     """One (batch, grid row) of a (b, n_i, n_j, ...) operand a step."""
     zeros = (0,) * (1 + len(minor))
     return pl.BlockSpec((1, 1, n_j) + minor, lambda bi, ii: (bi, ii) + zeros,
-                        memory_space=memory_space)
+                        memory_space=pltpu.VMEM)
 
 
 def _wcp_map_spec(f2):
@@ -802,56 +680,29 @@ def _wcp_block_scratch(radius):
             pltpu.VMEM((5, _PBLK, _XS), jnp.float32)]
 
 
-def _wcp_fwd_tpu(f1, f2_levels, coords, radius, interpret=False,
-                 band=None):
+def _wcp_fwd_tpu(f1, f2_levels, coords, radius, interpret=False):
     b, n_i, n_j, c = f1.shape
     k = 2 * radius + 1
     n_lvl = len(f2_levels)
     dims = tuple((f2.shape[1], f2.shape[2]) for f2 in f2_levels)
     f2p = _wcp_pad_f2(f2_levels, radius)
-    if band is None:
-        band = _wcp_band_enabled()
 
-    if band:
-        # the costs leave flat, (level, dx, dy) on the lanes: no reshape
-        n_jp, f1, coords = _wcp_block_pad(n_j, f1, coords)
-        out = pl.pallas_call(
-            functools.partial(_wcp_fwd_block_kernel, radius=radius,
-                              dims=dims, unroll=not interpret),
-            out_shape=jax.ShapeDtypeStruct((b, n_i, n_jp, n_lvl * k * k),
-                                           jnp.float32),
-            grid=(b, n_i),
-            in_specs=[_wcp_row_spec(n_jp, 2), _wcp_row_spec(n_jp, c)]
-            + [_wcp_map_spec(f2) for f2 in f2p],
-            out_specs=_wcp_row_spec(n_jp, n_lvl * k * k),
-            scratch_shapes=_wcp_block_scratch(radius),
-            compiler_params=_WCP_PARAMS,
-            interpret=interpret,
-        )(coords, f1, *f2p)
-        return out if n_jp == n_j else out[:, :, :n_j]
-
-    # j rides an untiled axis (the dummy sublane dim keeps the last-two
-    # dims static so per-position dynamic indexing is legal)
+    # the costs leave flat, (level, dx, dy) on the lanes: no reshape
+    n_jp, f1, coords = _wcp_block_pad(n_j, f1, coords)
     out = pl.pallas_call(
-        functools.partial(_wcp_fwd_kernel, radius=radius, dims=dims),
-        out_shape=jax.ShapeDtypeStruct((b, n_i, n_j, n_lvl * k, k),
+        functools.partial(_wcp_fwd_block_kernel, radius=radius, dims=dims,
+                          unroll=not interpret),
+        out_shape=jax.ShapeDtypeStruct((b, n_i, n_jp, n_lvl * k * k),
                                        jnp.float32),
         grid=(b, n_i),
-        in_specs=[_wcp_row_spec(n_j, 2, memory_space=pltpu.SMEM),
-                  _wcp_row_spec(n_j, 1, c)]
+        in_specs=[_wcp_row_spec(n_jp, 2), _wcp_row_spec(n_jp, c)]
         + [_wcp_map_spec(f2) for f2 in f2p],
-        out_specs=_wcp_row_spec(n_j, n_lvl * k, k),
+        out_specs=_wcp_row_spec(n_jp, n_lvl * k * k),
+        scratch_shapes=_wcp_block_scratch(radius),
         compiler_params=_WCP_PARAMS,
         interpret=interpret,
-    )(coords, f1.reshape(b, n_i, n_j, 1, c), *f2p)
-    # (level, dx, dy) channel flatten — (L*k, k) row-major is exactly that
-    return out.reshape(b, n_i, n_j, n_lvl * k * k)
-
-
-def _wcp_band_enabled():
-    from ..utils import env
-
-    return env.get_bool("RMD_WCP_BAND")
+    )(coords, f1, *f2p)
+    return out if n_jp == n_j else out[:, :, :n_j]
 
 
 def _wcp_strip(df2_l, dim, radius):
@@ -860,92 +711,50 @@ def _wcp_strip(df2_l, dim, radius):
     return df2_l[:, lo:lo + dim[0], lo:lo + dim[1], :]
 
 
-def _wcp_bwd_tpu(f1, f2_levels, coords, dout, radius, interpret=False,
-                 band=None):
+def _wcp_bwd_tpu(f1, f2_levels, coords, dout, radius, interpret=False):
     b, n_i, n_j, c = f1.shape
     f2p = _wcp_pad_f2(f2_levels, radius)
     dims = tuple((f2.shape[1], f2.shape[2]) for f2 in f2_levels)
-    if band is None:
-        band = _wcp_band_enabled()
-
     k = 2 * radius + 1
     n_lvl = len(f2_levels)
 
-    if band:
-        # the cotangent enters flat, as the costs left; df1 leaves in the
-        # features' type
-        n_jp, f1, dout, coords = _wcp_block_pad(n_j, f1, dout, coords)
-        coords_spec = _wcp_row_spec(n_jp, 2)
-        dout_spec = _wcp_row_spec(n_jp, n_lvl * k * k)
-        f1_spec = _wcp_row_spec(n_jp, c)
-        scratch = _wcp_block_scratch(radius) + [
-            pltpu.VMEM((8, _XS), jnp.float32)]
-
-        df1 = pl.pallas_call(
-            functools.partial(_wcp_bwd_df1_block_kernel, radius=radius,
-                              dims=dims, unroll=not interpret),
-            out_shape=jax.ShapeDtypeStruct((b, n_i, n_jp, c), f1.dtype),
-            grid=(b, n_i),
-            in_specs=[coords_spec, dout_spec]
-            + [_wcp_map_spec(f2) for f2 in f2p],
-            out_specs=f1_spec,
-            scratch_shapes=scratch + [pltpu.VMEM((_PBLK, c), jnp.float32)],
-            compiler_params=_WCP_PARAMS,
-            interpret=interpret,
-        )(coords, dout, *f2p)
-        if n_jp != n_j:
-            df1 = df1[:, :, :n_j]
-
-        df2_out = []
-        for lvl, f2 in enumerate(f2p):
-            df2_l = pl.pallas_call(
-                functools.partial(_wcp_bwd_df2_block_kernel, radius=radius,
-                                  lvl=lvl, n_lvl=n_lvl, dims=dims[lvl],
-                                  unroll=not interpret),
-                out_shape=jax.ShapeDtypeStruct(f2.shape, jnp.float32),
-                grid=(b, n_i),
-                in_specs=[coords_spec, f1_spec, dout_spec],
-                out_specs=_wcp_map_spec(f2),
-                scratch_shapes=scratch,
-                compiler_params=_WCP_PARAMS,
-                interpret=interpret,
-            )(coords, f1, dout)
-            df2_out.append(_wcp_strip(df2_l, dims[lvl], radius))
-        return df1, tuple(df2_out)
-
-    doutr = dout.reshape(b, n_i, n_j, n_lvl * k, k)
-    coords_spec = _wcp_row_spec(n_j, 2, memory_space=pltpu.SMEM)
-    row_spec = _wcp_row_spec(n_j, 1, c)
-    f1r = f1.reshape(b, n_i, n_j, 1, c)
+    # the cotangent enters flat, as the costs left; df1 leaves in the
+    # features' type
+    n_jp, f1, dout, coords = _wcp_block_pad(n_j, f1, dout, coords)
+    coords_spec = _wcp_row_spec(n_jp, 2)
+    dout_spec = _wcp_row_spec(n_jp, n_lvl * k * k)
+    f1_spec = _wcp_row_spec(n_jp, c)
+    scratch = _wcp_block_scratch(radius) + [pltpu.VMEM((8, _XS), jnp.float32)]
 
     df1 = pl.pallas_call(
-        functools.partial(_wcp_bwd_df1_kernel, radius=radius, dims=dims),
-        out_shape=jax.ShapeDtypeStruct((b, n_i, n_j, 1, c), jnp.float32),
+        functools.partial(_wcp_bwd_df1_block_kernel, radius=radius, dims=dims,
+                          unroll=not interpret),
+        out_shape=jax.ShapeDtypeStruct((b, n_i, n_jp, c), f1.dtype),
         grid=(b, n_i),
-        in_specs=[coords_spec, _wcp_row_spec(n_j, n_lvl * k, k)]
-        + [_wcp_map_spec(f2) for f2 in f2p],
-        out_specs=row_spec,
+        in_specs=[coords_spec, dout_spec] + [_wcp_map_spec(f2) for f2 in f2p],
+        out_specs=f1_spec,
+        scratch_shapes=scratch + [pltpu.VMEM((_PBLK, c), jnp.float32)],
         compiler_params=_WCP_PARAMS,
         interpret=interpret,
-    )(coords, doutr, *f2p).reshape(b, n_i, n_j, c)
+    )(coords, dout, *f2p)
+    if n_jp != n_j:
+        df1 = df1[:, :, :n_j]
 
     df2_out = []
     for lvl, f2 in enumerate(f2p):
-        # pass only this level's dout columns; the raised scoped-vmem cap
-        # holds the accumulated df2 block (revisited across the i-grid)
-        dout_l = doutr[:, :, :, lvl * k:(lvl + 1) * k, :]
         df2_l = pl.pallas_call(
-            functools.partial(_wcp_bwd_df2_kernel, radius=radius, lvl=lvl,
-                              dims=dims[lvl]),
+            functools.partial(_wcp_bwd_df2_block_kernel, radius=radius,
+                              lvl=lvl, n_lvl=n_lvl, dims=dims[lvl],
+                              unroll=not interpret),
             out_shape=jax.ShapeDtypeStruct(f2.shape, jnp.float32),
             grid=(b, n_i),
-            in_specs=[coords_spec, row_spec, _wcp_row_spec(n_j, k, k)],
+            in_specs=[coords_spec, f1_spec, dout_spec],
             out_specs=_wcp_map_spec(f2),
+            scratch_shapes=scratch,
             compiler_params=_WCP_PARAMS,
             interpret=interpret,
-        )(coords, f1r, dout_l)
+        )(coords, f1, dout)
         df2_out.append(_wcp_strip(df2_l, dims[lvl], radius))
-
     return df1, tuple(df2_out)
 
 
@@ -966,10 +775,9 @@ def _wcp_fits_vmem(f1, f2_levels, radius):
     every padded f2 map in VMEM; beyond ~64M even the raised compiler
     budget cannot place it, so oversized shapes take the XLA path.
 
-    Also gates on radius: the widened slab width _XW covers the
-    (k+1)-lane window plus the ≤7-lane alignment shift only for
-    radius ≤ 7 — beyond that the x-selection matrix would silently drop
-    the last lerp lane, so larger radii take the (exact) XLA path too.
+    Also gates on radius, kept as found: no kernel has been compiled or
+    tested past radius 7, where a window's k+1 columns are 16 of the
+    pad's _XMARGIN, so larger radii take the (exact) XLA path too.
     """
     if radius > 7:
         return False
@@ -1049,7 +857,7 @@ def windowed_corr_pyramid(f1, f2_levels, coords, radius=4, mask_costs=(),
     on the way to ``_WindowConv1x1``; the gradient is float32 costs in,
     ``df1`` and every ``df2`` out in the features' type, zero for the
     centres. ``wcp_shared_share`` gives the share of a field's blocks that
-    one pass serves (``RMD_WCP_BAND=0``: the per-position kernels).
+    one pass serves.
 
     Which form the call traced is counted (``wcp_fused_calls`` /
     ``wcp_fallback_calls``, ``telemetry.note_trace``), as the window
@@ -1196,7 +1004,7 @@ def _sw_fwd_kernel(coords_ref, f2_ref, out_ref, *, radius, dims):
 def _sw_bwd_kernel(coords_ref, dout_ref, df2_ref, *, radius, dims):
     """df2 accumulated across the i-grid (the padded output block is
     indexed by b only and stays resident in VMEM, like
-    ``_wcp_bwd_df2_kernel``)."""
+    ``_wcp_bwd_df2_block_kernel``)."""
     k = 2 * radius + 1
     h2, w2 = dims
     n_j = dout_ref.shape[2]

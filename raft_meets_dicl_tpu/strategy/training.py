@@ -102,9 +102,8 @@ def _device_prefetch(samples, put, depth=2):
     (host_batch, device_batch, meta, put_span, pull_span) with transfers
     already in flight. Loader exceptions re-raise at the consumption point.
 
-    ``RMD_PREFETCH=0`` swaps in :func:`_sync_transfer` (identical batch
-    stream, transfer left on the critical path — the A/B baseline);
-    ``RMD_PREFETCH_DEPTH`` tunes the buffer count.
+    The stream is the plain iterator with ``put`` applied, item for item
+    and in order; the training loop runs it at ``depth`` 2.
 
     ``put_span`` is the ``perf_counter`` ``(t0, t1)`` of the worker's
     ``put`` (wire encode + transfer initiation) of *this* batch: it rides
@@ -157,21 +156,6 @@ def _timed(samples):
         except StopIteration:
             return
         yield item, (t0, time.perf_counter())
-
-
-def _sync_transfer(samples, put):
-    """RMD_PREFETCH=0: the same (host, dev, meta, put_span, pull_span)
-    stream as :func:`_device_prefetch` with the transfer kept synchronous
-    on the critical path — the bit-identical A/B baseline for the prefetch
-    overlap, and an escape hatch for backends whose background-thread
-    ``device_put`` misbehaves. The pull and the put then lie inside the
-    consuming step's ``start`` → ``data`` and count towards its wall
-    time."""
-    for (img1, img2, flow, valid, meta), pull_span in _timed(samples):
-        host = (img1, img2, flow, valid)
-        t0 = time.perf_counter()
-        dev = put(host)
-        yield host, dev, meta, (t0, time.perf_counter()), pull_span
 
 
 class _StepResult:
@@ -800,11 +784,6 @@ class TrainingContext:
 
         base_put = ((lambda b: shard_batch(b, self.mesh))
                     if self.mesh is not None else jax.device_put)
-        if not utils.env.get_bool("RMD_PREFETCH_PUT"):
-            # host-only prefetch: overlap decode but let jit do the
-            # implicit arg transfer (fallback for backends whose explicit
-            # device_put path misbehaves)
-            base_put = lambda b: b  # noqa: E731
 
         if (self.wire is None
                 and getattr(getattr(self.model, "module", None),
@@ -826,17 +805,10 @@ class TrainingContext:
         else:
             put = _make_put(base_put, self.wire, tele)
 
-        # double-buffered prefetch (default): batch N+1's device_put runs
-        # on a background thread while step N executes, so the transfer
-        # never sits on the step critical path. RMD_PREFETCH=0 restores
-        # the synchronous put (bit-identical results, for A/B and as an
-        # escape hatch); RMD_PREFETCH_DEPTH tunes how far ahead.
-        prefetch = utils.env.get_bool("RMD_PREFETCH")
-        if not prefetch:
-            batches = _sync_transfer(samples, put)
-        else:
-            depth = max(1, utils.env.get_int("RMD_PREFETCH_DEPTH"))
-            batches = _device_prefetch(samples, put, depth=depth)
+        # double-buffered prefetch: batch N+1's device_put runs on a
+        # background thread while step N executes, so the transfer never
+        # sits on the step critical path
+        batches = _device_prefetch(samples, put, depth=2)
 
         it = enumerate(batches)
         while True:
@@ -848,7 +820,6 @@ class TrainingContext:
             if nxt is None:
                 break
             i, (host, dev, meta, strace.put, strace.pull) = nxt
-            strace.put_inline = not prefetch
             fetched = [m.fetch_s for m in meta if m.fetch_s is not None]
             if fetched:
                 strace.fetch = sum(fetched) / len(fetched)
@@ -872,17 +843,13 @@ class TrainingContext:
         self.inspector.flush()
         self._flush_finite_check(log)
 
-        # memory watermarks: RMD_DEBUG_MEM's ad-hoc print, promoted to a
-        # structured per-epoch event (snapshot cost is one procfs read +
-        # a live-array census — epoch-boundary cheap)
-        if tele.enabled or utils.env.get_bool("RMD_DEBUG_MEM"):
+        # memory watermarks: a structured per-epoch event (snapshot cost
+        # is one procfs read + a live-array census — epoch-boundary cheap)
+        if tele.enabled:
             snap = telemetry.memory_snapshot()
             self.last_memory = snap
             tele.emit("memory", stage=stage.index, epoch=epoch,
                       step=self.step, **snap)
-            if utils.env.get_bool("RMD_DEBUG_MEM"):
-                log.info(f"mem: rss {snap['host_rss_gib']:.2f} GiB, "
-                         f"live jax arrays {snap['live_arrays']}")
 
         if self._stop:
             # mid-epoch preemption: the epoch didn't complete, so neither

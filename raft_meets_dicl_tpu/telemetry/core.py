@@ -754,8 +754,7 @@ _install_listeners = install_listeners
 def memory_snapshot():
     """Host RSS + live jax arrays + device peak bytes (where exposed).
 
-    The promoted form of the old ad-hoc ``RMD_DEBUG_MEM`` print — cheap
-    enough to take at every epoch boundary.
+    Cheap enough to take at every epoch boundary.
     """
     rss = 0.0
     try:

@@ -390,7 +390,6 @@ def aot_stats(events):
                 "compile_cache": e.get("compile_cache"),
                 "aot_dir": e.get("aot_dir"),
                 "aot": e.get("aot"),
-                "prefetch": e.get("prefetch"),
             }
         elif e["kind"] == "aot" and e.get("event") != "owners":
             # (an ``owners`` record's seconds are its parse's, not an
@@ -1073,11 +1072,6 @@ def render(events, errors=(), warmup_steps=DEFAULT_WARMUP_STEPS,
                 f"compile cache: {boot['compile_cache'] or 'disabled'}")
             lines.append(
                 f"AOT programs:  {boot['aot_dir'] or 'disabled'}")
-            if boot.get("prefetch") is not None:
-                lines.append(
-                    "prefetch:      "
-                    + ("on (double-buffered device_put)"
-                       if boot["prefetch"] else "off (synchronous)"))
         for (program, model), agg in sorted(aot["programs"].items()):
             lines.append(
                 f"{program}[{model}]: {agg['hit']} AOT hits, "

@@ -59,8 +59,7 @@ STARVED_SHARE = 0.5
 class StepTrace:
     """Timestamps of one training step on a single perf_counter clock."""
 
-    __slots__ = ("step", "marks", "put", "pull", "put_inline", "fetch",
-                 "cpu")
+    __slots__ = ("step", "marks", "put", "pull", "fetch", "cpu")
 
     def __init__(self, step=None):
         self.step = step
@@ -69,9 +68,6 @@ class StepTrace:
         self.pull = None    # (t0, t1) of the pull that produced the batch
         self.fetch = None   # mean seconds a worker spent on one sample
         self.cpu = None     # process_time() at ``start``
-        # the put ran on the loop's own thread, inside the pull
-        # (RMD_PREFETCH=0): its time is then part of the step total
-        self.put_inline = False
 
     def mark(self, name, t=None):
         if name not in MARKS:
@@ -105,14 +101,10 @@ class StepTrace:
         """The phases as the ``step`` event reports them: ``device_put``
         is the batch's ``put`` (wire encode + transfer initiation). On the
         prefetch worker's thread it lies outside the step, the one phase
-        that is not part of the telescoping sum; with ``RMD_PREFETCH=0``
-        it ran inside the pull and is taken out of ``data_wait``, so the
-        sum stays the step's total."""
+        that is not part of the telescoping sum."""
         out = self.phases()
         if self.put is not None:
             p0, p1 = self.put
-            if self.put_inline and "data_wait" in out:
-                out["data_wait"] -= p1 - p0
             out["device_put"] = out.get("device_put", 0.0) + (p1 - p0)
         return out
 

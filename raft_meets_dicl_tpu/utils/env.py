@@ -13,11 +13,11 @@ touching ``os.environ`` directly. That buys three things:
    read but not registered, and on a README table that drifted from the
    registry.
 2. **Uniform semantics.** Default-on switches (``RMD_TELEMETRY=0``
-   disables), default-off flags (``RMD_DEBUG_MEM=1`` enables), and typed
+   disables), default-off flags (``RMD_DEVICE_AUG=1`` enables), and typed
    values (int/float/str) each parse exactly one way, instead of every
    call site re-inventing ``!= "0"`` vs ``bool(get(...))``.
-3. **Greppability.** ``env.get_bool("RMD_PREFETCH")`` names the knob as
-   a literal, so the registry-completeness check (and a human) can find
+3. **Greppability.** ``env.get_bool("RMD_AOT")`` names the knob as a
+   literal, so the registry-completeness check (and a human) can find
    every consumer.
 
 This module must stay dependency-free (no jax/numpy): it is imported by
@@ -57,9 +57,6 @@ KNOBS = dict([
     # -- telemetry ---------------------------------------------------------
     _k("RMD_TELEMETRY", "switch", True,
        "kill switch for the telemetry sink and jax.monitoring listeners",
-       "telemetry"),
-    _k("RMD_DEBUG_MEM", "flag", False,
-       "print per-epoch memory snapshots even with telemetry disabled",
        "telemetry"),
     _k("RMD_FINITE_CHECK_EVERY", "int", 10,
        "amortized cadence (steps) of the device finiteness fetch / "
@@ -127,14 +124,6 @@ KNOBS = dict([
        "default base seed of the synthetic scene generator (per-source "
        "'seed:' wins)", "input"),
     # -- training loop -----------------------------------------------------
-    _k("RMD_PREFETCH", "switch", True,
-       "double-buffered host-to-device prefetch (0 = synchronous "
-       "transfer, bit-identical)", "training"),
-    _k("RMD_PREFETCH_DEPTH", "int", 2,
-       "how many batches ahead the prefetch worker runs", "training"),
-    _k("RMD_PREFETCH_PUT", "switch", True,
-       "perform the device_put inside the prefetch worker (0 = put on "
-       "the consumer thread)", "training"),
     _k("RMD_NONFINITE", "str", None,
        "non-finite step policy (raise | skip | rollback); CLI "
        "--nonfinite wins", "training"),
@@ -164,9 +153,6 @@ KNOBS = dict([
     _k("RMD_DICL_FAST", "switch", True,
        "level-batched MatchingNets + fused Pallas window sampler (0 = "
        "reference loop)", "models"),
-    _k("RMD_WCP_BAND", "switch", True,
-       "windowed-correlation Pallas kernels on blocks of 80 positions "
-       "sharing a slab (0 = per-position form)", "models"),
     _k("RMD_FS_VOLUME_GIB", "float", 4.0,
        "raft/fs correlation-volume HBM budget steering the "
        "volume/windowed dispatch (per chip)", "models"),
@@ -256,9 +242,6 @@ KNOBS = dict([
        "deterministic fault injection spec (testing.faults)", "faults"),
     _k("RMD_FAULT_STATE", "str", None,
        "directory sharing fired-once fault state across processes",
-       "faults"),
-    _k("RMD_DRYRUN_BUDGET_S", "float", 420.0,
-       "wall-clock budget for the __graft_entry__ multi-chip dry run",
        "faults"),
 ])
 

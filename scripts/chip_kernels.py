@@ -12,10 +12,10 @@ the family's own ``_*_reference`` evaluated at highest matmul precision:
   at b6 400x720, 12 iterations: 324,000 rows of 576 logits;
 - ``wcp`` — the windowed correlation pyramid of ``raft/fs`` at
   cfg/strategy/highres/raft-fs.hd1k-1080p.yaml: b1 1072x2560, C=256,
-  r=4, block and per-position forms, with all 4 levels on the kernel and
-  with the prefix the volume/windowed dispatch leaves on it; and the
-  benchmark cell's shape, b1 1088x1920 (136x240), where that prefix is
-  level 0 alone, the block form there on three fields of centres (zero
+  r=4, with all 4 levels on the kernel and with the prefix the
+  volume/windowed dispatch leaves on it; and the benchmark cell's shape,
+  b1 1088x1920 (136x240), where that prefix is level 0 alone, there on
+  three fields of centres (zero
   flow, a smooth field, a Things-like field of objects that move up to
   40 grid cells against their background), each with the milliseconds a
   call of forward, ``df1`` and ``df2`` and the share of its blocks that
@@ -84,8 +84,8 @@ def _highest(fn):
 
 def _coords(rng, b, h, w):
     """Window centers as the recurrence produces them: the pixel grid
-    plus a smooth flow (band-shared chunks), per-pixel noise (chunks that
-    spread past the shared slab) and a strip thrown out of bounds."""
+    plus a smooth flow (blocks one slab serves), per-pixel noise (blocks
+    that spread past the slab) and a strip thrown out of bounds."""
     yy, xx = np.meshgrid(np.arange(h), np.arange(w), indexing="ij")
     fx = 12.0 * np.sin(yy / 17.0) + rng.normal(0, 0.7, (b, h, w))
     fy = 9.0 * np.cos(xx / 23.0) + rng.normal(0, 0.7, (b, h, w))
@@ -194,7 +194,7 @@ def _ms_a_call(fn, *args):
     return 1e3 * (time.perf_counter() - t0) / _CALLS
 
 
-def case_wcp(levels, band, dtype=jnp.bfloat16, h=134, w=320, c=256,
+def case_wcp(levels, dtype=jnp.bfloat16, h=134, w=320, c=256,
              field="noisy"):
     b, radius = 1, 4
     rng = np.random.default_rng(1)
@@ -210,10 +210,8 @@ def case_wcp(levels, band, dtype=jnp.bfloat16, h=134, w=320, c=256,
         raise RuntimeError("_wcp_fits_vmem says no: dispatch would take "
                            "the XLA path at this shape")
 
-    fwd = jax.jit(lambda a, bb, cc: K._wcp_fwd_tpu(a, bb, cc, radius,
-                                                   band=band))
-    bwd = jax.jit(lambda a, bb, cc, d: K._wcp_bwd_tpu(a, bb, cc, d, radius,
-                                                      band=band))
+    fwd = jax.jit(lambda a, bb, cc: K._wcp_fwd_tpu(a, bb, cc, radius))
+    bwd = jax.jit(lambda a, bb, cc, d: K._wcp_bwd_tpu(a, bb, cc, d, radius))
     out, cold_f, _ = _timed(fwd, f1, f2, coords)
     (df1, df2), cold_b, _ = _timed(bwd, f1, f2, coords, dout)
     # each kernel alone: the other calls of the jitted pair are dead code
@@ -245,7 +243,7 @@ def case_wcp(levels, band, dtype=jnp.bfloat16, h=134, w=320, c=256,
     ref_s = time.perf_counter() - t0
     report = {
         "shape": f"b1 {h}x{w} C={c} r={radius} levels={levels} "
-                 f"band={band} field={field}",
+                 f"field={field}",
         "dtype": jnp.dtype(dtype).name,
         "err": {"fwd": _err(out, jnp.concatenate(outs, 1)),
                 "df1": _err(df1, jnp.concatenate(df1s, 1)),
@@ -253,10 +251,9 @@ def case_wcp(levels, band, dtype=jnp.bfloat16, h=134, w=320, c=256,
                    for i, (g, w_) in enumerate(zip(df2, df2_sum))}},
         "compile_s": round(cold_f + cold_b, 2),
         "ms": {**ms, "ref_fwd_bwd_incl_compile": 1e3 * ref_s},
+        "shared_share": round(float(K.wcp_shared_share(
+            coords, [x.shape[1:3] for x in f2], radius)), 4),
     }
-    if band:
-        report["shared_share"] = round(float(K.wcp_shared_share(
-            coords, [x.shape[1:3] for x in f2], radius)), 4)
     return report
 
 
@@ -307,16 +304,13 @@ def cases(families):
         # the prefix the dispatch leaves on the kernel at this shape
         n_win = volume_level_split((1, 134, 320), 4, 2)
         for levels in sorted({4, n_win} - {0}):
-            yield f"wcp/levels{levels}/block", case_wcp, (levels, True)
-            yield f"wcp/levels{levels}/position", case_wcp, (levels, False)
+            yield f"wcp/levels{levels}/block", case_wcp, (levels,)
         # the cell fs-train-1080p: b1 1088x1920, level 0 alone (levels
         # 1-3 are materialised volumes there)
         n_win = volume_level_split((1, 136, 240), 4, 2)
         for field in ("zero", "smooth", "things"):
             yield f"wcp/136x240/levels{n_win}/block/{field}", case_wcp, (
-                n_win, True, jnp.bfloat16, 136, 240, 256, field)
-        yield f"wcp/136x240/levels{n_win}/position", case_wcp, (
-            n_win, False, jnp.bfloat16, 136, 240)
+                n_win, jnp.bfloat16, 136, 240, 256, field)
     if "sw" in families:
         yield "sw/f32", case_sw, (jnp.float32,)
         yield "sw/bf16", case_sw, (jnp.bfloat16,)

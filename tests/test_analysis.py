@@ -212,7 +212,7 @@ def test_envknob_flags_reads_not_writes():
         import os
 
         v = os.environ.get("RMD_TELEMETRY")
-        w = os.environ["RMD_PREFETCH"]
+        w = os.environ["RMD_AOT"]
         armed = "RMD_FAULT" in os.environ
         os.environ["RMD_FAULT"] = "decode:1"   # write: legal
         del os.environ["RMD_FAULT"]            # delete: legal
@@ -220,7 +220,7 @@ def test_envknob_flags_reads_not_writes():
     found = envknobs.check(m)
     assert len(found) == 3
     msgs = " ".join(f.message for f in found)
-    for name in ("RMD_TELEMETRY", "RMD_PREFETCH", "RMD_FAULT"):
+    for name in ("RMD_TELEMETRY", "RMD_AOT", "RMD_FAULT"):
         assert name in msgs
     assert all("utils.env" in f.message for f in found)
 

@@ -368,14 +368,12 @@ def test_a_batch_handed_out_is_never_written_again(workers):
         assert all(a.flags.owndata and a.flags.writeable for a in batch[:4])
 
 
-@pytest.mark.parametrize("prefetch", [True, False], ids=["prefetch", "sync"])
-def test_transfer_hands_on_the_pull_span(prefetch):
+def test_transfer_hands_on_the_pull_span():
     """Each batch comes with the interval of the ``next()`` that produced
     it, ahead of its own ``put``."""
     import time
 
-    from raft_meets_dicl_tpu.strategy.training import (_device_prefetch,
-                                                       _sync_transfer)
+    from raft_meets_dicl_tpu.strategy.training import _device_prefetch
 
     def slow():
         for i in range(4):
@@ -386,9 +384,7 @@ def test_transfer_hands_on_the_pull_span(prefetch):
         time.sleep(0.002)
         return batch
 
-    stream = _device_prefetch(slow(), put, depth=2) if prefetch \
-        else _sync_transfer(slow(), put)
-    got = list(stream)
+    got = list(_device_prefetch(slow(), put, depth=2))
     assert [meta for _, _, meta, _, _ in got] == [[0], [1], [2], [3]]
     for _host, _dev, _meta, (p0, p1), (t0, t1) in got:
         assert t1 - t0 >= 0.009 and p1 - p0 >= 0.0019

@@ -228,12 +228,13 @@ class TestPool:
         np.testing.assert_allclose(ours, t.permute(0, 2, 3, 1).numpy(), atol=1e-6)
 
 
-@pytest.mark.parametrize("band", [False, True])
-def test_windowed_corr_pyramid_kernel_matches_reference(band):
-    """The fused windowed-correlation kernel (interpreter mode off-TPU)
-    matches the per-level XLA composition, forward and backward — both
-    the per-position path and the band-shared chunk path (whose mixed
-    per-chunk flow spread exercises the shared/fallback lax.cond)."""
+@pytest.mark.parametrize("radius,n_levels", [(4, 4), (7, 2)])
+def test_windowed_corr_pyramid_kernel_matches_reference(radius, n_levels):
+    """The fused windowed-correlation kernels (interpreter mode off-TPU)
+    match the per-level XLA composition, forward and backward, on a row
+    that is no whole block and centres scattered far off the map (passes
+    over a block again, the zero padding), at the models' radius and at
+    the largest ``_wcp_fits_vmem`` admits (a window's 16 columns)."""
     from raft_meets_dicl_tpu.ops import pallas as pk
     from raft_meets_dicl_tpu.ops.pool import avg_pool2d
 
@@ -242,9 +243,10 @@ def test_windowed_corr_pyramid_kernel_matches_reference(band):
     f1 = jnp.asarray(rs.randn(b, h, w, c), jnp.float32)
     f2 = jnp.asarray(rs.randn(b, h, w, c), jnp.float32)
     levels = [f2]
-    for _ in range(3):
+    for _ in range(n_levels - 1):
         levels.append(avg_pool2d(levels[-1], 2))
     levels = tuple(levels)
+    assert pk._wcp_fits_vmem(f1, levels, radius)
 
     gy, gx = jnp.meshgrid(jnp.arange(h, dtype=jnp.float32),
                           jnp.arange(w, dtype=jnp.float32), indexing="ij")
@@ -252,16 +254,15 @@ def test_windowed_corr_pyramid_kernel_matches_reference(band):
     coords = (jnp.stack([gx, gy], -1)[None].repeat(b, 0)
               + jnp.asarray(rs.randn(b, h, w, 2) * 8, jnp.float32))
 
-    ref = pk._wcp_reference(f1, levels, coords, 4)
-    out = pk._wcp_fwd_interpret(f1, levels, coords, 4, band=band)
+    ref = pk._wcp_reference(f1, levels, coords, radius)
+    out = pk._wcp_fwd_interpret(f1, levels, coords, radius)
     assert np.allclose(np.asarray(out), np.asarray(ref), atol=1e-4)
 
     dout = jnp.asarray(rs.randn(*ref.shape), jnp.float32)
-    _, vjp = jax.vjp(lambda a, bb: pk._wcp_reference(a, bb, coords, 4),
+    _, vjp = jax.vjp(lambda a, bb: pk._wcp_reference(a, bb, coords, radius),
                      f1, levels)
     df1_r, df2_r = vjp(dout)
-    df1, df2 = pk._wcp_bwd_interpret(f1, levels, coords, dout, 4,
-                                     band=band)
+    df1, df2 = pk._wcp_bwd_interpret(f1, levels, coords, dout, radius)
     assert np.allclose(np.asarray(df1), np.asarray(df1_r), atol=1e-4)
     for got, want in zip(df2, df2_r):
         assert np.allclose(np.asarray(got), np.asarray(want), atol=1e-4)

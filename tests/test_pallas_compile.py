@@ -60,21 +60,19 @@ compile_for_v5e(lambda a, b: K._run_fwd(a, b, 0.25),
 compile_for_v5e(lambda a, b, c: K._run_bwd(a, b, c, 0.25),
                 ((m, 576), bf16), ((m, 18), f32), ((m, 128), f32))
 
-# windowed correlation pyramid, raft/fs at 1080p: every level resident,
-# in the block form (band: lane-wide blocks of 80 positions, the flat costs)
-# and in the per-position form
+# windowed correlation pyramid, raft/fs at 1080p: every level resident
+# (lane-wide blocks of 80 positions, the flat costs)
 h, w, c, levels = 134, 320, 256, 4
 f1 = ((1, h, w, c), bf16)
 f2 = tuple(((1, h >> l, w >> l, c), bf16) for l in range(levels))
 coords = ((1, h, w, 2), f32)
 dout = ((1, h, w, levels * 81), f32)
-for band in (True, False):
-    compile_for_v5e(
-        lambda a, cc, *bb: K._wcp_fwd_tpu(a, bb, cc, 4, band=band),
-        f1, coords, *f2)
-    compile_for_v5e(
-        lambda a, cc, d, *bb: K._wcp_bwd_tpu(a, bb, cc, d, 4, band=band),
-        f1, coords, dout, *f2)
+compile_for_v5e(
+    lambda a, cc, *bb: K._wcp_fwd_tpu(a, bb, cc, 4),
+    f1, coords, *f2)
+compile_for_v5e(
+    lambda a, cc, d, *bb: K._wcp_bwd_tpu(a, bb, cc, d, 4),
+    f1, coords, dout, *f2)
 
 # ... and the hybrid call of the cell fs-train-1080p: level 0 alone at
 # 136x240 (1088x1920), the coarser levels being materialised volumes
@@ -82,13 +80,12 @@ h, w = 136, 240
 f1 = f2 = ((1, h, w, c), bf16)
 coords = ((1, h, w, 2), f32)
 dout = ((1, h, w, 81), f32)
-for band in (True, False):
-    compile_for_v5e(
-        lambda a, cc, b: K._wcp_fwd_tpu(a, (b,), cc, 4, band=band),
-        f1, coords, f2)
-    compile_for_v5e(
-        lambda a, cc, d, b: K._wcp_bwd_tpu(a, (b,), cc, d, 4, band=band),
-        f1, coords, dout, f2)
+compile_for_v5e(
+    lambda a, cc, b: K._wcp_fwd_tpu(a, (b,), cc, 4),
+    f1, coords, f2)
+compile_for_v5e(
+    lambda a, cc, d, b: K._wcp_bwd_tpu(a, (b,), cc, d, 4),
+    f1, coords, dout, f2)
 
 # ... and the same call without the bf16 policy, which _wcp_fits_vmem
 # admits too: float32 features meet the MXU as they are
@@ -96,10 +93,10 @@ assert K._wcp_fits_vmem(jax.ShapeDtypeStruct((1, h, w, c), f32),
                         (jax.ShapeDtypeStruct((1, h, w, c), f32),), 4)
 f1 = f2 = ((1, h, w, c), f32)
 compile_for_v5e(
-    lambda a, cc, b: K._wcp_fwd_tpu(a, (b,), cc, 4, band=True),
+    lambda a, cc, b: K._wcp_fwd_tpu(a, (b,), cc, 4),
     f1, coords, f2)
 compile_for_v5e(
-    lambda a, cc, d, b: K._wcp_bwd_tpu(a, (b,), cc, d, 4, band=True),
+    lambda a, cc, d, b: K._wcp_bwd_tpu(a, (b,), cc, d, 4),
     f1, coords, dout, f2)
 
 # fused DICL window sampler, raft+dicl/ml: b6 384x704, C=32, 4 levels

@@ -6,7 +6,7 @@ train-validation and eval-CLI paths, AOT save→reload roundtrips
 version-mismatched artifacts falling back cleanly, per-program compile
 attribution (the warm-cache overcount bugfix), the configurable
 persistent-cache directory, the boot/aot telemetry schema + report
-section, and the RMD_PREFETCH on/off parity of the training loop.
+section, and the training loop's prefetched feed.
 """
 
 import os
@@ -755,7 +755,7 @@ def test_report_compiled_programs_section_and_anomaly():
 
     events = [
         ev("boot", compile_cache="/tmp/cc", aot_dir="/tmp/cc/programs",
-           aot=True, prefetch=True),
+           aot=True),
         ev("aot", event="save", program="train_step", model="m",
            bytes=2 ** 20, seconds=0.2),
         ev("aot", event="hit", program="eval_step", model="m",
@@ -784,54 +784,78 @@ def test_report_compiled_programs_section_and_anomaly():
 # -- prefetch -------------------------------------------------------------
 
 
-def _run_tiny_training(tmp_path, monkeypatch, prefetch):
-    from test_strategy import _make_context, _make_stage
-
-    monkeypatch.setenv("RMD_PREFETCH", "1" if prefetch else "0")
-    np.random.seed(1234)  # init seed + epoch order identical across runs
-    ctx, _ = _make_context(tmp_path, [_make_stage(epochs=1)])
-    ctx.run()
-    assert ctx.step == 2
-    return jax.tree.map(np.asarray, ctx.variables)
+def _batches(n):
+    return [(np.full((1,), i), np.full((1,), i), None, None, [i])
+            for i in range(n)]
 
 
-def test_prefetch_on_off_bit_identical(tmp_path, monkeypatch):
-    """RMD_PREFETCH only moves the device_put off the critical path —
-    training results are bit-identical with it on or off, and telemetry
-    records the device_put phase either way."""
-    sink_on = telemetry.activate(telemetry.Telemetry())
-    try:
-        v_on = _run_tiny_training(tmp_path / "on", monkeypatch, True)
-    finally:
-        telemetry.deactivate()
+def test_prefetched_stream_is_the_iterator_with_put_applied():
+    """What the training loop runs (depth 2): item for item and in order
+    the plain iterator with ``put`` applied once to each batch, the host
+    arrays handed through as they came, however far the worker runs ahead
+    of a slow consumer."""
+    import time
 
-    sink_off = telemetry.activate(telemetry.Telemetry())
-    try:
-        v_off = _run_tiny_training(tmp_path / "off", monkeypatch, False)
-    finally:
-        telemetry.deactivate()
+    from raft_meets_dicl_tpu.strategy.training import _device_prefetch
 
-    leaves_on = jax.tree.leaves(v_on)
-    leaves_off = jax.tree.leaves(v_off)
-    assert len(leaves_on) == len(leaves_off)
-    for a, b in zip(leaves_on, leaves_off):
-        assert np.array_equal(a, b)
+    items = _batches(7)
+    calls = []
 
-    for sink in (sink_on, sink_off):
-        steps = [e for e in sink.events if e["kind"] == "step"]
-        phases = set().union(*(e["phases"] for e in steps))
-        assert {"data_wait", "device_put", "dispatch"} <= phases
+    def put(host):
+        calls.append(int(host[0][0]))
+        return tuple(None if a is None else a * 10 + 1 for a in host)
+
+    stream = _device_prefetch(iter(items), put, depth=2)
+    got = []
+    for out in stream:
+        got.append(out)
+        time.sleep(0.005)     # the worker fills its queue meanwhile
+        # the bounded queue: two staged, one in the worker's hand
+        assert len(calls) <= len(got) + 3
+    assert calls == list(range(7))
+    assert len(got) == len(items)
+    for (host, dev, meta, _put, _pull), item in zip(got, items):
+        assert all(a is b for a, b in zip(host, item[:4]))
+        assert meta is item[4]
+        want = put(item[:4])
+        assert all(np.array_equal(a, b) if a is not None else b is None
+                   for a, b in zip(dev, want))
+
+
+def test_a_put_that_raises_on_the_worker_surfaces_at_its_own_batch():
+    """``put`` runs on the worker's thread: its exception comes out of the
+    consumer's ``next()`` for the batch it failed on, after every batch
+    before it, and ends the stream."""
+    from raft_meets_dicl_tpu.strategy.training import _device_prefetch
+
+    pulled = []
+
+    def source():
+        for item in _batches(5):
+            pulled.append(item[4][0])
+            yield item
+
+    def put(host):
+        if int(host[0][0]) == 2:
+            raise ValueError("no room on the device")
+        return host
+
+    stream = _device_prefetch(source(), put, depth=2)
+    assert [next(stream)[2] for _ in range(2)] == [[0], [1]]
+    with pytest.raises(ValueError, match="no room on the device"):
+        next(stream)
+    # the worker stopped at the failure: nothing was pulled behind it
+    assert pulled == [0, 1, 2]
+    with pytest.raises(StopIteration):
+        next(stream)
 
 
 def test_prefetch_depth_knob(monkeypatch):
     """The prefetch generator respects depth and re-raises loader
     errors at the consumption point."""
-    from raft_meets_dicl_tpu.strategy.training import (
-        _device_prefetch, _sync_transfer,
-    )
+    from raft_meets_dicl_tpu.strategy.training import _device_prefetch
 
-    items = [(np.full((1,), i), np.full((1,), i), None, None, [i])
-             for i in range(4)]
+    items = _batches(4)
     got = list(_device_prefetch(iter(items), lambda b: ("dev",) + b,
                                 depth=1))
     assert [m for _, _, m, _put, _pull in got] == [[0], [1], [2], [3]]
@@ -840,9 +864,6 @@ def test_prefetch_depth_knob(monkeypatch):
     puts = [put for *_, put, _pull in got]
     assert all(t0 <= t1 for t0, t1 in puts)
     assert all(a[1] <= b[0] for a, b in zip(puts, puts[1:]))
-
-    got = list(_sync_transfer(iter(items), lambda b: ("dev",) + b))
-    assert [m for _, _, m, _put, _pull in got] == [[0], [1], [2], [3]]
 
     def boom():
         yield items[0]

@@ -362,24 +362,28 @@ def test_smoke_train_emits_schema_valid_events(tmp_path, monkeypatch):
     assert "train_step" in text
 
 
-@pytest.mark.parametrize("prefetch", [True, False],
-                         ids=["prefetch-depth2", "sync"])
-def test_step_marks_phases_and_put(tmp_path, monkeypatch, prefetch):
-    """The step event carries the loop thread's marks, absolute and in
-    order; its phases are differences of those marks and telescope to the
-    step's total; ``put`` is the interval of this step's own batch: ahead
-    of the pull on the worker's thread, inside it with RMD_PREFETCH=0."""
+@pytest.fixture(scope="module")
+def tiny_run(tmp_path_factory):
+    """One run of the tiny loop (an epoch of two steps) with the sink on:
+    ``(sink, ctx)``."""
     from test_strategy import _make_context, _make_stage
 
-    monkeypatch.setenv("RMD_PREFETCH", "1" if prefetch else "0")
-    monkeypatch.setenv("RMD_PREFETCH_DEPTH", "2")
     sink = telemetry.activate(telemetry.Telemetry())
     try:
-        ctx, _ = _make_context(tmp_path, [_make_stage(epochs=1)])
+        ctx, _ = _make_context(tmp_path_factory.mktemp("tiny_run"),
+                               [_make_stage(epochs=1)])
         ctx.run()
     finally:
         telemetry.deactivate()
+    return sink, ctx
 
+
+def test_step_marks_phases_and_put(tiny_run):
+    """The step event carries the loop thread's marks, absolute and in
+    order; its phases are differences of those marks and telescope to the
+    step's total; ``put`` is the interval of this step's own batch, ahead
+    of the pull's return on the worker's thread."""
+    sink, ctx = tiny_run
     steps = [e for e in sink.events if e["kind"] == "step"]
     assert [e["step"] for e in steps] == [0, 1]
     for ev in steps:
@@ -389,20 +393,14 @@ def test_step_marks_phases_and_put(tmp_path, monkeypatch, prefetch):
         total = ev["marks"]["done"] - ev["marks"]["start"]
         p0, p1 = ev["put"]
         assert p0 <= p1
+        assert {"data_wait", "device_put", "dispatch"} <= set(ev["phases"])
         assert ev["phases"]["device_put"] == pytest.approx(p1 - p0, abs=5e-6)
-        on_loop = sum(ev["phases"].values())
-        if prefetch:
-            # the worker's put lies outside the step: staged before the
-            # pull returned, and the one phase not part of the sum
-            assert p1 <= ev["marks"]["data"]
-            on_loop -= ev["phases"]["device_put"]
-        else:
-            assert ev["marks"]["start"] <= p0 and p1 <= ev["marks"]["data"]
+        # the worker's put lies outside the step: the one phase that is
+        # not part of the sum
+        on_loop = sum(ev["phases"].values()) - ev["phases"]["device_put"]
         assert on_loop == pytest.approx(total, abs=1e-5)
-    # each step got its own batch's put, in the loader's order
-    assert steps[0]["put"][1] <= steps[1]["put"][0]
-    # and its own batch's fetch: the mean seconds a worker spent on one of
-    # its samples; ``cpu`` is the process's CPU clock at ``start``
+    # each step got its own batch's fetch: the mean seconds a worker spent
+    # on one of its samples; ``cpu`` is the process's CPU clock at ``start``
     for ev in steps:
         assert 0.0 < ev["fetch"] < 60.0
     assert steps[0]["cpu"] < steps[1]["cpu"]
@@ -414,6 +412,26 @@ def test_step_marks_phases_and_put(tmp_path, monkeypatch, prefetch):
         ctx.steptraces.snapshot()["count"] == 2
     # and no second set of timers: nothing else feeds step phases
     assert not hasattr(sink, "span") and not hasattr(sink, "add_phase")
+
+
+def test_a_steps_pull_and_put_lie_one_after_the_other_before_its_data_mark(
+        tiny_run):
+    """The feed is one worker that does one thing after the other, read
+    from the loop's own events: a step's batch was pulled, then put, and
+    both ended before the loop's ``data`` mark took the batch off the
+    queue; the next step's pull began after this step's put had ended.
+    ``pull_ms`` + ``put_ms`` is therefore the worker's period."""
+    sink, _ = tiny_run
+    steps = [e for e in sink.events if e["kind"] == "step"]
+    assert len(steps) == 2
+    for ev in steps:
+        (t0, t1), (p0, p1) = ev["pull"], ev["put"]
+        assert t0 <= t1 <= p0 <= p1 <= ev["marks"]["data"]
+    first, second = steps
+    assert first["put"][1] <= second["pull"][0]
+    # the worker ran ahead of the loop: the second batch was staged
+    # while the first step was still running
+    assert second["put"][1] <= first["marks"]["done"]
 
 
 def test_put_rides_with_its_own_batch():

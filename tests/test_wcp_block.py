@@ -106,12 +106,11 @@ def test_block_kernels_match_reference(shape, field, dtype):
             lambda a, bb: pk._wcp_reference(a, bb, coords, RADIUS), *wide)
         df1_ref, df2_ref = vjp(dout)
 
-    out = pk._wcp_fwd_interpret(f1, levels, coords, RADIUS, band=True)
+    out = pk._wcp_fwd_interpret(f1, levels, coords, RADIUS)
     assert out.dtype == jnp.float32
     _close(out, ref, 2e-5)
 
-    df1, df2 = pk._wcp_bwd_interpret(f1, levels, coords, dout, RADIUS,
-                                     band=True)
+    df1, df2 = pk._wcp_bwd_interpret(f1, levels, coords, dout, RADIUS)
     # df1 leaves in the features' type: a bf16 result rounds at 2^-9
     assert df1.dtype == f1.dtype
     _close(df1, df1_ref, 2 ** -8 if dtype == jnp.bfloat16 else 2e-5)
@@ -126,7 +125,7 @@ def test_off_map_windows_read_zeros():
     for side in ("left", "right", "top", "bottom"):
         f1, levels, coords, _ = _inputs("row104-l4", f"off-{side}",
                                         jnp.float32)
-        out = pk._wcp_fwd_interpret(f1, levels, coords, RADIUS, band=True)
+        out = pk._wcp_fwd_interpret(f1, levels, coords, RADIUS)
         assert not np.asarray(out[0, :, 20:50]).any(), side
         assert np.asarray(out[0, :, 60:]).any(), side
 
@@ -163,7 +162,7 @@ def test_shared_share_is_the_path_taken(monkeypatch, field, shape):
 
     blocks, whole, passes = _passes_taken(
         monkeypatch, lambda: pk._wcp_fwd_interpret(f1, levels, coords,
-                                                   RADIUS, band=True))
+                                                   RADIUS))
     h, w, n_lvl = SHAPES[shape]
     assert blocks == h * -(-w // pk._PBLK) * n_lvl
     assert share == pytest.approx(whole / blocks)
