@@ -1,0 +1,9 @@
+"""Device busy time inside one served batch of the DICL model (``dicl/...``)
+in a server of several models, median over the traced tail:
+``serve_device_batch_ms`` of that model's executions alone
+(``_models.alone``). Nothing where no traced batch names that model."""
+from . import _models, serve_device_batch_ms
+
+
+def read(run):
+    return _models.of_model(run, "dicl", serve_device_batch_ms.read)

@@ -1,6 +1,8 @@
 """The ``serve`` subcommand: online flow inference as a service.
 
-Boots one replica (model + warm compiled-program pool), then either:
+Boots one replica (model + warm compiled-program pool; with ``--model``
+given more than once or a ``models:`` list in the config, several models
+behind one scheduler, all resident), then either:
 
 - ``--prebuild``: compile and AOT-export every (model, bucket, wire)
   triple of the serve config — with ``--ladder``, every iteration-rung
@@ -46,6 +48,11 @@ def _resolve(path, cfg_path):
     return str(Path(cfg_path).parent / path)
 
 
+def _cli_models(args):
+    """The ``--model`` flags as a list (the flag appends)."""
+    return list(getattr(args, "model", None) or ())
+
+
 def serve(args):
     if getattr(args, "fleet", None):
         return _serve_fleet(args)
@@ -87,45 +94,67 @@ def serve(args):
         cfg = utils.config.load(args.config)
         cfg = cfg.get("serve", cfg)
 
-    model_src = args.model
-    if model_src is None:
-        model_src = cfg.get("model")
-        if isinstance(model_src, str):
-            model_src = _resolve(model_src, getattr(args, "config", None))
-    if model_src is None:
-        raise ValueError("serve needs a model: --model or the config's "
-                         "'model' key")
-    model_cfg = (utils.config.load(model_src) if isinstance(model_src, str)
-                 else model_src)
-    if "strategy" in model_cfg:
-        model_cfg = model_cfg["model"]
-    spec = models.load(model_cfg)
-    logging.info(f"serving model '{spec.id}'")
-
     from ..models.input import ShapeBuckets
     from ..models.wire import WireFormat
 
-    buckets_spec = _pick(args.buckets, cfg, "buckets",
-                         env.raw("RMD_SERVE_BUCKETS"))
-    buckets = ShapeBuckets.from_config(buckets_spec)
-    if buckets is None or not buckets.sizes:
-        raise ValueError(
-            "serve needs explicit bucket sizes: --buckets 'HxW,...', the "
-            "config's 'buckets' key, or RMD_SERVE_BUCKETS")
-    logging.info(f"shape buckets: {buckets.describe()}")
+    # the models to hold: each with its buckets, batch size and checkpoint
+    # (an entry of the config's 'models' list states its own; a --model
+    # flag and the config's 'model' key take the server's)
+    cfg_path = getattr(args, "config", None)
+    cli_models = _cli_models(args)
+    if cli_models:
+        entries = [{"model": m} for m in cli_models]
+    elif cfg.get("models"):
+        entries = [dict(e, model=_resolve(e["model"], cfg_path)
+                        if isinstance(e.get("model"), str) else e.get("model"))
+                   for e in cfg["models"]]
+    else:
+        src = cfg.get("model")
+        entries = [{"model": _resolve(src, cfg_path)
+                    if isinstance(src, str) else src}]
+    if any(e.get("model") is None for e in entries):
+        raise ValueError("serve needs a model: --model, the config's "
+                         "'model' key or its 'models' list")
+    several = len(entries) > 1
+    if several and args.checkpoint is not None:
+        raise ValueError("--checkpoint is one model's: give each entry of "
+                         "the config's 'models' list its own 'checkpoint'")
+
+    held = []   # (spec, buckets, batch size, checkpoint) a model
+    for entry in entries:
+        model_cfg = (utils.config.load(entry["model"])
+                     if isinstance(entry["model"], str) else entry["model"])
+        if "strategy" in model_cfg:
+            model_cfg = model_cfg["model"]
+        spec = models.load(model_cfg)
+        logging.info(f"serving model '{spec.id}'")
+
+        buckets_spec = _pick(args.buckets, entry, "buckets", _pick(
+            None, cfg, "buckets", env.raw("RMD_SERVE_BUCKETS")))
+        buckets = ShapeBuckets.from_config(buckets_spec)
+        if buckets is None or not buckets.sizes:
+            raise ValueError(
+                "serve needs explicit bucket sizes: --buckets 'HxW,...', "
+                "the config's 'buckets' key, or RMD_SERVE_BUCKETS")
+        logging.info(f"shape buckets: {buckets.describe()}")
+
+        batch_size = int(_pick(args.batch_size, entry, "batch-size", _pick(
+            None, cfg, "batch-size", env.get_int("RMD_SERVE_BATCH"))))
+        checkpoint = args.checkpoint
+        if checkpoint is None:
+            checkpoint = entry.get("checkpoint", cfg.get("checkpoint"))
+            if checkpoint is not None:
+                checkpoint = _resolve(checkpoint, cfg_path)
+        held.append((spec, buckets, batch_size, checkpoint))
+    if len({spec.id for spec, *_ in held}) < len(held):
+        raise ValueError("two of the models to serve share one id: "
+                         f"{[spec.id for spec, *_ in held]}")
 
     wire_cfg = _pick(getattr(args, "wire_format", None), cfg, "wire-format",
                      env.get_str("RMD_WIRE_FORMAT"))
     wire = WireFormat.from_config(wire_cfg)
     if wire is not None:
         logging.info(f"request wire format: {wire.describe()}")
-
-    batch_size = int(_pick(args.batch_size, cfg, "batch-size",
-                           env.get_int("RMD_SERVE_BATCH")))
-    checkpoint = args.checkpoint
-    if checkpoint is None and cfg.get("checkpoint") is not None:
-        checkpoint = _resolve(cfg["checkpoint"],
-                              getattr(args, "config", None))
 
     ladder_spec = _pick(getattr(args, "ladder", None), cfg, "ladder", None)
     ladder = None
@@ -148,9 +177,20 @@ def serve(args):
         logging.info(f"quantized matching tier: {quant} (fast class + "
                      "video warm frames)")
 
-    session = serving.ServeSession(
-        spec, buckets, wire=wire, checkpoint=checkpoint,
-        batch_size=batch_size, ladder=ladder, video=video, quant=quant)
+    if several and (ladder is not None or video or quant
+                    or getattr(args, "listen_port", None) is not None):
+        raise ValueError(
+            "a server of several models serves no --ladder, --video or "
+            "--quant and is no fleet replica: those are one model's "
+            "server's")
+
+    sessions = {
+        spec.id: serving.ServeSession(
+            spec, buckets, wire=wire, checkpoint=checkpoint,
+            batch_size=batch_size, ladder=ladder, video=video, quant=quant)
+        for spec, buckets, batch_size, checkpoint in held}
+    # one model: the session itself, as ever
+    session = sessions if several else next(iter(sessions.values()))
 
     aot_store = getattr(args, "aot_store", None)
     if aot_store and not getattr(args, "prebuild", False) \
@@ -160,7 +200,7 @@ def serve(args):
             f"AOT store '{aot_store}': fetched {fetched['copied']} "
             f"programs ({fetched['present']} already local)")
 
-    outcomes = session.warm_pool()
+    outcomes = [o for s in sessions.values() for o in s.warm_pool()]
     for o in outcomes:
         rung = f" rung {o['rung']}" if "rung" in o else ""
         logging.info(
@@ -187,9 +227,9 @@ def serve(args):
     queue_limit = int(_pick(args.queue_limit, cfg, "queue-limit",
                             env.get_int("RMD_SERVE_QUEUE")))
 
+    # each model's batch size is its session's
     scheduler = serving.Scheduler(
-        session, batch_size=batch_size, max_wait_ms=max_wait_ms,
-        queue_limit=queue_limit).start()
+        session, max_wait_ms=max_wait_ms, queue_limit=queue_limit).start()
 
     if getattr(args, "listen_port", None) is not None:
         _serve_replica_blocking(args, session, scheduler, tele)
@@ -209,12 +249,16 @@ def serve(args):
             f"/statusz /profilez")
 
     # built-in open-loop client: every bucket size plus an off-bucket
-    # variant of each (exercises quantization + partial batches)
-    shapes = []
-    for h, w in session.buckets.sizes:
-        shapes.append((h, w))
-        if h > 8 and w > 8:
-            shapes.append((h - 8, w - 8))
+    # variant of each (exercises quantization + partial batches); with
+    # several models each in turn, over its own buckets
+    by_model = []
+    for name, s in sessions.items():
+        shapes = []
+        for h, w in s.buckets.sizes:
+            shapes.append((h, w))
+            if h > 8 and w > 8:
+                shapes.append((h - 8, w - 8))
+        by_model.append((name, shapes))
 
     requests = int(_pick(args.requests, cfg, "requests", 32))
     rate = float(_pick(args.rate, cfg, "rate", 50.0))
@@ -222,6 +266,9 @@ def serve(args):
     if video:
         # sticky streams force the fast rung; class cycling is moot
         classes = None
+    if several:
+        logging.info(f"open-loop load over {len(by_model)} models in turn: "
+                     f"{[name for name, _ in by_model]}")
     logging.info(f"open-loop load: {requests} requests at {rate}/s over "
                  f"{len(shapes)} shapes"
                  + (f", classes {'/'.join(classes)}" if classes else "")
@@ -229,7 +276,7 @@ def serve(args):
 
     report = serving.loadgen.run_open_loop(
         scheduler, shapes, requests=requests, rate_hz=rate, classes=classes,
-        sequence=video)
+        sequence=video, models=by_model if several else None)
     if scheduler.slo:
         report["slo"] = scheduler.slo.snapshot()
     tail = scheduler.trace_summary.tail()
@@ -358,6 +405,10 @@ def _serve_fleet(args):
     if getattr(args, "config", None):
         cfg = utils.config.load(args.config)
         cfg = cfg.get("serve", cfg)
+    if len(_cli_models(args)) > 1 or len(cfg.get("models") or ()) > 1:
+        raise ValueError(
+            "--fleet: a replica holds one model; a server of several "
+            "models is one process (serve without --fleet)")
     buckets = ShapeBuckets.from_config(
         _pick(args.buckets, cfg, "buckets", env.raw("RMD_SERVE_BUCKETS")))
     if buckets is None or not buckets.sizes:

@@ -228,7 +228,11 @@ def main():
     serve.add_argument("-c", "--config",
                        help="serve configuration (yaml/json with a "
                             "'serve' section; CLI flags win)")
-    serve.add_argument("-m", "--model", help="model specification to serve")
+    serve.add_argument("-m", "--model", action="append",
+                       help="model specification to serve; given more "
+                            "than once, one server holds every model "
+                            "(each under the same --buckets and batch "
+                            "size; per model: the config's 'models' list)")
     serve.add_argument("--checkpoint", help="checkpoint to load")
     serve.add_argument("--buckets", metavar="SPEC",
                        help="canonical request shapes, comma-separated "

@@ -17,7 +17,7 @@ scheduler/session.
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -52,7 +52,9 @@ class ServeError(RuntimeError):
     - ``unknown_class`` — the latency class does not exist (or the
       session has no ladder);
     - ``no_video`` — a sequence request reached a session built without
-      video support (``serve --video``).
+      video support (``serve --video``);
+    - ``unknown_model`` — the request names a model this server does not
+      hold, or names none where the server holds several.
     """
 
     def __init__(self, kind, detail=""):
@@ -85,6 +87,7 @@ class FlowRequest:
     sequence: bool = False  # video-session member (warm-start eligible)
     products: bool = False  # also wants fw/bw occlusion + confidence
     trace: Any = None  # telemetry.trace.RequestTrace (the scheduler's)
+    model: str = ""  # id of the model that answers it ("" = a stand-in's)
 
 
 @dataclass
@@ -107,55 +110,89 @@ class FlowResult:
     warm: bool = False   # video session: started from a cached carry
     occlusion: Optional[np.ndarray] = None   # fw/bw products (H, W) bool
     confidence: Optional[np.ndarray] = None  # fw/bw products (H, W) f32
+    model: str = ""  # id of the model that answered
+
+
+class _ModelLanes(NamedTuple):
+    """What one model's lanes are held to."""
+
+    buckets: Any            # models.input.ShapeBuckets
+    batch_size: int
+    queue_limit: int
 
 
 class BucketBatcher:
     """Bounded per-lane FIFO queues + deterministic batch selection.
 
-    A lane is ``(bucket, klass, sequence)`` — requests only coalesce
-    with same-bucket, same-latency-class, same-sequence-ness neighbors,
-    so every dispatched batch runs one ladder policy (or the video
-    warm-start program) end to end. Without a ladder or video sessions
-    every request carries the empty class and lanes degenerate to plain
+    A lane is ``(model, bucket, klass, sequence)`` — requests only
+    coalesce with same-model, same-bucket, same-latency-class,
+    same-sequence-ness neighbors, so every dispatched batch runs one
+    model's program under one ladder policy (or the video warm-start
+    program) end to end. Without a ladder or video sessions every request
+    carries the empty class, and with one model lanes degenerate to plain
     per-bucket queues.
 
+    Buckets, batch size and queue bound are a model's own
+    (:meth:`add_model`; the constructor's are the first model's, which is
+    all a one-model server has). The bound is per lane, so one model's
+    overload sheds its own requests and never another's.
+
     Selection policy (documented because tests pin it): full batches
-    first — among lanes holding at least ``batch_size`` requests, the
-    one whose head request enqueued earliest wins (ties broken by bucket
-    size then class). With no full batch, the oldest head whose wait
-    exceeded the caller's deadline dispatches as a partial. Within a
-    lane, order is strict FIFO. Everything keys on the monotonic
-    enqueue stamp plus the lane tuple, so the same submission sequence
-    always coalesces identically. ``take`` returns the *bucket* (the
-    compiled-program shape); the batch's class rides on its requests.
+    first — among lanes holding at least their model's batch size, the
+    one whose head request enqueued earliest wins (ties broken by the
+    lane tuple: model, bucket size, class). With no full batch, the
+    oldest head whose wait exceeded the caller's deadline dispatches as a
+    partial. Within a lane, order is strict FIFO. Everything keys on the
+    monotonic enqueue stamp plus the lane tuple, so the same submission
+    sequence always coalesces identically. ``take`` returns the *bucket*
+    (the compiled-program shape); the batch's model and class ride on its
+    requests.
     """
 
-    def __init__(self, buckets, batch_size, queue_limit):
+    def __init__(self, buckets, batch_size, queue_limit, model=""):
+        self._models = {}
+        self._queues = {}
+        self.add_model(model, buckets, batch_size, queue_limit)
+        # the first model's: what a one-model server reads
+        self.buckets = buckets
+        self.batch_size = int(batch_size)
+        self.queue_limit = int(queue_limit)
+
+    def add_model(self, model, buckets, batch_size, queue_limit):
+        """One more model's lanes, under its own buckets and sizes."""
         if not buckets.sizes:
             raise ValueError(
                 "serving needs explicit bucket sizes ('HxW,...'): the "
                 "warm program pool is built per bucket")
-        self.buckets = buckets
-        self.batch_size = int(batch_size)
-        self.queue_limit = int(queue_limit)
-        self._queues = {(b, "", False): deque() for b in buckets.sizes}
+        if model in self._models:
+            raise ValueError(f"model {model!r} has its lanes already")
+        self._models[model] = _ModelLanes(buckets, int(batch_size),
+                                          int(queue_limit))
+        for b in buckets.sizes:
+            self._queues[(model, b, "", False)] = deque()
 
-    def assign(self, h, w) -> Optional[Tuple[int, int]]:
-        """Smallest bucket fitting (h, w), or None (oversized)."""
-        return self.buckets.assign(h, w)
+    def assign(self, h, w, model=None) -> Optional[Tuple[int, int]]:
+        """Smallest bucket of ``model`` fitting (h, w), or None
+        (oversized)."""
+        return self._buckets(model).assign(h, w)
 
-    def encode_pair(self, img1, img2, bucket, encode):
+    def encode_pair(self, img1, img2, bucket, encode, model=None):
         """Pad a raw HWC pair up to ``bucket`` and wire-encode it."""
-        img1 = self.buckets.pad_image(img1, bucket)
-        img2 = self.buckets.pad_image(img2, bucket)
+        buckets = self._buckets(model)
+        img1 = buckets.pad_image(img1, bucket)
+        img2 = buckets.pad_image(img2, bucket)
         return encode(img1), encode(img2)
+
+    def _buckets(self, model):
+        return self.buckets if model is None else self._models[model].buckets
 
     def offer(self, request) -> bool:
         """Enqueue, or refuse (lane queue at bound — backpressure)."""
-        lane = (request.bucket, getattr(request, "klass", ""),
+        model = getattr(request, "model", "")
+        lane = (model, request.bucket, getattr(request, "klass", ""),
                 getattr(request, "sequence", False))
         q = self._queues.setdefault(lane, deque())
-        if len(q) >= self.queue_limit:
+        if len(q) >= self._models[model].queue_limit:
             return False
         request.t_enqueue = time.perf_counter()
         q.append(request)
@@ -165,17 +202,13 @@ class BucketBatcher:
         return sum(len(q) for q in self._queues.values())
 
     def depths(self) -> Dict[str, int]:
-        """Per-lane queue depths keyed ``HxW[/klass][/seq]`` (klass
-        omitted for the empty ladderless class, ``/seq`` marking video
-        session lanes) — the /statusz live snapshot."""
+        """Per-lane queue depths keyed ``[model:]HxW[/klass][/seq]`` (the
+        model omitted for a stand-in session without an id, klass for the
+        empty ladderless class, ``/seq`` marking video session lanes) —
+        the /statusz live snapshot."""
         out = {}
-        for (bucket, klass, sequence), q in sorted(self._queues.items()):
-            name = f"{bucket[0]}x{bucket[1]}"
-            if klass:
-                name = f"{name}/{klass}"
-            if sequence:
-                name = f"{name}/seq"
-            out[name] = len(q)
+        for lane, q in sorted(self._queues.items()):
+            out[lane_name(*lane)] = len(q)
         return out
 
     def take(self, now, max_wait_s, drain=False):
@@ -188,10 +221,10 @@ class BucketBatcher:
         immediately (shutdown flush).
         """
         full = [(q[0].t_enqueue, lane) for lane, q in self._queues.items()
-                if len(q) >= self.batch_size]
+                if len(q) >= self._models[lane[0]].batch_size]
         if full:
             _, lane = min(full)
-            return lane[0], self._pop(lane)
+            return lane[1], self._pop(lane)
 
         heads = [(q[0].t_enqueue, lane)
                  for lane, q in self._queues.items() if q]
@@ -199,22 +232,36 @@ class BucketBatcher:
             return None, None
         t_head, lane = min(heads)
         if drain or now - t_head >= max_wait_s:
-            return lane[0], self._pop(lane)
+            return lane[1], self._pop(lane)
         return None, t_head + max_wait_s
 
     def _pop(self, lane):
         q = self._queues[lane]
-        return [q.popleft() for _ in range(min(len(q), self.batch_size))]
+        size = self._models[lane[0]].batch_size
+        return [q.popleft() for _ in range(min(len(q), size))]
 
     def assemble(self, requests):
-        """Stack a batch's encoded pairs, filling up to ``batch_size``
-        by tiling the last request (partial batches ride the full
+        """Stack a batch's encoded pairs, filling up to its model's batch
+        size by tiling the last request (partial batches ride the full
         batch's compiled program; filled outputs are dropped by the
         response crop). Returns ``(img1, img2, fill)``."""
         img1 = np.stack([r.img1 for r in requests])
         img2 = np.stack([r.img2 for r in requests])
-        fill = self.batch_size - len(requests)
+        size = self._models[getattr(requests[0], "model", "")].batch_size
+        fill = size - len(requests)
         if fill > 0:
             img1 = np.concatenate([img1, np.repeat(img1[-1:], fill, axis=0)])
             img2 = np.concatenate([img2, np.repeat(img2[-1:], fill, axis=0)])
         return img1, img2, fill
+
+
+def lane_name(model, bucket, klass="", sequence=False):
+    """``[model:]HxW[/klass][/seq]``: a lane as /statusz names it."""
+    name = f"{bucket[0]}x{bucket[1]}"
+    if model:
+        name = f"{model}:{name}"
+    if klass:
+        name = f"{name}/{klass}"
+    if sequence:
+        name = f"{name}/seq"
+    return name

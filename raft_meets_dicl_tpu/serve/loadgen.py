@@ -40,13 +40,15 @@ def synthetic_pair(shape, rng):
 
 
 def submit_with_retry(scheduler, img1, img2, client, klass, sequence,
-                      retries, backoff_s, rejects, retried):
+                      retries, backoff_s, rejects, retried, model=None):
     """One submission with bounded jittered-backoff retry on retryable
     typed sheds; returns the ticket or None (shed accounted)."""
+    # a router's submit knows no model: name one only where one is asked
+    named = {} if model is None else {"model": model}
     for attempt in range(int(retries) + 1):
         try:
             return scheduler.submit(img1, img2, client=client, klass=klass,
-                                    sequence=sequence)
+                                    sequence=sequence, **named)
         except ServeRejected as e:
             if e.reason not in RETRYABLE_SHEDS or attempt >= retries:
                 rejects[e.reason] = rejects.get(e.reason, 0) + 1
@@ -60,7 +62,7 @@ def submit_with_retry(scheduler, img1, img2, client, klass, sequence,
 def run_open_loop(scheduler, shapes, requests, rate_hz, client="loadgen",
                   seed=0, result_timeout_s=120.0, classes=None,
                   sequence=False, streams=4, retries=0,
-                  retry_backoff_s=0.05):
+                  retry_backoff_s=0.05, models=None):
     """Drive ``scheduler`` with ``requests`` submissions at ``rate_hz``.
 
     ``shapes`` is the (H, W) cycle the stream draws from (mixed
@@ -74,7 +76,11 @@ def run_open_loop(scheduler, shapes, requests, rate_hz, client="loadgen",
     a retryably-shed request with jittered backoff (``retry_backoff_s``
     base, doubling per attempt) before accounting the shed; the default
     0 keeps the pure open-loop measurement (a retry bends the schedule,
-    which is the client's choice, not the harness's). Returns the
+    which is the client's choice, not the harness's). ``models`` (a
+    server of several models) is a cycle of ``(model id, shapes)``:
+    request *i* asks the *i*-th model of the cycle and draws from that
+    model's own shapes, and the report carries a per-model breakdown;
+    ``shapes`` is then unused. Returns the
     report dict (see ``summarize``); deterministic for a fixed seed,
     shape list, and class list (retry jitter excepted).
     """
@@ -91,10 +97,15 @@ def run_open_loop(scheduler, shapes, requests, rate_hz, client="loadgen",
         delay = target - time.perf_counter()
         if delay > 0:
             time.sleep(delay)
+        model = None
         if sequence:
             stream = i % max(1, int(streams))
             shape = shapes[stream % len(shapes)]
             name = f"{client}-{stream}"
+        elif models:
+            model, mine = models[i % len(models)]
+            shape = mine[(i // len(models)) % len(mine)]
+            name = client
         else:
             shape = shapes[i % len(shapes)]
             name = client
@@ -103,7 +114,7 @@ def run_open_loop(scheduler, shapes, requests, rate_hz, client="loadgen",
         try:
             ticket = submit_with_retry(
                 scheduler, img1, img2, name, klass, sequence,
-                retries, retry_backoff_s, rejects, retried)
+                retries, retry_backoff_s, rejects, retried, model=model)
             if ticket is not None:
                 tickets.append(ticket)
         except ServeError as e:
@@ -171,6 +182,21 @@ def summarize(requests, results, rejects, errors, wall_s):
                 "mean_ms": round(1e3 * sum(c["lat"]) / len(c["lat"]), 3),
                 "iterations": dict(sorted(c["iterations"].items())),
             } for k, c in sorted(by_class.items())
+        }
+
+    # a server of several models: latency by the model that answered
+    by_model = {}
+    for r in results:
+        by_model.setdefault(getattr(r, "model", ""), []).append(
+            r.spans.get("total", 0.0))
+    if len(by_model) > 1:
+        report["models"] = {
+            m: {
+                "completed": len(lat),
+                "p50_ms": round(1e3 * _percentile(sorted(lat), 0.50), 3),
+                "p99_ms": round(1e3 * _percentile(sorted(lat), 0.99), 3),
+                "mean_ms": round(1e3 * sum(lat) / len(lat), 3),
+            } for m, lat in sorted(by_model.items())
         }
 
     # video breakdown: warm-start hit ratio across completed frames

@@ -12,7 +12,8 @@ same server — and this module keeps only the serve-side observer:
   compiled or AOT-loaded) and liveness (dispatch-loop heartbeat age
   under the threshold); 200 only when both hold, 503 otherwise, JSON
   body either way — the router's drain signal;
-- ``/statusz`` — JSON snapshot: per-lane queue depths, shed/error
+- ``/statusz`` — JSON snapshot: per-lane queue depths (a lane is named
+  ``[model:]HxW[/klass][/seq]``), shed/error
   counts, per-class p50/p99 plus the slowest-decile critical-path
   breakdown (telemetry.trace.TraceSummary), SLO windows;
 - ``/profilez?seconds=N`` — on-demand ``jax.profiler`` capture to a
@@ -25,6 +26,7 @@ serving API) and ``port=0`` picks an ephemeral port (tests).
 """
 
 import threading
+from collections.abc import Mapping
 
 from ..telemetry import metrics as metrics_mod
 from ..telemetry import sidecar
@@ -42,7 +44,9 @@ _Handler = sidecar.Handler
 
 class Observer:
     """Aggregates one replica's live state for the HTTP plane and keeps
-    the scrape-time gauges fresh."""
+    the scrape-time gauges fresh. ``session`` is the replica's session,
+    or the scheduler's mapping ``model id -> session`` where it holds
+    several: ready when all are, compiles summed."""
 
     def __init__(self, session, scheduler, sink=None, registry=None,
                  stale_heartbeat_s=STALE_HEARTBEAT_S):
@@ -63,15 +67,21 @@ class Observer:
             "telemetry events shed by the bounded non-blocking buffer")
         self._m_burn = self.registry.gauge(
             "rmd_slo_burn_rate",
-            "per-class SLO burn rate over the rolling window", ("klass",))
+            "per-class SLO burn rate over the rolling window",
+            ("klass", "model"))
         self._m_attain = self.registry.gauge(
             "rmd_slo_attainment",
-            "per-class SLO attainment over the rolling window", ("klass",))
+            "per-class SLO attainment over the rolling window",
+            ("klass", "model"))
 
     # -- state ---------------------------------------------------------------
 
+    def _sessions(self):
+        return (list(self.session.values())
+                if isinstance(self.session, Mapping) else [self.session])
+
     def ready(self):
-        return bool(getattr(self.session, "ready", False))
+        return all(getattr(s, "ready", False) for s in self._sessions())
 
     def heartbeat_age(self):
         age = getattr(self.scheduler, "heartbeat_age", None)
@@ -100,10 +110,11 @@ class Observer:
             self._m_dropped.set(self.sink.dropped())
         slo = getattr(self.scheduler, "slo", None)
         if slo:
-            for klass, snap in slo.snapshot().items():
-                label = klass or "default"
-                self._m_burn.labels(klass=label).set(snap["burn_rate"])
-                self._m_attain.labels(klass=label).set(snap["attainment"])
+            for snap in slo.snapshot().values():
+                labels = dict(klass=snap["klass"] or "default",
+                              model=snap["model"])
+                self._m_burn.labels(**labels).set(snap["burn_rate"])
+                self._m_attain.labels(**labels).set(snap["attainment"])
 
     # -- endpoint payloads ---------------------------------------------------
 
@@ -140,8 +151,9 @@ class Observer:
             "queues": depths,
             "pending": sum(depths.values()),
             "requests": snap.get("count", 0),
-            "compiles": (self.session.compiles()
-                         if hasattr(self.session, "compiles") else None),
+            "compiles": (sum(s.compiles() for s in self._sessions())
+                         if all(hasattr(s, "compiles")
+                                for s in self._sessions()) else None),
             "classes": snap.get("classes", {}),
             "tail": snap.get("tail"),
             "slo": slo.snapshot() if slo else {},

@@ -2,7 +2,10 @@
 
 One dispatch thread pulls batches from the :class:`BucketBatcher` and
 runs them through a :class:`~.session.ServeSession`; callers submit image
-pairs from any thread and block on the returned :class:`Ticket`. Three
+pairs from any thread and block on the returned :class:`Ticket`. A server
+may hold several models, one session each, all resident: a request names
+its model, the lanes are keyed by it, a batch runs on its lane's session
+and never mixes models, and every record says which model it was. Three
 invariants the tests pin:
 
 - **The dispatch loop never stalls.** Overload sheds at admission with a
@@ -41,6 +44,7 @@ stamped once; spans and phases are differences of those marks.
 import logging
 import threading
 import time
+from collections.abc import Mapping
 
 import numpy as np
 
@@ -51,7 +55,7 @@ from ..telemetry import trace as trace_mod
 from ..testing import faults
 from ..utils import env
 from .batcher import (BucketBatcher, FlowRequest, FlowResult, ServeError,
-                      ServeRejected)
+                      ServeRejected, lane_name)
 
 # the dispatch loop wakes at least this often even when idle, so the
 # liveness heartbeat (observe.py /healthz) keeps advancing
@@ -90,59 +94,92 @@ class Ticket:
         return self._result
 
 
-class Scheduler:
-    """Admission control + dispatch loop over one serve session.
+def model_id(session):
+    """The id a session's model answers to ("" for a stand-in without a
+    spec): the lane key's first element and every record's ``model``."""
+    return getattr(getattr(session, "spec", None), "id", "") or ""
 
-    ``batch_size``/``max_wait_ms``/``queue_limit`` default to the
+
+class Scheduler:
+    """Admission control + dispatch loop over one serve session, or over
+    several: a mapping ``model id -> session`` (each model's buckets, wire
+    and batch size are its session's own).
+
+    ``batch_size``/``max_wait_ms``/``queue_limit`` default to each
     session's batch size and the ``RMD_SERVE_MAX_WAIT_MS`` /
-    ``RMD_SERVE_QUEUE`` knobs.
+    ``RMD_SERVE_QUEUE`` knobs. Ladder and video sessions are one model's
+    server's: a server of several models refuses them at start.
     """
 
     def __init__(self, session, batch_size=None, max_wait_ms=None,
                  queue_limit=None):
-        if batch_size is None:
-            batch_size = session.batch_size
         if max_wait_ms is None:
             max_wait_ms = env.get_float("RMD_SERVE_MAX_WAIT_MS")
         if queue_limit is None:
             queue_limit = env.get_int("RMD_SERVE_QUEUE")
-        self.session = session
-        self.batcher = BucketBatcher(session.buckets, batch_size, queue_limit)
+        if isinstance(session, Mapping):
+            self.models = dict(session)
+            if not self.models:
+                raise ValueError("a scheduler needs at least one session")
+        else:
+            self.models = {model_id(session): session}
+        several = len(self.models) > 1
+        if several and any(getattr(s, "ladder", None) is not None
+                           or getattr(s, "video", False)
+                           for s in self.models.values()):
+            raise ValueError(
+                "a server of several models serves no iteration ladder and "
+                "no video sessions: both are one model's server's")
+        # the one session of a one-model server (None with several)
+        self.session = None if several else next(iter(self.models.values()))
+        self.batcher = None
+        for model, sess in self.models.items():
+            size = sess.batch_size if batch_size is None else batch_size
+            if self.batcher is None:
+                self.batcher = BucketBatcher(sess.buckets, size, queue_limit,
+                                             model=model)
+            else:
+                self.batcher.add_model(model, sess.buckets, size, queue_limit)
         self.max_wait_s = float(max_wait_ms) / 1e3
 
         # live observability plane: per-request trace summary, per-class
         # SLO burn windows (empty unless RMD_SLO_* targets are set), and
         # the rmd_serve_* metrics every instrumentation point feeds
         self.trace_summary = trace_mod.TraceSummary()
-        self.slo = slo_mod.SLOTracker()
+        self.slo = slo_mod.SLOTracker(by_model=several)
         self._heartbeat = time.monotonic()
         reg = metrics_mod.registry()
         self._m_requests = reg.counter(
             "rmd_serve_requests_total", "completed serve requests",
-            ("klass", "bucket"))
+            ("klass", "bucket", "model"))
         self._m_errors = reg.counter(
             "rmd_serve_errors_total", "failed serve requests by typed kind",
-            ("error",))
+            ("error", "model"))
         self._m_shed = reg.counter(
             "rmd_serve_shed_total", "admission rejections by reason",
-            ("reason",))
+            ("reason", "model"))
         self._m_batches = reg.counter(
             "rmd_serve_batches_total", "dispatched device batches",
-            ("bucket", "klass"))
+            ("bucket", "klass", "model"))
         self._m_fill = reg.counter(
             "rmd_serve_fill_slots_total",
-            "pad-tile fill slots dispatched in partial batches")
+            "pad-tile fill slots dispatched in partial batches", ("model",))
         self._m_latency = reg.histogram(
             "rmd_serve_request_latency_seconds",
-            "end-to-end request latency (submit to release)", ("klass",))
+            "end-to-end request latency (submit to release)",
+            ("klass", "model"))
         self._m_depth = reg.gauge(
             "rmd_serve_queue_depth", "queued requests across all lanes")
+        self._m_switches = reg.counter(
+            "rmd_serve_model_switches_total",
+            "dispatched batches whose model differs from the batch before")
+        self._last_model = None   # of the last batch (dispatch thread's)
 
         # video sessions: per-client warm-start carry, bounded + TTL
         # (hits/misses/evictions surface as rmd_serve_session_* metrics)
         self.sessions = None
         self._carry_factor = None  # (fy, fx) image-to-coarse-grid ratio
-        if getattr(session, "video", False):
+        if getattr(self.session, "video", False):
             from ..video import SessionCache
 
             self.sessions = SessionCache()
@@ -159,8 +196,12 @@ class Scheduler:
     # -- admission (caller threads) -----------------------------------------
 
     def submit(self, img1, img2, client="default", klass=None,
-               sequence=False, products=False):
+               sequence=False, products=False, model=None):
         """Admit one raw (un-normalized f32 HWC) image pair.
+
+        ``model`` names the model that answers: with one session ``None``
+        means that session; with several an unknown or missing model is
+        a :class:`ServeError` (``unknown_model``), never a default.
 
         ``klass`` picks the latency class (``ladder.CLASSES``) when the
         session serves an iteration ladder — defaulting to ``balanced``;
@@ -175,7 +216,7 @@ class Scheduler:
         confidence on the result.
 
         Returns a :class:`Ticket` on acceptance. Raises synchronously:
-        :class:`ServeError` (``malformed``/``oversized``/
+        :class:`ServeError` (``malformed``/``oversized``/``unknown_model``/
         ``unknown_class``/``no_video``) when the payload can never be
         served, :class:`ServeRejected` (``queue_full``/``shutdown``)
         when the system sheds it — admission is where backpressure
@@ -186,65 +227,45 @@ class Scheduler:
             rid = self._rid
             self._rid += 1
 
+        held = ""
         try:
-            if sequence:
-                if self.sessions is None:
-                    raise ServeError(
-                        "no_video",
-                        "sequence requests need a video session "
-                        "(serve --video)")
-                # warm-start frames always enter at the fast rung; the
-                # warm program rides its own batcher lanes per bucket
-                klass = ("fast" if getattr(self.session, "ladder", None)
-                         is not None else "")
-            else:
-                klass = self._validate_klass(klass)
+            held, session = self._session_of(model)
+            klass = self._admit_klass(session, klass, sequence)
             self._validate(rid, img1, img2)
             h, w = int(img1.shape[0]), int(img1.shape[1])
-            bucket = self.batcher.assign(h, w)
+            bucket = self.batcher.assign(h, w, model=held)
             if bucket is None or faults.fire("serve_oversized", index=rid):
                 raise ServeError(
                     "oversized",
-                    f"{h}x{w} fits no bucket ({self.session.buckets.describe()})")
+                    f"{h}x{w} fits no bucket ({session.buckets.describe()})")
         except ServeError as e:
-            # field name is 'error' (not 'kind'): the envelope's 'kind'
-            # slot is the event kind itself
-            self._m_errors.labels(error=e.kind).inc()
-            telemetry.get().emit("serve", event="error", rid=rid,
-                                 client=client, error=e.kind)
+            self._refused(rid, client, held, e)
             raise
 
         e1, e2 = self.batcher.encode_pair(img1, img2, bucket,
-                                          self.session.encode_image)
+                                          session.encode_image, model=held)
         return self._enqueue(rid, client, bucket, (h, w), e1, e2, t0,
-                             klass, sequence, products)
+                             klass, sequence, products, held)
 
     def submit_encoded(self, e1, e2, shape, client="default", klass=None,
-                       sequence=False, products=False):
+                       sequence=False, products=False, model=None):
         """Admit one *pre-encoded* pair: bucket-shaped arrays already in
         the session's wire dtype (the fleet front-end path — the client
         or router encoded at the edge, the bytes land on device
         untouched). ``shape`` is the original (H, W) the response crops
         to; the bucket is the arrays' spatial extent and must be one of
-        the configured buckets. Same typed error/shed contract as
-        :meth:`submit`.
+        the configured buckets of ``model``. Same typed error/shed
+        contract as :meth:`submit`.
         """
         t0 = time.perf_counter()
         with self._lock:
             rid = self._rid
             self._rid += 1
 
+        held = ""
         try:
-            if sequence:
-                if self.sessions is None:
-                    raise ServeError(
-                        "no_video",
-                        "sequence requests need a video session "
-                        "(serve --video)")
-                klass = ("fast" if getattr(self.session, "ladder", None)
-                         is not None else "")
-            else:
-                klass = self._validate_klass(klass)
+            held, session = self._session_of(model)
+            klass = self._admit_klass(session, klass, sequence)
             for img in (e1, e2):
                 if not isinstance(img, np.ndarray) or img.ndim != 3 \
                         or img.shape[-1] != 3:
@@ -256,18 +277,18 @@ class Scheduler:
                 raise ServeError(
                     "malformed", f"pair shapes differ: {e1.shape} vs "
                                  f"{e2.shape}")
-            want = getattr(self.session, "image_dtype", None)
+            want = getattr(session, "image_dtype", None)
             if want is not None and e1.dtype != want():
                 raise ServeError(
                     "malformed",
                     f"wire dtype {e1.dtype} does not match the "
                     f"session's {want()}")
             bucket = (int(e1.shape[0]), int(e1.shape[1]))
-            if bucket not in self.session.buckets.sizes:
+            if bucket not in session.buckets.sizes:
                 raise ServeError(
                     "oversized",
                     f"{bucket[0]}x{bucket[1]} is not a configured "
-                    f"bucket ({self.session.buckets.describe()})")
+                    f"bucket ({session.buckets.describe()})")
             h, w = int(shape[0]), int(shape[1])
             if h > bucket[0] or w > bucket[1] or h < 1 or w < 1:
                 raise ServeError(
@@ -275,39 +296,73 @@ class Scheduler:
                     f"crop shape {h}x{w} outside bucket "
                     f"{bucket[0]}x{bucket[1]}")
         except ServeError as e:
-            self._m_errors.labels(error=e.kind).inc()
-            telemetry.get().emit("serve", event="error", rid=rid,
-                                 client=client, error=e.kind)
+            self._refused(rid, client, held, e)
             raise
 
         return self._enqueue(rid, client, bucket, (h, w), e1, e2, t0,
-                             klass, sequence, products)
+                             klass, sequence, products, held)
+
+    def _session_of(self, model):
+        """``(model id, session)`` of the model a request names."""
+        if model is None and self.session is not None:
+            model = next(iter(self.models))
+        if model not in self.models:
+            raise ServeError(
+                "unknown_model",
+                f"{model!r} is not one of {sorted(self.models)}"
+                if model is not None else
+                f"a request to a server of several models names one of "
+                f"{sorted(self.models)}")
+        return model, self.models[model]
+
+    def _refused(self, rid, client, model, e):
+        """A request that can never be served, at admission. ``model`` is
+        "" where the request named none this server holds."""
+        # field name is 'error' (not 'kind'): the envelope's 'kind'
+        # slot is the event kind itself
+        self._m_errors.labels(error=e.kind, model=model).inc()
+        telemetry.get().emit("serve", event="error", rid=rid,
+                             client=client, error=e.kind, model=model)
+
+    def _admit_klass(self, session, klass, sequence):
+        if not sequence:
+            return self._validate_klass(klass, session)
+        if self.sessions is None:
+            raise ServeError(
+                "no_video",
+                "sequence requests need a video session (serve --video)")
+        # warm-start frames always enter at the fast rung; the warm
+        # program rides its own batcher lanes per bucket
+        return "fast" if getattr(session, "ladder", None) is not None else ""
 
     def _enqueue(self, rid, client, bucket, shape, e1, e2, t0, klass,
-                 sequence, products):
+                 sequence, products, model):
         ticket = Ticket(rid, client)
-        rtrace = trace_mod.RequestTrace(klass=klass, bucket=bucket)
+        rtrace = trace_mod.RequestTrace(klass=klass, bucket=bucket,
+                                        model=model)
         rtrace.mark("submit", t0)
         req = FlowRequest(rid=rid, client=client, seq=0, bucket=bucket,
                           shape=shape, img1=e1, img2=e2, ticket=ticket,
                           t_submit=t0, klass=klass,
                           sequence=bool(sequence), products=bool(products),
-                          trace=rtrace)
+                          trace=rtrace, model=model)
 
         with self._cond:
             if self._stopping:
-                self._m_shed.labels(reason="shutdown").inc()
+                self._m_shed.labels(reason="shutdown", model=model).inc()
                 telemetry.get().emit("serve", event="reject", rid=rid,
-                                     client=client, reason="shutdown")
+                                     client=client, reason="shutdown",
+                                     model=model)
                 raise ServeRejected("shutdown")
             if not self.batcher.offer(req):
-                self._m_shed.labels(reason="queue_full").inc()
+                self._m_shed.labels(reason="queue_full", model=model).inc()
                 telemetry.get().emit(
                     "serve", event="reject", rid=rid, client=client,
-                    reason="queue_full", bucket=f"{bucket[0]}x{bucket[1]}")
+                    reason="queue_full", bucket=f"{bucket[0]}x{bucket[1]}",
+                    model=model)
                 raise ServeRejected(
                     "queue_full",
-                    f"bucket {bucket[0]}x{bucket[1]} queue at bound "
+                    f"lane {lane_name(model, bucket)} queue at bound "
                     f"({self.batcher.queue_limit})")
             rtrace.mark("enqueue", req.t_enqueue)
             self._m_depth.set(self.batcher.pending())
@@ -316,10 +371,11 @@ class Scheduler:
             self._cond.notify()
         return ticket
 
-    def _validate_klass(self, klass):
+    def _validate_klass(self, klass, session=None):
         from . import ladder as ladder_mod
 
-        has_ladder = getattr(self.session, "ladder", None) is not None
+        session = self.session if session is None else session
+        has_ladder = getattr(session, "ladder", None) is not None
         if klass is None:
             return "balanced" if has_ladder else ""
         if not has_ladder:
@@ -390,7 +446,7 @@ class Scheduler:
         return time.monotonic() - self._heartbeat
 
     def queue_depths(self):
-        """Per-lane queue depths (``HxW[/klass]`` -> count)."""
+        """Per-lane queue depths (``[model:]HxW[/klass]`` -> count)."""
         with self._lock:
             return self.batcher.depths()
 
@@ -440,11 +496,17 @@ class Scheduler:
         if not live:
             return
         klass = live[0].klass  # lanes are same-class by construction
+        model = live[0].model  # and same-model: the batch's session
+        session = self.models[model]
+        if self._last_model is not None and model != self._last_model:
+            self._m_switches.inc()
+        self._last_model = model
         # test stand-in sessions may not expose a program fingerprint
-        fingerprint = getattr(self.session, "program_fingerprint", None)
+        fingerprint = getattr(session, "program_fingerprint", None)
         btrace = trace_mod.BatchTrace(
             bucket, klass,
-            program=fingerprint(klass) if fingerprint else None)
+            program=fingerprint(klass) if fingerprint else None,
+            model=model)
         if t_wait is not None:
             btrace.mark("wait", t_wait)
         btrace.mark("dispatch", t0)
@@ -455,18 +517,18 @@ class Scheduler:
         img1, img2, fill = self.batcher.assemble(live)
         btrace.mark("assembled")
         btrace.fill = fill
-        c0 = self.session.compiles()
+        c0 = session.compiles()
         sequence = live[0].sequence  # lanes are same-sequence-ness too
         warm_rows = [None] * len(live)
         state = None
         if sequence:
             carry, warm_rows = self._gather_carry(live, bucket, fill)
-            flow, state, info = self.session.run_video(img1, img2, carry)
+            flow, state, info = session.run_video(img1, img2, carry)
         elif klass:
-            flow, info = self.session.run_ladder(img1, img2, klass)
+            flow, info = session.run_ladder(img1, img2, klass)
         else:
-            flow, info = self.session.run(img1, img2), None
-        called, ready = self._run_marks()
+            flow, info = session.run(img1, img2), None
+        called, ready = self._run_marks(session)
         products = any(r.products for r in live)
         flow_bw = None
         if products:
@@ -474,25 +536,26 @@ class Scheduler:
             # compiled program (same shapes — zero new programs); video
             # batches reverse cold, a carry has no meaning backwards
             if sequence:
-                bw_dev, _, _ = self.session.run_video(img2, img1)
+                bw_dev, _, _ = session.run_video(img2, img1)
             elif klass:
-                bw_dev, _ = self.session.run_ladder(img2, img1, klass)
+                bw_dev, _ = session.run_ladder(img2, img1, klass)
             else:
-                bw_dev = self.session.run(img2, img1)
-            _, ready = self._run_marks()
+                bw_dev = session.run(img2, img1)
+            _, ready = self._run_marks(session)
         btrace.mark("called", called)
         t1 = btrace.mark("ready", ready)
-        flow = self.session.fetch(flow)
+        flow = session.fetch(flow)
         if products:
-            flow_bw = self.session.fetch(bw_dev)
+            flow_bw = session.fetch(bw_dev)
         if sequence:
             self._store_carry(live, bucket, state)
         t2 = btrace.mark("fetched")
 
         tele = telemetry.get()
         batch_event = dict(
-            bucket=f"{bucket[0]}x{bucket[1]}", size=len(live), fill=fill,
-            compiles=self.session.compiles() - c0,
+            model=model, bucket=f"{bucket[0]}x{bucket[1]}", size=len(live),
+            fill=fill,
+            compiles=session.compiles() - c0,
             seconds=round(t1 - t0, 6))
         if info is not None:
             batch_event.update(klass=klass, rungs=info["rungs"],
@@ -505,9 +568,10 @@ class Scheduler:
             batch_event.update(products=True)
         tele.emit("serve", event="batch", **batch_event)
         self._m_batches.labels(
-            bucket=f"{bucket[0]}x{bucket[1]}", klass=klass).inc()
+            bucket=f"{bucket[0]}x{bucket[1]}", klass=klass,
+            model=model).inc()
         if fill > 0:
-            self._m_fill.inc(fill)
+            self._m_fill.labels(model=model).inc(fill)
         self._m_depth.set(self.batcher.pending())
 
         for i, r in enumerate(live):
@@ -525,16 +589,16 @@ class Scheduler:
                 flow=flow[i, :h, :w, :], spans={}, klass=klass,
                 iterations=(info["iterations"] if info else 0),
                 warm=warm_rows[i] is not None,
-                occlusion=occ, confidence=conf))
+                occlusion=occ, confidence=conf, model=model))
         btrace.mark("completed")
         tele.emit("trace", event="batch", **btrace.record())
 
-    def _run_marks(self):
+    def _run_marks(self, session):
         """``(called, ready)`` of the session's last run: when the program
         call returned and when its result was ready on the device. The
         session stamps them around its own ``block_until_ready``; a
         stand-in session without them has just returned from both."""
-        marks = getattr(self.session, "run_marks", None)
+        marks = getattr(session, "run_marks", None)
         if marks is None:
             now = time.perf_counter()
             return now, now
@@ -615,24 +679,26 @@ class Scheduler:
                          if res.klass else {})
                 tele.emit(
                     "serve", event="request", rid=r.rid, client=r.client,
-                    bucket=f"{r.bucket[0]}x{r.bucket[1]}",
+                    model=r.model, bucket=f"{r.bucket[0]}x{r.bucket[1]}",
                     seconds=round(total, 6),
                     spans={k: round(v, 6) for k, v in res.spans.items()},
                     **extra)
                 self._m_requests.labels(
-                    klass=r.klass,
-                    bucket=f"{r.bucket[0]}x{r.bucket[1]}").inc()
-                self._m_latency.labels(klass=r.klass).observe(total)
+                    klass=r.klass, bucket=f"{r.bucket[0]}x{r.bucket[1]}",
+                    model=r.model).inc()
+                self._m_latency.labels(klass=r.klass,
+                                       model=r.model).observe(total)
                 record = r.trace.record()
                 tele.emit("trace", event="request", rid=r.rid, **record)
                 self.trace_summary.add(record)
-                self.slo.record(r.klass, total)
+                self.slo.record(r.klass, total, model=r.model)
                 self.slo.maybe_emit(tele)
             else:
                 self._m_errors.labels(
-                    error=getattr(err, "kind", "internal")).inc()
+                    error=getattr(err, "kind", "internal"),
+                    model=r.model).inc()
                 tele.emit("serve", event="error", rid=r.rid,
-                          client=r.client,
+                          client=r.client, model=r.model,
                           error=getattr(err, "kind", "internal"),
                           seconds=round(total, 6))
             r.ticket._complete(result=res, error=err)

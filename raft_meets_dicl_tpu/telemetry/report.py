@@ -444,6 +444,10 @@ def serve_stats(events):
     warmups = []
     spans = {}
     classes = {}
+    # a server of several models: two of them may share a bucket's size,
+    # so a bucket's row is a model's (one model: rows by bucket as ever)
+    several = len({e.get("model") for e in events if e["kind"] == "serve"
+                   and e.get("event") == "batch"} - {None, ""}) > 1
     for e in events:
         if e["kind"] != "serve":
             continue
@@ -468,7 +472,10 @@ def serve_stats(events):
             err = e.get("error", "?")
             errors[err] = errors.get(err, 0) + 1
         elif ev == "batch":
-            b = buckets.setdefault(e.get("bucket", "?"), {
+            name = e.get("bucket", "?")
+            if several:
+                name = f"{e.get('model', '')}:{name}"
+            b = buckets.setdefault(name, {
                 "batches": 0, "requests": 0, "fill": 0, "compiles": 0})
             b["batches"] += 1
             b["requests"] += e.get("size", 0)

@@ -29,12 +29,14 @@ from collections import deque
 class ClassSLO:
     """Rolling good/bad window for one latency class."""
 
-    def __init__(self, klass, target_ms, objective=0.99, window_s=60.0):
+    def __init__(self, klass, target_ms, objective=0.99, window_s=60.0,
+                 model=""):
         if target_ms <= 0:
             raise ValueError(f"target_ms must be > 0, got {target_ms}")
         if not 0.0 < objective < 1.0:
             raise ValueError(f"objective must be in (0, 1), got {objective}")
         self.klass = klass
+        self.model = model
         self.target_ms = float(target_ms)
         self.objective = float(objective)
         self.window_s = float(window_s)
@@ -66,6 +68,7 @@ class ClassSLO:
         burn = (1.0 - attainment) / (1.0 - self.objective)
         return {
             "klass": self.klass,
+            "model": self.model,
             "target_ms": self.target_ms,
             "objective": self.objective,
             "window_s": self.window_s,
@@ -95,11 +98,14 @@ class SLOTracker:
 
     Unconfigured classes are ignored (no target — nothing to burn).
     ``maybe_emit`` rate-limits ``slo`` events to one per class per
-    ``emit_interval_s``.
+    ``emit_interval_s``. A server of several models (``by_model``) keeps
+    a window per model and class, keyed ``model:klass`` and opened by the
+    model's first request; a one-model server's are keyed by class as
+    ever, and say their model in the snapshot.
     """
 
     def __init__(self, class_targets=None, objective=None, window_s=None,
-                 emit_interval_s=None):
+                 emit_interval_s=None, by_model=False):
         from ..utils import env
 
         if class_targets is None:
@@ -112,25 +118,34 @@ class SLOTracker:
             emit_interval_s = max(1.0, window_s / 6.0)
         self.emit_interval_s = float(emit_interval_s)
         default = class_targets.get("", 0.0)
+        self.by_model = bool(by_model)
+        self._slo_args = dict(objective=objective, window_s=window_s)
+        self._targets = {klass: target or default
+                         for klass, target in class_targets.items()
+                         if (target or default) > 0}
         self._slos = {}
-        for klass, target in class_targets.items():
-            target = target or default
-            if target and target > 0:
-                self._slos[klass] = ClassSLO(
-                    klass, target, objective=objective, window_s=window_s)
+        if not self.by_model:
+            for klass, target in self._targets.items():
+                self._slos[klass] = ClassSLO(klass, target, **self._slo_args)
         self._lock = threading.Lock()
         self._last_emit = {}
 
     def __bool__(self):
-        return bool(self._slos)
+        return bool(self._targets)
 
     def classes(self):
         return sorted(self._slos)
 
-    def record(self, klass, total_s, now=None):
-        slo = self._slos.get(klass)
-        if slo is None:
+    def record(self, klass, total_s, now=None, model=""):
+        if klass not in self._targets:
             return None
+        key = f"{model}:{klass}" if self.by_model else klass
+        slo = self._slos.get(key)
+        if slo is None:     # a model's first request opens its window
+            with self._lock:
+                slo = self._slos.setdefault(key, ClassSLO(
+                    klass, self._targets[klass], **self._slo_args))
+        slo.model = model
         return slo.record(total_s, now=now)
 
     def snapshot(self, now=None):
@@ -141,7 +156,7 @@ class SLOTracker:
         """Emit one ``slo`` event per class whose interval elapsed."""
         now = time.monotonic() if now is None else now
         emitted = []
-        for klass, slo in self._slos.items():
+        for klass, slo in list(self._slos.items()):
             with self._lock:
                 last = self._last_emit.get(klass)
                 if last is not None and now - last < self.emit_interval_s:

@@ -68,12 +68,14 @@ class RequestTrace:
     """Ordered monotonic marks for one request's life; phases are the
     gaps between consecutive marks actually hit."""
 
-    __slots__ = ("trace_id", "klass", "bucket", "batch_id", "marks")
+    __slots__ = ("trace_id", "klass", "bucket", "model", "batch_id",
+                 "marks")
 
-    def __init__(self, klass="", bucket=None):
+    def __init__(self, klass="", bucket=None, model=""):
         self.trace_id = f"req-{next(_req_ids):06d}"
         self.klass = klass
         self.bucket = bucket
+        self.model = model      # id of the model that answers the request
         self.batch_id = None
         self.marks = {}
 
@@ -113,6 +115,7 @@ class RequestTrace:
         return {
             "trace": self.trace_id,
             "batch": self.batch_id,
+            "model": self.model,
             "klass": self.klass,
             "bucket": (f"{self.bucket[0]}x{self.bucket[1]}"
                        if self.bucket else None),
@@ -124,17 +127,18 @@ class RequestTrace:
 
 
 class BatchTrace:
-    """One dispatch span: which requests fanned in, on which compiled
-    program (bucket/class/fingerprint), and the dispatch thread's marks
+    """One dispatch span: which requests fanned in, on which model's
+    compiled program (bucket/class/fingerprint), and the dispatch thread's marks
     (``BATCH_MARKS``) from going back to wait to the last release."""
 
-    __slots__ = ("batch_id", "bucket", "klass", "size", "fill",
+    __slots__ = ("batch_id", "bucket", "klass", "model", "size", "fill",
                  "program", "members", "marks")
 
-    def __init__(self, bucket, klass, program=None):
+    def __init__(self, bucket, klass, program=None, model=""):
         self.batch_id = f"batch-{next(_batch_ids):06d}"
         self.bucket = bucket
         self.klass = klass
+        self.model = model
         self.program = program
         self.size = 0
         self.fill = 0
@@ -158,6 +162,7 @@ class BatchTrace:
         hit = [self.marks[m] for m in BATCH_MARKS[1:] if m in self.marks]
         return {
             "batch": self.batch_id,
+            "model": self.model,
             "bucket": f"{self.bucket[0]}x{self.bucket[1]}",
             "klass": self.klass,
             "size": self.size,

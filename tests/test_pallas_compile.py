@@ -248,9 +248,9 @@ tiny = {"corr-radius": 2, "corr-channels": 32, "context-channels": 16,
         "recurrent-channels": 16, "mixed-precision": True}
 cases = [
     ("raft/baseline", dict(tiny, **{"corr-levels": 2}), "raft/sequence",
-     64, 96, {"iterations": 2}, ("Up8Network_",), ()),
+     32, 64, {"iterations": 2}, ("Up8Network_",), ()),
     ("raft/fs", dict(tiny, **{"corr-levels": 2}), "raft/sequence",
-     64, 96, {"iterations": 2}, ("wcp.", "Up8Network_"), ()),
+     32, 64, {"iterations": 2}, ("wcp.", "Up8Network_"), ()),
     ("raft+dicl/ctf-l3", tiny, {"type": "raft+dicl/mlseq", "arguments": {"alpha": [0.38, 0.6, 1.0]}},
      64, 128, {"iterations": [1, 1, 2]}, ("sampler.", "Up8Network_"),
      ("corr",)),        # nothing is built once a step for its look-ups

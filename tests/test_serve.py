@@ -36,8 +36,7 @@ TINY_SERVE_MODEL = {
     "name": "serve tiny", "id": "serve-tiny",
     "model": {"type": "raft/baseline",
               "parameters": {"corr-levels": 2, "corr-radius": 2,
-                             "corr-channels": 32, "context-channels": 16,
-                             "recurrent-channels": 16},
+                             "corr-channels": 32, "context-channels": 16},
               "arguments": {"iterations": 2}},
     "loss": {"type": "raft/sequence"},
     "input": {"padding": {"type": "modulo", "mode": "zeros",
@@ -74,10 +73,11 @@ class FakeSession:
     numpy function of the encoded inputs, so scheduler mechanics are
     testable without any device work."""
 
-    def __init__(self, buckets, batch_size=4, delay_s=0.0):
+    def __init__(self, buckets, batch_size=4, delay_s=0.0, gain=1.0):
         self.buckets = buckets
         self.batch_size = batch_size
         self.delay_s = delay_s
+        self.gain = gain            # tells one stand-in model from another
         self.batch_shapes = []
 
     def encode_image(self, img):
@@ -90,7 +90,7 @@ class FakeSession:
         self.batch_shapes.append(img1.shape)
         if self.delay_s:
             time.sleep(self.delay_s)
-        return (img1 + img2)[..., :2]
+        return (img1 + img2)[..., :2] * self.gain
 
     def fetch(self, flow):
         return np.asarray(flow)
@@ -354,6 +354,284 @@ def test_serve_report_section_renders(_serve_hygiene):
     assert "bucket 16x24" in text
 
 
+# -- one server, several models (host-only stand-ins) -------------------------
+
+# model "a": two buckets at batch 2; model "b": one bucket of its own at
+# batch 3, and a flow three times a's for the same pair
+A_BUCKETS, B_BUCKETS = [(16, 24), (32, 48)], [(16, 32)]
+
+
+def _two_model_scheduler(max_wait_ms=5.0, queue_limit=64, delay_s=0.0):
+    sessions = {
+        "a": FakeSession(ShapeBuckets(A_BUCKETS), batch_size=2,
+                         delay_s=delay_s),
+        "b": FakeSession(ShapeBuckets(B_BUCKETS), batch_size=3,
+                         delay_s=delay_s, gain=3.0),
+    }
+    return Scheduler(sessions, max_wait_ms=max_wait_ms,
+                     queue_limit=queue_limit), sessions
+
+
+def _fake_flow(img1, img2, gain=1.0):
+    return ((img1 * 2 - 1) + (img2 * 2 - 1))[..., :2] * gain
+
+
+def test_a_batch_never_mixes_models_and_a_reply_is_its_own_models(
+        _serve_hygiene):
+    sched, sessions = _two_model_scheduler(max_wait_ms=2.0)
+    asked = [("a", (14, 20)), ("b", (14, 30)), ("a", (30, 40)),
+             ("b", (16, 32)), ("a", (16, 24)), ("b", (10, 10)),
+             ("b", (12, 28)), ("a", (14, 20))]
+    pairs = [_pair(shape, seed=i) for i, (_, shape) in enumerate(asked)]
+    sched.start()
+    try:
+        tickets = [sched.submit(a, b, client=f"c{i % 3}", model=model)
+                   for i, ((model, _), (a, b)) in enumerate(zip(asked, pairs))]
+        results = [t.result(timeout=10.0) for t in tickets]
+    finally:
+        sched.stop(drain=True)
+    for (model, shape), (a, b), res in zip(asked, pairs, results):
+        assert res.model == model and res.shape == shape
+        assert res.bucket in (A_BUCKETS if model == "a" else B_BUCKETS)
+        # b's session triples the flow: a request run on the other model's
+        # session would read a third, or three times, of what it should
+        np.testing.assert_allclose(
+            res.flow, _fake_flow(a, b, sessions[model].gain), rtol=1e-6)
+    # each session saw only its own buckets, at its own batch size
+    assert {s[:3] for s in sessions["a"].batch_shapes} <= {
+        (2, 16, 24), (2, 32, 48)}
+    assert {s[:3] for s in sessions["b"].batch_shapes} == {(3, 16, 32)}
+    # every batch is one model's, and its members are that model's requests
+    requests = {e["trace"]: e for e in _serve_hygiene.events
+                if e["kind"] == "trace" and e["event"] == "request"}
+    batches = [e for e in _serve_hygiene.events
+               if e["kind"] == "trace" and e["event"] == "batch"]
+    assert sum(len(b["members"]) for b in batches) == len(asked)
+    for b in batches:
+        assert b["model"] in ("a", "b")
+        assert {requests[m]["model"] for m in b["members"]} == {b["model"]}
+    by_model = {}
+    for e in _serve_events(_serve_hygiene, "batch"):
+        by_model[e["model"]] = by_model.get(e["model"], 0) + e["size"]
+    assert by_model == {"a": 4, "b": 4}
+    # the report's rows are a model's bucket: two models may share a size
+    rows = treport.serve_stats(_serve_hygiene.events)["buckets"]
+    assert set(rows) <= {"a:16x24", "a:32x48", "b:16x32"}
+    assert sum(r["requests"] for name, r in rows.items()
+               if name.startswith("b:")) == 4
+
+
+def test_one_models_full_lane_sheds_its_own_requests_only(_serve_hygiene):
+    # not started: nothing drains, so a's 16x24 lane reaches its bound
+    sched, sessions = _two_model_scheduler(max_wait_ms=1e4, queue_limit=2)
+    img1, img2 = _pair((14, 20))
+    kept = [sched.submit(img1, img2, model="a") for _ in range(2)]
+    with pytest.raises(ServeRejected) as exc:
+        sched.submit(img1, img2, model="a")
+    assert exc.value.reason == "queue_full" and "a:16x24" in str(exc.value)
+    # the other model's requests of the same size are admitted: the bound
+    # is a lane's, and a lane is one model's
+    others = [sched.submit(img1, img2, model="b") for _ in range(2)]
+    # and a's other bucket has a lane of its own too
+    kept.append(sched.submit(*_pair((30, 40)), model="a"))
+    with pytest.raises(ServeRejected):
+        sched.submit(img1, img2, model="a")
+    rejects = _serve_events(_serve_hygiene, "reject")
+    assert [(e["model"], e["bucket"], e["reason"]) for e in rejects] == [
+        ("a", "16x24", "queue_full")] * 2
+    assert sched.queue_depths() == {"a:16x24": 2, "a:32x48": 1,
+                                    "b:16x32": 2}
+    sched.start()
+    sched.stop(drain=True)
+    for t in others:
+        res = t.result(timeout=5.0)
+        assert res.model == "b"
+        np.testing.assert_allclose(res.flow, _fake_flow(img1, img2, 3.0),
+                                   rtol=1e-6)
+    assert all(t.result(timeout=5.0).model == "a" for t in kept)
+    from raft_meets_dicl_tpu.telemetry import metrics as metrics_mod
+
+    shed = metrics_mod.parse_text(metrics_mod.registry().render())[
+        "rmd_serve_shed_total"]
+    assert shed[(("model", "a"), ("reason", "queue_full"))] >= 2.0
+    assert (("model", "b"), ("reason", "queue_full")) not in shed
+
+
+def test_release_order_per_client_holds_across_models():
+    sched, _ = _two_model_scheduler(max_wait_ms=1e4)   # never started
+    img1, img2 = _pair((14, 20))
+    # one client asks a, b, a: three lanes' worth of batches
+    tickets = [sched.submit(img1, img2, client="x", model=m)
+               for m in ("a", "b", "a")]
+    taken = {}
+    while True:
+        bucket, batch = sched.batcher.take(time.perf_counter(), 0.0,
+                                           drain=True)
+        if bucket is None:
+            break
+        taken[batch[0].model] = (bucket, batch)
+    assert [r.rid for r in taken["a"][1]] == [0, 2]
+    assert [r.rid for r in taken["b"][1]] == [1]
+    # a's batch holds the client's first and third request: the third is
+    # held until b's batch has released the second
+    sched._dispatch(*taken["a"])
+    assert tickets[0].done() and not tickets[2].done()
+    sched._dispatch(*taken["b"])
+    assert tickets[1].done() and tickets[2].done()
+    assert [t.result(timeout=1.0).model for t in tickets] == ["a", "b", "a"]
+
+
+def test_an_unknown_or_missing_model_is_refused_at_admission(_serve_hygiene):
+    sched, _ = _two_model_scheduler()
+    img1, img2 = _pair((14, 20))
+    for model in (None, "c", ""):
+        with pytest.raises(ServeError) as exc:
+            sched.submit(img1, img2, model=model)
+        assert exc.value.kind == "unknown_model"
+    e1 = np.zeros((16, 24, 3), np.float32)
+    with pytest.raises(ServeError) as exc:
+        sched.submit_encoded(e1, e1, (14, 20))
+    assert exc.value.kind == "unknown_model"
+    # never a default: nothing was queued for either model
+    assert sched.pending() == 0
+    errors = _serve_events(_serve_hygiene, "error")
+    assert [(e["error"], e["model"]) for e in errors] == [
+        ("unknown_model", "")] * 4
+    # a model's buckets are its own: b has no 32x48
+    with pytest.raises(ServeError) as exc:
+        sched.submit(*_pair((30, 40)), model="b")
+    assert exc.value.kind == "oversized"
+    # one session: no name means that session, another name is refused
+    one = _fake_scheduler()
+    assert one.submit(img1, img2).rid == 0
+    assert one.submit(img1, img2, model="").rid == 1
+    with pytest.raises(ServeError) as exc:
+        one.submit(img1, img2, model="a")
+    assert exc.value.kind == "unknown_model"
+
+
+def test_the_lane_order_of_a_fixed_submission_sequence_is_pinned():
+    """``take``'s rule over lanes of two models: full lanes first (a lane
+    is full at its model's batch size), the earliest head among them;
+    then the oldest head; within a lane FIFO."""
+    def coalesce():
+        b = BucketBatcher(ShapeBuckets(A_BUCKETS), batch_size=2,
+                          queue_limit=16, model="a")
+        b.add_model("b", ShapeBuckets(B_BUCKETS), batch_size=3,
+                    queue_limit=16)
+        order = [("b", (16, 32)), ("a", (16, 24)), ("b", (16, 32)),
+                 ("a", (32, 48)), ("a", (16, 24)), ("b", (16, 32)),
+                 ("b", (16, 32)), ("a", (32, 48)), ("a", (16, 24))]
+        for rid, (model, bucket) in enumerate(order):
+            h, w = bucket
+            img = np.zeros((h, w, 3), np.float32)
+            assert b.offer(serve.FlowRequest(
+                rid=rid, client="c", seq=rid, bucket=bucket, shape=bucket,
+                img1=img, img2=img, ticket=None,
+                t_submit=time.perf_counter(), model=model))
+        batches = []
+        while True:
+            bucket, batch = b.take(time.perf_counter() + 1e6,
+                                   max_wait_s=0.0, drain=True)
+            if bucket is None:
+                break
+            assert len({r.model for r in batch}) == 1
+            batches.append((batch[0].model, bucket, [r.rid for r in batch]))
+        return batches
+
+    assert coalesce() == coalesce()
+    # b's lane is full at three (head 0), a's at two (heads 1 and 3); the
+    # partials follow by the age of their heads
+    assert coalesce() == [("b", (16, 32), [0, 2, 5]),
+                          ("a", (16, 24), [1, 4]),
+                          ("a", (32, 48), [3, 7]),
+                          ("b", (16, 32), [6]),
+                          ("a", (16, 24), [8])]
+
+
+def test_every_serve_record_names_the_model(_serve_hygiene):
+    from raft_meets_dicl_tpu.telemetry import metrics as metrics_mod
+
+    def switches():
+        return metrics_mod.parse_text(metrics_mod.registry().render())[
+            "rmd_serve_model_switches_total"].get((), 0.0)
+
+    before = switches()
+    sched, _ = _two_model_scheduler(max_wait_ms=1e4)    # never started
+    img1, img2 = _pair((14, 20))
+    tickets = [sched.submit(img1, img2, client="x", model=m)
+               for m in ("a", "b", "b", "a")]
+    assert sched.queue_depths() == {"a:16x24": 2, "a:32x48": 0,
+                                    "b:16x32": 2}
+    # a (full), then b and a's none left: dispatch a, b, then a again
+    order = []
+    for _ in range(2):
+        bucket, batch = sched.batcher.take(time.perf_counter(), 0.0,
+                                           drain=True)
+        order.append(batch[0].model)
+        sched._dispatch(bucket, batch)
+    assert order == ["a", "b"]
+    t = sched.submit(img1, img2, client="y", model="a")
+    sched._dispatch(*sched.batcher.take(time.perf_counter(), 0.0,
+                                        drain=True))
+    assert t.done() and all(x.done() for x in tickets)
+    # a -> b -> a: two batches whose model differs from the one before
+    assert switches() - before == 2.0
+    for event in ("batch", "request"):
+        got = [e["model"] for e in _serve_events(_serve_hygiene, event)]
+        assert got and set(got) == {"a", "b"}
+    for event in ("batch", "request"):
+        traced = [e for e in _serve_hygiene.events
+                  if e["kind"] == "trace" and e["event"] == event]
+        assert traced and all(e["model"] in ("a", "b") for e in traced)
+    parsed = metrics_mod.parse_text(metrics_mod.registry().render())
+    key = (("bucket", "16x32"), ("klass", ""), ("model", "b"))
+    assert parsed["rmd_serve_requests_total"][key] >= 2.0
+    assert parsed["rmd_serve_batches_total"][key] >= 1.0
+    assert parsed["rmd_serve_request_latency_seconds_count"][
+        (("klass", ""), ("model", "b"))] >= 2.0
+    assert parsed["rmd_serve_fill_slots_total"][(("model", "b"),)] >= 1.0
+    # one stand-in session: the same records, the field empty, lanes
+    # named as ever
+    one = _fake_scheduler(max_wait_ms=1e4)
+    one.submit(img1, img2)
+    assert one.queue_depths() == {"16x24": 1, "32x48": 0}
+    one._dispatch(*one.batcher.take(time.perf_counter(), 0.0, drain=True))
+    assert _serve_events(_serve_hygiene, "batch")[-1]["model"] == ""
+
+
+def test_several_models_refuse_ladder_and_video_sessions_at_start():
+    plain = FakeSession(ShapeBuckets(A_BUCKETS), batch_size=2)
+    for attr, value in (("ladder", object()), ("video", True)):
+        odd = FakeSession(ShapeBuckets(B_BUCKETS), batch_size=2)
+        setattr(odd, attr, value)
+        with pytest.raises(ValueError, match="several models"):
+            Scheduler({"a": plain, "b": odd})
+    with pytest.raises(ValueError, match="at least one"):
+        Scheduler({})
+
+
+def test_the_slo_windows_of_several_models_are_a_models_own():
+    from raft_meets_dicl_tpu.telemetry import slo as slo_mod
+
+    tracker = slo_mod.SLOTracker(class_targets={"": 50.0}, objective=0.9,
+                                 window_s=10.0, by_model=True)
+    assert tracker and tracker.snapshot() == {}
+    tracker.record("", 0.010, now=100.0, model="a")
+    tracker.record("", 0.200, now=100.0, model="b")
+    snap = tracker.snapshot(now=100.0)
+    assert sorted(snap) == ["a:", "b:"]
+    assert (snap["a:"]["model"], snap["a:"]["good"], snap["a:"]["bad"]) == (
+        "a", 1, 0)
+    assert (snap["b:"]["model"], snap["b:"]["good"], snap["b:"]["bad"]) == (
+        "b", 0, 1)
+    # one model's server: keyed by class as ever, the model in the entry
+    one = slo_mod.SLOTracker(class_targets={"": 50.0})
+    one.record("", 0.010, now=100.0, model="a")
+    assert list(one.snapshot(now=100.0)) == [""]
+    assert one.snapshot(now=100.0)[""]["model"] == "a"
+
+
 # -- device half: real tiny model --------------------------------------------
 
 
@@ -443,20 +721,27 @@ TINY_DICL_MODEL = {
 }
 
 
+@pytest.fixture(scope="module")
+def tiny_dicl_session():
+    """The tiny ``dicl/baseline`` at two buckets, warm: one build for the
+    cases that serve it alone and beside ``tiny_session``."""
+    spec = models.load(TINY_DICL_MODEL)
+    session = ServeSession(spec, ShapeBuckets([(128, 128), (128, 256)]),
+                           wire=WireFormat.from_config("u8"), batch_size=2)
+    return session, session.warm_pool()
+
+
 def test_a_model_without_iterations_serves_through_batcher_and_scheduler(
-        _serve_hygiene):
+        _serve_hygiene, tiny_dicl_session):
     """``dicl/baseline``: no recurrence, no ``iterations`` argument, no
     ladder, no video. The session builds its eval program and its warm
     pool for it at two buckets, and what the scheduler releases is the
     model called directly on the padded, wire-decoded pair."""
     import jax
 
-    spec = models.load(TINY_DICL_MODEL)
+    session, outcomes = tiny_dicl_session
+    spec = session.spec
     assert "iterations" not in spec.model.arguments
-    buckets = [(128, 128), (128, 256)]
-    session = ServeSession(spec, ShapeBuckets(buckets),
-                           wire=WireFormat.from_config("u8"), batch_size=2)
-    outcomes = session.warm_pool()
     assert [(o["bucket"], o["compiles"]) for o in outcomes] == [
         ("128x128", 1), ("128x256", 1)]
     assert not any("rung" in o for o in outcomes)
@@ -494,6 +779,88 @@ def test_a_model_without_iterations_serves_through_batcher_and_scheduler(
         np.testing.assert_allclose(res.flow, want[:shape[0], :shape[1]],
                                    atol=1e-4)
         assert float(np.abs(res.flow).mean()) > 1e-3
+
+
+def _grid_pair(shape, seed):
+    """A pair on the 8-bit grid, so the u8 wire carries it exactly."""
+    a, b = _pair(shape, seed=seed)
+    return (np.rint(a * 255).astype(np.float32) / 255,
+            np.rint(b * 255).astype(np.float32) / 255)
+
+
+def test_two_models_behind_one_scheduler_answer_as_each_alone_would(
+        _serve_hygiene, tiny_session, tiny_dicl_session):
+    """The deployment's semantics: every request is answered as its own
+    model alone would answer it. A two-model scheduler's flows equal, bit
+    for bit, those of two one-model schedulers given the same requests
+    (the same programs on the same batches), and agree with the plain
+    references of both models on the sessions' own weights."""
+    import jax
+    import jax.numpy as jnp
+
+    sys.path.insert(0, str(REPO))
+    from benchmark.harness import serve_check
+    from benchmark.reference import common as refc
+    from benchmark.reference import dicl as ref_dicl
+    from benchmark.reference import raft as ref_raft
+
+    raft, (dicl, _) = tiny_session, tiny_dicl_session
+    raft.warm_pool()
+    sessions = {raft.spec.id: raft, dicl.spec.id: dicl}
+    assert sorted(sessions) == ["serve-tiny", "serve-tiny-dicl"]
+    # at the buckets' own sizes (followed by the references) and under them
+    asked = [("serve-tiny", (32, 48)), ("serve-tiny-dicl", (128, 128)),
+             ("serve-tiny-dicl", (100, 120)), ("serve-tiny", (28, 40)),
+             ("serve-tiny", (32, 48)), ("serve-tiny-dicl", (128, 256)),
+             ("serve-tiny-dicl", (128, 128)), ("serve-tiny", (30, 44))]
+    pairs = [_grid_pair(shape, seed=20 + i)
+             for i, (_, shape) in enumerate(asked)]
+    c0 = raft.compiles() + dicl.compiles()
+
+    def served(sched, mine, named):
+        # everything queued before the thread starts: a lane's batches are
+        # then the same FIFO chunks whoever else the scheduler serves
+        tickets = [sched.submit(*pairs[i], client=f"c{i % 3}",
+                                model=asked[i][0] if named else None)
+                   for i in mine]
+        sched.start()
+        sched.stop(drain=True)
+        return {i: t.result(timeout=120.0) for i, t in zip(mine, tickets)}
+
+    both = served(Scheduler(sessions, max_wait_ms=1e4),
+                  range(len(asked)), named=True)
+    alone = {}
+    for name, session in sessions.items():
+        mine = [i for i, (model, _) in enumerate(asked) if model == name]
+        alone.update(served(Scheduler(session, max_wait_ms=1e4), mine,
+                            named=False))
+    assert raft.compiles() + dicl.compiles() == c0   # nothing new compiled
+    for i, (model, shape) in enumerate(asked):
+        assert both[i].model == alone[i].model == model
+        assert both[i].flow.shape == (*shape, 2)
+        assert np.array_equal(both[i].flow, alone[i].flow)
+    batches = _serve_events(_serve_hygiene, "batch")
+    assert {b["model"] for b in batches} == set(sessions)
+
+    cfgs = {"serve-tiny": (ref_raft, TINY_SERVE_MODEL),
+            "serve-tiny-dicl": (ref_dicl, TINY_DICL_MODEL)}
+    with jax.default_matmul_precision("highest"):
+        for i, (model, shape) in enumerate(asked):
+            if shape not in sessions[model].buckets.sizes:
+                continue
+            module, cfg = cfgs[model]
+            flat = refc.flatten(jax.tree.map(
+                lambda x: x, dict(sessions[model].variables)))
+            want = jax.jit(lambda f, a, b, m=module, c=cfg, s=shape:
+                           serve_check.reference_flow(m, c, f, None, a, b, s))(
+                flat, jnp.asarray(pairs[i][0]), jnp.asarray(pairs[i][1]))
+            # test_a_model_without_iterations...'s tolerance on the flow,
+            # test_reference_dicl's on the gap
+            np.testing.assert_allclose(both[i].flow, np.asarray(want),
+                                       atol=1e-4)
+            gap, magnitude = serve_check.relative_epe(both[i].flow,
+                                                      np.asarray(want))
+            assert gap < 1e-4 and magnitude > 1e-3
 
 
 @pytest.mark.slow

@@ -427,11 +427,11 @@ def test_a_steps_pull_and_put_lie_one_after_the_other_before_its_data_mark(
     for ev in steps:
         (t0, t1), (p0, p1) = ev["pull"], ev["put"]
         assert t0 <= t1 <= p0 <= p1 <= ev["marks"]["data"]
+    # how far the worker runs ahead of the loop is the scheduler's to say
+    # (under six test workers the loop thread may finish step one before
+    # batch two is staged): only the order the construction guarantees
     first, second = steps
     assert first["put"][1] <= second["pull"][0]
-    # the worker ran ahead of the loop: the second batch was staged
-    # while the first step was still running
-    assert second["put"][1] <= first["marks"]["done"]
 
 
 def test_put_rides_with_its_own_batch():

@@ -460,10 +460,10 @@ def test_scheduler_metrics_counters(_obs_hygiene):
     finally:
         sched.stop(drain=True)
     parsed = metrics_mod.parse_text(reg.render())
-    key = (("bucket", "16x24"), ("klass", ""))
+    key = (("bucket", "16x24"), ("klass", ""), ("model", ""))
     assert parsed["rmd_serve_requests_total"][key] == 3.0
     assert parsed["rmd_serve_request_latency_seconds_count"][
-        (("klass", ""),)] == 3.0
+        (("klass", ""), ("model", ""))] == 3.0
     assert sum(parsed["rmd_serve_batches_total"].values()) >= 1.0
 
 
@@ -504,7 +504,7 @@ def test_endpoints_over_real_socket(_obs_hygiene):
         code, text = _get(server.url + "/metrics")
         assert code == 200
         parsed = metrics_mod.parse_text(text)
-        key = (("bucket", "16x24"), ("klass", ""))
+        key = (("bucket", "16x24"), ("klass", ""), ("model", ""))
         assert parsed["rmd_serve_requests_total"][key] == 4.0
         assert parsed["rmd_serve_ready"][()] == 1.0
         assert parsed["rmd_telemetry_dropped_total"][()] == 0.0
