@@ -6,9 +6,11 @@ for Accurate Optical Flow Estimation", Wang et al.; upstream
 jytime/DICL-Flow):
 
 - the full displacement-shifted matching volume is built from *static*
-  integer shifts — a pad + (2r+1)² slice stack XLA folds into cheap copies
-  (the reference fills a zero tensor per displacement in a python loop,
-  dicl.py:212-241),
+  integer shifts — a pad + (2r+1)² slice stack (the reference fills a zero
+  tensor per displacement in a python loop, dicl.py:212-241). The copies
+  are not cheap: the stack is the model's largest array, and how it is
+  masked and handed to the MatchingNet decides what the TPU compiler moves
+  (``displaced_pair_volume``),
 - cost volumes are (B, H, W, du, dv) channels-last, so the DAP is one MXU
   1x1 conv and soft-argmin/entropy are trailing-axis reductions,
 - the coarse-to-fine ladder (levels 6..2, GA-Net p26 features) warps the
@@ -82,29 +84,62 @@ def displaced_pair_volume(feat1, feat2, disp_range):
 
     Returns (B, du, dv, H, W, 2C): at displacement d, the second half of
     the channels holds ``feat2[p + d]`` (zeros outside), and hypotheses
-    whose displaced features are all-zero (out of bounds / holes) are
-    zeroed entirely — reference compute_cost semantics (dicl.py:212-241),
-    realized as static pad + slice instead of per-displacement copies.
+    whose displaced features sum to zero (out of bounds / holes) are
+    zeroed entirely, in both halves — reference compute_cost semantics
+    (dicl.py:212-241), realized as static pad + slice instead of
+    per-displacement copies.
+
+    Whether hypothesis (i, j) counts at (y, x) is a function of the padded
+    map at (y + j, x + i) alone, so the sum over channels and the
+    comparison are taken there, once, on one channel; the du·dv slices of
+    that mask select from the slices of the map and from ``feat1``. (Taken
+    on the stack, as until PR 47, it was a reduction, a comparison and two
+    multiplications over arrays of the stack's size: 1.64 GB a half in
+    float32 at level 2 of a served 512x1024 batch, 56 ms of a 207 ms batch
+    on the v5e, more than the MatchingNet they fed.)
+
+    The form is chosen by what the TPU compiler makes of it behind
+    ``MatchingNet`` (tests/test_matching_compile.py holds it to this):
+
+    - The mask selects, it does not multiply. A selection hands its
+      operand through unchanged, so the compiler may round the stack to
+      the bfloat16 its convolution reads *before* it moves it: both halves
+      are written, relaid to the item-minor layout the convolutions run in
+      and read as bfloat16, once each. A product's operand stays float32
+      at twice the bytes. The values are those of the product (a masked
+      element is ``+0`` where ``x * 0`` carried x's sign).
+    - The selection comes after the stack, with the mask's own stack (one
+      channel, 1/C of a half). Selected slice by slice before it, the
+      halves reach the first convolution straight from their relayout
+      copies, and the compiler then no longer fuses that convolution into
+      the second layer's as its producer: the first activation
+      (``392x128x256x96`` float32, 4.93 GB) is written and read back.
+    - The halves are concatenated, as ever: the compiler takes the two as
+      operands of that fusion and never writes the 2C-channel volume.
+      ``MatchingNet``'s pair form (frame one's half convolved once and
+      repeated by a one-hot contraction) cannot stand in: ``feat1`` under
+      the mask differs between hypotheses inside a 3x3 support, so the
+      shared half is not shared.
     """
     b, h, w, c = feat1.shape
     ru, rv = disp_range
     du, dv = 2 * ru + 1, 2 * rv + 1
 
     f2p = jnp.pad(feat2, ((0, 0), (rv, rv), (ru, ru), (0, 0)))
+    # occluded / out-of-bounds positions of the padded map
+    v = jax.lax.stop_gradient(f2p).sum(axis=-1, keepdims=True) != 0
 
-    rows = []
-    for i in range(du):  # x-displacement di = i - ru
-        cols = []
-        for j in range(dv):  # y-displacement dj = j - rv
-            cols.append(f2p[:, j : j + h, i : i + w, :])
-        rows.append(jnp.stack(cols, axis=1))
-    shifted = jnp.stack(rows, axis=1)  # (B, du, dv, H, W, C)
+    def hypotheses(x):
+        # x-displacement di = i - ru, y-displacement dj = j - rv
+        return jnp.stack(
+            [x[:, j : j + h, i : i + w] for i in range(du) for j in range(dv)],
+            axis=1,
+        ).reshape(b, du, dv, h, w, x.shape[-1])
 
-    # zero out occluded / out-of-bounds hypotheses
-    valid = jax.lax.stop_gradient(shifted).sum(axis=-1, keepdims=True) != 0
-
+    shifted, valid = hypotheses(f2p), hypotheses(v)
     f1 = jnp.broadcast_to(feat1[:, None, None], shifted.shape)
-    return jnp.concatenate((f1 * valid, shifted * valid), axis=-1)
+    return jnp.concatenate(
+        (jnp.where(valid, f1, 0), jnp.where(valid, shifted, 0)), axis=-1)
 
 
 class CtfContextNet(nn.Module):
